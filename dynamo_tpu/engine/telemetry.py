@@ -35,11 +35,15 @@ from dynamo_tpu.utils import tracing
 # The other /jax/core/compile/* keys (jaxpr trace, MLIR lowering) are
 # host-side and cheap; backend_compile is the multi-second one.
 _COMPILE_KEY = "/jax/core/compile/backend_compile_duration"
+# recorded once per program served from the persistent compilation cache
+# (utils/compile_cache.py) instead of being compiled
+_CACHE_HIT_KEY = "/jax/compilation_cache/cache_hits"
 
 _lock = threading.Lock()
 _installed = False
 _compile_events = 0
 _compile_time_s = 0.0
+_cache_hits = 0
 
 
 def _on_event_duration(name: str, duration_s: float, **_kw) -> None:
@@ -57,6 +61,13 @@ def _on_event_duration(name: str, duration_s: float, **_kw) -> None:
         )
 
 
+def _on_event(name: str, **_kw) -> None:
+    global _cache_hits
+    if name == _CACHE_HIT_KEY:
+        with _lock:
+            _cache_hits += 1
+
+
 def install_compile_listener() -> None:
     """Register the compile listener once per process (idempotent)."""
     global _installed
@@ -68,6 +79,7 @@ def install_compile_listener() -> None:
         jax.monitoring.register_event_duration_secs_listener(
             _on_event_duration
         )
+        jax.monitoring.register_event_listener(_on_event)
     except Exception:  # noqa: BLE001 — telemetry must never block init
         pass
 
@@ -78,6 +90,7 @@ def compile_stats() -> dict:
         return {
             "compile_events": _compile_events,
             "compile_time_s": round(_compile_time_s, 4),
+            "persistent_cache_hits": _cache_hits,
         }
 
 

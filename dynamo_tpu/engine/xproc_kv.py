@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -91,7 +90,7 @@ class XProcKvBridge:
             return jax.lax.ppermute(x, "host", [(0, 1)])
 
         self._xfer = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 oneway,
                 mesh=mesh,
                 in_specs=P("host", "dev"),

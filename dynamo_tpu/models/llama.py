@@ -22,7 +22,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import paged_attention, write_kv_slots
 from dynamo_tpu.ops.norm import rms_norm
@@ -203,7 +202,12 @@ def init_kv_cache(
     cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16,
     kv_quant: str | None = None, page_size: int = 16, tp: int = 1,
     packed: bool = False, kv_quant_group: int | None = None,
+    sharding=None, scale_sharding=None,
 ) -> KVCache:
+    """`sharding` / `scale_sharding` create the data / scale pools shard
+    by shard on their devices: the engine sizes the pool to each
+    device's free memory, so a layer's whole unsharded pool is tp times
+    what one device can hold and must never be built in one place."""
     shape = (num_slots, cfg.num_kv_heads * cfg.head_dim)
     if kv_quant is not None:
         if kv_quant not in ("int8", "int4"):
@@ -236,35 +240,43 @@ def init_kv_cache(
             pshape = (num_slots // 4, shape[1])
             return KVCache(
                 k=tuple(
-                    jnp.zeros(pshape, jnp.int32) for _ in range(cfg.num_layers)
+                    jnp.zeros(pshape, jnp.int32, device=sharding) for _ in range(cfg.num_layers)
                 ),
                 v=tuple(
-                    jnp.zeros(pshape, jnp.int32) for _ in range(cfg.num_layers)
+                    jnp.zeros(pshape, jnp.int32, device=sharding) for _ in range(cfg.num_layers)
                 ),
                 ks=tuple(
-                    init_kv_scale_pool(num_pages, page_size, s_ch, tp)
+                    init_kv_scale_pool(
+                        num_pages, page_size, s_ch, tp, scale_sharding
+                    )
                     for _ in range(cfg.num_layers)
                 ),
                 vs=tuple(
-                    init_kv_scale_pool(num_pages, page_size, s_ch, tp)
+                    init_kv_scale_pool(
+                        num_pages, page_size, s_ch, tp, scale_sharding
+                    )
                     for _ in range(cfg.num_layers)
                 ),
             )
         return KVCache(
-            k=tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.num_layers)),
-            v=tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.num_layers)),
+            k=tuple(jnp.zeros(shape, jnp.int8, device=sharding) for _ in range(cfg.num_layers)),
+            v=tuple(jnp.zeros(shape, jnp.int8, device=sharding) for _ in range(cfg.num_layers)),
             ks=tuple(
-                init_kv_scale_pool(num_pages, page_size, s_ch, tp)
+                init_kv_scale_pool(
+                    num_pages, page_size, s_ch, tp, scale_sharding
+                )
                 for _ in range(cfg.num_layers)
             ),
             vs=tuple(
-                init_kv_scale_pool(num_pages, page_size, s_ch, tp)
+                init_kv_scale_pool(
+                    num_pages, page_size, s_ch, tp, scale_sharding
+                )
                 for _ in range(cfg.num_layers)
             ),
         )
     return KVCache(
-        k=tuple(jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)),
-        v=tuple(jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)),
+        k=tuple(jnp.zeros(shape, dtype, device=sharding) for _ in range(cfg.num_layers)),
+        v=tuple(jnp.zeros(shape, dtype, device=sharding) for _ in range(cfg.num_layers)),
     )
 
 
@@ -296,7 +308,7 @@ def _attn_block(
     h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     if tp_axis is not None:
         # manual tp: this shard holds its local slice of the heads
-        tpn = compat.axis_size(tp_axis)
+        tpn = jax.lax.axis_size(tp_axis)
         h //= tpn
         kh //= tpn
     quant = kv_ks is not None
@@ -415,7 +427,7 @@ def _attn_block(
             scale_out = (
                 (P(None, "tp", None), P(None, "tp", None)) if quant else ()
             )
-            fused = compat.shard_map(
+            fused = jax.shard_map(
                 fused,
                 mesh=attn.mesh,
                 in_specs=(
@@ -501,7 +513,7 @@ def _attn_block(
             scale_out = (
                 (P(None, "tp", None), P(None, "tp", None)) if quant else ()
             )
-            wr = compat.shard_map(
+            wr = jax.shard_map(
                 wr,
                 mesh=attn.mesh,
                 in_specs=(
@@ -534,7 +546,7 @@ def _attn_block(
                 scale_specs = (
                     (P(None, "tp", None), P(None, "tp", None)) if quant else ()
                 )
-                fl = compat.shard_map(
+                fl = jax.shard_map(
                     fl,
                     mesh=attn.mesh,
                     in_specs=(
@@ -648,7 +660,7 @@ def _attn_block(
                 scale_specs = (
                     (P(None, "tp", None), P(None, "tp", None)) if quant else ()
                 )
-                rg = compat.shard_map(
+                rg = jax.shard_map(
                     rg,
                     mesh=attn.mesh,
                     in_specs=(
@@ -682,7 +694,7 @@ def _attn_block(
                 scale_specs = (
                     (P(None, "tp", None), P(None, "tp", None)) if quant else ()
                 )
-                ro = compat.shard_map(
+                ro = jax.shard_map(
                     ro,
                     mesh=attn.mesh,
                     in_specs=(
@@ -888,9 +900,23 @@ def logits(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp.ndarray
     )
 
 
+def _dense_init(key, shape, scale, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _dense_init_sharded(key, shape, scale, dtype, sharding):
+    """`_dense_init` born under `sharding`: each device computes only
+    its own shard. Module-level so one program per distinct (shape,
+    scale, dtype, sharding) serves every engine of the process."""
+    return jax.lax.with_sharding_constraint(
+        _dense_init(key, shape, scale, dtype), sharding
+    )
+
+
 def init_params(
     cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
-    quantize: bool = False,
+    quantize: bool = False, shardings=None,
 ) -> Params:
     """Random-init params (tests, benchmarks); HF loading lives in
     dynamo_tpu/models/weights.py.
@@ -899,25 +925,35 @@ def init_params(
     they are created (ops/quant.py scheme, same result as
     `quantize_params` on the full tree) — peak device memory stays at
     "int8 so far + one bf16 layer", which is what lets an 8B model
-    random-init on a 16 GB chip where the bf16 tree alone would OOM."""
+    random-init on a 16 GB chip where the bf16 tree alone would OOM.
+
+    `shardings` (the `parallel.mesh.param_shardings` tree) creates every
+    dense leaf directly under its target sharding, so no device ever
+    holds more than its own shard — without it the whole tree is built
+    on the default device first, which for an 8B bf16 model is the whole
+    of one chip's HBM. The values do not depend on the sharding
+    (jax's partitionable threefry)."""
     d, f = cfg.hidden_size, cfg.intermediate_size
     qs, kvs = cfg.q_size, cfg.kv_size
     keys = iter(jax.random.split(key, 4 + 9 * cfg.num_layers))
     if quantize:
         from dynamo_tpu.ops.quant import QUANT_KEYS, quantize_weight
 
-    def dense(k, shape, scale=None):
+    def dense(k, shape, scale=None, sharding=None):
         scale = scale or (shape[0] ** -0.5)
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+        if sharding is None:
+            return _dense_init(k, shape, scale, dtype)
+        return _dense_init_sharded(k, shape, scale, dtype, sharding)
 
     layers = []
-    for _ in range(cfg.num_layers):
+    for i in range(cfg.num_layers):
+        sh = shardings["layers"][i] if shardings else {}
         lp = {
             "attn_norm": jnp.ones((d,), dtype),
-            "wq": dense(next(keys), (d, qs)),
-            "wk": dense(next(keys), (d, kvs)),
-            "wv": dense(next(keys), (d, kvs)),
-            "wo": dense(next(keys), (qs, d)),
+            "wq": dense(next(keys), (d, qs), sharding=sh.get("wq")),
+            "wk": dense(next(keys), (d, kvs), sharding=sh.get("wk")),
+            "wv": dense(next(keys), (d, kvs), sharding=sh.get("wv")),
+            "wo": dense(next(keys), (qs, d), sharding=sh.get("wo")),
             "mlp_norm": jnp.ones((d,), dtype),
         }
         if cfg.num_experts:
@@ -926,9 +962,9 @@ def init_params(
             lp.update(init_moe_params(cfg, next(keys), dtype=dtype))
         else:
             lp.update({
-                "w_gate": dense(next(keys), (d, f)),
-                "w_up": dense(next(keys), (d, f)),
-                "w_down": dense(next(keys), (f, d)),
+                "w_gate": dense(next(keys), (d, f), sharding=sh.get("w_gate")),
+                "w_up": dense(next(keys), (d, f), sharding=sh.get("w_up")),
+                "w_down": dense(next(keys), (f, d), sharding=sh.get("w_down")),
             })
         if cfg.attn_bias:
             lp["bq"] = jnp.zeros((qs,), dtype)
@@ -941,13 +977,16 @@ def init_params(
             }
         layers.append(lp)
 
+    sh = shardings or {}
     params: Params = {
-        "embed": dense(next(keys), (cfg.vocab_size, d), scale=0.02),
+        "embed": dense(next(keys), (cfg.vocab_size, d), scale=0.02,
+                       sharding=sh.get("embed")),
         "layers": layers,
         "final_norm": jnp.ones((d,), dtype),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = dense(next(keys), (d, cfg.vocab_size))
+        params["lm_head"] = dense(next(keys), (d, cfg.vocab_size),
+                                  sharding=sh.get("lm_head"))
     if quantize:
         head = (
             params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu import compat
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -713,17 +712,17 @@ def fused_paged_decode_attention(
                 # pools pinned to HBM: under pl.ANY Mosaic may place the
                 # small scale pools in VMEM, where sub-lane-width (K < 128)
                 # memref slices fail to compile
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),  # k_pages
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),  # v_pages
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),  # ks_pages
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),  # vs_pages
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),  # k_pages
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),  # v_pages
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),  # ks_pages
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),  # vs_pages
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),
-                pl.BlockSpec(memory_space=compat.tpu_hbm_memory_space()),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             ],
             scratch_shapes=[
                 pltpu.VMEM(

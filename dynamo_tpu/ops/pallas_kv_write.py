@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu import compat
 
 
 def _kernel(tbl_ref, kp_ref, vp_ref, src_k_ref, src_v_ref, ok_ref, ov_ref):
@@ -128,7 +127,7 @@ def paged_kv_write(
                 jax.ShapeDtypeStruct(vs_cache.shape, vs_cache.dtype),
             ],
             input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3},
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,
@@ -163,7 +162,7 @@ def paged_kv_write(
             jax.ShapeDtypeStruct(vp.shape, vp.dtype),
         ],
         input_output_aliases={1: 0, 2: 1},  # kp -> ok, vp -> ov (in place)
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

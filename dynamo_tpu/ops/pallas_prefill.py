@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu import compat
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -360,7 +359,7 @@ def flash_prefill_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, t_pad * g, hd), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

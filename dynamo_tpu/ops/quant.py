@@ -132,10 +132,14 @@ def kv_scale_subl(num_kv_heads: int, tp: int = 1) -> int:
 
 
 def init_kv_scale_pool(
-    num_pages: int, page_size: int, num_kv_heads: int, tp: int = 1
+    num_pages: int, page_size: int, num_kv_heads: int, tp: int = 1,
+    sharding=None,
 ) -> jnp.ndarray:
+    """`sharding` creates the pool shard by shard on its devices (no
+    device ever holds the whole array)."""
     return jnp.ones(
-        (num_pages, kv_scale_subl(num_kv_heads, tp), page_size), jnp.float32
+        (num_pages, kv_scale_subl(num_kv_heads, tp), page_size), jnp.float32,
+        device=sharding,
     )
 
 

@@ -33,7 +33,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -106,7 +105,7 @@ def ring_self_attention(
     the prefix cache instead of re-prefilling whole prompts."""
     b, tl, h, hd = q.shape
     scale = hd ** -0.5
-    sp = compat.axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     base = idx * tl + jnp.arange(tl, dtype=jnp.int32)
     if pos0 is None:
@@ -182,7 +181,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "sp",
     P = jax.sharding.PartitionSpec
     spec = P("dp", axis_name, "tp", None)
     if prefix_k is None:
-        return compat.shard_map(
+        return jax.shard_map(
             functools.partial(ring_self_attention, axis_name=axis_name),
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -190,7 +189,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "sp",
             check_vma=False,
         )(q, k, v)
     pspec = P("dp", None, "tp", None)
-    return compat.shard_map(
+    return jax.shard_map(
         functools.partial(ring_self_attention, axis_name=axis_name),
         mesh=mesh,
         in_specs=(spec, spec, spec, P("dp"), pspec, pspec, P("dp")),

@@ -37,7 +37,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.norm import rms_norm
@@ -211,7 +210,7 @@ def pp_forward(
         )
         return outs, k_local, v_local
 
-    outs, k_pool, v_pool = compat.shard_map(
+    outs, k_pool, v_pool = jax.shard_map(
         stage_prog,
         mesh=mesh,
         in_specs=(

@@ -68,7 +68,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 from dynamo_tpu.ops.norm import rms_norm
 from dynamo_tpu.ops.quant import is_quantized, mm
 from dynamo_tpu.ops.rope import rope_cos_sin, rope_inv_freq
@@ -151,7 +150,7 @@ def collective_bytes_per_layer(
 def psum_allreduce(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     """The serialized manual-TP all-reduce, routed through the ledger:
     ring all-reduce wire bytes are 2(n-1)/n * S per device."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n > 1:
         _note("exposed", 2 * (n - 1) * x.size * x.dtype.itemsize // n)
     return jax.lax.psum(x, axis_name)
@@ -202,7 +201,7 @@ def ring_all_gather(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     movement, no arithmetic). Standalone spelling — counts as EXPOSED;
     the layer executor prefers `ring_ag_matmul`, which hides the same
     traffic under matmul slices."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -238,7 +237,7 @@ def ring_ag_matmul(
     bitwise identical to `all_gather(x) @ w` — the ring splits only the
     row axis, never the contraction axis, so no summation is reordered.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return [mm(x, w) for w in weights]
     idx = jax.lax.axis_index(axis_name)
@@ -273,7 +272,7 @@ def ring_reduce_scatter(y: jnp.ndarray, axis_name) -> jnp.ndarray:
     [n*m, ...] partial sums in, [m, ...] fully-reduced block `idx` out.
     Block j accumulates in cyclic shard order j+1, .., j-1, j — the
     documented cross-shard reduction order (see module docstring)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return y
     idx = jax.lax.axis_index(axis_name)
@@ -313,7 +312,7 @@ def ring_rs_matmul(x: jnp.ndarray, w, axis_name) -> jnp.ndarray:
 
     `x` [R, F_local] full rows (contraction dim sharded); returns the
     row-scattered [ceil(R/tp)*tp/tp, D] block for this shard."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if not is_quantized(w):
         return ring_reduce_scatter(pad_rows(mm(x, w), n), axis_name)
     xf = x.astype(jnp.float32)
@@ -336,7 +335,7 @@ def ring_rs_matmul(x: jnp.ndarray, w, axis_name) -> jnp.ndarray:
 def scatter_rows(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     """Slice this shard's row block out of a replicated [n*m, ...] array
     (free under shard_map — no collective)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -421,7 +420,7 @@ def single_layer_executor(
         return xs, kv_k, kv_v
 
     def run(lp, kv_k, kv_v, x, cos, sin, ws, sm, pos):
-        return compat.shard_map(
+        return jax.shard_map(
             prog,
             mesh=mesh,
             in_specs=(
@@ -545,7 +544,7 @@ def tp_overlap_forward(
     nl = len(layers)
     kv_spec = [_P(None, "tp")] * nl
     scale_spec = [_P(None, "tp", None)] * nl if quantized else []
-    hidden, new_k, new_v, new_ks, new_vs = compat.shard_map(
+    hidden, new_k, new_v, new_ks, new_vs = jax.shard_map(
         prog,
         mesh=mesh,
         in_specs=(

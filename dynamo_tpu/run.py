@@ -267,7 +267,11 @@ async def build_output(args, out: str, drt=None):
 # ---------------------------------------------------------------- in= modes
 
 
-async def run_http(args, out: str) -> None:
+async def build_http_service(args, out: str):
+    """The OpenAI HTTP service for `in=http`, wired to `out` but not yet
+    listening. Returns (service, jax_engine|None) so embedders (and
+    chip_smoke.py) can start it on a port of their choosing and read
+    the engine's own report."""
     from dynamo_tpu.llm.http.service import HttpService
     from dynamo_tpu.utils import instance, tracing
 
@@ -291,6 +295,7 @@ async def run_http(args, out: str) -> None:
     from dynamo_tpu.utils.counters import PromCounters
 
     svc.metrics.extra.append(PromCounters())
+    engine = None
     if out.startswith("dyn://"):
         # ingress: discover models from the hub
         from dynamo_tpu.llm.http.discovery import ModelWatcher
@@ -360,6 +365,11 @@ async def run_http(args, out: str) -> None:
                     ),
                     attainment_fn=_local_attain,
                 )
+    return svc, engine
+
+
+async def run_http(args, out: str) -> None:
+    svc, _engine = await build_http_service(args, out)
     await svc.start(args.http_host, args.http_port)
     log.info("serving OpenAI HTTP on %s:%d", args.http_host, svc.port)
     await asyncio.Event().wait()

@@ -11,31 +11,58 @@ they never grab the chips.
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass, field
+import subprocess
+import sys
+from dataclasses import dataclass
 from typing import Optional
+
+# The supervisor must never initialise a jax backend itself: a chip
+# belongs to one process at a time, and a parent that holds it starves
+# every worker it then spawns. The probe runs in a child that exits (and
+# lets go of the chip) before any worker starts.
+_PROBE = (
+    "import jax; d = jax.devices(); "
+    "print(len(d) if d[0].platform == 'tpu' else 0)"
+)
 
 
 def detect_num_chips() -> int:
+    """TPU chips on this host, found without touching jax in this
+    process: `DYN_TPU_NUM_CHIPS`, else a short-lived child process that
+    asks jax and exits (device files differ by TPU generation: the v5e
+    host shows only /dev/vfio). A probe
+    that FAILS (the chip is held by someone, libtpu cannot start) raises
+    — it does not read as "this host has no chips"."""
     env = os.environ.get("DYN_TPU_NUM_CHIPS")
     if env:
         return int(env)
-    try:
-        import jax
-
-        return len(jax.devices("tpu"))
-    except Exception:  # noqa: BLE001 — no TPU plugin / CPU-only host
-        return 0
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return 0  # the operator pinned this tree to the CPU
+    proc = subprocess.run(
+        [sys.executable, "-c", _PROBE], capture_output=True, text=True,
+        timeout=300,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            "TPU chip probe failed (is another process holding the chip?): "
+            + proc.stderr.strip()[-500:]
+        )
+    return int(proc.stdout.strip().splitlines()[-1])
 
 
 @dataclass
 class TpuAllocator:
-    total_chips: int = field(default_factory=detect_num_chips)
+    # None = detect on first use: a graph with no `tpu` resources never
+    # probes at all
+    total_chips: Optional[int] = None
     _next: int = 0
 
     def assign(self, num_chips: int) -> Optional[list[int]]:
         """A disjoint chip-id range, or None if the host is out of chips."""
         if num_chips == 0:
             return []
+        if self.total_chips is None:
+            self.total_chips = detect_num_chips()
         if self._next + num_chips > self.total_chips:
             return None
         ids = list(range(self._next, self._next + num_chips))

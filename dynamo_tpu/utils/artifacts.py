@@ -5,7 +5,7 @@ trace ring + phase stats + metrics NEXT TO the hang, so the postmortem
 does not depend on the process surviving to serve /debug/trace. This
 module is that writer, factored out so every timeout path — the engine
 watchdog, the multichip smoke's rc=124 path, future harnesses — leaves
-the same evidence instead of a bare exit code (the MULTICHIP_r05 lesson:
+the same evidence instead of a bare exit code (the lesson of an early multichip timeout:
 a timeout with no artifact cannot be bisected).
 
 Best-effort by contract: artifact IO must never take down the path that

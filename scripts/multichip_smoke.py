@@ -1,6 +1,6 @@
 """8-device multichip smoke: the sharded-path hang guard.
 
-MULTICHIP_r05 hit rc=124 (timeout) and shipped silently because no
+an early multichip run hit rc=124 (timeout) and shipped silently because no
 pre-merge gate exercised the sharded path (ROADMAP open item 1). This
 script is that gate: it forces 8 virtual CPU devices, serves greedy
 requests through a tp=8 engine with the step pipeline ON (the r05
@@ -55,7 +55,7 @@ PROMPTS = (
 MAX_TOKENS = 16
 
 # live engines, so the timeout path can still read their phase stats —
-# the MULTICHIP_r05 hang left a bare rc=124 with nothing to bisect on
+# that hang left a bare rc=124 with nothing to bisect on
 _ENGINES: list = []
 
 

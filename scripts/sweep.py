@@ -17,8 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_point(conc: int) -> dict:
-    # prepend (not replace) PYTHONPATH: the platform plugin may register
-    # through an existing PYTHONPATH entry
+    # prepend (not replace) PYTHONPATH so the caller's entries survive
     pp = os.environ.get("PYTHONPATH", "")
     env = dict(
         os.environ,

@@ -58,7 +58,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from dynamo_tpu import compat  # noqa: E402
 from dynamo_tpu.models import config as cfgmod, llama  # noqa: E402
 from dynamo_tpu.parallel import mesh as meshmod  # noqa: E402
 from dynamo_tpu.parallel import tp_overlap as ov  # noqa: E402
@@ -152,7 +151,7 @@ def _pallas_leg(tier: str, params, mesh) -> dict:
         )
     )
     kv8 = fresh_kv(TP)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with ov.record_collectives() as led:
             hidden = jax.block_until_ready(ov_fn(params, kv8)[0])
         ov_walls = []
@@ -175,7 +174,7 @@ def _pallas_leg(tier: str, params, mesh) -> dict:
             p, CFG, tok_j, pos_j, kv, ws_j, fb_spec
         )
     )
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fb_hidden = jax.block_until_ready(fb_fn(sh_params, kv8_fb)[0])
         fb_walls = []
         for _ in range(REPS):
@@ -288,7 +287,7 @@ def run() -> dict:
         jnp.asarray(wslots.reshape(-1)), jnp.asarray(smat),
     )
     kv8 = llama.init_kv_cache(CFG, 512, dtype=jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ov_hidden, _ = ov.tp_overlap_forward(
             params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
             jnp.asarray(wslots.reshape(-1)), jnp.asarray(smat), mesh,

@@ -253,7 +253,7 @@ async def test_tp2_pallas_matches_gather():
 
 def test_auto_backend_warns_on_tpu_gather_fallback(monkeypatch, caplog):
     """attn_backend='auto' must WARN loudly when a TPU mesh silently
-    gets gather attention (VERDICT r3 weak #4): dp>1 in one engine
+    gets gather attention: dp>1 in one engine
     cannot run the fused write kernel soundly."""
     import logging
 
@@ -322,7 +322,7 @@ async def test_bucketed_decode_dispatch_small_load():
 async def test_engine_phase_stats_and_first_meta_timing():
     """Engine-side accounting: phase counters advance with dispatches and
     the first frame's meta carries the submit->dispatch latency split
-    (the bench's engine-side TTFT/phase source, VERDICT r4 weak #2/#3)."""
+    (the bench's engine-side TTFT/phase source)."""
     engine = make_engine()
     ps0 = engine.phase_stats
     pre = greedy_request([3, 14, 15, 92, 65], max_tokens=6)

@@ -103,7 +103,7 @@ def test_sp_mode_requires_whole_prompt_prefill():
 
 
 async def test_sp2_engine_keeps_prefix_cache():
-    """sp>1 now composes with the prefix cache (VERDICT r3 weak #5): a
+    """sp>1 now composes with the prefix cache: a
     repeated prompt's second serve rides cached pages (the ring runs
     only over the uncached tail) and stays bit-identical."""
     prompt = list(range(40, 40 + 24))  # 3 pages of 8

@@ -151,9 +151,17 @@ def test_int4_grouped_scales():
 
 def test_forward_oracle_agreement_int4():
     """Gather-path forward with an int4 KV cache tracks the f32-KV
-    forward: same argmax, logit cosine > 0.98 (random-init weights are
+    forward: logit cosine > 0.98, and the same argmax at every position
+    whose f32 top-1 margin exceeds 4-bit noise (random-init weights are
     the worst case for 4-bit noise; trained nets sit much higher — the
-    kv_capacity bench's greedy-match rate is the deployment bound)."""
+    kv_capacity bench's greedy-match rate is the deployment bound).
+
+    Random weights put many positions at a near-tie (margin ~0.001
+    against a max logit error of ~0.05-0.1 from 4-bit KV), where the
+    argmax is decided by rounding and says nothing about the format —
+    so agreement is asserted on the positions with margin > 0.05 (a
+    third of the logit std, 0.16), over all B*T causal positions, and
+    the test insists that enough of them qualify."""
     cfg = CFG
     key = jax.random.PRNGKey(0)
     params = llama.init_params(cfg, key, dtype=jnp.float32)
@@ -180,7 +188,15 @@ def test_forward_oracle_agreement_int4():
         jnp.linalg.norm(lg_f) * jnp.linalg.norm(lg_q)
     )
     assert float(cos) > 0.98
-    assert bool((jnp.argmax(lg_f, -1) == jnp.argmax(lg_q, -1)).all())
+    all_f = llama.logits(params, cfg, h_f).reshape(B * T, -1)
+    all_q = llama.logits(params, cfg, h_q).reshape(B * T, -1)
+    top2 = jnp.sort(all_f, axis=-1)[:, -2:]
+    decided = (top2[:, 1] - top2[:, 0]) > 0.05
+    assert int(decided.sum()) >= 8, "too few positions with a real margin"
+    same = jnp.argmax(all_f, -1) == jnp.argmax(all_q, -1)
+    assert bool(same[decided].all())
+    # the error that decides the near-ties stays bounded (~1 logit std)
+    assert float(jnp.abs(all_f - all_q).max()) < 0.16
 
 
 # --------------------------------------------------------- pallas kernels

@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dynamo_tpu import compat
 import numpy as np
 
 from dynamo_tpu.models import config as cfgmod, llama
@@ -55,7 +54,7 @@ def test_tp_forward_matches_single_device():
         k=tuple(jax.device_put(x, meshmod.kv_cache_sharding(m)) for x in kv.k),
         v=tuple(jax.device_put(x, meshmod.kv_cache_sharding(m)) for x in kv.v),
     )
-    with compat.set_mesh(m):
+    with jax.set_mesh(m):
         tp_logits, kv_out = run(sp, kv)
 
     np.testing.assert_allclose(
@@ -97,3 +96,46 @@ def test_tp_sharded_param_layout():
     # column-parallel: each shard holds half the out features
     shard_shapes = {s.data.shape for s in wq.addressable_shards}
     assert shard_shapes == {(CFG.hidden_size, CFG.q_size // 2)}
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["bf16", "int8"])
+def test_init_params_under_target_shardings_matches_eager_init(quantize):
+    """Random init creates every dense leaf directly under its target
+    sharding (no device ever holds the whole tree — for an 8B bf16 model
+    that would be one chip's whole HBM) and the values do not depend on
+    the sharding: bitwise equal to the unsharded init."""
+    cfg = cfgmod.get_config("tiny").with_(num_kv_heads=4)
+    key = jax.random.PRNGKey(3)
+    mesh = meshmod.build_mesh(meshmod.MeshConfig(tp=4))
+    eager = llama.init_params(cfg, key, quantize=quantize)
+    sharded = llama.init_params(
+        cfg, key, quantize=quantize, shardings=meshmod.param_shardings(cfg, mesh)
+    )
+    wq = sharded["layers"][0]["wq"]
+    wq = wq["q"] if quantize else wq
+    assert wq.sharding.spec == jax.sharding.PartitionSpec(None, "tp")
+    assert len({s.device for s in wq.addressable_shards}) == 4
+    assert wq.addressable_shards[0].data.shape[1] == wq.shape[1] // 4
+    same = jax.tree.map(
+        lambda a, b: bool((np.asarray(a) == np.asarray(b)).all()),
+        eager, sharded,
+    )
+    assert all(jax.tree.leaves(same))
+
+
+def test_engine_creates_kv_pools_under_their_shardings():
+    """The pool is sized to each device's free memory, so a layer's whole
+    unsharded pool is tp times what a device holds: it must never be
+    built on one device first (that OOMed chip 0 of a four-chip host)."""
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+
+    cfg = cfgmod.get_config("tiny").with_(num_kv_heads=4)
+    eng = JaxEngine(EngineConfig(
+        model=cfg, mesh=meshmod.MeshConfig(tp=4), num_pages=32, page_size=16,
+        kv_quantization="int8",
+    ))
+    k0, ks0 = eng.kv.k[0], eng.kv.ks[0]
+    assert k0.sharding.spec == jax.sharding.PartitionSpec(None, "tp")
+    assert k0.addressable_shards[0].data.shape == (k0.shape[0], k0.shape[1] // 4)
+    assert ks0.sharding.spec == jax.sharding.PartitionSpec(None, "tp", None)
+    assert len({s.device for s in ks0.addressable_shards}) == 4

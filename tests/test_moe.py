@@ -6,7 +6,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 import numpy as np
 
 from dynamo_tpu.models import llama
@@ -151,7 +150,7 @@ def test_sharded_forward_matches_single_device():
     mesh = meshmod.build_mesh(mc, jax.devices()[:8])
     sharded = meshmod.shard_params(params, CFG, mesh)
     kv2 = llama.init_kv_cache(CFG, 256, dtype=jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got, _ = jax.jit(llama.forward, static_argnums=(1,))(
             sharded, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv2,
             jnp.asarray(wslots), jnp.asarray(smat),

@@ -7,7 +7,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 import numpy as np
 import pytest
 
@@ -52,7 +51,7 @@ def _run_pp(pp, tp, dp, m, b=4, t=16):
     kv2 = llama.init_kv_cache(CFG, 1024, dtype=jnp.float32)
     k_st, v_st = kv2.stacked()
     stacked, k_st, v_st = pp_sharded_put(mesh, stacked, k_st, v_st)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, (k_out, v_out) = jax.jit(
             pp_forward, static_argnums=(1, 8, 9),
         )(

@@ -6,7 +6,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu import compat
 import numpy as np
 
 from dynamo_tpu.ops.ring_attention import ring_attention_sharded, ring_self_attention
@@ -93,7 +92,7 @@ def test_model_forward_ring_matches_gather():
     )
     kv2 = llama.init_kv_cache(cfg, 512, dtype=jnp.float32)
     spec = llama.AttnSpec.ring(jnp.asarray(smat), mesh, page_size=page)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, kv2 = jax.jit(llama.forward, static_argnums=(1,))(
             params, cfg, jnp.asarray(tokens), jnp.asarray(positions), kv2,
             jnp.asarray(wslots), spec,

@@ -211,13 +211,19 @@ async def test_watchdog_fires_dumps_artifact_degrades_and_recovers(tmp_path):
     want, want_finish, _ = await collect(plain, greedy_request(prompt))
     await plain.close()
 
+    # the watchdog cannot tell a compile from a hang (config.py says so),
+    # and every step variant of a new engine compiles on first use: the
+    # budget sits above a tiny-model CPU compile, so the ONLY stall past
+    # it is the injected one and the ladder sheds exactly one rung. The
+    # re-probe window outlasts the rest of the faulted request, so the
+    # rung is seen held first and recovers only in the second request.
     engine = make_engine(
-        watchdog_dispatch_s=0.25,
-        degrade_reprobe_s=0.25,
+        watchdog_dispatch_s=2.0,
+        degrade_reprobe_s=6.0,
         crash_dir=str(tmp_path),
     )
     # slow the FIRST decode dispatch well past the watchdog budget
-    faults.configure("engine.dispatch.delay=0.6@1x1")
+    faults.configure("engine.dispatch.delay=3.5@1x1")
     got, finish, _ = await asyncio.wait_for(
         collect(engine, greedy_request(prompt)), 120
     )
@@ -225,22 +231,26 @@ async def test_watchdog_fires_dumps_artifact_degrades_and_recovers(tmp_path):
         "a degraded engine must stay byte-identical on greedy streams"
     )
     m = engine.metrics()
-    assert m["watchdog_fired"] >= 1
-    assert m["degrades_total"] >= 1
+    assert m["watchdog_fired"] == 1
+    assert m["degrades_total"] == 1
+    assert m["degraded_step_pipeline"] == 1 and m["recoveries_total"] == 0
     assert engine.last_crash_artifact and os.path.exists(
         engine.last_crash_artifact
     )
     art = json.load(open(engine.last_crash_artifact))
-    assert art["rung_tripped"] in RUNGS
+    assert art["op"] == "decode.dispatch"
+    assert art["rung_tripped"] == "step_pipeline"
     assert "phase_stats" in art and "trace" in art
-    assert art["stalled_s"] >= 0.25
+    assert art["stalled_s"] >= 2.0
 
-    # recovery: wait out the re-probe window, run again — gates re-open
-    await asyncio.sleep(0.3)
+    # recovery: wait out the re-probe window, run again — the engine's
+    # own gate check (`_pipe_on`) re-opens the rung during this request
+    await asyncio.sleep(6.0)
     got2, finish2, _ = await collect(engine, greedy_request(prompt))
     assert got2 == want and finish2 == want_finish
     m2 = engine.metrics()
-    assert m2["recoveries_total"] >= 1
+    assert m2["watchdog_fired"] == 1, "the re-probed variant's compile re-tripped"
+    assert m2["recoveries_total"] == 1
     assert all(m2[f"degraded_{r}"] == 0 for r in RUNGS), m2
     await engine.close()
 

@@ -152,3 +152,28 @@ def test_for_graph_honors_restart_policy_keys():
     front = sup.watchers["EchoFrontend"]
     assert front.restart_backoff_s == 1.0
     assert front.max_restarts == 5
+
+
+def test_worker_chip_env_sets_libtpu_process_bounds(monkeypatch):
+    """Several one-chip workers on one host each need libtpu's process
+    bounds beside TPU_VISIBLE_DEVICES (without them the second worker
+    dies on libtpu's lockfile — seen on a four-chip v5e host)."""
+    from dynamo_tpu.sdk import worker
+
+    # a private copy: _apply_chip_env writes os.environ directly, and
+    # nothing it sets may leak into the tests that follow in this process
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("TPU_", "DYN_TPU_"))}
+    monkeypatch.setattr(os, "environ", env)
+    worker._apply_chip_env(0)  # no allocation: nothing is touched
+    assert "TPU_VISIBLE_DEVICES" not in os.environ
+    env["DYN_TPU_CHIPS"] = "0,1,2,3"
+    env["DYN_TPU_CHIPS_PER_WORKER"] = "1"
+    worker._apply_chip_env(2)
+    assert os.environ["TPU_VISIBLE_DEVICES"] == "2"
+    assert os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert os.environ["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    env["DYN_TPU_CHIPS_PER_WORKER"] = "2"
+    worker._apply_chip_env(1)
+    assert os.environ["TPU_VISIBLE_DEVICES"] == "2,3"
+    assert os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"

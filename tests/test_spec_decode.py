@@ -174,7 +174,12 @@ def test_verify_preserves_sampling_distribution():
     the draft was accepted or replaced by the residual resample."""
     V, K = 12, 3
     logits = jax.random.normal(jax.random.PRNGKey(7), (K + 1, V)) * 2.0
-    draft = jnp.asarray([[3, 5, 3]], jnp.int32)
+    # draft the MOST LIKELY token at positions 0 and 1 (read off the
+    # logits, not a hard-coded id): the conditioning event below — "the
+    # first draft token was accepted" — then has thousands of samples
+    # whatever this jax version's RNG stream makes of PRNGKey(7)
+    d0, d1 = (int(x) for x in jnp.argmax(logits[:2], axis=-1))
+    draft = jnp.asarray([[d0, d1, 3]], jnp.int32)
     temp = jnp.asarray([0.8])
     topk = jnp.asarray([0])
     topp = jnp.asarray([1.0])
@@ -199,8 +204,8 @@ def test_verify_preserves_sampling_distribution():
     rc = np.bincount(ref(0), minlength=V) / N
     assert np.abs(sc - rc).max() < 0.015
     # position-1 marginal GIVEN the first draft was accepted
-    mask = (o0 == 3) & (ns >= 2)
-    assert mask.sum() > 500
+    mask = (o0 == d0) & (ns >= 2)
+    assert mask.sum() > 2000
     sc1 = np.bincount(o1[mask], minlength=V) / mask.sum()
     rc1 = np.bincount(ref(1), minlength=V) / N
     assert np.abs(sc1 - rc1).max() < 0.05
@@ -315,11 +320,18 @@ async def test_rejected_tail_never_registered_in_prefix_cache():
     SPECULATIVE serve; if a rejected draft's garbage KV page had been
     hash-registered, the cached continuation would diverge."""
     spec = make_engine(spec_decode=True)
-    t1, frames1 = await collect(spec, request(REPETITIVE, max_tokens=32))
+    # a prompt whose drafts are only PARTLY right on the seeded model
+    # (REPETITIVE's drafts are all accepted under this jax's RNG, which
+    # would make the test vacuous) — the precondition is asserted, so a
+    # future drift fails here by name instead of passing on nothing
+    prompt = [9, 9, 9, 9] * 5
+    t1, frames1 = await collect(spec, request(prompt, max_tokens=32))
     assert frames1[0]["meta"]["prefix_cached_tokens"] == 0
     st1 = spec_stats(spec)
-    assert st1["spec_drafted"] > st1["spec_accepted"]  # some rejections
-    t2, frames2 = await collect(spec, request(REPETITIVE, max_tokens=32))
+    assert st1["spec_accepted"] > 0, "precondition: some drafts accepted"
+    assert st1["spec_drafted"] > st1["spec_accepted"], (
+        "precondition: some drafts rejected")
+    t2, frames2 = await collect(spec, request(prompt, max_tokens=32))
     assert frames2[0]["meta"]["prefix_cached_tokens"] > 0
     assert t1 == t2
     await spec.close()

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu import compat
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import get_config
 from dynamo_tpu.parallel import mesh as meshmod
@@ -49,7 +48,7 @@ def _inputs(b, t, page=8):
 
 def _shmap(fn, mesh, n_in, out_specs):
     P = jax.sharding.PartitionSpec
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(P("tp", None),) * n_in,
         out_specs=out_specs, check_vma=False,
     )
@@ -110,9 +109,9 @@ def test_ring_ag_matmul_matches_gathered_matmul():
 
     specs = (P("tp", None), P(None, "tp"), P(None, "tp"))
     out = (P(None, "tp"), P(None, "tp"))
-    got = compat.shard_map(fused, mesh=mesh, in_specs=specs,
+    got = jax.shard_map(fused, mesh=mesh, in_specs=specs,
                            out_specs=out, check_vma=False)(x, w1, w2)
-    want = compat.shard_map(serial, mesh=mesh, in_specs=specs,
+    want = jax.shard_map(serial, mesh=mesh, in_specs=specs,
                             out_specs=out, check_vma=False)(x, w1, w2)
     # row-only chunking: no reduction is reordered, so the fused ring
     # reproduces the gathered matmul bit-for-bit (the documented
@@ -130,7 +129,7 @@ def test_pad_rows_and_scatter_roundtrip():
         xs = ov.scatter_rows(ov.pad_rows(xr, TP), "tp")
         return ov.ring_all_gather(xs, "tp")
 
-    got = compat.shard_map(
+    got = jax.shard_map(
         roundtrip, mesh=mesh, in_specs=(P(),), out_specs=P(None, None),
         check_vma=False,
     )(x)
@@ -199,7 +198,7 @@ def test_forward_overlap_matches_tp1_greedy():
     )
 
     kv8 = llama.init_kv_cache(CFG, 512, dtype=jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, kv_out = ov.tp_overlap_forward(
             params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
             jnp.asarray(wslots.reshape(-1)), jnp.asarray(smat), mesh,
@@ -241,7 +240,7 @@ def test_pp_composes_with_tp_overlap():
     stacked = stack_layer_params(params)
     k_st, v_st = llama.init_kv_cache(cfg, 512, dtype=jnp.float32).stacked()
     stacked, k_st, v_st = pp_sharded_put(mesh, stacked, k_st, v_st)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, _ = jax.jit(pp_forward, static_argnums=(1, 8, 9, 10))(
             stacked, cfg, jnp.asarray(tokens), jnp.asarray(positions),
             k_st, v_st, jnp.asarray(wslots), jnp.asarray(smat), mesh, 2,
@@ -297,7 +296,7 @@ def test_forward_overlap_int8_kv_matches_tp1():
     # tp=8 pools carry the tp-blocked scale layout (ops/quant.kv_scale_subl)
     kv8 = llama.init_kv_cache(CFG, 512, kv_quant="int8", page_size=8, tp=TP)
     spec8 = llama.AttnSpec.gather(jnp.asarray(smat), page_size=8, kv_tp=TP)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, kv_out = ov.tp_overlap_forward(
             params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
             jnp.asarray(wslots.reshape(-1)), spec8, mesh,
@@ -381,7 +380,7 @@ def test_forward_overlap_packed_pallas_prefill_matches_tp1(tier):
     kv8 = llama.init_kv_cache(
         CFG, 512, kv_quant=quant, page_size=page, tp=TP, packed=True
     )
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, kv_out = ov.tp_overlap_forward(
             params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
             jnp.asarray(wslots.reshape(-1)), spec(TP), mesh,
@@ -423,7 +422,7 @@ def test_forward_overlap_quantized_weights_matches_tp1_bitwise():
         jnp.asarray(wslots.reshape(-1)), jnp.asarray(smat),
     )
     kv8 = llama.init_kv_cache(CFG, 512, dtype=jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hidden, _ = ov.tp_overlap_forward(
             params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
             jnp.asarray(wslots.reshape(-1)), jnp.asarray(smat), mesh,
