@@ -1,0 +1,8 @@
+"""Device: share of the traced slice in which the chip was idle between programs while the event loop's thread was in the HTTP frontend (fe.*) or in no engine phase at all, and neither of the above.
+One of five shares that sum to `device_idle_pct`; the rule is at the top
+of lib/trace_host.py. Left out where the program writes no `eng.` phase."""
+import trace_host
+
+
+def read(art):
+    return trace_host.idle_pct(art, "frontend")

@@ -466,8 +466,7 @@ async def run_requests(base: str, model: str, prompts: dict,
         k: _metric(scrape, f"dynamo_tpu_engine_{k}") for k in (
             "prefix_reused_tokens", "kv_pages_peak_used",
             "tp_overlap_dispatches", "gspmd_fallback_dispatches",
-            # host walls inside dispatch calls vs result fetches
-            "step_device_s", "step_stall_s",
+            "preemptions_total",
         )
     }}
     if out["metrics"]["prefix_reused_tokens"] <= 0:

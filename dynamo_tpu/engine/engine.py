@@ -45,6 +45,7 @@ admission and preemption-resume are the same code path.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import itertools
 import logging
 import os
@@ -140,11 +141,13 @@ class _DecodeBuild:
     __slots__ = ("positions", "tables", "act", "temp", "topk", "topp",
                  "pos_act", "dirty", "use_ext", "want_lps",
                  "want_tops", "overrides", "active", "steps", "all_greedy",
-                 "width", "spec", "tokens", "draft", "dlen", "pos0")
+                 "width", "spec", "tokens", "draft", "dlen", "pos0",
+                 "build_s")
 
     def __init__(self, **kw):
         self.spec = False  # speculative verify build (host-built tokens)
         self.dirty = None  # pending device-state scatter snapshot
+        self.build_s = 0.0  # host time of the build (the digest's column)
         for k, v in kw.items():
             setattr(self, k, v)
 
@@ -768,6 +771,11 @@ class JaxEngine:
         # updates run in worker threads outside _kv_lock (serving prefill
         # + concurrent prefill_only dispatches) — guard the RMWs
         self._phase_lock = threading.Lock()
+        self._preemptions = 0  # sequences preempted for want of KV pages
+        # newest dispatch's first output (under _kv_lock): ready = the
+        # device has drained all that was queued
+        self._last_out = None
+        self._t_fetched = 0.0  # when the newest fetch landed on the host
         # per-token exposed collective bytes across the layer stack (0
         # when tp collectives are absent or owned by another executor:
         # tp=1, sp ring prefill, pp stage rotation)
@@ -1143,17 +1151,6 @@ class JaxEngine:
         active = sum(1 for s in self.slots if s is not None)
         usable = self.num_pages - 1
         ps = self._phase_stats
-        # dispatch-call vs result-fetch walls (telemetry plane):
-        # dispatch walls are host enqueue time, sync walls are host
-        # stalls waiting on device results. Neither is device busy time
-        # — that needs a profiler trace (ROADMAP queue 1, S0)
-        device_s = (
-            ps["prefill_dispatch_s"] + ps["decode_dispatch_s"]
-            + ps["spec_dispatch_s"] + ps["mixed_dispatch_s"]
-        )
-        stall_s = (
-            ps["decode_sync_s"] + ps["spec_sync_s"] + ps["mixed_sync_s"]
-        )
         return {
             "request_active_slots": active,
             "request_total_slots": len(self.slots),
@@ -1208,9 +1205,9 @@ class JaxEngine:
             # HBM gauges from device memory_stats(); absent on backends
             # that expose none (CPU)
             **telemetry.device_memory_stats(),
-            # device-time vs host-stall split per step walls
-            "step_device_s": round(device_s, 4),
-            "step_stall_s": round(stall_s, 4),
+            # sequences preempted for want of KV pages (cumulative; the
+            # flight digests carry it per step as `preempted`)
+            "preemptions_total": self._preemptions,
             # speculative decode health (ForwardPassMetrics.from_dict
             # drops unknown keys, so the router wire stays compatible)
             "spec_acceptance_rate": (
@@ -2434,112 +2431,11 @@ class JaxEngine:
         tracing.set_request(None)
         try:
             while not self._closed:
-                # custody audit (off the dispatch path; gated on its
-                # period so steady-state ticks pay one clock read)
-                if self._kv_audit_s > 0:
-                    now = time.monotonic()
-                    if now >= self._kv_audit_next:
-                        self._kv_audit_next = now + self._kv_audit_s
-                        self._run_kv_audit()
-                # offload first: pending write-through copies must pin
-                # their pages before this tick's admission can evict them
-                self._maybe_start_offload()
-                # deadline shed: queue members whose budget expired leave
-                # with 429/timeout before they can claim a slot or pages
-                progressed = self._shed_expired_waiting()
-                progressed |= self._admit_new()
-                # stall-free mixed step first: when decode-ready rows
-                # and pending prefill chunks coexist, ONE token-budgeted
-                # dispatch advances both planes and the normal
-                # prefill/decode ticks stand down. With the step
-                # pipeline (default) the mixed tick dispatches BEHIND
-                # any in-flight dispatch (q_len=1 rows read the device
-                # carry), syncs the old one while the new executes, and
-                # leaves its own dispatch in flight ("pipelined");
-                # serialized engines instead "hold" a tick whenever a
-                # dispatch is in flight (host-built windows need synced
-                # token history)
-                mixed = None
-                if self.config.mixed_batching:
-                    mixed = await self._mixed_tick()
-                    progressed |= mixed in (True, "pipelined")
-                # per tick: prefill chunks enqueue first (they own self.kv
-                # until their dispatch call returns), then decode dispatch
-                # N+1 runs in a worker thread WHILE the loop fetches
-                # dispatch N's tokens — the dispatch call holds _kv_lock
-                # and may wait on the runtime's queue, the fetch waits
-                # on the device; in separate threads neither wait sits
-                # on the event loop
-                if mixed is None:
-                    progressed |= await self._prefill_tick()
-                pipe = self._pipe_on()
-                if not pipe and mixed != "pipelined":
-                    # serialized A/B baseline: dispatch -> fetch -> sync,
-                    # nothing overlaps — the old dispatch lands BEFORE
-                    # the next one is even built
-                    old, self._inflight = self._inflight, None
-                    if old is not None:
-                        await self._sync_dispatch(old)
-                        progressed = True
-                new_task = None
-                snapshot = (
-                    self._maybe_dispatch_decode() if mixed is None else None
-                )
-                if snapshot == "sync_first":
-                    # worthwhile spec drafts behind an in-flight
-                    # dispatch: sync it NOW and re-enter the build, so
-                    # the verify window dispatches THIS tick instead of
-                    # after a dead tick (the standalone-spec half of the
-                    # step pipeline — verify windows are host-built, so
-                    # the sync is a real data dependency, but the dead
-                    # tick between it and the verify dispatch was not)
-                    old, self._inflight = self._inflight, None
-                    if old is not None:
-                        await self._sync_dispatch(old)
-                        progressed = True
-                    snapshot = self._maybe_dispatch_decode()
-                    if snapshot == "sync_first":  # nothing left in flight
-                        snapshot = None
-                if snapshot is not None:
-                    new_task = asyncio.create_task(
-                        asyncio.to_thread(self._run_decode_dispatch, snapshot)
-                    )
-                    progressed = True
-                if pipe and mixed != "pipelined":
-                    old, self._inflight = self._inflight, None
-                    if old is not None:
-                        await self._sync_dispatch(
-                            old, overlapped=new_task is not None
-                        )
-                        progressed = True
-                if new_task is not None:
-                    self._inflight = await new_task
-                if progressed:
-                    # yield so producers/consumers interleave with the loop
-                    await asyncio.sleep(0)
-                    continue
-                self._wake.clear()
-                if self._closed:
-                    return
-                if self.waiting or self._prefilling or self._inflight:
-                    continue
-                if self._kv_audit_s > 0:
-                    # idle must not stall the custody audit: a request
-                    # that leaked pages at _finish has no successor to
-                    # wake the loop, so bound the sleep by the next
-                    # audit tick (zero cost while busy)
-                    try:
-                        await asyncio.wait_for(
-                            self._wake.wait(),
-                            timeout=max(
-                                self._kv_audit_next - time.monotonic(),
-                                0.001,
-                            ),
-                        )
-                    except asyncio.TimeoutError:
-                        pass
-                else:
-                    await self._wake.wait()
+                # one iteration = one eng.tick; its children say what
+                # the loop's thread did (docs/observability.md)
+                with profiler.phase("eng.tick"):
+                    if await self._tick():
+                        return
         except Exception:
             log.exception("engine loop crashed; failing all requests")
             for seq in list(self.waiting) + [s for s in self.slots if s]:
@@ -2553,6 +2449,120 @@ class JaxEngine:
             self._prefilling.clear()
             self._inflight = None
             raise
+
+    async def _tick(self) -> bool:
+        """One iteration of the loop; True when the engine closed."""
+        with profiler.phase("eng.admit"):
+            # custody audit (off the dispatch path; gated on its
+            # period so steady-state ticks pay one clock read)
+            if self._kv_audit_s > 0:
+                now = time.monotonic()
+                if now >= self._kv_audit_next:
+                    self._kv_audit_next = now + self._kv_audit_s
+                    self._run_kv_audit()
+            # offload first: pending write-through copies must
+            # pin their pages before this tick's admission can
+            # evict them
+            self._maybe_start_offload()
+            # deadline shed: queue members whose budget expired
+            # leave with 429/timeout before they can claim a
+            # slot or pages
+            progressed = self._shed_expired_waiting()
+            progressed |= self._admit_new()
+        # stall-free mixed step first: when decode-ready rows
+        # and pending prefill chunks coexist, ONE token-budgeted
+        # dispatch advances both planes and the normal
+        # prefill/decode ticks stand down. With the step
+        # pipeline (default) the mixed tick dispatches BEHIND
+        # any in-flight dispatch (q_len=1 rows read the device
+        # carry), syncs the old one while the new executes, and
+        # leaves its own dispatch in flight ("pipelined");
+        # serialized engines instead "hold" a tick whenever a
+        # dispatch is in flight (host-built windows need synced
+        # token history)
+        mixed = None
+        if self.config.mixed_batching:
+            mixed = await self._mixed_tick()
+            progressed |= mixed in (True, "pipelined")
+        # per tick: prefill chunks enqueue first (they own self.kv
+        # until their dispatch call returns), then decode dispatch
+        # N+1 runs in a worker thread WHILE the loop fetches
+        # dispatch N's tokens — the dispatch call holds _kv_lock
+        # and may wait on the runtime's queue, the fetch waits
+        # on the device; in separate threads neither wait sits
+        # on the event loop
+        if mixed is None:
+            progressed |= await self._prefill_tick()
+        pipe = self._pipe_on()
+        if not pipe and mixed != "pipelined":
+            # serialized A/B baseline: dispatch -> fetch -> sync,
+            # nothing overlaps — the old dispatch lands BEFORE
+            # the next one is even built
+            old, self._inflight = self._inflight, None
+            if old is not None:
+                await self._sync_dispatch(old)
+                progressed = True
+        new_task = None
+        snapshot = (
+            self._maybe_dispatch_decode() if mixed is None else None
+        )
+        if snapshot == "sync_first":
+            # worthwhile spec drafts behind an in-flight
+            # dispatch: sync it NOW and re-enter the build, so
+            # the verify window dispatches THIS tick instead of
+            # after a dead tick (the standalone-spec half of the
+            # step pipeline — verify windows are host-built, so
+            # the sync is a real data dependency, but the dead
+            # tick between it and the verify dispatch was not)
+            old, self._inflight = self._inflight, None
+            if old is not None:
+                await self._sync_dispatch(old)
+                progressed = True
+            snapshot = self._maybe_dispatch_decode()
+            if snapshot == "sync_first":  # nothing left in flight
+                snapshot = None
+        if snapshot is not None:
+            new_task = asyncio.create_task(
+                asyncio.to_thread(self._run_decode_dispatch, snapshot)
+            )
+            progressed = True
+        if pipe and mixed != "pipelined":
+            old, self._inflight = self._inflight, None
+            if old is not None:
+                await self._sync_dispatch(
+                    old, overlapped=new_task is not None
+                )
+                progressed = True
+        if new_task is not None:
+            self._inflight = await new_task
+        if progressed:
+            # yield so producers/consumers interleave with the loop
+            await asyncio.sleep(0)
+            return False
+        self._wake.clear()
+        if self._closed:
+            return True
+        if self.waiting or self._prefilling or self._inflight:
+            return False
+        with profiler.phase("eng.wait"):
+            if self._kv_audit_s > 0:
+                # idle must not stall the custody audit: a request
+                # that leaked pages at _finish has no successor to
+                # wake the loop, so bound the sleep by the next
+                # audit tick (zero cost while busy)
+                try:
+                    await asyncio.wait_for(
+                        self._wake.wait(),
+                        timeout=max(
+                            self._kv_audit_next - time.monotonic(),
+                            0.001,
+                        ),
+                    )
+                except asyncio.TimeoutError:
+                    pass
+            else:
+                await self._wake.wait()
+        return False
 
     # ---- admission ----------------------------------------------------
 
@@ -2839,38 +2849,9 @@ class JaxEngine:
     def _write_slot(self, seq: Sequence, pos: int) -> int:
         return seq.page_ids[pos // self.page_size] * self.page_size + pos % self.page_size
 
-    async def _prefill_tick(self) -> bool:
-        """Dispatch up to `prefill_group_tokens` worth of prefill chunks,
-        batching same-bucket chunks into one [n, bucket] model step —
-        one dispatch per prompt pays the fixed host cost of a dispatch
-        per prompt and leaves the MXU short rows. The per-tick
-        token budget bounds how long active decode streams stall: one
-        group dispatch per tick, decode interleaves between waves."""
-        if not self._prefilling:
-            return False
-        # admission batching window (paced arrivals): while decode
-        # streams run, hold a small pending set briefly so trickling
-        # arrivals share one dispatch — each tiny group pays a fixed
-        # dispatch+fetch overhead that serializes against decode.
-        # Mid-prompt continuations (num_computed > 0) never wait.
-        win = self.config.prefill_batch_window_s
-        if win > 0 and len(self._prefilling) < self.config.prefill_batch_min_rows:
-            now = time.perf_counter()
-            # fresh = first chunk of this serve (a prefix-cache hit has
-            # num_computed == num_cached at admission and is still a
-            # fresh arrival); mid-prompt chunk continuations never wait
-            fresh = all(
-                s.num_computed == s.num_cached and s.preloaded is None
-                for s in self._prefilling
-            )
-            oldest = min(s.t_admit for s in self._prefilling)
-            if fresh and self._any_mid_decode() and now - oldest < win:
-                # re-arm the loop when the window expires
-                loop = asyncio.get_running_loop()
-                loop.call_later(
-                    max(win - (now - oldest), 0.001), self._wake.set
-                )
-                return False
+    def _pick_prefill_groups(self) -> tuple:
+        """This tick's prefill chunks, grouped by bucket under the
+        per-tick token budget (loop thread); (groups, progressed)."""
         progressed = False
         groups: dict[int, list[Sequence]] = {}
 
@@ -2930,6 +2911,42 @@ class JaxEngine:
                 # misconfiguration) — dispatch it alone
                 groups[bucket] = [seq]
                 break
+        return groups, progressed
+
+    async def _prefill_tick(self) -> bool:
+        """Dispatch up to `prefill_group_tokens` worth of prefill chunks,
+        batching same-bucket chunks into one [n, bucket] model step —
+        one dispatch per prompt pays the fixed host cost of a dispatch
+        per prompt and leaves the MXU short rows. The per-tick
+        token budget bounds how long active decode streams stall: one
+        group dispatch per tick, decode interleaves between waves."""
+        if not self._prefilling:
+            return False
+        # admission batching window (paced arrivals): while decode
+        # streams run, hold a small pending set briefly so trickling
+        # arrivals share one dispatch — each tiny group pays a fixed
+        # dispatch+fetch overhead that serializes against decode.
+        # Mid-prompt continuations (num_computed > 0) never wait.
+        win = self.config.prefill_batch_window_s
+        if win > 0 and len(self._prefilling) < self.config.prefill_batch_min_rows:
+            now = time.perf_counter()
+            # fresh = first chunk of this serve (a prefix-cache hit has
+            # num_computed == num_cached at admission and is still a
+            # fresh arrival); mid-prompt chunk continuations never wait
+            fresh = all(
+                s.num_computed == s.num_cached and s.preloaded is None
+                for s in self._prefilling
+            )
+            oldest = min(s.t_admit for s in self._prefilling)
+            if fresh and self._any_mid_decode() and now - oldest < win:
+                # re-arm the loop when the window expires
+                loop = asyncio.get_running_loop()
+                loop.call_later(
+                    max(win - (now - oldest), 0.001), self._wake.set
+                )
+                return False
+        with profiler.phase("eng.prefill.build"):
+            groups, progressed = self._pick_prefill_groups()
         for bucket, seqs in groups.items():
             progressed = True
             try:
@@ -2949,7 +2966,8 @@ class JaxEngine:
                     )
                 finally:
                     self._op_end(wd)
-                self._note_prefilled(seqs, bucket)
+                with profiler.phase("eng.emit"):
+                    self._note_prefilled(seqs, bucket)
             except Exception:
                 log.exception(
                     "prefill group of %d seqs failed; retrying singly",
@@ -2986,21 +3004,23 @@ class JaxEngine:
                     else:
                         self._prefilling.append(seq)
                 continue
-            finals = []
-            for j, seq in enumerate(seqs):
-                if seq.num_computed >= seq.total_tokens:
-                    # final chunk: the sampled token stays on device as
-                    # the slot's decode carry override AND one per-GROUP
-                    # async fetch emits it early (_start_first_emit) —
-                    # TTFT no longer waits for the next decode dispatch
-                    self._mark_decode_ready(
-                        seq, (toks[0], toks[1], toks[2], toks[3], j)
-                    )
-                    finals.append((seq, j))
-                else:
-                    self._prefilling.append(seq)
-            if finals:
-                self._start_first_emit(finals, toks)
+            with profiler.phase("eng.emit"):
+                finals = []
+                for j, seq in enumerate(seqs):
+                    if seq.num_computed >= seq.total_tokens:
+                        # final chunk: the sampled token stays on device
+                        # as the slot's decode carry override AND one
+                        # per-GROUP async fetch emits it early
+                        # (_start_first_emit) — TTFT no longer waits for
+                        # the next decode dispatch
+                        self._mark_decode_ready(
+                            seq, (toks[0], toks[1], toks[2], toks[3], j)
+                        )
+                        finals.append((seq, j))
+                    else:
+                        self._prefilling.append(seq)
+                if finals:
+                    self._start_first_emit(finals, toks)
         await asyncio.sleep(0)
         return progressed
 
@@ -3081,12 +3101,11 @@ class JaxEngine:
 
     def _flight_record(
         self, kind: str, wall_s: float, rows: int = 0, tokens: int = 0,
-        budget: int = 0,
+        budget: int = 0, **host,
     ) -> None:
-        """Sample one step digest into the flight recorder — called from
-        the exact sites that feed _phase_stats, so the digests and the
-        counters can never disagree about a dispatch. Must never take
-        down the dispatch it observes."""
+        """Sample one step digest into the flight recorder (`host`: the
+        tick's host-side columns, flight_recorder.FIELDS). Must never
+        take down the dispatch it observes."""
         fr = self.flight
         if fr is None:
             return
@@ -3099,9 +3118,104 @@ class JaxEngine:
                 kv_frac=round(self.allocator.usage(), 4),
                 degrade_mask=self._degrade.mask(),
                 step=self._step_count,
+                preempted=self._preemptions, **host,
             )
         except Exception:  # noqa: BLE001 — forensics must not break serving
             log.exception("flight-recorder digest failed")
+
+    @contextlib.contextmanager
+    def _dispatching(self, kind: str, t0: float, rec: dict):
+        """The one recorder of a dispatch (worker thread): the xprof step
+        marker and the dispatch annotation (named like the
+        ``engine.steps`` span, so a capture and the ring join by name),
+        `_kv_lock` under ``eng.lock``, the body, then [t0, now] booked
+        where it is read: `_phase_stats`, collective bytes, the flight
+        digest, the ring. `rec`: rows, tokens, phys_rows (token rows
+        through the layer stack, padding included), optionally budget,
+        build_s, span (more ring attributes); `_enqueue` adds starved. A body that raises books nothing. The
+        wall is a dispatch-CALL wall (a jit call returns once the work
+        is enqueued); the counts are the load-bearing part."""
+        with profiler.step_annotation(self._step_count), \
+                profiler.annotate(kind):
+            with profiler.phase("eng.lock"):
+                self._kv_lock.acquire()
+            try:
+                yield
+            finally:
+                self._kv_lock.release()
+        t1 = time.perf_counter()
+        fam = "spec" if kind == "spec_verify" else kind
+        rows, tokens = rec["rows"], rec["tokens"]
+        with self._phase_lock:
+            st = self._phase_stats
+            st[f"{fam}_dispatch_s"] += t1 - t0
+            if fam != "mixed":  # mixed steps are counted where they land
+                st[f"{fam}_dispatches"] += 1
+            if fam in ("prefill", "decode"):
+                st[f"{fam}_tokens"] += tokens
+        self._note_collectives(fam, rec["phys_rows"], t1)
+        self._flight_record(
+            kind, t1 - t0, rows=rows, tokens=tokens,
+            budget=rec.get("budget", 0), build_s=rec.get("build_s", 0.0),
+            starved=rec.get("starved", 0),
+        )
+        if tracing.enabled():
+            tracing.complete(
+                kind, t0, t1, cat="step", track="engine.steps",
+                rows=rows, tokens=tokens, **rec.get("span", {}),
+            )
+
+    def _enqueue(self, rec: dict, fn, *args):
+        """The jit call alone (under `_kv_lock`) as ``eng.enqueue``;
+        notes in `rec` whether the device had drained by then
+        (`starved`: the newest dispatch's first output was ready)."""
+        last = self._last_out
+        try:
+            rec["starved"] = int(last is None or last.is_ready())
+        except Exception:  # noqa: BLE001 — a deleted buffer at shutdown
+            rec["starved"] = 0
+        with profiler.phase("eng.enqueue"):
+            out = fn(*args)
+        self._last_out = jax.tree.leaves(out[0])[0]
+        return out
+
+    def _record_sync(
+        self, family: str, rows: int, t0: float, t1: float,
+        overlapped: bool = False, bld_t0: Optional[float] = None,
+    ) -> None:
+        """The one recorder of a result fetch [t0, t1] (loop thread,
+        before its tokens land): `_phase_stats`, the flight digest
+        (`sync` / `overlap` row; `_land` fills its `emit_s`), the ring."""
+        with self._phase_lock:
+            if overlapped:
+                # ANOTHER dispatch was already queued on device: a wait
+                # the step pipeline hid. It lands in the overlap counter
+                # INSTEAD of the family's `*_sync_s` (the bench
+                # pipeline_ab fraction and the engine.overlap track rely
+                # on this split)
+                self._phase_stats["pipeline_overlap_s"] += t1 - t0
+                self._phase_stats["pipeline_overlapped"] += 1
+            else:
+                # families stay separable: a spec verify step's fetch
+                # wall belongs with its dispatch wall, not in the
+                # scanned-decode sync ratio
+                self._phase_stats[f"{family}_sync_s"] += t1 - t0
+            if bld_t0 is not None:
+                # the whole dispatch+fetch wall is time the decode rows
+                # did NOT spend parked behind a separate prefill dispatch
+                self._phase_stats["mixed_decode_stall_saved_s"] += t1 - bld_t0
+        self._flight_record(
+            "overlap" if overlapped else "sync", t1 - t0, rows=rows,
+        )
+        if tracing.enabled():
+            tracing.complete(
+                ("spec_verify" if family == "spec" else family) + ".sync",
+                t0, t1, cat="step",
+                # overlapped syncs land on their own track so the
+                # timeline shows which fetch walls the pipeline hid
+                track="engine.overlap" if overlapped else "engine.sync",
+                rows=rows,
+            )
 
     def _any_mid_decode(self) -> bool:
         """Is decode actually RUNNING? True when a decode dispatch with
@@ -3181,6 +3295,7 @@ class JaxEngine:
             seq.carry_pending = False
             seq.num_computed = seq.total_tokens
             self._stamp_first_meta(seq)
+            self._t_fetched = time.perf_counter()
             self._append_token(seq, int(tok), extra_meta=seq.first_meta)
             seq.first_meta = None
 
@@ -3205,17 +3320,20 @@ class JaxEngine:
 
     async def _emit_first_group(self, finals, S) -> None:
         try:
-            toks, lps, tid, tlp = await asyncio.to_thread(
-                lambda: (
-                    np.asarray(S[0]),
-                    np.asarray(S[1]) if S[1] is not None else None,
-                    np.asarray(S[2]) if S[2] is not None else None,
-                    np.asarray(S[3]) if S[3] is not None else None,
+            with profiler.phase("eng.fetch"):
+                toks, lps, tid, tlp = await asyncio.to_thread(
+                    lambda: tuple(
+                        np.asarray(a) if a is not None else None for a in S
+                    )
                 )
-            )
         except Exception:
             log.exception("first-token fetch failed; decode sync will emit")
             return
+        self._t_fetched = time.perf_counter()
+        with profiler.phase("eng.emit"):
+            self._emit_first_tokens(finals, toks, lps, tid, tlp)
+
+    def _emit_first_tokens(self, finals, toks, lps, tid, tlp) -> None:
         me = asyncio.current_task()
         for seq, row in finals:
             if (
@@ -3249,168 +3367,152 @@ class JaxEngine:
         compiled graphs stays bounded (padding rows write the trash
         page)."""
         faults.fire("engine.prefill")
-        n = 1 << (len(seqs) - 1).bit_length()
-        smat = np.zeros((n, self._smat_width), np.int32)
-        tok_arr = np.zeros((n, bucket), np.int32)
-        pos_arr = np.zeros((n, bucket), np.int32)
-        wslots = np.zeros((n, bucket), np.int32)
-        last_idx = np.zeros(n, np.int32)
-        temp = np.zeros(n, np.float32)
-        topk = np.zeros(n, np.int32)
-        topp = np.ones(n, np.float32)
-        # penalties/seeds need a slot-keyed count row; prefill_only seqs
-        # (slot -1, disagg) sample their first token on the plain path
-        use_ext = any(
-            (s.has_penalties or s.seed >= 0) and s.slot >= 0 for s in seqs
-        )
-        slot_rows = np.zeros(n, np.int32)
-        fp = np.zeros(n, np.float32)
-        prp = np.zeros(n, np.float32)
-        rp = np.ones(n, np.float32)
-        seeds = np.full(n, -1, np.int32)
-        final_row = np.zeros(n, bool)
-        ps = self.page_size
-        ppc = -(-bucket // ps)  # page blocks per chunk (pallas write path)
-        wtables = np.zeros((n, ppc), np.int32)
-        # multimodal: a separate compiled family only when THIS chunk of
-        # some sequence overlaps its embed span — the common path (and
-        # later text-only chunks of an image prompt) pays nothing
-        def _chunk_overlaps(s) -> bool:
-            if s.prompt_embeds is None:
-                return False
-            c0 = s.num_computed
-            c1 = c0 + min(s.total_tokens - c0, bucket)
-            return c0 < s.embeds_offset + len(s.prompt_embeds) and s.embeds_offset < c1
-
-        has_embeds = any(_chunk_overlaps(s) for s in seqs)
-        emb = emb_mask = None
-        if has_embeds:
-            d_model = self.model_cfg.hidden_size
-            emb = np.zeros(
-                (n, bucket, d_model), self._dtype.dtype
-            )  # model dtype: forward casts anyway, halve the H2D bytes
-            emb_mask = np.zeros((n, bucket), bool)
-        # attention table width: pages actually attended this chunk,
-        # bucketed to a power of two so compile families stay bounded —
-        # full width would DMA every (mostly trash) page per query tile
-        w_need = max(
-            -(-(seq.num_computed + min(seq.total_tokens - seq.num_computed,
-                                       bucket)) // ps)
-            for seq in seqs
-        )
-        w_b = min(
-            1 << (w_need - 1).bit_length(), self.config.max_pages_per_seq
-        )
-        btables = np.zeros((n, w_b), np.int32)
-        for j, seq in enumerate(seqs):
-            tokens = seq.tokens
-            start = seq.num_computed
-            chunk = min(len(tokens) - start, bucket)
-            smat[j] = self._slot_matrix_row(seq)
-            tok_arr[j, :chunk] = tokens[start : start + chunk]
-            idx = np.arange(start, start + chunk)
-            pos_arr[j, :chunk] = idx
-            pages = np.asarray(seq.page_ids, np.int32)
-            wslots[j, :chunk] = pages[idx // ps] * ps + idx % ps
-            # chunk starts are page-aligned (prefill_chunk % ps == 0,
-            # cache hits/preemption resume at page boundaries), so chunk
-            # page p covers positions start + [p*ps, (p+1)*ps)
-            n_pages_used = -(-chunk // ps)
-            wtables[j, :n_pages_used] = pages[start // ps : start // ps + n_pages_used]
-            npg = min(len(pages), w_b)
-            btables[j, :npg] = pages[:npg]
-            if has_embeds and seq.prompt_embeds is not None:
-                # overlap of [start, start+chunk) with the embed span
-                e0 = seq.embeds_offset
-                e1 = e0 + len(seq.prompt_embeds)
-                lo, hi = max(start, e0), min(start + chunk, e1)
-                if lo < hi:
-                    emb[j, lo - start:hi - start] = seq.prompt_embeds[
-                        lo - e0:hi - e0
-                    ]
-                    emb_mask[j, lo - start:hi - start] = True
-            last_idx[j] = chunk - 1
-            temp[j] = seq.temperature
-            topk[j] = seq.top_k
-            topp[j] = seq.top_p
-            slot_rows[j] = seq.slot if seq.slot >= 0 else 0
-            fp[j] = seq.frequency_penalty
-            prp[j] = seq.presence_penalty
-            rp[j] = seq.repetition_penalty
-            seeds[j] = seq.seed
-            final_row[j] = seq.num_computed + chunk >= seq.total_tokens
-        t_dispatch0 = time.perf_counter()  # dispatch section only: the
-        # host-side input build above must not skew the phase split
-        # xprof annotation named like the engine.steps span, so an
-        # on-device capture joins the Perfetto ring export by name
-        with profiler.step_annotation(self._step_count), \
-                profiler.annotate("prefill"), self._kv_lock:
-            self._key, sub = jax.random.split(self._key)
-            common = (
-                self.params, self.kv,
-                jnp.asarray(tok_arr), jnp.asarray(pos_arr),
-                jnp.asarray(wslots.reshape(-1)),
-                jnp.asarray(smat), jnp.asarray(last_idx),
-                jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(topp),
-                sub,
-                jnp.asarray(wtables.reshape(-1)) if self._attn_pallas else None,
-                jnp.asarray(btables) if self._attn_pallas else None,
-                jnp.asarray(emb) if has_embeds else None,
-                jnp.asarray(emb_mask) if has_embeds else None,
-                bool((temp <= 0.0).all()),
-                any(s.want_logprobs for s in seqs),
+        t_build0 = time.perf_counter()
+        with profiler.phase("eng.prefill.build"):
+            n = 1 << (len(seqs) - 1).bit_length()
+            smat = np.zeros((n, self._smat_width), np.int32)
+            tok_arr = np.zeros((n, bucket), np.int32)
+            pos_arr = np.zeros((n, bucket), np.int32)
+            wslots = np.zeros((n, bucket), np.int32)
+            last_idx = np.zeros(n, np.int32)
+            temp = np.zeros(n, np.float32)
+            topk = np.zeros(n, np.int32)
+            topp = np.ones(n, np.float32)
+            # penalties/seeds need a slot-keyed count row; prefill_only seqs
+            # (slot -1, disagg) sample their first token on the plain path
+            use_ext = any(
+                (s.has_penalties or s.seed >= 0) and s.slot >= 0 for s in seqs
             )
-            want_tops = any(s.top_logprobs > 0 for s in seqs)
-            # sp cached-prefix continuation: the static value is a
-            # power-of-two PAGE bucket over the group's longest cached
-            # prefix (0 = no cache; bounds both the compiled-family count
-            # and the per-layer prefix gather width)
-            spc = 0
-            if self._sp:
-                max_cached = max(
-                    (s.num_cached for s in seqs), default=0
-                ) // self.page_size
-                if max_cached:
-                    spc = 1 << (max_cached - 1).bit_length()
-                    spc = min(spc, self.config.max_pages_per_seq)
-            if use_ext:
-                S, self.kv, self._counts = self._step_ext_fn(
-                    *common, self._ensure_counts(), jnp.asarray(slot_rows),
-                    jnp.asarray(fp), jnp.asarray(prp), jnp.asarray(rp),
-                    jnp.asarray(final_row), jnp.asarray(seeds), want_tops,
-                    sp_cached=spc,
-                )
-            elif want_tops:
-                S, self.kv = self._step_fn(
-                    *common, None, None, None, None, None, None, None, True,
-                    sp_cached=spc,
-                )
-            else:
-                S, self.kv = self._step_fn(*common, sp_cached=spc)
-        # engine-side phase accounting + per-sequence first-token stamp.
-        # NOTE dispatch-call walls are NOT device walls — a jit call
-        # returns once the work is enqueued; the token counters are the
-        # load-bearing part
-        now = time.perf_counter()
+            slot_rows = np.zeros(n, np.int32)
+            fp = np.zeros(n, np.float32)
+            prp = np.zeros(n, np.float32)
+            rp = np.ones(n, np.float32)
+            seeds = np.full(n, -1, np.int32)
+            final_row = np.zeros(n, bool)
+            ps = self.page_size
+            ppc = -(-bucket // ps)  # page blocks per chunk (pallas write path)
+            wtables = np.zeros((n, ppc), np.int32)
+            # multimodal: a separate compiled family only when THIS chunk of
+            # some sequence overlaps its embed span — the common path (and
+            # later text-only chunks of an image prompt) pays nothing
+            def _chunk_overlaps(s) -> bool:
+                if s.prompt_embeds is None:
+                    return False
+                c0 = s.num_computed
+                c1 = c0 + min(s.total_tokens - c0, bucket)
+                return c0 < s.embeds_offset + len(s.prompt_embeds) and s.embeds_offset < c1
+
+            has_embeds = any(_chunk_overlaps(s) for s in seqs)
+            emb = emb_mask = None
+            if has_embeds:
+                d_model = self.model_cfg.hidden_size
+                emb = np.zeros(
+                    (n, bucket, d_model), self._dtype.dtype
+                )  # model dtype: forward casts anyway, halve the H2D bytes
+                emb_mask = np.zeros((n, bucket), bool)
+            # attention table width: pages actually attended this chunk,
+            # bucketed to a power of two so compile families stay bounded —
+            # full width would DMA every (mostly trash) page per query tile
+            w_need = max(
+                -(-(seq.num_computed + min(seq.total_tokens - seq.num_computed,
+                                           bucket)) // ps)
+                for seq in seqs
+            )
+            w_b = min(
+                1 << (w_need - 1).bit_length(), self.config.max_pages_per_seq
+            )
+            btables = np.zeros((n, w_b), np.int32)
+            for j, seq in enumerate(seqs):
+                tokens = seq.tokens
+                start = seq.num_computed
+                chunk = min(len(tokens) - start, bucket)
+                smat[j] = self._slot_matrix_row(seq)
+                tok_arr[j, :chunk] = tokens[start : start + chunk]
+                idx = np.arange(start, start + chunk)
+                pos_arr[j, :chunk] = idx
+                pages = np.asarray(seq.page_ids, np.int32)
+                wslots[j, :chunk] = pages[idx // ps] * ps + idx % ps
+                # chunk starts are page-aligned (prefill_chunk % ps == 0,
+                # cache hits/preemption resume at page boundaries), so chunk
+                # page p covers positions start + [p*ps, (p+1)*ps)
+                n_pages_used = -(-chunk // ps)
+                wtables[j, :n_pages_used] = pages[start // ps : start // ps + n_pages_used]
+                npg = min(len(pages), w_b)
+                btables[j, :npg] = pages[:npg]
+                if has_embeds and seq.prompt_embeds is not None:
+                    # overlap of [start, start+chunk) with the embed span
+                    e0 = seq.embeds_offset
+                    e1 = e0 + len(seq.prompt_embeds)
+                    lo, hi = max(start, e0), min(start + chunk, e1)
+                    if lo < hi:
+                        emb[j, lo - start:hi - start] = seq.prompt_embeds[
+                            lo - e0:hi - e0
+                        ]
+                        emb_mask[j, lo - start:hi - start] = True
+                last_idx[j] = chunk - 1
+                temp[j] = seq.temperature
+                topk[j] = seq.top_k
+                topp[j] = seq.top_p
+                slot_rows[j] = seq.slot if seq.slot >= 0 else 0
+                fp[j] = seq.frequency_penalty
+                prp[j] = seq.presence_penalty
+                rp[j] = seq.repetition_penalty
+                seeds[j] = seq.seed
+                final_row[j] = seq.num_computed + chunk >= seq.total_tokens
+        t_dispatch0 = time.perf_counter()  # dispatch section only: the
+        # host-side input build above is the digest's build_s
         n_tok = int(
             sum(min(s.total_tokens - s.num_computed, bucket) for s in seqs)
         )
-        with self._phase_lock:
-            self._phase_stats["prefill_dispatch_s"] += now - t_dispatch0
-            self._phase_stats["prefill_dispatches"] += 1
-            self._phase_stats["prefill_tokens"] += n_tok
-        self._note_collectives("prefill", len(seqs) * bucket, now)
-        self._flight_record(
-            "prefill", now - t_dispatch0, rows=len(seqs), tokens=n_tok,
+        rec = dict(
+            rows=len(seqs), tokens=n_tok, phys_rows=len(seqs) * bucket,
+            build_s=t_dispatch0 - t_build0, span={"bucket": bucket},
         )
-        if tracing.enabled():
-            # step timeline: same site that feeds _phase_stats, so the
-            # trace and the counters can never disagree about a dispatch
-            tracing.complete(
-                "prefill", t_dispatch0, now, cat="step",
-                track="engine.steps", rows=len(seqs), tokens=n_tok,
-                bucket=bucket,
+        with self._dispatching("prefill", t_dispatch0, rec):
+            with profiler.phase("eng.upload"):
+                self._key, sub = jax.random.split(self._key)
+                common = (
+                    self.params, self.kv,
+                    jnp.asarray(tok_arr), jnp.asarray(pos_arr),
+                    jnp.asarray(wslots.reshape(-1)),
+                    jnp.asarray(smat), jnp.asarray(last_idx),
+                    jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(topp),
+                    sub,
+                    jnp.asarray(wtables.reshape(-1)) if self._attn_pallas else None,
+                    jnp.asarray(btables) if self._attn_pallas else None,
+                    jnp.asarray(emb) if has_embeds else None,
+                    jnp.asarray(emb_mask) if has_embeds else None,
+                    bool((temp <= 0.0).all()),
+                    any(s.want_logprobs for s in seqs),
+                )
+                want_tops = any(s.top_logprobs > 0 for s in seqs)
+                # sp cached-prefix continuation: the static value is a
+                # power-of-two PAGE bucket over the group's longest cached
+                # prefix (0 = no cache; bounds both the compiled-family count
+                # and the per-layer prefix gather width)
+                spc = 0
+                if self._sp:
+                    max_cached = max(
+                        (s.num_cached for s in seqs), default=0
+                    ) // self.page_size
+                    if max_cached:
+                        spc = 1 << (max_cached - 1).bit_length()
+                        spc = min(spc, self.config.max_pages_per_seq)
+                if use_ext:
+                    more = (
+                        self._ensure_counts(), jnp.asarray(slot_rows),
+                        jnp.asarray(fp), jnp.asarray(prp), jnp.asarray(rp),
+                        jnp.asarray(final_row), jnp.asarray(seeds), want_tops,
+                    )
+                else:
+                    more = (None,) * 7 + (True,) if want_tops else ()
+            fn = self._step_ext_fn if use_ext else self._step_fn
+            S, self.kv, *counts = self._enqueue(
+                rec, lambda: fn(*common, *more, sp_cached=spc)
             )
+            if use_ext:
+                self._counts = counts[0]
+        now = time.perf_counter()
         for seq in seqs:
             if seq.num_computed + min(
                 seq.total_tokens - seq.num_computed, bucket
@@ -3449,6 +3551,7 @@ class JaxEngine:
         for seq in seqs:
             chunk = min(seq.total_tokens - seq.num_computed, bucket)
             seq.num_computed += chunk
+            seq.prefill_chunks += 1
             self._register_full_pages(seq)
 
     def _prefill_chunk_dispatch(self, seq: Sequence):
@@ -3782,9 +3885,11 @@ class JaxEngine:
         ]
         if not rows or not picks:
             return None
-        bld = self._build_mixed(
-            rows, picks, drafts, carry_rows=carry_rows, pipelined=pipeline
-        )
+        with profiler.phase("eng.mixed.build"):
+            bld = self._build_mixed(
+                rows, picks, drafts, carry_rows=carry_rows,
+                pipelined=pipeline,
+            )
         bld["n_shed"] = shed
         # the picked chunks leave the prefill queue while the step is in
         # flight (a pipelined step may still be unsynced when the next
@@ -3808,33 +3913,14 @@ class JaxEngine:
                 return None
             self._inflight = _Dispatch(S, [], 1, mixed=True, bld=bld)
             return "pipelined"
-        t0 = bld["t0"]
         try:
             S = await asyncio.to_thread(self._run_mixed_dispatch, bld)
-            t_sync0 = time.perf_counter()
-            # spec mode returns (out_tokens [n, k+1], n_emit [n])
-            toks = await asyncio.to_thread(
-                lambda: tuple(np.asarray(a) for a in S)
-                if isinstance(S, tuple) else np.asarray(S)
-            )
+            d = _Dispatch(S, [], 1, mixed=True, bld=bld)
+            fetched = await self._fetch(d)
         except Exception:
             self._mixed_dispatch_failed(bld)
             return None
-        now = time.perf_counter()
-        with self._phase_lock:
-            self._phase_stats["mixed_sync_s"] += now - t_sync0
-            # the whole dispatch+fetch wall is time the decode rows did
-            # NOT spend parked behind a separate prefill dispatch
-            self._phase_stats["mixed_decode_stall_saved_s"] += now - t0
-        self._flight_record(
-            "sync", now - t_sync0, rows=len(bld["entries"]),
-        )
-        if tracing.enabled():
-            tracing.complete(
-                "mixed.sync", t_sync0, now, cat="step",
-                track="engine.sync", rows=len(bld["entries"]),
-            )
-        self._sync_mixed(bld, toks)
+        self._land(d, *fetched)
         return True
 
     def _mixed_dispatch_failed(self, bld: dict) -> None:
@@ -3996,6 +4082,7 @@ class JaxEngine:
             spec=use_spec, draft=draft_arr, dlen=dlen_arr, pos0=pos0_arr,
             all_greedy=all_greedy, w_b=w_b, pipelined=pipelined,
             n_carry=n_carry, n_shed=0, t0=t0, dirty=self._snap_dirty(),
+            build_s=time.perf_counter() - t0,
         )
 
     def _run_mixed_dispatch(self, bld: dict):
@@ -4008,48 +4095,40 @@ class JaxEngine:
         host-round-trip-free)."""
         faults.fire("engine.mixed")
         t0 = time.perf_counter()
+        entries, hot = bld["entries"], bld["hot"]
+        rec = dict(
+            rows=len(entries), tokens=sum(e[3] for e in entries),
+            # physical rows: every hot row x its chunk width flows the stack
+            phys_rows=int(hot.shape[1] * hot.shape[2]),
+            budget=self.config.mixed_step_tokens, build_s=bld["build_s"],
+            span=dict(
+                decode_rows=sum(1 for e in entries if e[0] == "dec"),
+                spec=bld["spec"], pipelined=bld["pipelined"],
+            ),
+        )
         wd = self._op_begin("mixed.dispatch")
         try:
-            # xprof phase annotation matches the engine.steps span name
-            with profiler.step_annotation(self._step_count), \
-                    profiler.annotate("mixed"), self._kv_lock:
-                self._flush_dev_state_locked(bld["dirty"])
-                self._key, sub = jax.random.split(self._key)
-                S, self.kv, self._carry_toks = self._mixed_fn(
-                    self.params, self.kv,
-                    jnp.asarray(bld["hot"]), jnp.asarray(bld["meta"]),
-                    self._dev_samp_f, self._dev_samp_i, self._dev_tables,
-                    self._carry_toks, sub,
-                    jnp.asarray(bld["draft"]) if bld["spec"] else None,
-                    jnp.asarray(bld["dlen"]) if bld["spec"] else None,
-                    bld["all_greedy"], bld["w_b"],
+            with self._dispatching("mixed", t0, rec):
+                with profiler.phase("eng.upload"):
+                    self._flush_dev_state_locked(bld["dirty"])
+                    self._key, sub = jax.random.split(self._key)
+                    args = (
+                        self.params, self.kv,
+                        jnp.asarray(hot), jnp.asarray(bld["meta"]),
+                        self._dev_samp_f, self._dev_samp_i, self._dev_tables,
+                        self._carry_toks, sub,
+                        jnp.asarray(bld["draft"]) if bld["spec"] else None,
+                        jnp.asarray(bld["dlen"]) if bld["spec"] else None,
+                        bld["all_greedy"], bld["w_b"],
+                    )
+                S, self.kv, self._carry_toks = self._enqueue(
+                    rec, self._mixed_fn, *args
                 )
-            self._step_count += 1
-            for arr in (S if isinstance(S, tuple) else (S,)):
-                arr.copy_to_host_async()
+                self._step_count += 1
+                for arr in (S if isinstance(S, tuple) else (S,)):
+                    arr.copy_to_host_async()
         finally:
             self._op_end(wd)
-        t1 = time.perf_counter()
-        with self._phase_lock:
-            self._phase_stats["mixed_dispatch_s"] += t1 - t0
-        # physical rows: every hot row x its chunk width flows the stack
-        self._note_collectives(
-            "mixed", int(bld["hot"].shape[1] * bld["hot"].shape[2]), t1
-        )
-        self._flight_record(
-            "mixed", t1 - t0, rows=len(bld["entries"]),
-            tokens=sum(e[3] for e in bld["entries"]),
-            budget=self.config.mixed_step_tokens,
-        )
-        if tracing.enabled():
-            entries = bld["entries"]
-            tracing.complete(
-                "mixed", t0, t1, cat="step", track="engine.steps",
-                rows=len(entries),
-                decode_rows=sum(1 for e in entries if e[0] == "dec"),
-                tokens=sum(e[3] for e in entries),
-                spec=bld["spec"], pipelined=bld["pipelined"],
-            )
         return S
 
     def _sync_mixed(self, bld: dict, toks) -> None:
@@ -4107,6 +4186,7 @@ class JaxEngine:
                     self._overrides[slot] = tok
                 continue
             seq.num_computed += chunk
+            seq.prefill_chunks += 1
             self._register_full_pages(seq)
             try:
                 self._prefilling.remove(seq)
@@ -4171,11 +4251,20 @@ class JaxEngine:
 
     def _maybe_dispatch_decode(self) -> Optional["_DecodeBuild"]:
         """Host-side build of the next decode dispatch (cancellation
-        sweep, page growth, input tables); returns None when nothing is
-        decode-ready. The jax calls happen in `_run_decode_dispatch`,
-        which the loop runs in a worker thread — the dispatch call can
-        wait (on _kv_lock, a compile, the runtime's queue), and that
-        wait must overlap the previous dispatch's result fetch."""
+        sweep, page growth, input tables) as ``eng.decode.build``;
+        returns None when nothing is decode-ready. The jax calls happen
+        in `_run_decode_dispatch`, which the loop runs in a worker
+        thread — the dispatch call can wait (on _kv_lock, a compile, the
+        runtime's queue), and that wait must overlap the previous
+        dispatch's result fetch."""
+        t0 = time.perf_counter()
+        with profiler.phase("eng.decode.build"):
+            bld = self._build_decode()
+        if isinstance(bld, _DecodeBuild):
+            bld.build_s = time.perf_counter() - t0
+        return bld
+
+    def _build_decode(self):
         if self._closed:
             return None
         ready = self._decode_ready_rows()
@@ -4397,74 +4486,57 @@ class JaxEngine:
         under _kv_lock (the loop awaits it before its own next kv use,
         but the public prefill_only path can dispatch concurrently)."""
         t0 = time.perf_counter()
+        rows = len(bld.active)
+        if bld.spec:
+            rec = dict(
+                rows=rows, tokens=rows + int(np.sum(bld.dlen)),
+                phys_rows=int(np.asarray(bld.tokens).size),
+            )
+        else:
+            rec = dict(
+                # dispatched decode token-SLOTS (active rows x steps):
+                # includes the <= steps-1 overshoot positions of rows
+                # that finish mid-scan, so this bounds emitted tokens
+                # from above
+                rows=rows, tokens=int(bld.pos_act[:, 1].sum()) * bld.steps,
+                # physical rows: the scan runs the FULL padded batch
+                # every step
+                phys_rows=int(bld.pos_act.shape[0]) * bld.steps,
+                span={"steps": bld.steps},
+            )
+        rec["build_s"] = bld.build_s
         wd = self._op_begin("spec.dispatch" if bld.spec else "decode.dispatch")
         try:
             # inside the watchdog's window: an injected slow dispatch is
             # a slow dispatch
             faults.fire("engine.dispatch")
-            # xprof phase annotation matches the engine.steps span name
-            with profiler.step_annotation(self._step_count), \
-                    profiler.annotate("spec_verify" if bld.spec else "decode"), \
-                    self._kv_lock:
+            with self._dispatching(
+                "spec_verify" if bld.spec else "decode", t0, rec
+            ):
                 if bld.spec:
-                    out = self._run_spec_dispatch_locked(bld)
-                else:
-                    out = self._run_decode_dispatch_locked(bld)
+                    return self._run_spec_dispatch_locked(bld, rec)
+                return self._run_decode_dispatch_locked(bld, rec)
         finally:
             self._op_end(wd)
-        t1 = time.perf_counter()
-        rows = len(bld.active)
-        if bld.spec:
-            n_tok = rows + int(np.sum(bld.dlen))
-            with self._phase_lock:
-                self._phase_stats["spec_dispatch_s"] += t1 - t0
-                self._phase_stats["spec_dispatches"] += 1
-            self._note_collectives(
-                "spec", int(np.asarray(bld.tokens).size), t1
-            )
-            self._flight_record(
-                "spec_verify", t1 - t0, rows=rows, tokens=n_tok,
-            )
-            if tracing.enabled():
-                tracing.complete(
-                    "spec_verify", t0, t1, cat="step",
-                    track="engine.steps", rows=rows, tokens=n_tok,
-                )
-            return out
-        n_tok = int(bld.pos_act[:, 1].sum()) * bld.steps
-        with self._phase_lock:
-            self._phase_stats["decode_dispatch_s"] += t1 - t0
-            self._phase_stats["decode_dispatches"] += 1
-            # dispatched decode token-SLOTS (active rows x steps):
-            # includes the <= steps-1 overshoot positions of rows that
-            # finish mid-scan, so this bounds emitted tokens from above
-            self._phase_stats["decode_tokens"] += n_tok
-        # physical rows: the scan runs the FULL padded batch every step
-        self._note_collectives(
-            "decode", int(bld.pos_act.shape[0]) * bld.steps, t1
-        )
-        self._flight_record("decode", t1 - t0, rows=rows, tokens=n_tok)
-        if tracing.enabled():
-            tracing.complete(
-                "decode", t0, t1, cat="step", track="engine.steps",
-                rows=rows, tokens=n_tok, steps=bld.steps,
-            )
-        return out
 
-    def _run_spec_dispatch_locked(self, bld: "_DecodeBuild") -> _Dispatch:
+    def _run_spec_dispatch_locked(
+        self, bld: "_DecodeBuild", rec: dict
+    ) -> _Dispatch:
         """Jax half of a speculative verify dispatch: one multi-query
         model step + on-device acceptance. The device carry vector is
         NOT updated (spec windows are host-built); sync re-arms the
         carry for a following normal dispatch via an int override."""
-        self._key, sub = jax.random.split(self._key)
-        S, self.kv = self._spec_fn(
-            self.params, self.kv,
-            jnp.asarray(bld.tokens), jnp.asarray(bld.positions),
-            jnp.asarray(bld.tables), jnp.asarray(bld.act),
-            jnp.asarray(bld.draft), jnp.asarray(bld.dlen),
-            jnp.asarray(bld.temp), jnp.asarray(bld.topk),
-            jnp.asarray(bld.topp), sub, bld.all_greedy,
-        )
+        with profiler.phase("eng.upload"):
+            self._key, sub = jax.random.split(self._key)
+            args = (
+                self.params, self.kv,
+                jnp.asarray(bld.tokens), jnp.asarray(bld.positions),
+                jnp.asarray(bld.tables), jnp.asarray(bld.act),
+                jnp.asarray(bld.draft), jnp.asarray(bld.dlen),
+                jnp.asarray(bld.temp), jnp.asarray(bld.topk),
+                jnp.asarray(bld.topp), sub, bld.all_greedy,
+            )
+        S, self.kv = self._enqueue(rec, self._spec_fn, *args)
         self._step_count += 1
         for arr in S:
             arr.copy_to_host_async()
@@ -4473,7 +4545,47 @@ class JaxEngine:
             draft_lens=bld.dlen,
         )
 
-    def _run_decode_dispatch_locked(self, bld: "_DecodeBuild") -> _Dispatch:
+    def _run_decode_dispatch_locked(
+        self, bld: "_DecodeBuild", rec: dict
+    ) -> _Dispatch:
+        with profiler.phase("eng.upload"):
+            args = self._decode_inputs_locked(bld)
+        res = self._enqueue(
+            rec, self._decode_ext_fn if bld.use_ext else self._decode_fn,
+            *args,
+        )
+        # the write-back of the carries: eager operations queued behind
+        # the scan, each a launch of its own on the device
+        with profiler.phase("eng.carry"):
+            w = bld.width
+            full = w == len(self.slots)
+            if bld.use_ext:
+                S, self.kv, new_counts = res
+                self._counts = (
+                    new_counts if full else self._counts.at[:w].set(new_counts)
+                )
+            else:
+                S, self.kv = res
+            self._step_count += 1
+            if full:
+                self._carry_toks = S[0][-1]
+                self._carry_lps = S[1][-1]
+                if bld.want_tops:
+                    self._carry_tid = S[2][-1]
+                    self._carry_tlp = S[3][-1]
+            else:
+                self._carry_toks = self._carry_toks.at[:w].set(S[0][-1])
+                self._carry_lps = self._carry_lps.at[:w].set(S[1][-1])
+                if bld.want_tops:
+                    self._carry_tid = self._carry_tid.at[:w].set(S[2][-1])
+                    self._carry_tlp = self._carry_tlp.at[:w].set(S[3][-1])
+            for arr in S:
+                arr.copy_to_host_async()
+        return _Dispatch(S, bld.active, bld.steps)
+
+    def _decode_inputs_locked(self, bld: "_DecodeBuild") -> tuple:
+        """The decode program's arguments: the device-state flush, the
+        carry overrides and the one fused upload (``eng.upload``)."""
         self._flush_dev_state_locked(bld.dirty)
         w = bld.width  # bucketed dispatch width (power of two >= highest
         # active slot + 1; carries/counts slice to it and write back)
@@ -4527,7 +4639,6 @@ class JaxEngine:
                 if bld.want_tops:
                     tlp = tlp.at[sl].set(jnp.nan)
         self._key, sub = jax.random.split(self._key)
-        fn = self._decode_ext_fn if bld.use_ext else self._decode_fn
         full = w == len(self.slots)
         counts_in = None
         if bld.use_ext:
@@ -4538,7 +4649,7 @@ class JaxEngine:
             counts_in = (
                 self._ensure_counts() if full else self._ensure_counts()[:w]
             )
-        res = fn(
+        return (
             self.params, self.kv,
             toks, lps, jnp.asarray(bld.pos_act),
             self._dev_tables[:w], self._dev_samp_f[:w],
@@ -4550,29 +4661,6 @@ class JaxEngine:
             tlp if bld.want_tops else None,
             bld.want_tops,
         )
-        if bld.use_ext:
-            S, self.kv, new_counts = res
-            self._counts = (
-                new_counts if full else self._counts.at[:w].set(new_counts)
-            )
-        else:
-            S, self.kv = res
-        self._step_count += 1
-        if full:
-            self._carry_toks = S[0][-1]
-            self._carry_lps = S[1][-1]
-            if bld.want_tops:
-                self._carry_tid = S[2][-1]
-                self._carry_tlp = S[3][-1]
-        else:
-            self._carry_toks = self._carry_toks.at[:w].set(S[0][-1])
-            self._carry_lps = self._carry_lps.at[:w].set(S[1][-1])
-            if bld.want_tops:
-                self._carry_tid = self._carry_tid.at[:w].set(S[2][-1])
-                self._carry_tlp = self._carry_tlp.at[:w].set(S[3][-1])
-        for arr in S:
-            arr.copy_to_host_async()
-        return _Dispatch(S, bld.active, bld.steps)
 
     async def _sync_dispatch(self, d: _Dispatch, overlapped: bool = False) -> None:
         # first-token fetch tasks for sequences in this dispatch must
@@ -4583,67 +4671,54 @@ class JaxEngine:
                 await task
             except Exception:
                 log.exception("first-token emit task failed")
-        t_sync0 = time.perf_counter()
+        self._land(d, *await self._fetch(d, overlapped), overlapped)
+
+    async def _fetch(self, d: _Dispatch, overlapped: bool = False) -> tuple:
+        """Wait for a dispatch's outputs on the host (``eng.fetch``; the
+        wait runs in a worker thread, the loop serves other tasks
+        meanwhile). Returns (arrays, t0, t1)."""
+        out = d.out_dev
+        t0 = time.perf_counter()
         wd = self._op_begin("sync.fetch")
         try:
-            if d.mixed:
-                out = d.out_dev
+            with profiler.phase("eng.fetch"):
+                # mixed: sampled [n], or (out [n, k+1], n_emit [n]) with
+                # spec rows; else (toks, lps[, top_ids, top_lps]) each
+                # [K+1, B(, 8)]
                 arrs = await asyncio.to_thread(
                     lambda: tuple(np.asarray(a) for a in out)
-                    if isinstance(out, tuple) else np.asarray(out)
-                )  # sampled [n], or (out [n, k+1], n_emit [n]) with spec rows
-            else:
-                arrs = await asyncio.to_thread(
-                    lambda: tuple(np.asarray(a) for a in d.out_dev)
-                )  # (toks, lps[, top_ids, top_lps]) each [K+1, B(, 8)]
+                    if isinstance(out, (tuple, list)) else np.asarray(out)
+                )
         finally:
             self._op_end(wd)
-        t_sync1 = time.perf_counter()
-        with self._phase_lock:
-            if overlapped:
-                # this fetch wall ran while ANOTHER dispatch was already
-                # queued on device — host wait the step pipeline hid
-                # behind device compute instead of serializing against
-                # it. It lands in the overlap counter INSTEAD of the
-                # family sync counter: `*_sync_s` measures stalls where
-                # the device sat idle behind a host fetch, and a hidden
-                # wall is by definition not one (the bench pipeline_ab
-                # fraction and the engine.overlap trace track both rely
-                # on this split)
-                self._phase_stats["pipeline_overlap_s"] += t_sync1 - t_sync0
-                self._phase_stats["pipeline_overlapped"] += 1
-            else:
-                # keep the phase families separable: a spec verify
-                # step's fetch wall belongs with its dispatch wall, not
-                # in the scanned-decode sync ratio
-                self._phase_stats[
-                    "mixed_sync_s" if d.mixed
-                    else "spec_sync_s" if d.spec else "decode_sync_s"
-                ] += t_sync1 - t_sync0
-            if d.mixed:
-                self._phase_stats["mixed_decode_stall_saved_s"] += (
-                    t_sync1 - d.bld["t0"]
-                )
-        self._flight_record(
-            "overlap" if overlapped else "sync", t_sync1 - t_sync0,
-            rows=len(d.bld["entries"]) if d.mixed else len(d.snapshot),
+        return arrs, t0, time.perf_counter()
+
+    def _land(self, d: _Dispatch, arrs, t0: float, t1: float,
+              overlapped: bool = False) -> None:
+        """Book a fetched dispatch, then land it (``eng.emit``: token
+        loop, stop checks, out_queue puts, finishes; the digest's
+        `emit_s`)."""
+        self._t_fetched = t1
+        self._record_sync(
+            "mixed" if d.mixed else "spec" if d.spec else "decode",
+            len(d.bld["entries"]) if d.mixed else len(d.snapshot),
+            t0, t1, overlapped, bld_t0=d.bld["t0"] if d.mixed else None,
         )
-        if tracing.enabled():
-            tracing.complete(
-                "mixed.sync" if d.mixed
-                else "spec_verify.sync" if d.spec else "decode.sync",
-                t_sync0, t_sync1, cat="step",
-                # overlapped syncs land on their own track so the
-                # timeline shows which fetch walls the pipeline hid
-                track="engine.overlap" if overlapped else "engine.sync",
-                rows=len(d.bld["entries"]) if d.mixed else len(d.snapshot),
+        with profiler.phase("eng.emit"):
+            if d.mixed:
+                self._sync_mixed(d.bld, arrs)
+            elif d.spec:
+                self._sync_spec(d, arrs)
+            else:
+                self._sync_decode(d, arrs)
+        if self.flight is not None:
+            self.flight.amend(
+                "overlap" if overlapped else "sync",
+                emit_s=time.perf_counter() - t1,
             )
-        if d.mixed:
-            self._sync_mixed(d.bld, arrs)
-            return
-        if d.spec:
-            self._sync_spec(d, arrs)
-            return
+
+    def _sync_decode(self, d: _Dispatch, arrs) -> None:
+        """Land a decode scan: row 0 first tokens, then the steps."""
         out, out_lps = arrs[0], arrs[1]
         tops = arrs[2:] if len(arrs) == 4 else None
 
@@ -4777,6 +4852,7 @@ class JaxEngine:
 
     def _preempt(self, seq: Sequence) -> None:
         log.info("preempting seq %s (out of KV pages)", seq.seq_id)
+        self._preemptions += 1
         self._register_full_pages(seq)
         self._kv_drop(seq.page_ids, seq.ctx.id)
         self.allocator.release(seq.page_ids)
@@ -5060,6 +5136,10 @@ class JaxEngine:
         seq.generated += 1
         if seq.generated == 1:
             seq.t_first_emit = time.perf_counter()
+            if seq.t_admit:
+                # ttft_s = queue_wait_s + prefill_s + first_emit_s
+                seq.prefill_s = self._t_fetched - seq.t_admit
+                seq.first_emit_s = seq.t_first_emit - self._t_fetched
             if tracing.enabled():
                 tracing.instant(
                     "seq.first_token", cat="lifecycle", req=seq.ctx.id,
@@ -5147,6 +5227,14 @@ class JaxEngine:
                 (now - seq.t_first_emit) / (seq.generated - 1)
                 if seq.t_first_emit and seq.generated > 1 else None
             ),
+            # ttft_s split: admit -> first token on the host (the fetch
+            # that carried it landed) -> its out_queue put; the identity
+            # ttft_s = queue_wait_s + prefill_s + first_emit_s holds for
+            # a request not preempted after its first token (a
+            # re-admission restamps queue_wait_s)
+            "prefill_s": seq.prefill_s,
+            "first_emit_s": seq.first_emit_s,
+            "prefill_chunks": seq.prefill_chunks,
         }
         # record the request span BEFORE notifying observers: an
         # observer can dump a forensic artifact for this very request

@@ -72,6 +72,11 @@ FIELDS = (
     "kv_frac",       # KV-pool occupancy fraction
     "degrade_mask",  # bit i = degrade.RUNGS[i] tripped
     "outlier",       # 1 = this step breached its phase baseline
+    # the tick's host side (PR 24), on the rows that exist:
+    "build_s",       # host time of this tick before the dispatch call
+    "emit_s",        # sync/overlap rows: landing the tokens after the fetch
+    "starved",       # 1 = the device had drained when the jit call was made
+    "preempted",     # the engine's cumulative preemption count
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 
@@ -233,6 +238,20 @@ class FlightRecorder:
 
     # ------------------------------------------------------------ record
 
+    def amend(self, kind: str, **host) -> None:
+        """Fill `host` columns of the newest digest of `kind`, for what
+        its writer learns only after the step (a sync row's `emit_s`).
+        The writer's own thread appended it a moment ago; a dispatch
+        worker may have appended a few rows since."""
+        code = _KIND_CODE[kind]
+        with self._lock:
+            for back in range(1, min(self._n, self.capacity, 8) + 1):
+                row = self._buf[(self._n - back) % self.capacity]
+                if row[_COL["kind"]] == code:
+                    for name, v in host.items():
+                        row[_COL[name]] = v
+                    return
+
     @property
     def count(self) -> int:
         """Digests currently held (<= capacity)."""
@@ -250,8 +269,10 @@ class FlightRecorder:
         kv_frac: float = 0.0,
         degrade_mask: int = 0,
         step: int = 0,
+        **host,
     ) -> bool:
-        """Append one step digest; returns whether the step was a
+        """Append one step digest (`host`: any of the columns after
+        ``outlier``, by name; the rest stay 0); returns whether the step was a
         latency outlier for its phase (always False for sync kinds)."""
         outlier = False
         base = self._baselines.get(kind)
@@ -261,7 +282,7 @@ class FlightRecorder:
         # concurrent snapshot_rows (trigger dump) copies the buffer
         # under the same lock, so it can never capture a half-written
         # newest digest — the rows a postmortem reads first
-        row = np.empty(len(FIELDS), np.float64)
+        row = np.zeros(len(FIELDS), np.float64)
         row[_COL["ts_unix"]] = time.time()
         row[_COL["step"]] = step
         row[_COL["kind"]] = _KIND_CODE.get(kind, -1)
@@ -274,6 +295,8 @@ class FlightRecorder:
         row[_COL["kv_frac"]] = kv_frac
         row[_COL["degrade_mask"]] = degrade_mask
         row[_COL["outlier"]] = 1.0 if outlier else 0.0
+        for name, v in host.items():
+            row[_COL[name]] = v
         with self._lock:
             self._buf[self._n % self.capacity] = row
             self._n += 1

@@ -12,6 +12,15 @@ device time went*. This module crosses that boundary two ways:
   xprof's step-time analysis groups kernels under real engine steps.
   Annotations are TraceMe no-ops (~ns) while no capture is running, so
   they stay on unconditionally.
+- **Host phases.** `phase(name)` (`utils/tracing.py`'s, under this
+  module's name too) is the one way to mark what the host is doing: it
+  opens a `TraceAnnotation` (so the interval lies on the device trace's
+  clock during a capture) and on exit hands the same interval to the
+  trace ring when that is armed. The engine loop's tick (``eng.tick`` and its children), the
+  dispatch workers (``eng.lock`` / ``eng.upload`` / ``eng.enqueue``
+  inside the dispatch annotation) and the frontend (``fe.*``) all use
+  it; `benchmark/lib/trace_host.py` reads them back to say who owes the
+  device's idle time. Names: docs/observability.md.
 - **On-demand capture.** ``POST /debug/profile?duration_ms=`` on a live
   engine runs `jax.profiler.start_trace` into ``DYN_PROFILE_DIR`` for
   the requested window and stops — replacing the ad-hoc one-off
@@ -35,7 +44,7 @@ import threading
 import time
 from typing import Optional
 
-from dynamo_tpu.utils import counters
+from dynamo_tpu.utils import counters, tracing
 from dynamo_tpu.utils.logging import get_logger
 
 log = get_logger("dynamo_tpu.profiler")
@@ -77,6 +86,13 @@ def annotate(name: str):
     if _jprof is None:
         return _NOOP
     return _jprof.TraceAnnotation(name)
+
+
+# the one way to mark a host phase (utils/tracing.py, which stays off
+# jax); importing this module puts its annotations on the device trace
+phase = tracing.phase
+if _jprof is not None:
+    tracing.annotation = _jprof.TraceAnnotation
 
 
 def step_annotation(step_num: int):

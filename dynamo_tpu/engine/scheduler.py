@@ -71,6 +71,13 @@ class Sequence:
     # request's mean ITL — the inputs of the request-finish summaries the
     # engine hands to subscribe_requests (Prometheus histograms)
     t_first_emit: float = 0.0
+    # the finish summary's split of that TTFT, stamped with it:
+    # prefill_s = admit -> the fetch that carried the first token landed
+    # on the host, first_emit_s = from there to its out_queue put;
+    # prefill_chunks counts the chunks dispatched for this request
+    prefill_s: Optional[float] = None
+    first_emit_s: Optional[float] = None
+    prefill_chunks: int = 0
     # disagg: (first_token, k [L,T,Kh*Hd], v) delivered by a remote prefill
     # worker — admission injects this into pages instead of computing it
     preloaded: Optional[tuple] = None

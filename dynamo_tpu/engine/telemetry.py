@@ -38,16 +38,23 @@ _COMPILE_KEY = "/jax/core/compile/backend_compile_duration"
 # recorded once per program served from the persistent compilation cache
 # (utils/compile_cache.py) instead of being compiled
 _CACHE_HIT_KEY = "/jax/compilation_cache/cache_hits"
+# wall of reading one such program back (a duration event)
+_CACHE_READ_KEY = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 _lock = threading.Lock()
 _installed = False
 _compile_events = 0
 _compile_time_s = 0.0
 _cache_hits = 0
+_cache_read_s = 0.0
 
 
 def _on_event_duration(name: str, duration_s: float, **_kw) -> None:
-    global _compile_events, _compile_time_s
+    global _compile_events, _compile_time_s, _cache_read_s
+    if name == _CACHE_READ_KEY:
+        with _lock:
+            _cache_read_s += duration_s
+        return
     if name != _COMPILE_KEY:
         return
     with _lock:
@@ -85,12 +92,18 @@ def install_compile_listener() -> None:
 
 
 def compile_stats() -> dict:
-    """Cumulative compile gauges for `Engine.metrics()`."""
+    """Cumulative compile gauges for `Engine.metrics()`. jax reports a
+    ``backend_compile`` event for a program it reads back from the
+    persistent cache too, so ``compile_events`` counts both;
+    ``backend_compiles`` is what the compiler really built (events less
+    cache hits) and ``cache_read_s`` the wall of the reads."""
     with _lock:
         return {
             "compile_events": _compile_events,
             "compile_time_s": round(_compile_time_s, 4),
             "persistent_cache_hits": _cache_hits,
+            "backend_compiles": max(_compile_events - _cache_hits, 0),
+            "cache_read_s": round(_cache_read_s, 4),
         }
 
 

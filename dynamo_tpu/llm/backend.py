@@ -27,6 +27,7 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.llm.tokenizer import HuggingFaceTokenizer
 from dynamo_tpu.runtime.pipeline.context import Context
 from dynamo_tpu.runtime.pipeline.engine import AsyncEngine, Operator
+from dynamo_tpu.utils import tracing
 
 
 def _held_suffix_len(text: str, stops: list[str]) -> int:
@@ -178,13 +179,14 @@ class Backend(Operator):
                     return
                 text_parts: list[str] = []
                 consumed = 0
-                for tid in out.token_ids:
-                    piece = decoder.step(tid)
-                    consumed += 1
-                    if piece:
-                        text_parts.append(piece)
-                    if decoder.finished:
-                        break
+                with tracing.phase("fe.stream"):  # the detokenizer's share
+                    for tid in out.token_ids:
+                        piece = decoder.step(tid)
+                        consumed += 1
+                        if piece:
+                            text_parts.append(piece)
+                        if decoder.finished:
+                            break
                 # only the consumed prefix: tokens past a mid-chunk stop must
                 # not leak into usage accounting downstream
                 pending_ids.extend(out.token_ids[:consumed])

@@ -579,7 +579,9 @@ class HttpService:
                         f"event: {name}\ndata: {json.dumps(data)}\n\n".encode(),
                     )
                     continue
-                yield "data", f"data: {json.dumps(item)}\n\n".encode()
+                with tracing.phase("fe.stream"):  # serializing one chunk
+                    frame = f"data: {json.dumps(item)}\n\n".encode()
+                yield "data", frame
             yield "done", b"data: [DONE]\n\n"
         except (ConnectionResetError, asyncio.CancelledError):
             raise

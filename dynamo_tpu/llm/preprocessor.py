@@ -193,7 +193,7 @@ class OpenAIPreprocessor(Operator):
         self, request: Context, next_engine: AsyncEngine
     ) -> AsyncIterator[dict]:
         req = request.payload
-        with tracing.span("preprocess", cat="preprocess", req=request.id) as sp:
+        with tracing.phase("fe.preprocess", req=request.id) as sp:
             if isinstance(req, ChatCompletionRequest):
                 pre, prompt = self.preprocess_chat(req)
                 kind = "chat"

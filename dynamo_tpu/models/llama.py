@@ -34,6 +34,18 @@ from dynamo_tpu.ops.quant import (
 )
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
+# Names on the device's operations: `jax.named_scope` puts its name into
+# every traced operation's HLO metadata (`op_name`), where a profile
+# finds it (benchmark/lib/trace_host.py: attn.qkv / attn.rope /
+# attn.kv_write / attn.kernel / attn.o, mlp.gate_up / mlp.down, norm,
+# head; the engine adds sample). Metadata only: the compiled code is
+# the same without it. The persistent compile cache's key is the same
+# only for a program without a pallas kernel: a kernel's serialized body
+# keeps its own source locations, which the key does not strip, so a
+# scope around the call re-keys the step programs once, as any edit
+# that moves the caller's lines does (PERF.md, Findings, PR 24).
+_norm = jax.named_scope("norm")(rms_norm)
+
 Params = dict[str, Any]
 
 
@@ -317,6 +329,7 @@ def _attn_block(
     int4 = quant and attn.int4_groups > 0
     s_ch = kh * attn.int4_groups if int4 else kh
 
+    @jax.named_scope("attn.kv_write")
     def _quant_rows(rows):
         """Quantize fresh KV rows for the pool's tier (int8 or int4)."""
         if int4:
@@ -325,6 +338,7 @@ def _attn_block(
             return quantize_kv_rows_int4(rows, kh, hd // attn.int4_groups)
         return quantize_kv_rows(rows, kh)
 
+    @jax.named_scope("attn.kv_write")
     def _write_rows(kv_k, kv_v, kv_ks, kv_vs, kr, vr):
         """Row-scatter this chunk's KV into the pools (ring and gather
         modes); quantized pools quantize the rows and scatter the scales
@@ -359,32 +373,35 @@ def _attn_block(
         kv_k, kv_v = write_kv_slots(kv_k, kv_v, write_slots, kr, vr)
         return kv_k, kv_v, kv_ks, kv_vs
 
-    if tp_overlap:
-        # one gather ring serves all three projections: x's row chunks
-        # circulate over ICI while the resident chunk multiplies into
-        # the local head shards — the all-gather half of the decomposed
-        # psum never runs as a standalone collective
-        from dynamo_tpu.parallel import tp_overlap as _ov
+    with jax.named_scope("attn.qkv"):
+        if tp_overlap:
+            # one gather ring serves all three projections: x's row chunks
+            # circulate over ICI while the resident chunk multiplies into
+            # the local head shards — the all-gather half of the decomposed
+            # psum never runs as a standalone collective
+            from dynamo_tpu.parallel import tp_overlap as _ov
 
-        q, k, v = _ov.ring_ag_matmul(
-            x, (lp["wq"], lp["wk"], lp["wv"]), tp_axis
-        )
-        # drop the ring's row padding; attention never sees pad rows
-        q, k, v = q[: b * t], k[: b * t], v[: b * t]
-    else:
-        q = mm(x, lp["wq"])
-        k = mm(x, lp["wk"])
-        v = mm(x, lp["wv"])
-    if cfg.attn_bias:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(b, t, h, hd)
-    k = k.reshape(b, t, kh, hd)
-    v = v.reshape(b, t, kh, hd)
+            q, k, v = _ov.ring_ag_matmul(
+                x, (lp["wq"], lp["wk"], lp["wv"]), tp_axis
+            )
+            # drop the ring's row padding; attention never sees pad rows
+            q, k, v = q[: b * t], k[: b * t], v[: b * t]
+        else:
+            q = mm(x, lp["wq"])
+            k = mm(x, lp["wk"])
+            v = mm(x, lp["wv"])
+        if cfg.attn_bias:
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(b, t, h, hd)
+        k = k.reshape(b, t, kh, hd)
+        v = v.reshape(b, t, kh, hd)
 
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn.rope"):
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    kernel = jax.named_scope("attn.kernel")
 
     if attn.block_tables is not None and attn.write_pos is not None:
         from dynamo_tpu.ops.pallas_attention import fused_paged_decode_attention
@@ -441,6 +458,7 @@ def _attn_block(
                 ),
                 check_vma=False,
             )
+        fused = kernel(fused)  # it also writes the new rows' pages
         if quant:
             out, kv_k, kv_v, kv_ks, kv_vs = fused(
                 q[:, 0], new_k, new_v, kv_k, kv_v,
@@ -524,6 +542,7 @@ def _attn_block(
                 out_specs=(P(None, "tp"), P(None, "tp"), *scale_out),
                 check_vma=False,
             )
+        wr = jax.named_scope("attn.kv_write")(wr)
         if quant:
             kv_k, kv_v, kv_ks, kv_vs = wr(
                 kv_k, kv_v, attn.write_tables, k_pages, v_pages,
@@ -556,6 +575,7 @@ def _attn_block(
                     out_specs=P(None, None, "tp", None),
                     check_vma=False,
                 )
+            fl = kernel(fl)
             if quant:
                 out = fl(
                     q, kv_k, kv_v, attn.block_tables, attn.q_pos0,
@@ -567,7 +587,7 @@ def _attn_block(
                     attn.lengths,
                 )
         else:
-            out = paged_attention(
+            out = kernel(paged_attention)(
                 q, kv_k, kv_v, attn.slot_matrix, positions,
                 k_scales=kv_ks, v_scales=kv_vs, scale_tp=attn.kv_tp,
                 int4_groups=attn.int4_groups or None,
@@ -629,13 +649,13 @@ def _attn_block(
             else:
                 pk = kv_k[sm].reshape(b, c, kh, hd)
                 pv = kv_v[sm].reshape(b, c, kh, hd)
-            out = ring_attention_sharded(
+            out = kernel(ring_attention_sharded)(
                 q, k, v, attn.mesh,
                 pos0=attn.q_pos0, prefix_k=pk, prefix_v=pv,
                 prefix_len=attn.q_pos0,
             )
         else:
-            out = ring_attention_sharded(q, k, v, attn.mesh)
+            out = kernel(ring_attention_sharded)(q, k, v, attn.mesh)
     else:
         kv_k, kv_v, kv_ks, kv_vs = _write_rows(
             kv_k, kv_v, kv_ks, kv_vs,
@@ -670,6 +690,7 @@ def _attn_block(
                     out_specs=P(None, None, "tp", None),
                     check_vma=False,
                 )
+            rg = kernel(rg)
             if quant:
                 out = rg(
                     q, kv_k, kv_v, attn.block_tables, attn.q_pos0,
@@ -704,6 +725,7 @@ def _attn_block(
                     out_specs=P(None, "tp", None),
                     check_vma=False,
                 )
+            ro = kernel(ro)
             if quant:
                 out = ro(
                     q[:, 0], kv_k, kv_v, attn.block_tables, attn.lengths,
@@ -717,29 +739,30 @@ def _attn_block(
             # `lengths` on a plain gather spec = per-row ragged query
             # lengths (mixed steps); None for the classic single-shape
             # dispatches whose callers slice their own valid columns
-            out = paged_attention(
+            out = kernel(paged_attention)(
                 q, kv_k, kv_v, attn.slot_matrix, positions,
                 k_scales=kv_ks, v_scales=kv_vs, scale_tp=attn.kv_tp,
                 q_lens=attn.lengths,
                 int4_groups=attn.int4_groups or None,
             )
-    if tp_overlap:
-        # decomposed psum, half 1: ring reduce-scatter back to the
-        # row-scattered residual view (the all-gather half rides the
-        # next layer segment's ring matmuls). ring_rs_matmul folds the
-        # matmul in so quantized wo keeps its int32 accumulator across
-        # the ring (bitwise tp=1 dequant epilogue).
-        from dynamo_tpu.parallel import tp_overlap as _ov
+    with jax.named_scope("attn.o"):
+        if tp_overlap:
+            # decomposed psum, half 1: ring reduce-scatter back to the
+            # row-scattered residual view (the all-gather half rides the
+            # next layer segment's ring matmuls). ring_rs_matmul folds the
+            # matmul in so quantized wo keeps its int32 accumulator across
+            # the ring (bitwise tp=1 dequant epilogue).
+            from dynamo_tpu.parallel import tp_overlap as _ov
 
-        proj = _ov.ring_rs_matmul(
-            out.reshape(b * t, h * hd), lp["wo"], tp_axis
-        )
-    else:
-        proj = mm(out.reshape(b, t, h * hd), lp["wo"])
-        if tp_axis is not None:
-            from dynamo_tpu.parallel.tp_overlap import psum_allreduce
+            proj = _ov.ring_rs_matmul(
+                out.reshape(b * t, h * hd), lp["wo"], tp_axis
+            )
+        else:
+            proj = mm(out.reshape(b, t, h * hd), lp["wo"])
+            if tp_axis is not None:
+                from dynamo_tpu.parallel.tp_overlap import psum_allreduce
 
-            proj = psum_allreduce(proj, tp_axis)
+                proj = psum_allreduce(proj, tp_axis)
     return proj, kv_k, kv_v, kv_ks, kv_vs
 
 
@@ -761,19 +784,22 @@ def _mlp_block(
         # scattered view for the residual add
         from dynamo_tpu.parallel import tp_overlap as _ov
 
-        gate, up = _ov.ring_ag_matmul(
-            x, (lp["w_gate"], lp["w_up"]), tp_axis
-        )
-        return _ov.ring_rs_matmul(
-            _ACTIVATIONS[act](gate) * up, lp["w_down"], tp_axis
-        )
-    gate = _ACTIVATIONS[act](mm(x, lp["w_gate"]))
-    up = mm(x, lp["w_up"])
-    out = mm(gate * up, lp["w_down"])
-    if tp_axis is not None:
-        from dynamo_tpu.parallel.tp_overlap import psum_allreduce
+        with jax.named_scope("mlp.gate_up"):
+            gate, up = _ov.ring_ag_matmul(
+                x, (lp["w_gate"], lp["w_up"]), tp_axis
+            )
+            hidden = _ACTIVATIONS[act](gate) * up
+        with jax.named_scope("mlp.down"):
+            return _ov.ring_rs_matmul(hidden, lp["w_down"], tp_axis)
+    with jax.named_scope("mlp.gate_up"):
+        gate = _ACTIVATIONS[act](mm(x, lp["w_gate"]))
+        hidden = gate * mm(x, lp["w_up"])
+    with jax.named_scope("mlp.down"):
+        out = mm(hidden, lp["w_down"])
+        if tp_axis is not None:
+            from dynamo_tpu.parallel.tp_overlap import psum_allreduce
 
-        out = psum_allreduce(out, tp_axis)
+            out = psum_allreduce(out, tp_axis)
     return out
 
 
@@ -841,7 +867,7 @@ def forward(
         ks=tuple(new_ks_layers) if kv.quantized else None,
         vs=tuple(new_vs_layers) if kv.quantized else None,
     )
-    x = rms_norm(
+    x = _norm(
         x, params["final_norm"], cfg.rms_norm_eps,
         weight_offset=cfg.norm_weight_offset,
     )
@@ -864,14 +890,14 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
     if tp_overlap and cfg.num_experts:
         raise ValueError("tp_overlap layer executor covers dense models")
     w_off = cfg.norm_weight_offset
-    attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, weight_offset=w_off)
+    attn_in = _norm(x, lp["attn_norm"], cfg.rms_norm_eps, weight_offset=w_off)
     attn_out, kv_k, kv_v, kv_ks, kv_vs = _attn_block(
         lp, cfg, attn_in, cos, sin, kv_k, kv_v, write_slots, attn, positions,
         kv_ks=kv_ks, kv_vs=kv_vs, tp_axis=tp_axis,
         tp_overlap=tp_overlap, bt_shape=bt_shape,
     )
     x = x + attn_out
-    mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, weight_offset=w_off)
+    mlp_in = _norm(x, lp["mlp_norm"], cfg.rms_norm_eps, weight_offset=w_off)
     if cfg.num_experts:
         from dynamo_tpu.models.moe import moe_block
 
@@ -884,6 +910,7 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
     return x, kv_k, kv_v, kv_ks, kv_vs
 
 
+@jax.named_scope("head")
 def logits(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp.ndarray:
     """Vocab projection [..., D] -> [..., V] in float32.
 

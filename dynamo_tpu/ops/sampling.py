@@ -102,6 +102,7 @@ def _shortlist_mask(scaled, top_k, top_p):
     return cand_ids.astype(jnp.int32), masked
 
 
+@jax.named_scope("sample")  # a name for profiles (models/llama.py)
 def sample_tokens(
     logits: jnp.ndarray,       # [B, V] float
     key: jax.Array,            # PRNG key
@@ -178,6 +179,7 @@ def sample_tokens(
     return ids
 
 
+@jax.named_scope("sample")
 def verify_draft_tokens(
     logits: jnp.ndarray,       # [B, T, V] float; row j is the model's
     #                            distribution for position pos0 + j + 1

@@ -414,6 +414,52 @@ def span(
     return _Span(name, cat, req, track, args)
 
 
+# the device trace's clock: `jax.profiler.TraceAnnotation`, set by
+# engine/profiler.py when it is imported (this module stays off jax);
+# None = no profiler in this process, a phase is a ring event only
+annotation = None
+
+
+class phase:
+    """The one way to mark a host phase, on both clocks: an annotation
+    named `name` carrying `attrs` on the device trace (a no-op of well
+    under a microsecond while no capture runs) and a complete event on
+    this ring when it is armed (the request's track inside a request,
+    else ``engine.phases``). `set()` adds attributes found inside the
+    body; they reach the ring only (the annotation's are fixed when it
+    opens). Names: docs/observability.md."""
+
+    __slots__ = ("_name", "_attrs", "_req", "_ann", "_t0")
+
+    def __init__(self, name: str, req: Optional[str] = None, **attrs):
+        self._name = name
+        self._attrs = attrs
+        self._req = req
+        self._ann = annotation(name, **attrs) if annotation else None
+        self._t0 = None
+
+    def __enter__(self) -> "phase":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if _enabled:
+            self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        self._attrs.update(attrs)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._t0 is not None:
+            req = self._req or current_request()
+            complete(
+                self._name, self._t0, time.perf_counter(), cat="phase",
+                req=req, track=None if req else "engine.phases",
+                **self._attrs,
+            )
+
+
 class _Span:
     __slots__ = ("_name", "_cat", "_req", "_track", "_args", "_t0")
 

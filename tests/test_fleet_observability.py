@@ -196,9 +196,10 @@ async def test_ingress_binds_traceparent():
 
 
 async def test_engine_telemetry_gauges_cpu():
-    """KV pool gauges, slot occupancy, compile counters and the
-    device/host split must render on the CPU backend (HBM gauges are
-    absent there — memory_stats() returns None)."""
+    """KV pool gauges, slot occupancy, the compile counters (with their
+    split into real compiles and cache reads) and the preemption count
+    must render on the CPU backend (HBM gauges are absent there —
+    memory_stats() returns None)."""
     engine = make_engine()
     tokens, _, _ = await collect(engine, greedy_request([5, 6, 7], max_tokens=3))
     assert len(tokens) == 3
@@ -211,7 +212,13 @@ async def test_engine_telemetry_gauges_cpu():
     # compile listener: the serve jitted at least one step family
     assert m["compile_events"] >= 1
     assert m["compile_time_s"] > 0
-    assert m["step_device_s"] >= 0
+    # the split of compile_events: nothing is served from a persistent
+    # cache on the CPU backend, so every event is a real compile
+    assert m["backend_compiles"] == m["compile_events"] - m["persistent_cache_hits"]
+    assert m["cache_read_s"] >= 0
+    assert m["preemptions_total"] == 0
+    # dispatch-call walls are not device time and are no longer exported
+    assert "step_device_s" not in m and "step_stall_s" not in m
     # pool accounting consistency: used + cached + free == usable pages
     assert (
         m["kv_pages_used"] + m["kv_pages_cached"] + m["kv_pages_free"]

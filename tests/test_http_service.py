@@ -296,7 +296,7 @@ async def test_debug_trace_request_span():
             # the preprocessor span joined the same request id via the
             # handler's contextvar binding
             assert any(
-                e["name"] == "preprocess"
+                e["name"] == "fe.preprocess"
                 and e["args"].get("request_id") == "trace-me-1"
                 for e in evs
             )

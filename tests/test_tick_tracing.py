@@ -1,0 +1,237 @@
+"""The engine's tick on the profiler's clock (utils/tracing.py `phase`,
+docs/observability.md "Host phases"): every `eng.*` phase shows up on the
+host plane of a `jax.profiler` capture beside the four dispatch
+annotations, the flight digests carry the tick's host-side columns, the
+engine counts its preemptions, and a finish summary splits its TTFT."""
+
+from __future__ import annotations
+
+import asyncio
+import glob
+import os
+
+import jax
+import pytest
+
+from dynamo_tpu.engine import flight_recorder as flightmod
+from dynamo_tpu.engine import profiler
+from dynamo_tpu.utils import tracing
+
+from .test_engine import collect, greedy_request, make_engine
+
+LOOP_PHASES = {"eng.tick", "eng.admit", "eng.prefill.build",
+               "eng.decode.build", "eng.fetch", "eng.emit", "eng.wait"}
+WORKER_PHASES = {"eng.lock", "eng.upload", "eng.enqueue", "eng.carry"}
+DISPATCH = ("prefill", "decode", "mixed", "spec_verify")
+REPETITIVE = [5, 17, 42, 9] * 6
+
+
+async def _capture(engine, tmp_path, prompts, max_tokens=24):
+    """Serve `prompts` under a profiler capture (after a warm-up that
+    compiles outside it); returns {line index: [(name, start, end, stats)]}
+    of the host plane."""
+    await collect(engine, greedy_request(prompts[0], max_tokens=max_tokens))
+    await asyncio.sleep(0.2)  # the pipeline's overshoot dispatch drains
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    async def later(p):  # prompts that arrive beside running decodes
+        await asyncio.sleep(0.1)
+        return await collect(engine, greedy_request(p, max_tokens=max_tokens))
+    try:
+        await asyncio.gather(
+            collect(engine, greedy_request(prompts[0], max_tokens=max_tokens)),
+            *(later(p) for p in prompts[1:]))
+        await asyncio.sleep(0.05)  # the loop goes idle: eng.wait
+        await collect(engine, greedy_request(prompts[-1], max_tokens=4))
+        await asyncio.sleep(0.2)  # no dispatch open when the capture stops
+    finally:
+        jax.profiler.stop_trace()
+    await engine.close()
+    files = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert files, "the capture wrote no xplane"
+    data = jax.profiler.ProfileData.from_file(files[0])
+    plane = next(p for p in data.planes if p.name == "/host:CPU")
+    lines = {}
+    for i, line in enumerate(plane.lines):
+        evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                dict(e.stats)) for e in line.events
+               if e.name.startswith("eng.") or e.name in DISPATCH]
+        if evs:
+            lines[i] = evs
+    return lines
+
+
+def _inside(child, parents) -> bool:
+    return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+
+
+async def test_every_phase_is_on_the_host_plane(tmp_path):
+    lines = await _capture(
+        make_engine(), tmp_path, [list(range(3, 40)), [9, 8, 7, 6]])
+    names = {e[0] for evs in lines.values() for e in evs}
+    assert LOOP_PHASES | WORKER_PHASES <= names, sorted(names)
+    # the dispatch annotations keep their exact names (the accepted
+    # reducer matches them) and carry no attribute
+    assert {"prefill", "decode"} <= names
+    for evs in lines.values():
+        assert all(not e[3] for e in evs if e[0] in DISPATCH)
+    # one thread runs the loop: every tick and the loop's own phases
+    loop = [evs for evs in lines.values()
+            if any(e[0] == "eng.tick" for e in evs)]
+    assert len(loop) == 1
+    ticks = [e for e in loop[0] if e[0] == "eng.tick"]
+    assert len(ticks) >= 3
+    first, last = min(t[1] for t in ticks), max(t[2] for t in ticks)
+    for e in loop[0]:
+        # the loop task's children lie inside a tick (a first-token
+        # fetch is a task of its own and may straddle two; the tick open
+        # when the capture starts or stops is not recorded)
+        if e[0] in ("eng.admit", "eng.prefill.build", "eng.decode.build",
+                    "eng.wait") and first <= e[1] and e[2] <= last:
+            assert _inside(e, ticks), e
+    assert all(e[0] in LOOP_PHASES for e in loop[0]
+               if e[0].startswith("eng.") and e[0] != "eng.prefill.build")
+    # workers: lock, upload and the jit call inside a dispatch annotation
+    for evs in lines.values():
+        spans = [e for e in evs if e[0] in DISPATCH]
+        for e in evs:
+            if e[0] in WORKER_PHASES:
+                assert _inside(e, spans), e
+    # a phase says where the time went and carries nothing else: no
+    # attribute that no reader wants
+    assert all(not e[3] for evs in lines.values() for e in evs)
+
+
+@pytest.mark.parametrize("kind,config", [
+    ("mixed", dict(mixed_batching=True, mixed_step_tokens=64)),
+    ("spec_verify", dict(spec_decode=True)),
+])
+async def test_other_dispatch_kinds_keep_their_annotation(
+        tmp_path, kind, config):
+    lines = await _capture(
+        make_engine(max_model_len=256, **config), tmp_path,
+        [REPETITIVE, REPETITIVE[2:] + [7, 7] + REPETITIVE, REPETITIVE[1:] * 2],
+        max_tokens=60)
+    names = {e[0] for evs in lines.values() for e in evs}
+    assert kind in names, sorted(names)
+    # its jit call is an eng.enqueue inside the annotation
+    for evs in lines.values():
+        spans = [e for e in evs if e[0] == kind]
+        if spans:
+            assert any(e[0] == "eng.enqueue" and _inside(e, spans)
+                       for e in evs)
+
+
+def test_phase_feeds_the_ring_when_armed():
+    # one helper: the engine's name for it is the frontend's object, and
+    # importing the profiler module put its annotations on it
+    assert profiler.phase is tracing.phase
+    assert tracing.annotation is jax.profiler.TraceAnnotation
+    tracing.clear()
+    tracing.enable()
+    try:
+        with profiler.phase("eng.admit") as ph:
+            ph.set(admitted=2)
+        with tracing.phase("fe.stream", req="r-1"):
+            pass
+        evs = {e["name"]: e for e in tracing.export()["traceEvents"]
+               if e["ph"] == "X"}
+    finally:
+        tracing.disable()
+        tracing.clear()
+    assert evs["eng.admit"]["args"] == {"admitted": 2}
+    assert evs["fe.stream"]["args"]["request_id"] == "r-1"
+    # off: nothing recorded, nothing raised
+    with profiler.phase("eng.admit"), tracing.phase("fe.stream"):
+        pass
+    assert not [e for e in tracing.export()["traceEvents"] if e["ph"] != "M"]
+
+
+async def test_digest_columns():
+    engine = make_engine()
+    await asyncio.gather(
+        collect(engine, greedy_request(list(range(3, 43)), max_tokens=20)),
+        collect(engine, greedy_request([9, 8, 7], max_tokens=20)))
+    rows = engine.flight.snapshot()
+    await engine.close()
+    assert flightmod.FIELDS[-4:] == (
+        "build_s", "emit_s", "starved", "preempted")
+    assert flightmod.FIELDS[:12] == (  # the accepted columns, in place
+        "ts_unix", "step", "kind", "rows", "tokens", "wall_s", "budget_fill",
+        "queue_depth", "slots_active", "kv_frac", "degrade_mask", "outlier")
+    by = {}
+    for r in rows:
+        by.setdefault(r["kind"], []).append(r)
+    assert all(r["build_s"] > 0 and r["starved"] in (0, 1)
+               for r in by["decode"] + by["prefill"])
+    landed = by.get("sync", []) + by.get("overlap", [])
+    assert landed and all(r["emit_s"] > 0 for r in landed)
+    assert all(r["emit_s"] == 0 for r in by["decode"])
+    assert all(r["preempted"] == 0 for r in rows)
+
+
+def test_amend_fills_the_newest_digest_of_its_kind():
+    """A sync row is booked before its tokens land (its pool and queue
+    columns are the step's, not the landing's) and gets `emit_s` after;
+    a dispatch worker may have booked rows in between."""
+    rec = flightmod.FlightRecorder(capacity=8)
+    rec.amend("sync", emit_s=1.0)  # nothing to amend: no-op
+    rec.record("sync", 0.01, rows=3)
+    rec.record("sync", 0.02, rows=4)
+    rec.record("decode", 0.1, rows=4, build_s=0.5)
+    rec.amend("sync", emit_s=0.25)
+    rows = rec.snapshot()
+    assert [r["emit_s"] for r in rows] == [0.0, 0.25, 0.0]
+    assert rows[2]["build_s"] == 0.5 and rec.count == 3
+    for _ in range(7):  # wrapped around: the sync rows are gone
+        rec.record("decode", 0.1)
+    rec.amend("sync", emit_s=9.0)
+    assert all(r["emit_s"] == 0.0 for r in rec.snapshot())
+
+
+async def test_preemptions_are_counted():
+    # 15 usable pages, two long sequences: someone is preempted
+    engine = make_engine(num_pages=16, max_model_len=96, max_batch_size=2)
+    prompts = [list(range(20, 52)), list(range(60, 92))]
+    await asyncio.gather(
+        *(collect(engine, greedy_request(p, max_tokens=24)) for p in prompts))
+    n = engine.metrics()["preemptions_total"]
+    rows = engine.flight.snapshot()
+    await engine.close()
+    assert n >= 1
+    assert max(r["preempted"] for r in rows) == n
+    assert [r["preempted"] for r in rows] == sorted(
+        r["preempted"] for r in rows)
+
+
+async def test_serialized_engine_starves_every_decode_dispatch():
+    """Without the step pipeline a dispatch is fetched before the next is
+    built, so under decode-only traffic each decode program is enqueued
+    on a drained device."""
+    engine = make_engine(step_pipeline=False)
+    await collect(engine, greedy_request([5, 6, 7], max_tokens=40))
+    decodes = [r for r in engine.flight.snapshot() if r["kind"] == "decode"]
+    await engine.close()
+    # the first follows the prefill dispatch, which may still be running
+    assert len(decodes) >= 3
+    assert all(r["starved"] == 1 for r in decodes[1:])
+
+
+async def test_summary_splits_its_ttft():
+    engine = make_engine(max_batch_size=2)
+    got = []
+    engine.subscribe_requests(got.append)
+    prompts = [list(range(3, 73)), [9, 8, 7], list(range(40, 80))]
+    await asyncio.gather(
+        *(collect(engine, greedy_request(p, max_tokens=6)) for p in prompts))
+    await engine.close()
+    assert len(got) == 3
+    for s in got:
+        assert s["ttft_s"] == pytest.approx(
+            s["queue_wait_s"] + s["prefill_s"] + s["first_emit_s"], abs=1e-9)
+        assert s["prefill_s"] > 0 and s["first_emit_s"] >= 0
+        # chunks of at most 32 tokens
+        assert s["prefill_chunks"] == -(-s["prompt_tokens"] // 32)
