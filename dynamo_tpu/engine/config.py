@@ -125,8 +125,9 @@ class EngineConfig:
     mixed_decode_priority: bool = True
     # zero-stall step pipeline: build and dispatch step N+1 while step
     # N's sampled tokens are still in flight to the host. Mixed steps'
-    # q_len=1 decode rows read their input token from the device-
-    # resident carry vector (no host round trip), so a mixed window can
+    # q_len=1 decode rows read their input token from the carry the
+    # step programs keep on the device (no host round trip; a dispatch
+    # is host arrays plus one launch), so a mixed window can
     # launch behind an in-flight decode or mixed dispatch instead of
     # holding a tick; spec-eligible rows whose host history is stale
     # shed their drafts and still advance at q_len=1 (drafts resume

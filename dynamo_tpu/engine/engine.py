@@ -26,11 +26,12 @@ Decode — and, with `EngineConfig.step_pipeline` (default), mixed
 prefill+decode steps — are **pipelined**: dispatch N+1 is enqueued in a
 worker thread (using the on-device sampled tokens of dispatch N as carry
 — no host round trip) while N's tokens are fetched for emission, so host
-work overlaps device compute. Slow-changing dispatch inputs (block
-tables, sampling/penalty params) are device-resident, scatter-updated
-only on admit/growth, so the steady-state hot path uploads one fused
-[positions, active] array per dispatch (docs/architecture.md "Step
-pipeline").
+work overlaps device compute. A dispatch is host arrays plus ONE launch:
+what decode keeps on the device between dispatches (carry, sampling key,
+penalty counts: `StepState`) is taken and returned by the step programs
+themselves, and the slow-changing inputs (block tables, sampling/penalty
+params) ride from host mirrors in each dispatch's fused uploads
+(docs/architecture.md "Step pipeline").
 Overshoot tokens of sequences that finished in N are discarded at sync;
 their trailing writes land in pages that are never hash-registered, so the
 prefix cache stays sound.
@@ -52,7 +53,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import AsyncIterator, Callable, Optional
+from typing import AsyncIterator, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -100,13 +101,42 @@ from dynamo_tpu.utils import (
 log = logging.getLogger("dynamo_tpu.engine")
 
 
-def _pad_pow2(vals: list) -> list:
-    """Pad an index/value vector to a power of two by REPEATING the last
-    entry (same slot, same value — idempotent under scatter): every
-    distinct length is a distinct XLA program, and unpadded each new
-    length costs a fresh compile mid-serve."""
-    m = 1 << (len(vals) - 1).bit_length()
-    return vals + [vals[-1]] * (m - len(vals))
+class StepState(NamedTuple):
+    """What the decode path keeps on the device between dispatches. The
+    step programs own it the way they own `kv`: every program takes it
+    whole (donated), slices to its own static width inside, and returns
+    its successor, so a dispatch is host arrays plus ONE launch. Rows a
+    program does not advance keep their values, so a program that ends
+    late can never clobber a row that another one armed."""
+
+    toks: jax.Array  # [B] i32: each slot's next input token (the carry)
+    lps: jax.Array   # [B] f32: its logprob
+    tid: jax.Array   # [B, TOP_LOGPROBS_MAX] i32: its alternatives
+    tlp: jax.Array   # [B, TOP_LOGPROBS_MAX] f32
+    key: jax.Array   # sampling key; a program splits it and returns the rest
+    # [B, V] int8 occurrence counts: rides only into the penalty / seeded
+    # programs (allocated on first use); None for every other program
+    counts: Optional[jax.Array] = None
+
+    def split(self) -> tuple:
+        """(state holding the successor key, this program's key): the
+        stream `key, sub = jax.random.split(key)` draws on the host."""
+        ks = jax.random.split(self.key)
+        return self._replace(key=ks[0]), ks[1]
+
+    def arm(self, slots, S) -> "StepState":
+        """Write sampled rows `S` = (toks, lps[, tid, tlp]) into the
+        carry at `slots` [n]; a slot >= B drops the row."""
+        new = self._replace(
+            toks=self.toks.at[slots].set(S[0], mode="drop"),
+            lps=self.lps.at[slots].set(S[1], mode="drop"),
+        )
+        if len(S) == 4:
+            new = new._replace(
+                tid=self.tid.at[slots].set(S[2], mode="drop"),
+                tlp=self.tlp.at[slots].set(S[3], mode="drop"),
+            )
+        return new
 
 
 class _Dispatch:
@@ -139,14 +169,13 @@ class _DecodeBuild:
     JaxEngine._maybe_dispatch_decode)."""
 
     __slots__ = ("positions", "tables", "act", "temp", "topk", "topp",
-                 "pos_act", "dirty", "use_ext", "want_lps",
-                 "want_tops", "overrides", "active", "steps", "all_greedy",
+                 "rows_i", "rows_f", "use_ext", "want_lps",
+                 "want_tops", "active", "steps", "all_greedy",
                  "width", "spec", "tokens", "draft", "dlen", "pos0",
                  "build_s")
 
     def __init__(self, **kw):
         self.spec = False  # speculative verify build (host-built tokens)
-        self.dirty = None  # pending device-state scatter snapshot
         self.build_s = 0.0  # host time of the build (the digest's column)
         for k, v in kw.items():
             setattr(self, k, v)
@@ -600,18 +629,12 @@ class JaxEngine:
         self.slots: list[Optional[Sequence]] = [None] * config.max_batch_size
         self._prefilling: deque[Sequence] = deque()
         self._inflight: Optional[_Dispatch] = None
-        self._carry_toks = jnp.zeros(config.max_batch_size, jnp.int32)
-        self._carry_lps = jnp.zeros(config.max_batch_size, jnp.float32)
-        # top-logprob alternatives carry (TOP_LOGPROBS_MAX wide)
-        self._carry_tid = jnp.zeros(
-            (config.max_batch_size, TOP_LOGPROBS_MAX), jnp.int32
-        )
-        self._carry_tlp = jnp.zeros(
-            (config.max_batch_size, TOP_LOGPROBS_MAX), jnp.float32
-        )
-        # slot -> first-token carry override: (device token vector, row)
-        # from a batched prefill dispatch, or a host int (disagg inject)
-        self._overrides: dict[int, object] = {}
+        # slot -> host-known carry override (a disagg inject's first
+        # token, the re-arm after a speculative / mixed sync): it enters
+        # the next decode program as a value and a mask column of the
+        # fused upload. First tokens sampled on the device need none:
+        # the prefill program writes them into the carry itself.
+        self._overrides: dict[int, int] = {}
         # device-carry validity: _carry_ok[slot] means the device carry
         # vector row holds the slot's CURRENT input token (set after a
         # decode dispatch updates it, or after a mixed step's in-jit
@@ -621,39 +644,43 @@ class JaxEngine:
         # (prefill first tokens, spec verify syncs, disagg injects) and
         # on preemption/finish (the slot may be reused).
         self._carry_ok = np.zeros(config.max_batch_size, bool)
-        # device-resident slow-changing dispatch inputs (the step
-        # pipeline's second leg): block tables and sampling/penalty
-        # params live on device and are scatter-updated only when a
-        # slot's state changes (admit / page growth) instead of being
-        # re-uploaded with every dispatch. Host mirrors stay
-        # authoritative on the loop thread; `_dirty_slots` collects
-        # changed slots, each dispatch BUILD snapshots them
-        # (`_snap_dirty`) and the dispatch worker applies the scatter
-        # under _kv_lock (`_flush_dev_state_locked`) — pow2-padded index
-        # vectors, same contract as the override batching below. Layout:
-        # samp_f = [temp, top_p, freq_pen, pres_pen, rep_pen],
-        # samp_i = [top_k, seed]. Rows of released slots keep garbage
-        # (inactive rows are masked / write the trash page).
+        # slow-changing per-slot dispatch inputs: block tables and
+        # sampling/penalty params. The loop thread keeps these host
+        # mirrors current (`_mark_slot_state`: admit / page growth) and
+        # every dispatch BUILD snapshots the rows it needs into its
+        # fused upload (a few KB) — nothing of them lives on the device
+        # between dispatches. Layout: samp_f = [temp, top_p, freq_pen,
+        # pres_pen, rep_pen], rows_i = [top_k, seed, block table]. Rows
+        # of released slots keep garbage (inactive rows are masked /
+        # write the trash page).
         _B = config.max_batch_size
         _W = config.max_pages_per_seq
-        self._host_tables = np.zeros((_B, _W), np.int32)
+        self._host_rows_i = np.zeros((_B, 2 + _W), np.int32)
+        self._host_samp_i = self._host_rows_i[:, :2]  # views of one
+        self._host_tables = self._host_rows_i[:, 2:]  # upload block
+        self._host_samp_i[:, 1] = -1  # seed sentinel
         self._host_samp_f = np.zeros((_B, 5), np.float32)
         self._host_samp_f[:, 1] = 1.0  # top_p
         self._host_samp_f[:, 4] = 1.0  # rep_pen
-        self._host_samp_i = np.zeros((_B, 2), np.int32)
-        self._host_samp_i[:, 1] = -1  # seed sentinel
-        self._dev_tables = jnp.zeros((_B, _W), jnp.int32)
-        self._dev_samp_f = jnp.asarray(self._host_samp_f)
-        self._dev_samp_i = jnp.asarray(self._host_samp_i)
-        self._dirty_slots: set[int] = set()
-        # serializes the donated self.kv (and self._key) between the
+        # serializes the donated self.kv and self._state between the
         # decode worker thread and prefill dispatches the event-loop
         # thread may run concurrently via the public prefill_only path
         self._kv_lock = threading.Lock()
         self._wake = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         self._closed = False
-        self._key = jax.random.PRNGKey(config.seed ^ 0x5EED)
+        # replicated over the mesh, as the step programs return it
+        self._state_sharding = meshmod.replicated(self.mesh)
+        self._state = jax.device_put(
+            StepState(
+                toks=np.zeros(_B, np.int32),
+                lps=np.zeros(_B, np.float32),
+                tid=np.zeros((_B, TOP_LOGPROBS_MAX), np.int32),
+                tlp=np.zeros((_B, TOP_LOGPROBS_MAX), np.float32),
+                key=jax.random.PRNGKey(config.seed ^ 0x5EED),
+            ),
+            self._state_sharding,
+        )
         self._step_count = 0
         # engine-side phase accounting: cumulative wall spent inside the
         # (device-serializing) prefill/decode dispatch calls and the
@@ -846,47 +873,39 @@ class JaxEngine:
 
         # one jitted step; jax retraces per (B, T, C) shape family (and
         # per all_greedy variant — static so the pure-greedy batch skips
-        # the sampling shortlist entirely)
+        # the sampling shortlist entirely). Every step program takes
+        # (params, kv, state, ...) and donates kv and the state; a state
+        # that holds counts keys the penalty / seeded variant
         self._step_fn = jax.jit(
-            self._model_step, donate_argnums=(1,),
-            static_argnums=(15, 16, 24), static_argnames=("sp_cached",),
-        )
-        # prefill step on the penalty/seeded path (separate trace: counts
-        # threaded through, donated so the scatter updates in place)
-        self._step_ext_fn = jax.jit(
-            self._model_step, donate_argnums=(1, 17),
-            static_argnums=(15, 16, 24), static_argnames=("sp_cached",),
+            self._model_step, donate_argnums=(1, 2),
+            static_argnums=(13, 14, 15), static_argnames=("sp_cached",),
         )
         # multi-step decode: `decode_steps` iterations per dispatch;
         # want_lps static so the common no-logprobs batch skips the
         # per-step logsumexp over [B, V]
         self._decode_fn = jax.jit(
-            self._decode_multi, donate_argnums=(1,), static_argnums=(9, 10, 15)
-        )
-        # decode with penalties / per-request seeds (rare path; counts
-        # [B, V] int8 donated through the scan)
-        self._decode_ext_fn = jax.jit(
-            self._decode_multi, donate_argnums=(1, 11), static_argnums=(9, 10, 15)
+            self._decode_multi, donate_argnums=(1, 2),
+            static_argnums=(5, 6, 7),
         )
         # speculative verify: one multi-query step over [carry, drafts]
         # with rejection-sampling acceptance (all_greedy static)
         self._spec_fn = jax.jit(
-            self._spec_verify_step, donate_argnums=(1,), static_argnums=(12,)
+            self._spec_verify_step, donate_argnums=(1, 2),
+            static_argnums=(12,),
         )
         # mixed prefill+decode step: decode rows (q_len=1 — or ragged
         # 1+k VERIFY windows when spec composes) + prefill chunk rows in
         # ONE [n, T] ragged dispatch; every row samples at its last
         # valid column (all_greedy + the pallas table width static). The
-        # carry vector (argnum 7) is donated: the step scatters decode
-        # rows' samples into it in-jit, which is what lets a pipelined
-        # build read the next input token without a host round trip.
+        # step scatters decode rows' samples into the state's carry
+        # in-jit, which is what lets a pipelined build read the next
+        # input token without a host round trip.
         self._mixed_fn = jax.jit(
-            self._mixed_model_step, donate_argnums=(1, 7),
-            static_argnums=(11, 12),
+            self._mixed_model_step, donate_argnums=(1, 2),
+            static_argnums=(8, 9),
         )
-        # occurrence counts for penalty sampling, allocated on first use
-        # (B x V int8; ~33 MB at B=256, V=128k)
-        self._counts = None
+        # occurrence counts for penalty sampling (`_state.counts`) are
+        # allocated on first use (B x V int8; ~33 MB at B=256, V=128k)
         self._reset_count_fn = jax.jit(
             self._reset_and_count, donate_argnums=(0,), static_argnums=(3,)
         )
@@ -1375,20 +1394,28 @@ class JaxEngine:
             attn, embeds=embeds, embeds_mask=embeds_mask,
         )
 
-    def _model_step(self, params, kv, tokens, positions, write_slots, slot_matrix,
-                    last_idx, temp, topk, topp, key, wtables=None,
+    def _model_step(self, params, kv, state, tokens, positions, write_slots,
+                    slot_matrix, rows_i, rows_f, wtables=None,
                     btables=None, embeds=None, embeds_mask=None,
-                    all_greedy=False, want_lps=False, counts=None,
-                    slot_rows=None, fp=None, prp=None, rp=None,
-                    final_row=None, seeds=None, want_tops=False,
+                    all_greedy=False, want_lps=False, want_tops=False,
                     sp_cached=False):
-        """One prefill step. Returns ((sampled [n], logprobs [n]), kv) —
-        plus updated counts when the penalty path is active (counts
-        gathered per slot row, the final-chunk rows' sampled token
-        bumped). `want_lps` (static) gates the logsumexp; when off the
-        logprob vector is zeros."""
+        """One prefill step. Returns ((sampled [n], logprobs [n][, top
+        ids, top logprobs]), kv, state). Per-row inputs ride in two fused
+        uploads: `rows_i` [n, 5] = [last_idx, top_k, slot (-1: none),
+        final chunk, seed] and `rows_f` [n, 5] = [temp, top_p, freq_pen,
+        pres_pen, rep_pen]. A row whose chunk is final has sampled its
+        first token: the step writes it into the state's carry at the
+        row's slot, where the next decode program reads it. A state that
+        holds counts switches on the penalty path (counts gathered per
+        slot row, the final-chunk rows' sampled token bumped).
+        `want_lps` (static) gates the logsumexp; when off the logprob
+        vector is zeros."""
+        last_idx, topk, slot_rows = rows_i[:, 0], rows_i[:, 1], rows_i[:, 2]
+        final_row, seeds = rows_i[:, 3].astype(bool), rows_i[:, 4]
+        temp, topp = rows_f[:, 0], rows_f[:, 1]
+        state, key = state.split()
 
-        def _sample(lg, key, **kw):
+        def _sample(lg, **kw):
             if want_lps:
                 return sample_tokens(
                     lg, key, temp, topk, topp, all_greedy=all_greedy,
@@ -1403,23 +1430,61 @@ class JaxEngine:
             hidden, kv = self._pp_forward(
                 params, kv, tokens, positions, write_slots, slot_matrix
             )
-            last_h = jnp.take_along_axis(
-                hidden, last_idx[:, None, None].astype(jnp.int32), axis=1
-            )[:, 0]
-            lg = llama.logits(params, self.model_cfg, last_h)
-            return _sample(lg, key), kv
+        else:
+            hidden, kv = self._forward(
+                params, kv, tokens, positions, write_slots,
+                self._prefill_attn(
+                    slot_matrix, wtables, btables, positions, last_idx,
+                    sp_cached,
+                ),
+                embeds=embeds, embeds_mask=embeds_mask,
+            )
+        last_h = jnp.take_along_axis(
+            hidden, last_idx[:, None, None].astype(jnp.int32), axis=1
+        )[:, 0]  # [B, D]
+        lg = llama.logits(params, self.model_cfg, last_h)
+        if state.counts is not None:
+            # penalties/seeds on the first sampled token: counts rows
+            # live per SLOT; gather this group's rows
+            counts, rows = state.counts, jnp.maximum(slot_rows, 0)
+            S = _sample(
+                lg, counts=counts[rows],
+                freq_pen=rows_f[:, 2], pres_pen=rows_f[:, 3],
+                rep_pen=rows_f[:, 4],
+                seeds=seeds, positions=last_idx + positions[:, 0],
+            )
+            # bump only final-chunk rows (others' samples are garbage);
+            # scatter back through the slot mapping
+            cur = counts[rows, S[0]].astype(jnp.int32)
+            inc = jnp.where(final_row, 1, 0)
+            state = state._replace(counts=counts.at[rows, S[0]].set(
+                jnp.minimum(cur + inc, 127).astype(jnp.int8)
+            ))
+        else:
+            S = _sample(lg)
+        # padding rows, mid-prompt chunks and prefill_only rows (no
+        # slot) scatter out of range and drop
+        state = state.arm(
+            jnp.where(final_row & (slot_rows >= 0), slot_rows,
+                      state.toks.shape[0]), S,
+        )
+        return S, kv, self._pin_state(state)
+
+    def _prefill_attn(self, slot_matrix, wtables, btables, positions,
+                      last_idx, sp_cached):
+        """The prefill step's attention spec, by backend."""
         if wtables is not None:
             # pallas prefill: page-scatter write + flash attention over
             # the streamed pages (the XLA row scatter serializes; the
             # gather oracle materializes [B,K,G,T,C] f32 logits/probs)
-            attn = llama.AttnSpec.gather(
+            return llama.AttnSpec.gather(
                 slot_matrix, write_tables=wtables, page_size=self.page_size,
                 interpret=self._attn_interpret, mesh=self._attn_mesh,
                 block_tables=btables, q_pos0=positions[:, 0],
                 lengths=last_idx + 1, kv_tp=self.config.mesh.tp,
                 int4_groups=self._kv_int4_groups,
             )
-        elif self._sp:
+        if self._sp:
             # long-context mode: ring attention over sp; on a prefix-
             # cache hit the chunk is the uncached tail and the cached
             # pool rows join as extra softmax blocks. `sp_cached` is the
@@ -1427,7 +1492,7 @@ class JaxEngine:
             # prefix (0 = none): the gather below is sliced to it, so a
             # short cached prefix on a 128k-context config never
             # materializes the full slot matrix
-            attn = llama.AttnSpec.ring(
+            return llama.AttnSpec.ring(
                 slot_matrix, self.mesh, page_size=self.page_size,
                 q_pos0=(
                     positions[:, 0] if sp_cached else None
@@ -1436,86 +1501,71 @@ class JaxEngine:
                 kv_tp=self.config.mesh.tp,
                 int4_groups=self._kv_int4_groups,
             )
-        else:
-            attn = llama.AttnSpec.gather(
-                slot_matrix, page_size=self.page_size,
-                kv_tp=self.config.mesh.tp,
-                int4_groups=self._kv_int4_groups,
-            )
-        hidden, kv = self._forward(
-            params, kv, tokens, positions, write_slots, attn,
-            embeds=embeds, embeds_mask=embeds_mask,
+        return llama.AttnSpec.gather(
+            slot_matrix, page_size=self.page_size,
+            kv_tp=self.config.mesh.tp,
+            int4_groups=self._kv_int4_groups,
         )
-        last_h = jnp.take_along_axis(
-            hidden, last_idx[:, None, None].astype(jnp.int32), axis=1
-        )[:, 0]  # [B, D]
-        lg = llama.logits(params, self.model_cfg, last_h)
-        if counts is not None:
-            # penalties/seeds on the first sampled token: counts rows
-            # live per SLOT; gather this group's rows
-            row_counts = counts[slot_rows]
-            S = _sample(
-                lg, key, counts=row_counts,
-                freq_pen=fp, pres_pen=prp, rep_pen=rp,
-                seeds=seeds, positions=last_idx + positions[:, 0],
-            )
-            toks = S[0]
-            # bump only final-chunk rows (others' samples are garbage);
-            # scatter back through the slot mapping
-            cur = counts[slot_rows, toks].astype(jnp.int32)
-            inc = jnp.where(final_row, 1, 0)
-            counts = counts.at[slot_rows, toks].set(
-                jnp.minimum(cur + inc, 127).astype(jnp.int8)
-            )
-            return S, kv, counts
-        return _sample(lg, key), kv
 
-    def _decode_multi(self, params, kv, tokens, carry_lps, pos_act,
-                      block_tables, samp_f, samp_i, key,
-                      all_greedy=False, want_lps=False, counts=None,
-                      fresh=None, carry_tid=None, carry_tlp=None,
-                      want_tops=False):
+    def _pin_state(self, state: StepState) -> StepState:
+        """A step program returns the state replicated over the mesh, as
+        it took it: the next program's input sharding never changes."""
+        if self.mesh.size == 1:
+            return state
+        return jax.lax.with_sharding_constraint(state, self._state_sharding)
+
+    def _decode_multi(self, params, kv, state, rows_i, rows_f,
+                      all_greedy=False, want_lps=False, want_tops=False):
         """`decode_steps` decode iterations in ONE dispatch (lax.scan with
         on-device token feedback + slot computation) — the antidote to
         per-token host round trips, which dominate wall clock when the
         device is remote or fast. Returns ((tokens [K+1, B],
-        logprobs [K+1, B]), kv) — row 0 is the input carry — plus updated
-        counts on the penalty path.
+        logprobs [K+1, B]), kv, state) — row 0 is the input carry.
 
-        Inputs follow the step pipeline's H2D split: `pos_act` [B, 2] =
-        [positions, active] is the ONE fused per-dispatch upload;
-        `block_tables`, `samp_f` = [temp, top_p, freq_pen, pres_pen,
-        rep_pen] and `samp_i` = [top_k, seed] are the persistent
-        device-resident arrays (scatter-updated on admit/growth only).
+        The dispatch is two fused uploads of the program's width `w` and
+        this one launch: `rows_i` [w, 6 + W] = [position, active,
+        override value, override mask, top_k, seed, block table] and
+        `rows_f` [w, 5] = [temp, top_p, freq_pen, pres_pen, rep_pen],
+        the host mirrors' rows as the build snapshot them. The carry
+        (input tokens, their logprobs and alternatives), the sampling
+        key and the counts are the full-length `state`: the program
+        slices it to `w`, takes a host-known token where the override
+        mask is set (a disagg inject, the re-arm after a speculative or
+        mixed sync), and returns the state with the ACTIVE rows' newest
+        sample; every other row keeps what it held.
 
-        `counts` switches on the penalty/seeded sampling path: carry
-        tokens of `fresh` rows (prefill/disagg overrides never counted
+        A state that holds counts switches on the penalty/seeded
+        sampling path: override tokens (sampled remotely, never counted
         before) are bumped first, then each step's sampled token."""
-        positions = pos_act[:, 0]
-        active = pos_act[:, 1].astype(bool)
-        temp, topp = samp_f[:, 0], samp_f[:, 1]
-        fp, prp, rp = samp_f[:, 2], samp_f[:, 3], samp_f[:, 4]
-        topk, seeds = samp_i[:, 0], samp_i[:, 1]
+        w = rows_i.shape[0]
+        positions = rows_i[:, 0]
+        active = rows_i[:, 1].astype(bool)
+        ovr = rows_i[:, 3].astype(bool)
+        topk, seeds = rows_i[:, 4], rows_i[:, 5]
+        block_tables = rows_i[:, 6:]
+        temp, topp = rows_f[:, 0], rows_f[:, 1]
+        fp, prp, rp = rows_f[:, 2], rows_f[:, 3], rows_f[:, 4]
+        state, key = state.split()
+        tokens = jnp.where(ovr, rows_i[:, 2], state.toks[:w])
+        # a host-known token has no local logprob; NaN -> emitted as None
+        carry_lps = jnp.where(ovr, jnp.nan, state.lps[:w])
         s = self.page_size
-        b, w = block_tables.shape
+        w_pages = block_tables.shape[1]
         smat = None
         if not self._attn_pallas:
             smat = (
                 block_tables[:, :, None] * s + jnp.arange(s, dtype=jnp.int32)
-            ).reshape(b, -1)
+            ).reshape(w, -1)
 
+        counts = state.counts
         use_pen = counts is not None
         if use_pen:
-            # `fresh` rows carry a token never counted before (disagg
-            # injects; locally-prefilled first tokens were bumped by the
-            # prefill ext step already and are NOT fresh)
-            counts = bump_counts(counts, tokens, active & fresh)
+            # locally-prefilled first tokens were bumped by the prefill
+            # step already; an override's token never was
+            counts = bump_counts(counts, tokens, active & ovr)
 
         def body(carry, _):
-            if use_pen:
-                tokens, positions, kv, key, counts = carry
-            else:
-                tokens, positions, kv, key = carry
+            tokens, positions, kv, key, counts = carry  # counts: maybe None
             key, sub = jax.random.split(key)
             max_len = self.config.max_model_len
             if self._attn_pallas:
@@ -1538,7 +1588,7 @@ class JaxEngine:
                     int4_groups=self._kv_int4_groups,
                 )
             else:
-                page_idx = jnp.minimum(positions // s, w - 1)
+                page_idx = jnp.minimum(positions // s, w_pages - 1)
                 wslots = (
                     jnp.take_along_axis(
                         block_tables, page_idx[:, None], axis=1
@@ -1580,45 +1630,52 @@ class JaxEngine:
 
             if use_pen:
                 ys = _sample(
-                    counts=counts, freq_pen=fp, pres_pen=prp, rep_pen=rp,
-                    seeds=seeds, positions=positions,
+                    counts=counts[:w], freq_pen=fp, pres_pen=prp,
+                    rep_pen=rp, seeds=seeds, positions=positions,
                 )
-                toks = ys[0]
-                new_counts = bump_counts(counts, toks, active)
-                return (toks, positions + 1, kv, key, new_counts), ys
-            ys = _sample()
-            return (ys[0], positions + 1, kv, key), ys
+                counts = bump_counts(counts, ys[0], active)
+            else:
+                ys = _sample()
+            return (ys[0], positions + 1, kv, key, counts), ys
 
-        if use_pen:
-            (_, _, kv, _, counts), out_t = jax.lax.scan(
-                body, (tokens, positions, kv, key, counts), None,
-                length=self.config.decode_steps,
-            )
-        else:
-            (_, _, kv, _), out_t = jax.lax.scan(
-                body, (tokens, positions, kv, key), None,
-                length=self.config.decode_steps,
-            )
-        # row 0 = the input carry (prefill first tokens ride in via slot
-        # overrides): syncing the dispatch delivers them with no separate
+        (_, _, kv, _, counts), out_t = jax.lax.scan(
+            body, (tokens, positions, kv, key, counts), None,
+            length=self.config.decode_steps,
+        )
+        # row 0 = the input carry (a prefill step's first tokens among
+        # them): syncing the dispatch delivers them with no separate
         # fetch — a per-sequence fetch is one more device-to-host copy
         # and sync per sequence
         S = (
             jnp.concatenate([tokens[None], out_t[0]], axis=0),
             jnp.concatenate([carry_lps[None], out_t[1]], axis=0),
         )
+
+        def keep(old, new):
+            # active rows carry their newest sample on; a row this
+            # dispatch does not advance keeps what another program wrote
+            mask = active.reshape((w,) + (1,) * (new.ndim - 1))
+            return old.at[:w].set(jnp.where(mask, new, old[:w]))
+
+        state = state._replace(
+            toks=keep(state.toks, out_t[0][-1]),
+            lps=keep(state.lps, out_t[1][-1]), counts=counts,
+        )
         if want_tops:
+            carry_tlp = jnp.where(ovr[:, None], jnp.nan, state.tlp[:w])
             S = S + (
-                jnp.concatenate([carry_tid[None], out_t[2]], axis=0),
+                jnp.concatenate([state.tid[:w][None], out_t[2]], axis=0),
                 jnp.concatenate([carry_tlp[None], out_t[3]], axis=0),
             )
-        if use_pen:
-            return S, kv, counts
-        return S, kv
+            state = state._replace(
+                tid=keep(state.tid, out_t[2][-1]),
+                tlp=keep(state.tlp, out_t[3][-1]),
+            )
+        return S, kv, self._pin_state(state)
 
-    def _spec_verify_step(self, params, kv, tokens, positions, block_tables,
-                          active, draft, draft_len, temp, topk, topp, key,
-                          all_greedy=False):
+    def _spec_verify_step(self, params, kv, state, tokens, positions,
+                          block_tables, active, draft, draft_len, temp,
+                          topk, topp, all_greedy=False):
         """One speculative verify step: every row carries `1 + draft_len`
         candidate tokens — its decode carry plus the n-gram proposer's
         drafts — through the model in ONE forward (tokens [B, T] with
@@ -1640,7 +1697,12 @@ class JaxEngine:
         query can reach them (host-side num_computed/device_pos rewind
         keeps page registration behind the accepted prefix).
 
-        Returns ((out_tokens [B, T], n_emit [B]), kv)."""
+        Of the state the step touches the key alone: windows are
+        host-built, and the sync re-arms the carry through the next
+        decode program's override columns.
+
+        Returns ((out_tokens [B, T], n_emit [B]), kv, state)."""
+        state, key = state.split()
         s = self.page_size
         b, w = block_tables.shape
         t = tokens.shape[1]
@@ -1684,11 +1746,10 @@ class JaxEngine:
             lg, draft, draft_len, key, temp, topk, topp,
             all_greedy=all_greedy,
         )
-        return (out, n_emit), kv
+        return (out, n_emit), kv, self._pin_state(state)
 
-    def _mixed_model_step(self, params, kv, hot, row_meta, samp_f, samp_i,
-                          dev_tables, carry, key, draft=None, dlen=None,
-                          all_greedy=False, w_b=1):
+    def _mixed_model_step(self, params, kv, state, hot, rows_i, rows_f,
+                          draft=None, dlen=None, all_greedy=False, w_b=1):
         """One MIXED prefill+decode step — the stall-free batching
         dispatch (Sarathi-style): decode rows carry their last token at
         q_len=1 and prefill rows carry one chunk, per-row query lengths
@@ -1701,16 +1762,16 @@ class JaxEngine:
 
         Step-pipeline input contract: `hot` [3, n, T] packs the
         per-step tokens/positions/write-slots into ONE fused H2D
-        upload; `row_meta` [n, 4] = [last_idx, slot_row, carry_mask,
-        dec_mask] is the second. Everything slow-changing is gathered
-        in-jit from the persistent device arrays by slot row — block
-        tables from `dev_tables` (pallas: sliced to the static `w_b`
-        page bucket; gather: expanded to the full slot matrix) and
-        sampling params from `samp_f`/`samp_i`. Rows with carry_mask
-        read their input token from the device `carry` vector instead
-        of host token history (their previous step's sample has not
-        reached the host yet — the pipelined build), and every decode
-        row's newest sample is scattered back into `carry` (donated) so
+        upload; `rows_i` [n, 6 + W] = [last_idx, slot_row, carry_mask,
+        dec_mask, top_k, seed, block table] is the second and `rows_f`
+        [n, 5] (temp, top_p first) the third: the slow-changing columns
+        are the host mirrors' rows of each row's slot, as the build
+        snapshot them (pallas: the table sliced to the static `w_b`
+        page bucket; gather: expanded to the full slot matrix). Rows
+        with carry_mask read their input token from the state's carry
+        instead of host token history (their previous step's sample has
+        not reached the host yet — the pipelined build), and every
+        decode row's newest sample is scattered back into the carry so
         the NEXT pipelined build needs no host round trip either.
 
         spec x mixed composition (`draft` [n, k_max] + `dlen` [n] set):
@@ -1723,7 +1784,7 @@ class JaxEngine:
         their window column 0 IS the plain sample at last_idx (greedy:
         the same argmax; sampled: the same shortlist distribution) and
         n_emit=1. Returns ((out_tokens [n, k_max+1], n_emit [n]), kv,
-        new_carry) in spec mode, (sampled [n], kv, new_carry) otherwise.
+        state) in spec mode, (sampled [n], kv, state) otherwise.
 
         Attention backends: the gather oracle with ragged `q_lens`
         everywhere; on pallas engines a row-scatter KV write + the
@@ -1732,14 +1793,16 @@ class JaxEngine:
         Verify rows need nothing new from either backend: they are just
         ragged rows whose q_pos0 is mid-page."""
         tokens, positions, wslots = hot[0], hot[1], hot[2]
-        last_idx = row_meta[:, 0]
-        slot_rows = row_meta[:, 1]
-        carry_mask = row_meta[:, 2].astype(bool)
-        dec_mask = row_meta[:, 3].astype(bool)
+        last_idx = rows_i[:, 0]
+        slot_rows = rows_i[:, 1]
+        carry_mask = rows_i[:, 2].astype(bool)
+        dec_mask = rows_i[:, 3].astype(bool)
         n = tokens.shape[0]
-        temp, topp = samp_f[slot_rows, 0], samp_f[slot_rows, 1]
-        topk = samp_i[slot_rows, 0]
-        tbl = dev_tables[slot_rows]  # [n, W] per-row block tables
+        temp, topp = rows_f[:, 0], rows_f[:, 1]
+        topk = rows_i[:, 4]
+        tbl = rows_i[:, 6:]  # [n, W] per-row block tables
+        state, key = state.split()
+        carry = state.toks
         # pipelined decode rows take their input token from the device
         # carry; padding rows gather slot 0 and are masked off
         tokens = tokens.at[:, 0].set(
@@ -1774,7 +1837,9 @@ class JaxEngine:
             # slot 0 with whatever lives there — it must not race the
             # real row's write)
             idx = jnp.where(dec_mask, slot_rows, carry.shape[0])
-            return carry.at[idx].set(vals, mode="drop")
+            return self._pin_state(state._replace(
+                toks=carry.at[idx].set(vals, mode="drop")
+            ))
 
         if draft is not None:
             # spec window: gather (k_max+1) hidden columns per row ending
@@ -2641,10 +2706,10 @@ class JaxEngine:
         return progressed
 
     def _mark_slot_state(self, seq: Sequence) -> None:
-        """Refresh a slot's device-resident input rows (block table +
-        sampling params) in the host mirrors and queue the scatter —
-        called on admit and on page growth, the only times a LIVE slot's
-        slow-changing inputs change (loop thread only)."""
+        """Refresh a slot's rows (block table + sampling params) in the
+        host mirrors every dispatch build snapshots — called on admit
+        and on page growth, the only times a LIVE slot's slow-changing
+        inputs change (loop thread only)."""
         i = seq.slot
         row = self._host_tables[i]
         row[:] = 0
@@ -2655,31 +2720,30 @@ class JaxEngine:
             seq.presence_penalty, seq.repetition_penalty,
         )
         self._host_samp_i[i] = (seq.top_k, seq.seed)
-        self._dirty_slots.add(i)
 
-    def _snap_dirty(self):
-        """Snapshot (loop thread) the slots whose device-resident rows
-        changed since the last dispatch; the dispatch worker applies it
-        under _kv_lock via `_flush_dev_state_locked`. None when nothing
-        changed — the steady-state decode path then uploads NOTHING
-        slow-changing."""
-        if not self._dirty_slots:
-            return None
-        idx = np.asarray(_pad_pow2(sorted(self._dirty_slots)), np.int32)
-        self._dirty_slots.clear()
-        return (
-            idx, self._host_tables[idx].copy(),
-            self._host_samp_f[idx].copy(), self._host_samp_i[idx].copy(),
-        )
+    def _take_state(self, counts: bool = False) -> StepState:
+        """The state a step program is about to consume (under
+        `_kv_lock`). The counts ride only into the penalty / seeded
+        programs, allocated on first use: an upload, not a launch."""
+        st = self._state
+        if not counts:
+            return st._replace(counts=None)
+        if st.counts is None:
+            st = self._state = st._replace(counts=jax.device_put(
+                np.zeros(
+                    (self.config.max_batch_size, self.model_cfg.vocab_size),
+                    np.int8,
+                ),
+                self._state_sharding,
+            ))
+        return st
 
-    def _flush_dev_state_locked(self, snap) -> None:
-        if snap is None:
-            return
-        idx, tb, sf, si = snap
-        sl = jnp.asarray(idx)
-        self._dev_tables = self._dev_tables.at[sl].set(jnp.asarray(tb))
-        self._dev_samp_f = self._dev_samp_f.at[sl].set(jnp.asarray(sf))
-        self._dev_samp_i = self._dev_samp_i.at[sl].set(jnp.asarray(si))
+    def _put_state(self, new: StepState) -> None:
+        """The state a step program returned (under `_kv_lock`); a
+        program that did not take the counts left them where they are."""
+        if new.counts is None:
+            new = new._replace(counts=self._state.counts)
+        self._state = new
 
     def _reset_and_count(self, counts, row, tokens, reset=True):
         """Zero a slot's occurrence-count row (first chunk) and
@@ -2690,34 +2754,25 @@ class JaxEngine:
             counts = counts.at[row].set(0)
         return count_tokens(counts, row, tokens)
 
-    def _ensure_counts(self):
-        if self._counts is None:
-            self._counts = jnp.zeros(
-                (self.config.max_batch_size, self.model_cfg.vocab_size),
-                jnp.int8,
-            )
-        return self._counts
-
     def _count_prompt(self, seq: Sequence) -> None:
         """Seed the slot's count row with the prompt so penalties see
         "the text so far" (prompt + completion, OpenAI semantics).
         Chunked to the prefill buckets to bound compiled shapes; token
         id 0 in a prompt is not counted (pad sentinel)."""
-        self._ensure_counts()
         tokens = seq.tokens
         buckets = self.config.prefill_buckets()
-        row = jnp.asarray(seq.slot, jnp.int32)
+        row = np.asarray(seq.slot, np.int32)
         start = 0
         with self._kv_lock:
+            counts = self._take_state(counts=True).counts
             while start < len(tokens):
                 chunk = tokens[start:start + buckets[-1]]
                 bucket = next(b for b in buckets if b >= len(chunk))
                 padded = np.zeros(bucket, np.int32)
                 padded[: len(chunk)] = chunk
-                self._counts = self._reset_count_fn(
-                    self._counts, row, jnp.asarray(padded), start == 0
-                )
+                counts = self._reset_count_fn(counts, row, padded, start == 0)
                 start += len(chunk)
+            self._state = self._state._replace(counts=counts)
 
     def _reserve_pages(self, seq: Sequence) -> bool:
         """Prefix-match (HBM, then host tier) and allocate pages covering
@@ -2997,9 +3052,7 @@ class JaxEngine:
                         self._finish(seq, FINISH_REASON_ERROR)
                         continue
                     if seq.num_computed >= seq.total_tokens:
-                        self._mark_decode_ready(
-                            seq, (tok1[0], tok1[1], tok1[2], tok1[3], 0)
-                        )
+                        self._mark_decode_ready(seq)
                         self._start_first_emit([(seq, 0)], tok1)
                     else:
                         self._prefilling.append(seq)
@@ -3008,14 +3061,12 @@ class JaxEngine:
                 finals = []
                 for j, seq in enumerate(seqs):
                     if seq.num_computed >= seq.total_tokens:
-                        # final chunk: the sampled token stays on device
-                        # as the slot's decode carry override AND one
-                        # per-GROUP async fetch emits it early
-                        # (_start_first_emit) — TTFT no longer waits for
-                        # the next decode dispatch
-                        self._mark_decode_ready(
-                            seq, (toks[0], toks[1], toks[2], toks[3], j)
-                        )
+                        # final chunk: the step wrote the sampled token
+                        # into the slot's decode carry AND one per-GROUP
+                        # async fetch emits it early (_start_first_emit)
+                        # — TTFT no longer waits for the next decode
+                        # dispatch
+                        self._mark_decode_ready(seq)
                         finals.append((seq, j))
                     else:
                         self._prefilling.append(seq)
@@ -3165,18 +3216,26 @@ class JaxEngine:
                 rows=rows, tokens=tokens, **rec.get("span", {}),
             )
 
-    def _enqueue(self, rec: dict, fn, *args):
-        """The jit call alone (under `_kv_lock`) as ``eng.enqueue``;
-        notes in `rec` whether the device had drained by then
-        (`starved`: the newest dispatch's first output was ready)."""
+    def _enqueue(self, rec: dict, fn, *args, counts: bool = False, **static):
+        """The dispatch's ONE launch (under `_kv_lock`) as
+        ``eng.enqueue``: the step program `fn` takes the params, the
+        donated kv and state (with the counts on the penalty path), then
+        `args`, and returns (outputs, kv, state); kv and state are kept,
+        the outputs returned. Notes in `rec` whether the device had
+        drained by then (`starved`: the newest dispatch's first output
+        was ready)."""
         last = self._last_out
         try:
             rec["starved"] = int(last is None or last.is_ready())
         except Exception:  # noqa: BLE001 — a deleted buffer at shutdown
             rec["starved"] = 0
+        state = self._take_state(counts)
         with profiler.phase("eng.enqueue"):
-            out = fn(*args)
-        self._last_out = jax.tree.leaves(out[0])[0]
+            out, self.kv, state = fn(
+                self.params, self.kv, state, *args, **static
+            )
+        self._put_state(state)
+        self._last_out = jax.tree.leaves(out)[0]
         return out
 
     def _record_sync(
@@ -3280,19 +3339,24 @@ class JaxEngine:
                 "queue_wait_s", round(seq.t_admit - seq.t_submit, 4)
             )
 
-    def _mark_decode_ready(self, seq: Sequence, tok) -> None:
+    def _mark_decode_ready(
+        self, seq: Sequence, tok: Optional[int] = None
+    ) -> None:
+        """`tok` None: the prefill step that just ran sampled the first
+        token and wrote it into the device carry at the slot; the host
+        has not seen it (`carry_pending`). An int: a disagg inject's."""
         seq.prefilling = False
         seq.device_pos = seq.num_computed
-        self._overrides[seq.slot] = tok
-        # the override supersedes whatever the device carry row holds
-        # (a previous tenant's token, or garbage) — the step pipeline
-        # must not read it until a dispatch re-arms it
+        # the first token supersedes what the host knows of the carry
+        # row (a previous tenant's token) — the step pipeline must not
+        # read it until a decode dispatch re-arms it
         self._carry_ok[seq.slot] = False
-        seq.carry_pending = True
-        if not isinstance(tok, tuple):
-            # disagg-injected first token: sampled remotely, already on
-            # the host — emit immediately, no fetch needed
-            seq.carry_pending = False
+        seq.carry_pending = tok is None
+        self._overrides.pop(seq.slot, None)
+        if tok is not None:
+            # sampled remotely, already on the host: it enters the next
+            # decode program as an override — emit immediately, no fetch
+            self._overrides[seq.slot] = int(tok)
             seq.num_computed = seq.total_tokens
             self._stamp_first_meta(seq)
             self._t_fetched = time.perf_counter()
@@ -3374,21 +3438,19 @@ class JaxEngine:
             tok_arr = np.zeros((n, bucket), np.int32)
             pos_arr = np.zeros((n, bucket), np.int32)
             wslots = np.zeros((n, bucket), np.int32)
-            last_idx = np.zeros(n, np.int32)
-            temp = np.zeros(n, np.float32)
-            topk = np.zeros(n, np.int32)
-            topp = np.ones(n, np.float32)
+            # the per-row inputs, two fused uploads (`_model_step`):
+            # [last_idx, top_k, slot, final chunk, seed] and [temp,
+            # top_p, freq_pen, pres_pen, rep_pen]; padding rows have no
+            # slot and sample greedily
+            rows_i = np.zeros((n, 5), np.int32)
+            rows_i[:, 2] = rows_i[:, 4] = -1
+            rows_f = np.zeros((n, 5), np.float32)
+            rows_f[:, 1] = rows_f[:, 4] = 1.0
             # penalties/seeds need a slot-keyed count row; prefill_only seqs
             # (slot -1, disagg) sample their first token on the plain path
             use_ext = any(
                 (s.has_penalties or s.seed >= 0) and s.slot >= 0 for s in seqs
             )
-            slot_rows = np.zeros(n, np.int32)
-            fp = np.zeros(n, np.float32)
-            prp = np.zeros(n, np.float32)
-            rp = np.ones(n, np.float32)
-            seeds = np.full(n, -1, np.int32)
-            final_row = np.zeros(n, bool)
             ps = self.page_size
             ppc = -(-bucket // ps)  # page blocks per chunk (pallas write path)
             wtables = np.zeros((n, ppc), np.int32)
@@ -3449,16 +3511,14 @@ class JaxEngine:
                             lo - e0:hi - e0
                         ]
                         emb_mask[j, lo - start:hi - start] = True
-                last_idx[j] = chunk - 1
-                temp[j] = seq.temperature
-                topk[j] = seq.top_k
-                topp[j] = seq.top_p
-                slot_rows[j] = seq.slot if seq.slot >= 0 else 0
-                fp[j] = seq.frequency_penalty
-                prp[j] = seq.presence_penalty
-                rp[j] = seq.repetition_penalty
-                seeds[j] = seq.seed
-                final_row[j] = seq.num_computed + chunk >= seq.total_tokens
+                rows_i[j] = (
+                    chunk - 1, seq.top_k, seq.slot,
+                    seq.num_computed + chunk >= seq.total_tokens, seq.seed,
+                )
+                rows_f[j] = (
+                    seq.temperature, seq.top_p, seq.frequency_penalty,
+                    seq.presence_penalty, seq.repetition_penalty,
+                )
         t_dispatch0 = time.perf_counter()  # dispatch section only: the
         # host-side input build above is the digest's build_s
         n_tok = int(
@@ -3470,22 +3530,6 @@ class JaxEngine:
         )
         with self._dispatching("prefill", t_dispatch0, rec):
             with profiler.phase("eng.upload"):
-                self._key, sub = jax.random.split(self._key)
-                common = (
-                    self.params, self.kv,
-                    jnp.asarray(tok_arr), jnp.asarray(pos_arr),
-                    jnp.asarray(wslots.reshape(-1)),
-                    jnp.asarray(smat), jnp.asarray(last_idx),
-                    jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(topp),
-                    sub,
-                    jnp.asarray(wtables.reshape(-1)) if self._attn_pallas else None,
-                    jnp.asarray(btables) if self._attn_pallas else None,
-                    jnp.asarray(emb) if has_embeds else None,
-                    jnp.asarray(emb_mask) if has_embeds else None,
-                    bool((temp <= 0.0).all()),
-                    any(s.want_logprobs for s in seqs),
-                )
-                want_tops = any(s.top_logprobs > 0 for s in seqs)
                 # sp cached-prefix continuation: the static value is a
                 # power-of-two PAGE bucket over the group's longest cached
                 # prefix (0 = no cache; bounds both the compiled-family count
@@ -3498,20 +3542,22 @@ class JaxEngine:
                     if max_cached:
                         spc = 1 << (max_cached - 1).bit_length()
                         spc = min(spc, self.config.max_pages_per_seq)
-                if use_ext:
-                    more = (
-                        self._ensure_counts(), jnp.asarray(slot_rows),
-                        jnp.asarray(fp), jnp.asarray(prp), jnp.asarray(rp),
-                        jnp.asarray(final_row), jnp.asarray(seeds), want_tops,
-                    )
-                else:
-                    more = (None,) * 7 + (True,) if want_tops else ()
-            fn = self._step_ext_fn if use_ext else self._step_fn
-            S, self.kv, *counts = self._enqueue(
-                rec, lambda: fn(*common, *more, sp_cached=spc)
+                args = (
+                    jnp.asarray(tok_arr), jnp.asarray(pos_arr),
+                    jnp.asarray(wslots.reshape(-1)),
+                    jnp.asarray(smat), jnp.asarray(rows_i),
+                    jnp.asarray(rows_f),
+                    jnp.asarray(wtables.reshape(-1)) if self._attn_pallas else None,
+                    jnp.asarray(btables) if self._attn_pallas else None,
+                    jnp.asarray(emb) if has_embeds else None,
+                    jnp.asarray(emb_mask) if has_embeds else None,
+                    bool((rows_f[:, 0] <= 0.0).all()),
+                    any(s.want_logprobs for s in seqs),
+                    any(s.top_logprobs > 0 for s in seqs),
+                )
+            S = self._enqueue(
+                rec, self._step_fn, *args, counts=use_ext, sp_cached=spc
             )
-            if use_ext:
-                self._counts = counts[0]
         now = time.perf_counter()
         for seq in seqs:
             if seq.num_computed + min(
@@ -3556,14 +3602,14 @@ class JaxEngine:
 
     def _prefill_chunk_dispatch(self, seq: Sequence):
         """Single-sequence chunk dispatch (disagg prefill_only path;
-        worker thread). Returns (token vector [1], bucket) — the CALLER
+        worker thread). Returns (token vector, bucket) — the CALLER
         runs `_note_prefilled` on the event-loop thread (the allocator
         has no lock; bookkeeping must not race loop-side callbacks)."""
         bucket = self._bucket_for(
             min(seq.total_tokens - seq.num_computed, self.config.prefill_chunk)
         )
         toks, _lps, _tid, _tlp = self._prefill_group_dispatch([seq], bucket)
-        return toks[:1], bucket
+        return toks, bucket
 
     async def _prefill_forward(self, seq: Sequence) -> int:
         """Blocking chunked prefill (disagg prefill_only path): writes KV,
@@ -3931,10 +3977,9 @@ class JaxEngine:
         carry vector may predate earlier steps — in the pipelined case
         the previous dispatch was already synced before the failure
         surfaced, so `last_token` IS current), restore the prefill picks
-        in FIFO order, re-queue the unflushed device-state scatter, then
-        disable mixed steps on this engine — retrying a failing dispatch
-        family every tick would wedge the loop instead of degrading to
-        the contained normal paths."""
+        in FIFO order, then disable mixed steps on this engine —
+        retrying a failing dispatch family every tick would wedge the
+        loop instead of degrading to the contained normal paths."""
         log.exception(
             "mixed step of %d rows failed; disabling mixed batching "
             "(normal prefill/decode paths take over)", len(bld["entries"])
@@ -3954,10 +3999,6 @@ class JaxEngine:
                 pf_restore.append(seq)
         for seq in reversed(pf_restore):
             self._prefilling.appendleft(seq)
-        if bld["dirty"] is not None:
-            # the device-state scatter may never have run; re-dirty so
-            # the next normal dispatch flushes it
-            self._dirty_slots.update(int(i) for i in bld["dirty"][0])
         self._mixed_disabled = True
         # mirror into the degrade ladder (permanent: a FAILED dispatch
         # family must not re-probe — retrying it every tick would wedge
@@ -3984,11 +4025,10 @@ class JaxEngine:
         placeholder here); when `pipelined`, every q_len=1 decode row's
         `device_pos` advances NOW — deterministically, exactly like the
         decode scan's build — so the NEXT build can launch behind this
-        still-unsynced step. Sampling params and block tables are NOT
-        built here: the step gathers them in-jit from the persistent
-        device arrays via `slot_rows` (`w_b` is the static pallas
-        attended-page bucket; 0 on gather engines, which expand the
-        full slot matrix in-jit)."""
+        still-unsynced step. Sampling params and block tables are the
+        host mirrors' rows of each row's slot, snapshot here (`w_b` is
+        the static pallas attended-page bucket; 0 on gather engines,
+        which expand the full slot matrix in-jit)."""
         ps = self.page_size
         use_spec = bool(drafts)
         k_max = self.config.spec_k_max if use_spec else 0
@@ -4001,8 +4041,8 @@ class JaxEngine:
         t0 = time.perf_counter()
         hot = np.zeros((3, n, t_b), np.int32)  # [tokens, positions, wslots]
         tok_arr, pos_arr, wslots = hot[0], hot[1], hot[2]
-        # [last_idx, slot_row, carry_mask, dec_mask] per row — the second
-        # fused upload
+        # [last_idx, slot_row, carry_mask, dec_mask] per row — the head
+        # of the second fused upload (`rows_i`, completed below)
         meta = np.zeros((n, 4), np.int32)
         all_greedy = True
         draft_arr = np.zeros((n, k_max), np.int32) if use_spec else None
@@ -4077,22 +4117,24 @@ class JaxEngine:
         w_b = min(
             1 << (w_need - 1).bit_length(), self.config.max_pages_per_seq
         ) if self._attn_pallas else 0
+        slot_rows = meta[:, 1]  # padding rows read slot 0's, masked off
         return dict(
-            hot=hot, meta=meta, entries=entries,
+            hot=hot, entries=entries,
+            rows_i=np.concatenate([meta, self._host_rows_i[slot_rows]], 1),
+            rows_f=self._host_samp_f[slot_rows],
             spec=use_spec, draft=draft_arr, dlen=dlen_arr, pos0=pos0_arr,
             all_greedy=all_greedy, w_b=w_b, pipelined=pipelined,
-            n_carry=n_carry, n_shed=0, t0=t0, dirty=self._snap_dirty(),
+            n_carry=n_carry, n_shed=0, t0=t0,
             build_s=time.perf_counter() - t0,
         )
 
     def _run_mixed_dispatch(self, bld: dict):
         """Jax half of a mixed step (worker thread, _kv_lock): returns
         the device sampled-token vector [n], or (out_tokens [n, k+1],
-        n_emit [n]) when spec verify rows composed in. Flushes any
-        pending device-state scatter first, uploads the two fused hot
-        arrays, and threads the donated carry vector through the step
-        (the in-jit decode-row scatter that makes pipelined builds
-        host-round-trip-free)."""
+        n_emit [n]) when spec verify rows composed in. Uploads the
+        build's fused arrays and threads the donated state through the
+        step (the in-jit decode-row scatter into its carry is what makes
+        pipelined builds host-round-trip-free)."""
         faults.fire("engine.mixed")
         t0 = time.perf_counter()
         entries, hot = bld["entries"], bld["hot"]
@@ -4110,20 +4152,14 @@ class JaxEngine:
         try:
             with self._dispatching("mixed", t0, rec):
                 with profiler.phase("eng.upload"):
-                    self._flush_dev_state_locked(bld["dirty"])
-                    self._key, sub = jax.random.split(self._key)
                     args = (
-                        self.params, self.kv,
-                        jnp.asarray(hot), jnp.asarray(bld["meta"]),
-                        self._dev_samp_f, self._dev_samp_i, self._dev_tables,
-                        self._carry_toks, sub,
+                        jnp.asarray(hot), jnp.asarray(bld["rows_i"]),
+                        jnp.asarray(bld["rows_f"]),
                         jnp.asarray(bld["draft"]) if bld["spec"] else None,
                         jnp.asarray(bld["dlen"]) if bld["spec"] else None,
                         bld["all_greedy"], bld["w_b"],
                     )
-                S, self.kv, self._carry_toks = self._enqueue(
-                    rec, self._mixed_fn, *args
-                )
+                S = self._enqueue(rec, self._mixed_fn, *args)
                 self._step_count += 1
                 for arr in (S if isinstance(S, tuple) else (S,)):
                     arr.copy_to_host_async()
@@ -4327,17 +4363,17 @@ class JaxEngine:
             return None
         active, b = prep
 
-        # the ONE fused per-dispatch H2D upload: [positions, active];
-        # block tables + sampling/penalty params stay device-resident
-        # (scatter-updated on admit/growth via the dirty snapshot below)
-        pos_act = np.zeros((b, 2), np.int32)
+        # the dispatch's two fused uploads (`_decode_multi`): [position,
+        # active, override value, override mask] beside the host
+        # mirrors' rows as they stand now, and the float params
+        rows_i = np.zeros((b, 4 + self._host_rows_i.shape[1]), np.int32)
+        rows_i[:, 4:] = self._host_rows_i[:b]
         use_ext = False
         want_lps = False
         want_tops = False
         all_greedy = True
         for i, seq in active:
-            pos_act[i, 0] = seq.device_pos
-            pos_act[i, 1] = 1
+            rows_i[i, :2] = (seq.device_pos, 1)
             all_greedy = all_greedy and seq.temperature <= 0.0
             use_ext = use_ext or seq.has_penalties or seq.seed >= 0
             want_lps = want_lps or seq.want_logprobs
@@ -4348,16 +4384,14 @@ class JaxEngine:
             # it before this dispatch syncs
             self._carry_ok[i] = True
 
-        overrides = {
-            slot: val for slot, val in self._overrides.items()
-            if pos_act[slot, 1]
-        }
+        for slot, tok in self._overrides.items():
+            if slot < b and rows_i[slot, 1]:
+                rows_i[slot, 2:4] = (tok, 1)
         self._overrides.clear()
         return _DecodeBuild(
-            pos_act=pos_act, use_ext=use_ext, want_lps=want_lps,
-            want_tops=want_tops, overrides=overrides, active=active,
-            steps=k_steps, width=b, all_greedy=all_greedy,
-            dirty=self._snap_dirty(),
+            rows_i=rows_i, rows_f=self._host_samp_f[:b].copy(),
+            use_ext=use_ext, want_lps=want_lps, want_tops=want_tops,
+            active=active, steps=k_steps, width=b, all_greedy=all_greedy,
         )
 
     def _grow_and_collect(self, ready, upto):
@@ -4498,10 +4532,10 @@ class JaxEngine:
                 # includes the <= steps-1 overshoot positions of rows
                 # that finish mid-scan, so this bounds emitted tokens
                 # from above
-                rows=rows, tokens=int(bld.pos_act[:, 1].sum()) * bld.steps,
+                rows=rows, tokens=rows * bld.steps,
                 # physical rows: the scan runs the FULL padded batch
                 # every step
-                phys_rows=int(bld.pos_act.shape[0]) * bld.steps,
+                phys_rows=bld.width * bld.steps,
                 span={"steps": bld.steps},
             )
         rec["build_s"] = bld.build_s
@@ -4527,16 +4561,14 @@ class JaxEngine:
         NOT updated (spec windows are host-built); sync re-arms the
         carry for a following normal dispatch via an int override."""
         with profiler.phase("eng.upload"):
-            self._key, sub = jax.random.split(self._key)
             args = (
-                self.params, self.kv,
                 jnp.asarray(bld.tokens), jnp.asarray(bld.positions),
                 jnp.asarray(bld.tables), jnp.asarray(bld.act),
                 jnp.asarray(bld.draft), jnp.asarray(bld.dlen),
                 jnp.asarray(bld.temp), jnp.asarray(bld.topk),
-                jnp.asarray(bld.topp), sub, bld.all_greedy,
+                jnp.asarray(bld.topp), bld.all_greedy,
             )
-        S, self.kv = self._enqueue(rec, self._spec_fn, *args)
+        S = self._enqueue(rec, self._spec_fn, *args)
         self._step_count += 1
         for arr in S:
             arr.copy_to_host_async()
@@ -4548,119 +4580,18 @@ class JaxEngine:
     def _run_decode_dispatch_locked(
         self, bld: "_DecodeBuild", rec: dict
     ) -> _Dispatch:
+        """Jax half of a decode dispatch: the build's two fused uploads
+        (``eng.upload``) and the one launch (``eng.enqueue``)."""
         with profiler.phase("eng.upload"):
-            args = self._decode_inputs_locked(bld)
-        res = self._enqueue(
-            rec, self._decode_ext_fn if bld.use_ext else self._decode_fn,
-            *args,
-        )
-        # the write-back of the carries: eager operations queued behind
-        # the scan, each a launch of its own on the device
-        with profiler.phase("eng.carry"):
-            w = bld.width
-            full = w == len(self.slots)
-            if bld.use_ext:
-                S, self.kv, new_counts = res
-                self._counts = (
-                    new_counts if full else self._counts.at[:w].set(new_counts)
-                )
-            else:
-                S, self.kv = res
-            self._step_count += 1
-            if full:
-                self._carry_toks = S[0][-1]
-                self._carry_lps = S[1][-1]
-                if bld.want_tops:
-                    self._carry_tid = S[2][-1]
-                    self._carry_tlp = S[3][-1]
-            else:
-                self._carry_toks = self._carry_toks.at[:w].set(S[0][-1])
-                self._carry_lps = self._carry_lps.at[:w].set(S[1][-1])
-                if bld.want_tops:
-                    self._carry_tid = self._carry_tid.at[:w].set(S[2][-1])
-                    self._carry_tlp = self._carry_tlp.at[:w].set(S[3][-1])
-            for arr in S:
-                arr.copy_to_host_async()
-        return _Dispatch(S, bld.active, bld.steps)
-
-    def _decode_inputs_locked(self, bld: "_DecodeBuild") -> tuple:
-        """The decode program's arguments: the device-state flush, the
-        carry overrides and the one fused upload (``eng.upload``)."""
-        self._flush_dev_state_locked(bld.dirty)
-        w = bld.width  # bucketed dispatch width (power of two >= highest
-        # active slot + 1; carries/counts slice to it and write back)
-        toks = self._carry_toks[:w]
-        lps = self._carry_lps[:w]
-        tid, tlp = self._carry_tid[:w], self._carry_tlp[:w]
-        fresh = np.zeros(w, bool)  # rows carrying a token
-        # never counted before (prefill first tokens, disagg injects)
-        if bld.overrides:
-            # batch the carry overrides into one scatter per source
-            # vector — a per-slot .at[].set is a separate device
-            # dispatch each. Index vectors pad to a power of two
-            # (_pad_pow2): every distinct length is a distinct XLA
-            # program, and under paced arrivals the override count
-            # varies per dispatch — unpadded, each new length costs a
-            # fresh compile mid-serve
-            by_vec: dict[int, tuple] = {}
-            ints: list[tuple[int, int]] = []
-            for slot, val in bld.overrides.items():
-                if isinstance(val, tuple):
-                    vec, lvec, tidm, tlpm, row = val
-                    ent = by_vec.setdefault(
-                        id(vec), (vec, lvec, tidm, tlpm, [], [])
-                    )
-                    ent[4].append(slot)
-                    ent[5].append(row)
-                else:
-                    # disagg-injected first token: sampled remotely, never
-                    # counted locally -> bump as fresh in the decode scan
-                    fresh[slot] = True
-                    ints.append((slot, int(val)))
-
-            for vec, lvec, tidm, tlpm, slots, rows in by_vec.values():
-                sl = jnp.asarray(_pad_pow2(slots), jnp.int32)
-                rw = jnp.asarray(_pad_pow2(rows), jnp.int32)
-                toks = toks.at[sl].set(vec[rw])
-                if bld.want_lps:  # each .at[].set is a device dispatch;
-                    lps = lps.at[sl].set(lvec[rw])  # skip when unused
-                if bld.want_tops and tidm is not None:
-                    tid = tid.at[sl].set(tidm[rw])
-                    tlp = tlp.at[sl].set(tlpm[rw])
-            if ints:
-                sl = jnp.asarray(_pad_pow2([s for s, _ in ints]), jnp.int32)
-                toks = toks.at[sl].set(
-                    jnp.asarray(_pad_pow2([v for _, v in ints]), jnp.int32)
-                )
-                if bld.want_lps:
-                    # remotely-sampled first tokens (disagg) have no
-                    # local logprob; NaN -> emitted as None
-                    lps = lps.at[sl].set(jnp.nan)
-                if bld.want_tops:
-                    tlp = tlp.at[sl].set(jnp.nan)
-        self._key, sub = jax.random.split(self._key)
-        full = w == len(self.slots)
-        counts_in = None
-        if bld.use_ext:
-            # the counts arg is DONATED: at full width pass the array
-            # itself (a full-width slice can alias it, and donating an
-            # alias deletes self._counts); below full width the slice is
-            # a fresh buffer and donation is safe
-            counts_in = (
-                self._ensure_counts() if full else self._ensure_counts()[:w]
+            args = (
+                jnp.asarray(bld.rows_i), jnp.asarray(bld.rows_f),
+                bld.all_greedy, bld.want_lps, bld.want_tops,
             )
-        return (
-            self.params, self.kv,
-            toks, lps, jnp.asarray(bld.pos_act),
-            self._dev_tables[:w], self._dev_samp_f[:w],
-            self._dev_samp_i[:w],
-            sub, bld.all_greedy, bld.want_lps,
-            counts_in,
-            jnp.asarray(fresh) if bld.use_ext else None,
-            tid if bld.want_tops else None,
-            tlp if bld.want_tops else None,
-            bld.want_tops,
-        )
+        S = self._enqueue(rec, self._decode_fn, *args, counts=bld.use_ext)
+        self._step_count += 1
+        for arr in S:
+            arr.copy_to_host_async()
+        return _Dispatch(S, bld.active, bld.steps)
 
     async def _sync_dispatch(self, d: _Dispatch, overlapped: bool = False) -> None:
         # first-token fetch tasks for sequences in this dispatch must
@@ -4794,9 +4725,9 @@ class JaxEngine:
             seq.spec.observe(drafted, accepted)
         if self.slots[slot] is seq:
             # the last emitted token is the new decode carry; a
-            # following NORMAL dispatch consumes it via the int
-            # override scatter (verify windows are host-built and
-            # never touch the device carry vector)
+            # following NORMAL dispatch consumes it via its override
+            # columns (verify windows are host-built and never touch
+            # the device carry vector)
             self._overrides[slot] = int(out_row[n - 1])
         return emitted, accepted
 
@@ -4846,7 +4777,7 @@ class JaxEngine:
                 return False
         if grew:
             # page growth is one of the two events (with admit) that
-            # change a live slot's device-resident block-table row
+            # change a live slot's block-table row
             self._mark_slot_state(seq)
         return True
 

@@ -59,19 +59,34 @@ async def collect(engine, pre):
     return [t for f in frames for t in f.get("token_ids") or []]
 
 
-async def _admission_wave(engine, settle_s=1.0):
+async def _held_mid_decode(engine, prompt, max_tokens):
+    """Start one held stream; return (its task, its token list) once its
+    first decode dispatch has landed: a point in the stream, not a time
+    on the clock (how long a cold engine takes to get there is not the
+    tests' business)."""
+    tokens = []
+    decoding = asyncio.Event()
+
+    async def held():
+        pre = greedy_request(prompt, max_tokens)
+        async for f in await engine.generate(Context(pre.to_dict())):
+            tokens.extend(f.get("token_ids") or [])
+            if len(tokens) > 1:
+                decoding.set()
+
+    task = asyncio.create_task(held())
+    await decoding.wait()
+    return task, tokens
+
+
+async def _admission_wave(engine, wave_len=45):
     """One held decode stream + a 3-prompt admission wave arriving after
     the stream is mid-decode; returns (held tokens, wave streams)."""
     rng = np.random.RandomState(0)
     held_prompt = rng.randint(1, 200, size=20).tolist()
-    out = {}
-
-    async def held():
-        out["held"] = await collect(engine, greedy_request(held_prompt, 40))
-
-    task = asyncio.create_task(held())
-    await asyncio.sleep(settle_s)  # reach steady decode before the wave
-    wave = [rng.randint(1, 200, size=45).tolist() for _ in range(3)]
+    task, held = await _held_mid_decode(engine, held_prompt, 40)
+    out = {"held": held}
+    wave = [rng.randint(1, 200, size=wave_len).tolist() for _ in range(3)]
     streams = await asyncio.gather(
         *(collect(engine, greedy_request(p, 10)) for p in wave)
     )
@@ -201,7 +216,7 @@ async def test_mixed_runtime_toggle_on_unsupported_engine_degrades():
     # pp>1: the stage executor has no ragged multi-query step
     engine = make_engine(mesh=MeshConfig(pp=2))
     engine.config.mixed_batching = True
-    held, streams = await _admission_wave(engine, settle_s=0.5)
+    held, streams = await _admission_wave(engine)
     ps = engine.phase_stats
     await engine.close()
     assert ps["mixed_steps"] == 0  # degraded, never built a mixed step
@@ -217,24 +232,11 @@ async def test_mixed_decode_priority_off_defers_decode_when_budget_tight():
     engine = make_engine(
         mixed_batching=True, mixed_step_tokens=32, mixed_decode_priority=False
     )
-    rng = np.random.RandomState(0)
-    held_prompt = rng.randint(1, 200, size=20).tolist()
-    out = {}
-
-    async def held():
-        out["held"] = await collect(engine, greedy_request(held_prompt, 40))
-
-    task = asyncio.create_task(held())
-    await asyncio.sleep(1.0)
-    wave = [rng.randint(1, 200, size=64).tolist() for _ in range(3)]
-    streams = await asyncio.gather(
-        *(collect(engine, greedy_request(p, 10)) for p in wave)
-    )
-    await task
+    held, streams = await _admission_wave(engine, wave_len=64)
     ps = engine.phase_stats
     await engine.close()
     assert ps["mixed_steps"] == 0
-    assert len(out["held"]) == 40 and all(len(s) == 10 for s in streams)
+    assert len(held) == 40 and all(len(s) == 10 for s in streams)
 
 
 # ---------------------------------------------------------------------------

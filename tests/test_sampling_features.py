@@ -612,7 +612,7 @@ async def test_engine_200_repeat_stream_counts_stay_saturated():
     counts = None
     for _ in range(100):
         try:
-            counts = np.asarray(engine._counts)
+            counts = np.asarray(engine._state.counts)
             break
         except RuntimeError:
             await asyncio.sleep(0.02)

@@ -262,18 +262,20 @@ async def test_adversarial_input_never_speculates():
     # prompt (generated text may repeat by chance — the permutation
     # prompt itself guarantees a draft-free prefill/first dispatches)
     assert t0 == t1
-    st = spec_stats(spec)
-    ps, pp = spec.phase_stats, plain.phase_stats
-    # steps-per-token parity within 5%: model steps = scanned decode
-    # steps + one per spec dispatch
-    plain_steps = pp["decode_dispatches"] * plain.config.decode_steps
-    spec_steps = (
-        ps["decode_dispatches"] * spec.config.decode_steps
-        + st["spec_dispatches"]
-    )
-    assert spec_steps <= plain_steps * 1.05
+    # close first: a dispatch books itself when its call returns, and the
+    # pipelined overshoot dispatch is still in its worker when the last
+    # frame arrives (read then, the count is one short or not by chance)
     await plain.close()
     await spec.close()
+    st = spec_stats(spec)
+    ps, pp = spec.phase_stats, plain.phase_stats
+    # the scanned decode path is today's: no more scan dispatches than
+    # the plain engine's. What the chance repeats of the generated text
+    # earn on top is a couple of one-step verify dispatches
+    assert ps["decode_dispatches"] <= pp["decode_dispatches"]
+    assert st["spec_dispatches"] <= 0.1 * (
+        pp["decode_dispatches"] * plain.config.decode_steps
+    )
 
 
 async def test_sampled_spec_stream_smoke():

@@ -69,19 +69,25 @@ async def collect(engine, pre):
     return [t for f in frames for t in f.get("token_ids") or []]
 
 
-async def _admission_wave(engine, settle_s=1.0):
+async def _admission_wave(engine):
     """One REPETITIVE held stream (draftable) + a 3-prompt admission
     wave arriving after the stream is mid-decode — the wave prompts are
     mid-wave admissions by construction (they enter _prefilling while
     the held row decodes, so decode rows and prefill chunks coexist)."""
     rng = np.random.RandomState(0)
-    out = {}
+    out = {"held": []}
+    decoding = asyncio.Event()  # the wave's cue: a point in the held
+    # stream (its first decode dispatch landed), not a time on the clock
 
     async def held():
-        out["held"] = await collect(engine, greedy_request(REPETITIVE, 48))
+        pre = greedy_request(REPETITIVE, 48)
+        async for f in await engine.generate(Context(pre.to_dict())):
+            out["held"] += f.get("token_ids") or []
+            if len(out["held"]) > 1:
+                decoding.set()
 
     task = asyncio.create_task(held())
-    await asyncio.sleep(settle_s)  # reach steady decode before the wave
+    await decoding.wait()
     wave = [rng.randint(1, 200, size=45).tolist() for _ in range(3)]
     streams = await asyncio.gather(
         *(collect(engine, greedy_request(p, 10)) for p in wave)

@@ -1,7 +1,8 @@
 """Zero-stall step pipeline (`EngineConfig.step_pipeline`): mixed and
-spec steps dispatched BEHIND in-flight dispatches via the device-resident
-carry vector, with slow-changing batch state (block tables, sampling
-params) living on device.
+spec steps dispatched BEHIND in-flight dispatches via the carry the step
+programs keep on the device (`StepState`), with slow-changing batch
+state (block tables, sampling params) riding from host mirrors in each
+dispatch's fused upload.
 
 Contract under test (docs/architecture.md "Step pipeline"):
 
@@ -20,13 +21,21 @@ Contract under test (docs/architecture.md "Step pipeline"):
 - a failed mixed dispatch degrades to the contained normal paths and
   SAYS so: `Engine.metrics()["mixed_disabled"]` == 1 (the satellite:
   one log line is easy to miss, the /metrics scrape is not);
-- device-resident block tables follow page growth (decode crossing
-  page boundaries reads/writes through freshly-scattered table rows).
+- block tables follow page growth (decode crossing page boundaries
+  reads/writes through the rows the build snapshot from the host mirror);
+- a dispatch is host arrays plus ONE launch: between `_kv_lock` acquire
+  and release exactly one compiled program runs and no eager primitive;
+- rows that join beside an in-flight dispatch, are preempted and
+  re-armed, or are injected by a disagg prefill keep the tokens they
+  emit alone (greedy, and seeded sampling).
 """
 
 import asyncio
+import contextlib
+import threading
 
 import numpy as np
+import pytest
 
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.spec import NgramProposer
@@ -71,20 +80,25 @@ async def collect(engine, pre):
     return [t for f in frames for t in f.get("token_ids") or []]
 
 
-async def _admission_wave(engine, settle_s=1.0, held_tokens=48):
+async def _admission_wave(engine, held_tokens=48):
     """One held stream decoding + a 3-prompt admission wave arriving
-    mid-decode, so decode rows and prefill chunks coexist and the mixed
-    tick finds an in-flight dispatch to pipeline behind."""
+    mid-decode (once the held stream's first decode dispatch has landed:
+    a point in its stream, not a time on the clock), so decode rows and
+    prefill chunks coexist and the mixed tick finds an in-flight
+    dispatch to pipeline behind."""
     rng = np.random.RandomState(0)
-    out = {}
+    out = {"held": []}
+    decoding = asyncio.Event()
 
     async def held():
-        out["held"] = await collect(
-            engine, greedy_request(REPETITIVE, held_tokens)
-        )
+        pre = greedy_request(REPETITIVE, held_tokens)
+        async for f in await engine.generate(Context(pre.to_dict())):
+            out["held"] += f.get("token_ids") or []
+            if len(out["held"]) > 1:
+                decoding.set()
 
     task = asyncio.create_task(held())
-    await asyncio.sleep(settle_s)
+    await decoding.wait()
     wave = [rng.randint(1, 200, size=45).tolist() for _ in range(3)]
     streams = await asyncio.gather(
         *(collect(engine, greedy_request(p, 10)) for p in wave)
@@ -178,6 +192,7 @@ async def test_spec_stale_history_sheds_drafts(monkeypatch):
     # overlap for drafting when the gate is open) stands down and every
     # spec-eligible carry row takes the shed path
     monkeypatch.setattr(NgramProposer, "gate_open", lambda self: False)
+    monkeypatch.setattr(NgramProposer, "maybe_draft", lambda self, k: [])
     engine = make_engine(
         mixed_batching=True, mixed_step_tokens=64, spec_decode=True
     )
@@ -222,14 +237,19 @@ async def test_pipelined_spec_sync_keeps_carried_row_position(monkeypatch):
     held_b = list(range(60, 84))
 
     async def two_held_wave(engine):
-        out = {}
+        out = {"a": [], "b": []}
+        decoding = asyncio.Event()
 
         async def held(name, prompt):
-            out[name] = await collect(engine, greedy_request(prompt, 64))
+            pre = greedy_request(prompt, 64)
+            async for f in await engine.generate(Context(pre.to_dict())):
+                out[name] += f.get("token_ids") or []
+                if min(len(out["a"]), len(out["b"])) > 1:
+                    decoding.set()  # both decode: the wave's cue
 
         ta = asyncio.create_task(held("a", REPETITIVE))
         tb = asyncio.create_task(held("b", held_b))
-        await asyncio.sleep(1.0)
+        await decoding.wait()
         wave = [([11 + w, 29, 5, 60] * 12)[:45] for w in range(6)]
         streams = await asyncio.gather(
             *(collect(engine, greedy_request(p, 10)) for p in wave)
@@ -303,10 +323,11 @@ async def test_healthy_engine_reports_mixed_enabled():
 
 
 async def test_device_tables_follow_page_growth():
-    """Device-resident block tables must be re-scattered on page growth:
+    """The block table a decode program reads must follow page growth:
     a single stream decoding across several page boundaries exercises
-    exactly the admit -> grow -> grow chain (regression for the stale
-    dev-table bug: divergence a few tokens past the first boundary)."""
+    exactly the admit -> grow -> grow chain, each dispatch reading the
+    row its build snapshot from the host mirror (regression for the
+    stale-table bug: divergence a few tokens past the first boundary)."""
     prompt = [3, 14, 15, 92, 65, 35, 89, 79, 32, 38, 46]
     plain = make_engine(step_pipeline=False)
     ref = await collect(plain, greedy_request(prompt, 40))
@@ -315,4 +336,216 @@ async def test_device_tables_follow_page_growth():
     got = await collect(engine, greedy_request(prompt, 40))
     await engine.close()
     assert len(ref) == 40
+    assert got == ref
+
+
+# ---- one launch per dispatch ------------------------------------------
+
+
+class _Launches:
+    """Stands in for the engine's `_kv_lock` and lists, per critical
+    section, the compiled programs its holder launched (`programs`) and
+    which of them were eager primitives (`eager`)."""
+
+    def __init__(self, engine):
+        self.lock, engine._kv_lock = engine._kv_lock, self
+        self.holder = None
+        self.sections = []
+
+    def acquire(self, *a, **kw):
+        got = self.lock.acquire(*a, **kw)
+        if got:
+            self.holder = threading.get_ident()
+            self.sections.append({"programs": [], "eager": []})
+        return got
+
+    def release(self):
+        self.holder = None
+        self.lock.release()
+
+    def __enter__(self):
+        self.acquire()
+
+    def __exit__(self, *exc):
+        self.release()
+
+    def note(self, name, eager=False):
+        if threading.get_ident() == self.holder:
+            self.sections[-1]["programs"].append(name)
+            if eager:
+                self.sections[-1]["eager"].append(name)
+
+    def of(self, step):
+        """The sections that launched the step program `step`."""
+        return [sec for sec in self.sections
+                if any(step in name for name in sec["programs"])]
+
+
+@contextlib.contextmanager
+def _count_launches(engine):
+    """Every launch passes one of two places in jax 0.9 (the
+    installation pyproject.toml pins): a jit call served by the C++
+    fast path ends in the post hook (`fun._fun._apply_primitive` marks
+    an eager primitive's program); the first call of a program, and any
+    call off the fast path, goes through `ExecuteReplicated.__call__`."""
+    from jax._src import api
+    from jax._src.interpreters import pxla
+
+    rec = _Launches(engine)
+
+    def fast(fun, args, kwargs, out):
+        f = getattr(fun, "_fun", fun)
+        rec.note(getattr(f, "__name__", str(f)),
+                 eager=getattr(f, "_apply_primitive", False))
+
+    slow = pxla.ExecuteReplicated.__call__
+
+    def first(self, *args):
+        rec.note(self.name)
+        return slow(self, *args)
+
+    api._post_hook_state.set_global(fast)
+    pxla.ExecuteReplicated.__call__ = first
+    try:
+        yield rec
+    finally:
+        pxla.ExecuteReplicated.__call__ = slow
+        api._post_hook_state.set_global(None)
+        engine._kv_lock = rec.lock
+
+
+async def _steady_decode(engine, seen):
+    await collect(engine, greedy_request(REPETITIVE, 40))
+    return "_decode_multi", lambda: True
+
+
+async def _decode_joined_grown_overridden(engine, seen):
+    """Beside a held stream: a prompt whose row becomes ready in the
+    tick of a decode dispatch, page growth (every 8 tokens) and a disagg
+    inject, whose first token is an integer override."""
+    inject = greedy_request(list(range(100, 130)), 12)
+    first, k, v, ks, vs = await engine.prefill_only(inject)
+
+    async def injected():
+        ctx = Context(inject.to_dict())
+        return [f async for f in await engine.generate_remote(
+            ctx, first, k, v, ks, vs)]
+
+    await asyncio.gather(
+        _admission_wave(engine), injected(),
+    )
+    return "_decode_multi", lambda: (
+        any(b.rows_i[:, 3].any() for b in seen)           # an override
+        and len({len(b.active) for b in seen}) > 1        # rows joined
+        and len({b.rows_i[:, 6:].tobytes() for b in seen}) > 2  # tables moved
+    )
+
+
+async def _prefill_group(engine, seen):
+    rng = np.random.RandomState(1)
+    wave = [rng.randint(1, 200, size=45).tolist() for _ in range(3)]
+    await asyncio.gather(*(collect(engine, greedy_request(p, 4)) for p in wave))
+    return "_model_step", lambda: True
+
+
+@pytest.mark.parametrize("traffic", [
+    _steady_decode, _decode_joined_grown_overridden, _prefill_group,
+], ids=lambda f: f.__name__.strip("_"))
+async def test_a_dispatch_is_one_launch(traffic):
+    """Between `_kv_lock` acquire and release a dispatch runs exactly
+    one compiled program, its step, and no eager primitive: whatever
+    else touched a device array there (a slice, an `.at[].set`, a key
+    split) would be a launch of its own that the runtime cannot queue
+    behind the running step (PERF.md, PR 24 / PR 25)."""
+    engine = make_engine()
+    seen = []
+    run = engine._run_decode_dispatch_locked
+    engine._run_decode_dispatch_locked = (
+        lambda bld, rec: (seen.append(bld), run(bld, rec))[1])
+    with _count_launches(engine) as launches:
+        step, happened = await traffic(engine, seen)
+        await engine.close()
+    assert happened(), "the traffic never made the dispatch under test"
+    sections = launches.of(step)
+    assert len(sections) >= 2
+    for sec in sections:
+        assert len(sec["programs"]) == 1, sec
+        assert not sec["eager"], sec
+    # and nothing eager under the lock anywhere else (`_extract`,
+    # `_inject` are programs of their own sections)
+    assert not [sec for sec in launches.sections if sec["eager"]]
+
+
+def _seeded(prompt, max_tokens, seed):
+    return PreprocessedRequest(
+        token_ids=list(prompt),
+        stop_conditions=StopConditions(max_tokens=max_tokens, ignore_eos=True),
+        sampling_options=SamplingOptions(
+            temperature=0.9, top_k=40, seed=seed),
+    )
+
+
+def disturbed_requests(seeded: bool):
+    """(held, wave of 3, inject): the requests of `disturbed_run`."""
+    rng = np.random.RandomState(0)
+    wave = [rng.randint(1, 200, size=45).tolist() for _ in range(3)]
+    req = (lambda p, n, i: _seeded(p, n, 1000 + i)) if seeded else (
+        lambda p, n, i: greedy_request(p, n))
+    return (req(REPETITIVE, 48, 0),
+            [req(p, 10, 1 + i) for i, p in enumerate(wave)],
+            # a prefill_only row has no slot and samples on the plain
+            # path: its first token is reproducible only when greedy
+            greedy_request(list(range(100, 130)), 12))
+
+
+async def disturbed_run(engine, seeded: bool):
+    """A held stream; once it decodes, three prompts join beside the
+    dispatch in flight (24 pages: someone is preempted and re-armed
+    through a re-prefill) and a disagg inject enters with its integer
+    first token. Public entry points only. Returns every stream."""
+    held, wave, inject = disturbed_requests(seeded)
+    first, k, v, ks, vs = await engine.prefill_only(inject)
+    out = []
+    decoding = asyncio.Event()
+
+    async def hold():
+        async for f in await engine.generate(Context(held.to_dict())):
+            out.extend(f.get("token_ids") or [])
+            if len(out) > 1:
+                decoding.set()
+
+    async def injected():
+        frames = [f async for f in await engine.generate_remote(
+            Context(inject.to_dict()), first, k, v, ks, vs)]
+        return [t for f in frames for t in f.get("token_ids") or []]
+
+    task = asyncio.create_task(hold())
+    await decoding.wait()
+    streams = await asyncio.gather(
+        *(collect(engine, r) for r in wave), injected())
+    await task
+    return [out, *streams]
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["greedy", "seeded"])
+async def test_disturbed_rows_keep_their_tokens(seeded, caplog):
+    """A row that joins while a dispatch is in flight (its first token
+    written into the carry by the prefill program), a preempted row
+    (re-armed by its re-prefill) and a disagg inject (an integer
+    override in the next decode program's upload) emit what each
+    request emits alone on a serialized engine. (The same run gave the
+    parent commit's tokens, stream for stream: CHANGES.md, PR 25.)"""
+    import logging
+
+    held, wave, inject = disturbed_requests(seeded)
+    alone = make_engine(step_pipeline=False)
+    ref = [await collect(alone, r) for r in (held, *wave, inject)]
+    await alone.close()
+    engine = make_engine(num_pages=24)
+    with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine"):
+        got = await disturbed_run(engine, seeded)
+    await engine.close()
+    assert any("preempting" in r.message for r in caplog.records), (
+        "workload never preempted — shrink num_pages"
+    )
     assert got == ref

@@ -21,7 +21,7 @@ from .test_engine import collect, greedy_request, make_engine
 
 LOOP_PHASES = {"eng.tick", "eng.admit", "eng.prefill.build",
                "eng.decode.build", "eng.fetch", "eng.emit", "eng.wait"}
-WORKER_PHASES = {"eng.lock", "eng.upload", "eng.enqueue", "eng.carry"}
+WORKER_PHASES = {"eng.lock", "eng.upload", "eng.enqueue"}
 DISPATCH = ("prefill", "decode", "mixed", "spec_verify")
 REPETITIVE = [5, 17, 42, 9] * 6
 
