@@ -170,7 +170,7 @@ class KVCache(NamedTuple):
     """Per-layer flat slot pools: k/v are length-L tuples of
     [num_slots, K*Hd] arrays.
 
-    Two deliberate layout choices (both measured on v5e):
+    Three deliberate layout choices (all measured on v5e):
 
     - per-layer buffers (not one stacked [L, ...] array) so each layer's
       pool aliases straight through jit donation and the Pallas kernels —
@@ -181,7 +181,21 @@ class KVCache(NamedTuple):
       "page" a strided scatter across the whole pool and every page DMA
       ~15x slower. [N, K*Hd] keeps row-major tiling, so a page
       ([page_size, K*Hd]) is one contiguous DMA and the reshape to
-      [num_pages, page_size, K*Hd] is a free bitcast.
+      [num_pages, page_size, K*Hd] is a free bitcast;
+    - a quantized pool stays in HBM for the whole step, and every kernel
+      that takes one SAYS so (`ops/pallas_attention.in_hbm` on the
+      operand, `hbm_out` on the aliased result). Unsaid, XLA's
+      memory-space assignment treats a loop-carried buffer that fits
+      VMEM as a prefetch candidate: a 3.3 MB f32 scale pool fits, a
+      26 MB K pool does not, so each of the 64 scale pools went to VMEM
+      in four `slice-start/-done` pieces before its decode kernel and
+      came back by `copy-start/-done` after it, on every step of the
+      decode scan: 3.5-4.5 ms of a 19.4 ms step, 23% / 42% of the
+      device's time in the two benchmark cells (PERF.md, PR 29). The
+      kernels only DMA single pages out of the pools. A later change
+      must not hand a quantized pool to a pallas_call without `in_hbm`
+      (tests/test_tpu_compile.py compiles the scan for a described v5e
+      and fails on any such move).
 
     int8 KV mode (`kv_quant="int8"`): k/v hold int8 and `ks`/`vs` hold
     the per-token-per-kv-head f32 scale pools in the page-blocked

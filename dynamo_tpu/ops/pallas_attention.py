@@ -51,6 +51,30 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def in_hbm(pool: jax.Array, interpret: bool = False) -> jax.Array:
+    """A quantized pool as a kernel OPERAND, its memory space stated: HBM.
+
+    Left unstated, XLA's memory-space assignment takes any loop-carried
+    buffer that fits (a 3.3 MB f32 scale pool does, a 26 MB K pool does
+    not) for a prefetch candidate: it moved each of the 64 scale pools
+    into VMEM in four slices before its kernel and copied it back after,
+    on every step of the decode scan (3.5-4.5 ms of a 19.4 ms step on
+    v5e; KVCache docstring, docs/kv_cache.md). Every pallas_call that
+    takes a quantized pool wraps it with this and declares the pool it
+    returns with `hbm_out`, so the pass has nothing to decide. The
+    interpreter has no memory spaces and refuses an operand that names
+    one, so under `interpret` the pool passes through as it is."""
+    if interpret:
+        return pool
+    return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+
+
+def hbm_out(pool: jax.Array):
+    """The `out_shape` entry of a pool a kernel returns (aliased onto its
+    `in_hbm` operand): same shape and dtype, memory space HBM."""
+    return pltpu.HBM(pool.shape, pool.dtype)
+
+
 def _decode_kernel(
     # scalar prefetch
     lengths_ref,       # [B] i32: attended KV count per sequence (0 = inactive)
@@ -697,8 +721,11 @@ def fused_paged_decode_attention(
 
     scale = hd ** -0.5
     if quant:
-        ks_pages = k_scales   # already page-blocked [P, SUBL, S]
-        vs_pages = v_scales
+        # scale pools arrive page-blocked [P, SUBL, S]
+        k_pages, v_pages, ks_pages, vs_pages = (
+            in_hbm(p, interpret)
+            for p in (k_pages, v_pages, k_scales, v_scales)
+        )
         subl = k_scales.shape[1]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
@@ -795,10 +822,7 @@ def fused_paged_decode_attention(
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((b, hk, kwf), q.dtype),
-                jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-                jax.ShapeDtypeStruct(ks_pages.shape, jnp.float32),
-                jax.ShapeDtypeStruct(vs_pages.shape, jnp.float32),
+                *map(hbm_out, (k_pages, v_pages, ks_pages, vs_pages)),
             ],
             input_output_aliases=aliases,
             interpret=interpret,

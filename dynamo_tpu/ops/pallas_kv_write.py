@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.pallas_attention import hbm_out, in_hbm
 
 
 def _kernel(tbl_ref, kp_ref, vp_ref, src_k_ref, src_v_ref, ok_ref, ov_ref):
@@ -97,6 +98,9 @@ def paged_kv_write(
 
     if quant:
         subl = ks_cache.shape[1]
+        kp, vp, ks_cache, vs_cache = (
+            in_hbm(p, interpret) for p in (kp, vp, ks_cache, vs_cache)
+        )
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
@@ -120,12 +124,7 @@ def paged_kv_write(
         ok, ov, oks, ovs = pl.pallas_call(
             _kernel_q,
             grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct(kp.shape, kp.dtype),
-                jax.ShapeDtypeStruct(vp.shape, vp.dtype),
-                jax.ShapeDtypeStruct(ks_cache.shape, ks_cache.dtype),
-                jax.ShapeDtypeStruct(vs_cache.shape, vs_cache.dtype),
-            ],
+            out_shape=[*map(hbm_out, (kp, vp, ks_cache, vs_cache))],
             input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.pallas_attention import in_hbm
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -318,6 +319,10 @@ def flash_prefill_attention(
     subl = 0
     if quant:
         subl = k_scales.shape[1]
+        k_pages, v_pages, k_scales, v_scales = (
+            in_hbm(p, interpret)
+            for p in (k_pages, v_pages, k_scales, v_scales)
+        )
         scale_inputs = [*[k_scales] * ppb, *[v_scales] * ppb]
 
         def scale_spec(j):

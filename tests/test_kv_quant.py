@@ -13,6 +13,8 @@ baselines (docs/architecture.md:76-83) plus the block-copy machinery
 (lib/llm/src/kernels/block_copy.cu) that moves those pages.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +28,11 @@ from dynamo_tpu.llm.protocols.common import (
 )
 from dynamo_tpu.models import config as cfgmod
 from dynamo_tpu.models import llama
-from dynamo_tpu.ops.quant import dequantize_kv_rows, quantize_kv_rows
+from dynamo_tpu.ops.quant import (
+    dequantize_kv_rows,
+    quantize_kv_rows,
+    unpack_kv_slots,
+)
 from dynamo_tpu.runtime.pipeline.context import Context
 
 CFG = cfgmod.get_config("tiny")
@@ -662,3 +668,136 @@ async def test_engine_packed_tp2_serving_and_inject():
     assert got == a
     for e in (e1, e2, de):
         await e.close()
+
+
+# ------------------------------------- the pools' pass-through the scan
+#
+# Every quantized pool (k, v AND the f32 scale pools ks, vs) goes through
+# the decode scan in place: donated to the step program, aliased onto its
+# outputs, written by the kernel (or the row scatter) where it lies. On
+# the v5e a scale pool that XLA may place freely is moved to VMEM and
+# back around its kernel on every step (KVCache docstring); what the CPU
+# can hold is the contract around it: donation and aliasing of all
+# 4 x num_layers leaves, and the bytes the scan leaves in them.
+
+POOL_FORMATS = [
+    pytest.param(dict(kv_quantization="int8", attn_backend="pallas"),
+                 id="int8-packed"),
+    pytest.param(dict(kv_quantization="int8", attn_backend="gather"),
+                 id="int8-dense"),
+    pytest.param(dict(kv_quantization="int4", attn_backend="pallas"),
+                 id="int4"),
+]
+POOL_ENGINE = dict(
+    page_size=128, num_pages=8, max_model_len=512, prefill_chunk=128,
+    max_batch_size=2,
+)
+POOL_PROMPT = list(range(7, 47))
+
+
+async def _decode_3_dispatches(engine):
+    """Greedy tokens of one request that ends with the third decode
+    dispatch (the first token is the prefill's), and how many positions
+    then hold a written KV row (every token but the last was fed back)."""
+    n_new = 1 + 3 * engine.config.decode_steps
+    toks, _ = await collect(engine, req(POOL_PROMPT, n_new))
+    return toks, len(POOL_PROMPT) + n_new - 1
+
+
+@pytest.mark.parametrize("fmt", POOL_FORMATS)
+async def test_decode_scan_donates_and_aliases_every_pool(fmt):
+    """The compiled `_decode_multi` takes every pool leaf as a donated
+    parameter and its input/output aliasing maps each onto an output:
+    4 x num_layers leaves (k, v, ks, vs), none left out."""
+    e = make_engine(**POOL_ENGINE, **fmt)
+    calls = []
+    jitted = e._decode_fn
+
+    def recording(*args, **kw):
+        calls.append(jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+            if isinstance(a, jax.Array) else a, args))
+        return jitted(*args, **kw)
+
+    e._decode_fn = recording
+    await _decode_3_dispatches(e)
+    assert len(calls) >= 3
+    lowered = jitted.lower(*calls[0])
+    n_pools = 4 * CFG.num_layers
+    assert len(jax.tree.leaves(calls[0][1])) == n_pools
+
+    # donated: jit marks every kv leaf (argument 1) as donated
+    donated = jax.tree.leaves(lowered.args_info[0][1])
+    assert len(donated) == n_pools and all(a.donated for a in donated)
+
+    # aliased: each `kv.*` parameter of the optimized module appears in
+    # its input_output_alias map
+    text = lowered.compile().as_text()
+    header = text.split("\n", 1)[0]
+    aliased = {
+        int(p) for p in re.findall(r"\}: \((\d+), \{\}", header)
+    }
+    pool_params = {
+        name: int(num) for num, name in re.findall(
+            r"parameter\((\d+)\)[^\n]*op_name=\"(kv\.[a-z]+\[\d+\])\"", text)
+    }
+    assert sorted(pool_params) == sorted(
+        f"kv.{leaf}[{l}]" for leaf in ("k", "v", "ks", "vs")
+        for l in range(CFG.num_layers)
+    )
+    missing = {n for n, p in pool_params.items() if p not in aliased}
+    assert not missing, f"pool leaves without an aliased output: {missing}"
+    await e.close()
+
+
+@pytest.mark.parametrize("fmt", POOL_FORMATS)
+async def test_decode_scan_pool_bytes_match_gather_oracle(fmt):
+    """Three dispatches x `decode_steps` through the scan leave the
+    tokens and the pools of the gather oracle stepping ONE token a
+    dispatch: every written row's K / V bytes and scale columns, leaf by
+    leaf. Rows past the sequence are not compared (a page-granular
+    prefill write pads its page; a pipelined dispatch overshoots)."""
+    e = make_engine(**POOL_ENGINE, **fmt)
+    oracle = make_engine(**POOL_ENGINE, **{
+        **fmt, "attn_backend": "gather", "decode_steps": 1,
+    })
+    toks, n = await _decode_3_dispatches(e)
+    want = (await collect(oracle, req(POOL_PROMPT, len(toks))))[0]
+    assert toks == want
+    assert n <= e.page_size  # one page: the first the allocator hands out
+    same_arithmetic = not e._attn_pallas
+
+    def pools(eng):
+        # under the lock: a dispatch in flight has donated `eng.kv`
+        with eng._kv_lock:
+            kv = eng.kv
+            if eng._kv_packed:
+                kv = kv._replace(
+                    k=tuple(map(unpack_kv_slots, kv.k)),
+                    v=tuple(map(unpack_kv_slots, kv.v)),
+                )
+            return jax.tree.map(np.asarray, kv)
+
+    got_kv, ref_kv = pools(e), pools(oracle)
+    page = slice(e.page_size, e.page_size + n)
+    for l in range(CFG.num_layers):
+        for leaf in ("k", "v"):
+            np.testing.assert_array_equal(
+                getattr(got_kv, leaf)[l][page],
+                getattr(ref_kv, leaf)[l][page], err_msg=f"{leaf}[{l}]",
+            )
+        for leaf in ("ks", "vs"):
+            got = getattr(got_kv, leaf)[l][1, :, :n]
+            ref = getattr(ref_kv, leaf)[l][1, :, :n]
+            assert (ref != 1.0).any(), f"{leaf}[{l}]: oracle wrote no scale"
+            if l == 0 or same_arithmetic:
+                np.testing.assert_array_equal(got, ref, err_msg=f"{leaf}[{l}]")
+            else:
+                # a deeper layer's rows come through the layers below,
+                # whose attention the kernels sum in another order than
+                # the gather path: the last bit of a scale may differ
+                # (measured 1.5e-8 absolute), a lost write-back reads 1.0
+                np.testing.assert_allclose(
+                    got, ref, rtol=1e-5, atol=0, err_msg=f"{leaf}[{l}]")
+    await e.close()
+    await oracle.close()

@@ -21,6 +21,7 @@ may load libtpu (tests run under several xdist workers).
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,7 +104,7 @@ def _i32(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
 
-def _decode_step(cfg, page, mesh=None, kv_tp=1):
+def _decode_fn(cfg, page, mesh=None, kv_tp=1):
     def step(params, kv, tokens, positions, tables, lengths, write_pos):
         attn = llama.AttnSpec.pallas_decode(
             tables, lengths, page, write_pos=write_pos, interpret=False,
@@ -115,7 +116,11 @@ def _decode_step(cfg, page, mesh=None, kv_tp=1):
         )
         return llama.logits(params, cfg, hidden[:, 0]), kv
 
-    return jax.jit(step, donate_argnums=(1,))
+    return step
+
+
+def _decode_step(cfg, page, mesh=None, kv_tp=1):
+    return jax.jit(_decode_fn(cfg, page, mesh, kv_tp), donate_argnums=(1,))
 
 
 def _prefill_step(cfg, page):
@@ -170,6 +175,61 @@ def test_decode_layer_compiles(one_chip, no_persistent_cache,
     _assert_kernel(compiled)
 
 
+def _pool_moves(compiled, pool) -> list[str]:
+    """Instructions of the optimized module that move `pool` (a shape
+    and dtype), or a slice of it along its first dimension, between
+    memory spaces: XLA's prefetches and evictions (`slice-start`,
+    `copy-start`) and plain `copy`."""
+    assert pool.dtype == jnp.float32
+    dims = ",".join(map(str, pool.shape[1:]))
+    moved = re.compile(
+        r"\s*(?:ROOT )?%\S+ = .*?\bf32\[\d+," + dims
+        + r"\].*? (slice-start|copy-start|copy)\("
+    )
+    return [
+        line.strip()[:160] for line in compiled.as_text().splitlines()
+        if moved.match(line)
+    ]
+
+
+def test_decode_scan_leaves_scale_pools_in_hbm(one_chip, no_persistent_cache):
+    """The engine's decode dispatch at the benchmark's shapes (Mistral-7B
+    widths, int8 weights, 807 int32-packed pages of 128, width 64), two
+    layers through a two-step scan: no scale pool is moved. Left to
+    choose, XLA's memory-space assignment prefetched each loop-carried
+    3.3 MB f32 scale pool into VMEM in four slices before its kernel and
+    copied it back after, on every step (`ops/pallas_attention.in_hbm`);
+    the int32 K / V pools (26 MB) were never candidates."""
+    cfg = PRESETS["mistral-7b"].with_(num_layers=2)
+    page, num_pages, width, max_len = 128, 807, 64, 4096
+    params, kv = _shapes(cfg, kv_quant="int8", weights_int8=True,
+                         page=page, num_pages=num_pages)
+
+    step = _decode_fn(cfg, page)
+
+    def dispatch(params, kv, tokens, positions, tables):
+        def body(carry, _):
+            tokens, positions, kv = carry
+            lg, kv = step(params, kv, tokens, positions, tables,
+                          positions + 1, positions)
+            return (jnp.argmax(lg, -1).astype(jnp.int32), positions + 1,
+                    kv), None
+
+        (tokens, _, kv), _ = jax.lax.scan(
+            body, (tokens, positions, kv), None, length=2)
+        return tokens, kv
+
+    compiled = jax.jit(dispatch, donate_argnums=(1,)).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((width,), one_chip), _i32((width,), one_chip),
+        _i32((width, max_len // page), one_chip),
+    ).compile()
+    _assert_kernel(compiled, at_least=2)
+    assert kv.ks[0].shape == (num_pages, 8, page)
+    moves = _pool_moves(compiled, kv.ks[0])
+    assert not moves, "scale pools moved inside the step:\n" + "\n".join(moves)
+
+
 @pytest.mark.parametrize("preset,kv_quant,w8,page", FORMATS)
 @pytest.mark.parametrize("rows,bucket,wb", [(1, 64, 1), (2, 512, 16)],
                          ids=["n1-t64", "n2-t512"])
@@ -217,30 +277,39 @@ def test_ragged_attention_compiles(one_chip, no_persistent_cache,
     _assert_kernel(compiled)
 
 
-def _tp4_decode_args(tp4_mesh, page=64, batch=16):
+def _tp4_decode_args(tp4_mesh, page=64, batch=16, kv_quant=None):
     """(cfg, lowering arguments) of a one-layer Llama-3.1-8B decode step
     on the four-device mesh: params and pools under the engine's own
     shardings, the small per-row inputs replicated."""
     cfg = _one_layer("llama-3.1-8b")
-    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+    params, kv = _shapes(cfg, kv_quant=kv_quant, weights_int8=False,
                          page=page, num_pages=512, tp=4)
     params = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         params, meshmod.param_shardings(cfg, tp4_mesh),
     )
-    kv = _on(kv, meshmod.kv_cache_sharding(tp4_mesh))
+    data = meshmod.kv_cache_sharding(tp4_mesh)
+    kv = kv._replace(k=_on(kv.k, data), v=_on(kv.v, data))
+    if kv.quantized:
+        scale = NamedSharding(tp4_mesh, P(None, "tp", None))
+        kv = kv._replace(ks=_on(kv.ks, scale), vs=_on(kv.vs, scale))
     rep = NamedSharding(tp4_mesh, P())
     row = _i32((batch,), rep)
     tables = _i32((batch, MAX_MODEL_LEN // page), rep)
     return cfg, (params, kv, row, row, tables, row, row)
 
 
-def test_tp4_sharded_decode_compiles(tp4_mesh, no_persistent_cache):
+@pytest.mark.parametrize("kv_quant,page", [(None, 64), ("int8", 128)],
+                         ids=["bf16", "int8-packed"])
+def test_tp4_sharded_decode_compiles(tp4_mesh, no_persistent_cache,
+                                     kv_quant, page):
     """`--tp 4` at Llama-3.1-8B widths: the decode kernel under
-    shard_map over the four-device mesh; each device must hold a quarter
-    of the layer, and the program must hold collectives."""
-    cfg, args = _tp4_decode_args(tp4_mesh)
-    compiled = _decode_step(cfg, 64, mesh=tp4_mesh, kv_tp=4).lower(
+    shard_map over the four-device mesh (quantized: the pools' stated
+    memory space inside it, scale pools sharded over their sublane
+    rows); each device must hold a quarter of the layer, and the program
+    must hold collectives."""
+    cfg, args = _tp4_decode_args(tp4_mesh, page=page, kv_quant=kv_quant)
+    compiled = _decode_step(cfg, page, mesh=tp4_mesh, kv_tp=4).lower(
         *args).compile()
     _assert_kernel(compiled)
     text = compiled.as_text()
