@@ -139,8 +139,8 @@ class EngineConfig:
     # latency-hiding manual-TP layer executor (parallel/tp_overlap.py)
     # — per-layer psums decomposed into ring reduce-scatter +
     # matmul-fused all-gather with norms/residuals on the row-scattered
-    # view, halving EXPOSED collective bytes per layer (measured by the
-    # BENCH_TP_OVERLAP section). Greedy streams stay byte-identical to
+    # view, halving EXPOSED collective bytes per layer (asserted in
+    # tests/test_tp_overlap.py). Greedy streams stay byte-identical to
     # tp=1 (docs/parallelism.md documents the reduction-order
     # invariant). Serves the pallas backend with int8/int4 packed KV
     # (the kernels' per-layer shard_maps collapse into the executor's

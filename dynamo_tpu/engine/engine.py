@@ -354,8 +354,8 @@ class JaxEngine:
             )
             self._attn_pallas = False
         # int32-PACKED int8 pools (ops/quant.pack_kv_slots): f32-class DMA
-        # tiling recovers the int8 (32,128)-tile penalty (+12% decode at
-        # B=256, scripts/probe_decode_attrib.py). Serving (pallas) path
+        # tiling recovers the int8 (32,128)-tile penalty (the packed
+        # format is what both benchmark cells run). Serving (pallas) path
         # only — the gather/sp/pp paths keep dense int8 pools, and the
         # wire/offload formats stay dense int8 (pack/unpack at the edges)
         self._kv_packed = bool(
@@ -774,7 +774,7 @@ class JaxEngine:
             # per-layer TP collective attribution (tp>1 tp-only meshes;
             # docs/parallelism.md "TP comm/compute overlap"): EXPOSED
             # collective bytes per dispatch kind — the closed form
-            # behind the BENCH_TP_OVERLAP 0.5x invariant
+            # behind the exposed-bytes 0.5x invariant
             # (tp_overlap.collective_bytes_per_layer) times the
             # dispatch's physical token rows — plus collective_wall_s,
             # those bytes over the init-time psum bandwidth probe (an
@@ -3300,8 +3300,8 @@ class JaxEngine:
         parking its first tokens until a full decode dispatch + sync.
         Cold serves amortize that shadow over a long prefill; a
         prefix-hit's short tail lives entirely inside it — measured on
-        the CPU tiny rig as warm-TTFT ~0.84x of cold (the BENCH_r06
-        0.68x class). Dead dispatches must not gate emission."""
+        the CPU tiny rig as a warm TTFT no better than a cold one.
+        Dead dispatches must not gate emission."""
         if self._inflight_live():
             return True
         return any(

@@ -29,7 +29,7 @@ is gone. This module is the black box:
 
 Module registry: engines register their recorder at init (bounded,
 strong refs — a closed scenario engine's ring stays dumpable) so the
-HTTP ``/debug/snapshot`` handler and `scripts/run_scenarios.py` can
+HTTP ``/debug/snapshot`` handler and a multi-process proof can
 dump without holding an engine reference. See docs/observability.md
 "Forensics plane".
 """

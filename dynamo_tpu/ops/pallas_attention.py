@@ -313,13 +313,13 @@ def _decode_kernel_q(
 ):
     """int8 variant of `_decode_kernel`: pages are int8 plus transposed
     f32 scale pages [SUBL>=8, page_size] (ops/quant.py pool layout — the
-    only shape Mosaic can DMA). The streamed-page HBM traffic — 71% of
-    the int8-weights decode step at B=256 (KERNEL_TPU r3) — halves.
+    only shape Mosaic can DMA). The streamed-page HBM traffic — most of
+    an int8-weights decode step at wide batches (PERF.md section 5) — halves.
 
     `packed`: the pools arrive int32 [*, page_size//4, K*Hd] (4 token
     rows per int32 row, little-endian — ops/quant.pack_kv_slots). int8's
     (32, 128) VMEM tiles DMA ~1.4x slower per byte than f32-class
-    (8, 128) tiles (scripts/probe_decode_attrib.py), so the DMA moves
+    (8, 128) tiles (a round-4 probe, not in the ledger), so the DMA moves
     int32 tiles and the kernel reinterprets with pltpu.bitcast (probed:
     expands sublanes 4x in exactly the pack order). The new token's row
     is injected in the int32 domain — one byte lane of one packed row —

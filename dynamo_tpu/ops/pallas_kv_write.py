@@ -6,8 +6,8 @@ that is 32k rows x 16 layers ~= 390 ms, the single largest prefill cost.
 This kernel writes whole pages instead: the grid walks the chunk's page
 blocks and an output BlockSpec index_map routed by a scalar-prefetched
 page table lands each [page_size, K*Hd] block in place (input/output
-aliased pools, no copy). Measured 15.7x over the XLA scatter
-(scripts/proto_page_write.py; 1.57 ms vs 24.5 ms per layer).
+aliased pools, no copy): one block DMA per page where the XLA scatter
+moves every row by itself (the ratio on this chip is not measured).
 
 The TPU-native counterpart of the reference's block-copy kernel
 (reference: lib/llm/src/kernels/block_copy.cu:41-731 — cache-line-chunked

@@ -182,11 +182,11 @@ def gather_kv_scales(
 #
 # int8 VMEM tiles are (32, 128): the page DMA writes them ~1.4x slower per
 # byte than f32-class (8, 128) tiles (measured via the decode kernel's
-# nocompute ablation, scripts/probe_decode_attrib.py — the DMA floor was
+# nocompute ablation by a round-4 probe, not in the ledger — the floor was
 # 0.72x bf16's where bytes alone say 0.53x). Storing the pools as int32
 # [num_slots/4, K*Hd] gets the f32-class tiling; the kernels reinterpret
-# with pltpu.bitcast, whose measured v5e semantics (scripts/
-# probe_bitcast.py) expand the SUBLANE dim 4x with int32 row t holding
+# with pltpu.bitcast, whose v5e semantics (chip_smoke.py's int8 phase
+# holds them) expand the SUBLANE dim 4x with int32 row t holding
 # int8 rows 4t..4t+3 as its little-endian bytes. The XLA-side pack must
 # therefore interleave groups of 4 consecutive token rows into each int32
 # row — exactly what these helpers do (lax.bitcast_convert_type is also

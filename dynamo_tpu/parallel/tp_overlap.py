@@ -43,7 +43,7 @@ manual-TP path. Cross-shard summation order differs (the RS ring
 accumulates block j in cyclic order j+1, .., j-1, j; psum's order is
 XLA's choice) — exactly the class of difference the GSPMD tp path
 already carries vs tp=1 — and greedy streams stay byte-identical to
-tp=1 (gated by scripts/multichip_smoke.py and the tp_overlap bench).
+tp=1 (gated by scripts/multichip_smoke.py and tests/test_tp_overlap.py).
 
 Composition matrix (docs/parallelism.md "TP comm/compute overlap"):
 composes with mixed batching, the step pipeline, spec decode, the
@@ -386,9 +386,9 @@ def _layer_in_specs(layers: list[dict]) -> list[dict]:
 def single_layer_executor(
     cfg, mesh, b: int, t: int, page_size: int = 16, overlap: bool = True,
 ):
-    """One `layer_step` under shard_map — the bench/test harness behind
-    the tp_overlap BENCH_OUT section's serialized-vs-overlapped per-layer
-    wall and its amortization-free measured byte ratio.
+    """One `layer_step` under shard_map — the test harness behind
+    tests/test_tp_overlap.py's serialized-vs-overlapped per-layer
+    comparison and its amortization-free measured byte ratio.
 
     The overlap leg returns the residual STILL SCATTERED (out_spec
     P('tp', None) reassembles the global [Rp, D] for free — shard_map

@@ -27,12 +27,9 @@ Timeline (all durations configurable):
    lets the planner scale back down — scored: the drain was graceful
    (lease revoked BEFORE the process stopped, no SIGTERM escalation).
 
-Emits one JSON dict (the ``control`` BENCH_OUT section); run directly
-it prints the JSON and exits non-zero if the loop failed to close
-(no scale-up, infinite recovery, or an ungraceful drain). Also
-registered in the loadgen scenario registry as the ``control_chaos``
-adapter (docs/loadgen.md), so ``scripts/run_scenarios.py --scenarios
-all`` runs this proof too.
+Run directly it prints one JSON dict and exits non-zero if the loop
+failed to close (no scale-up, infinite recovery, or an ungraceful
+drain).
 
 ``--connector operator`` (or ``run_scenario(connector="operator")``)
 drives the SAME scenario through the planner's OTHER scale connector:

@@ -35,15 +35,13 @@ directly (`DataPlaneServer._die_abruptly`, the exact action the
 holds live streams; the cold leg goes through the fault registry
 itself to prove the DYN_FAULTS story end to end.
 
-Scored (the ``failover`` BENCH_OUT section): per-leg and pooled
-``recovered_frac`` (broken streams that finished clean),
+Scored: per-leg and pooled ``recovered_frac`` (broken streams that
+finished clean),
 ``replay_ttft_gap_p50_s`` (how long the client stalled across the
 death), and the continuation-token economics (recompute vs reused vs
 pulled). Run directly it prints the JSON and exits non-zero when the
 proof failed (a stream repeated/gapped a token, a broken stream was
-lost, or the reuse/pull legs recomputed). Also registered in the
-loadgen scenario registry as the ``failover`` adapter
-(docs/loadgen.md), so ``scripts/run_scenarios.py`` runs this proof too.
+lost, or the reuse/pull legs recomputed).
 """
 
 from __future__ import annotations
@@ -123,7 +121,7 @@ def _cfgs(d: dict):
         max_model_len=isl + max(d["osl"], d["hold_osl"]) + 32,
         prefill_chunk=isl,
         # routing/replay economics, not kernels: the gather oracle runs
-        # identically on CPU CI and on-TPU bench rigs
+        # identically on CPU CI and on a chip
         attn_backend="gather",
     )
     return mcfg, ecfg, isl
@@ -142,6 +140,7 @@ async def _fleet(
     data plane, so its severed streams' pages must still drain."""
     from dynamo_tpu.engine import JaxEngine
     from dynamo_tpu.llm.http.discovery import RouterEngine
+    from dynamo_tpu.llm.http.engine_service import engine_http_service
     from dynamo_tpu.llm.http.failover import FailoverConfig, FailoverEngine
     from dynamo_tpu.llm.kv_router import (
         KvEventPublisher,
@@ -150,7 +149,6 @@ async def _fleet(
         KvRouter,
     )
     from dynamo_tpu.llm.kv_router.pull import KvExportHandler, PrefixPuller
-    from dynamo_tpu.loadgen.http import engine_http_service
 
     mcfg, ecfg, isl = _cfgs(d)
     hub = HubServer()

@@ -8,8 +8,8 @@ Writes KERNEL_TPU.json at the repo root:
 Timing methodology: each config is timed as N chained kernel calls
 (each consuming the previous pool) ended by a value fetch, so the fixed
 cost of one dispatch is amortized over N and the fetch is the fence.
-The recorded KERNEL_TPU.json predates the directly attached v5e; its
-rates are claims to re-measure (ROADMAP queue 1).
+No copy of KERNEL_TPU.json is kept in the tree (it is gitignored): the
+serving path's kernel rates are the ledger's `decode_attn_roofline`.
 """
 
 from __future__ import annotations

@@ -29,16 +29,8 @@ Phases:
    PULLS the prefix from the holder (export_prefix -> ingest_prefix)
    instead. Scored: pulls landed + tokens moved.
 
-The $-per-million-tokens line converts each phase's wall into dollars
-at BENCH_CHIP_HOUR_USD (default 1.20 $/chip-hour, v5e-class on-demand):
-the warm phase serving the same token volume in less wall IS the cache
-economics, in the unit the ROADMAP asks for.
-
-Emits one JSON dict (the ``prefix_fleet`` BENCH_OUT section); run
-directly it prints the JSON and exits non-zero when the plane failed
-(no routing reuse, or no pull landed). Also registered in the loadgen
-scenario registry as the ``prefix_fleet`` adapter (docs/loadgen.md),
-so ``scripts/run_scenarios.py --scenarios all`` runs this proof too.
+Run directly it prints one JSON dict and exits non-zero when the plane
+failed (no routing reuse, no pull landed, or a page left in custody).
 """
 
 from __future__ import annotations
@@ -81,26 +73,7 @@ def _defaults() -> dict:
         hold_osl=64,          # held-stream length during the pull phase
         pull_threshold_pages=2,
         poll_interval=0.25,   # aggregator scrape cadence
-        chip_hour_usd=float(os.environ.get("BENCH_CHIP_HOUR_USD", "1.20")),
-        # KV pool tier for the fleet workers (None = engine-dtype KV).
-        # BENCH_PREFIX_FLEET_KV=int8|int4 runs the SAME routing/pull
-        # economics on quantized pools — the cross-worker pulls then
-        # move packed bytes (quantize-once: export/ingest carries the
-        # pool representation, never a requantization hop)
-        kv_quant=(os.environ.get("BENCH_PREFIX_FLEET_KV") or None),
     )
-
-
-def _phase_dollars(tokens: int, wall_s: float, usd_hour: float) -> dict:
-    return {
-        "tokens": tokens,
-        "wall_s": round(wall_s, 4),
-        "toks_per_sec": round(tokens / wall_s, 1) if wall_s else None,
-        "usd_per_mtok": (
-            round(usd_hour * (wall_s / 3600.0) / (tokens / 1e6), 4)
-            if tokens else None
-        ),
-    }
 
 
 async def run_scenario(**overrides) -> dict:
@@ -134,9 +107,8 @@ async def run_scenario(**overrides) -> dict:
             prefill_chunk=isl,
             # the scenario scores routing/transfer economics, not
             # kernels — the gather oracle runs identically on CPU CI
-            # and on-TPU bench rigs
+            # and on a chip
             attn_backend="gather",
-            kv_quantization=d["kv_quant"],
         )
 
     hub = HubServer()
@@ -153,7 +125,6 @@ async def run_scenario(**overrides) -> dict:
     wids: list[int] = []           # engine index -> hub worker id
     served: dict[str, int] = {}   # request_id -> worker index
     ledgers: dict[str, dict] = {}  # request_id -> prefix ledger
-    tokens_served: list[int] = [0]
     try:
         for i in range(2):
             drt = await DistributedRuntime.from_settings(hub_addr=hub_addr)
@@ -165,10 +136,6 @@ async def run_scenario(**overrides) -> dict:
             def _observe(summary, i=i):
                 served[summary["request_id"]] = i
                 ledgers[summary["request_id"]] = summary.get("prefix") or {}
-                tokens_served[0] += (
-                    (summary.get("prompt_tokens") or 0)
-                    + (summary.get("tokens") or 0)
-                )
 
             engine.subscribe_requests(_observe)
             ep = drt.namespace(NS).component(COMP).endpoint(EP)
@@ -243,22 +210,14 @@ async def run_scenario(**overrides) -> dict:
                 async for _ in await engine.generate(Context(pre.to_dict())):
                     pass
 
-        t_total0 = time.perf_counter()
-        tok_total0 = tokens_served[0]  # warmup tokens stay OUT of the
-        # headline dollars line: its wall starts here too
-        tok0 = tok_total0
-
         # ---- phase 1: cold — every tenant's first serve, nothing
         # cached. SEQUENTIAL serving in both measured phases: the two
         # tiny workers have max_batch slots each, and a concurrent
         # gather would fold queue-wait noise into the TTFT comparison
         cold_recs = [dict() for _ in range(d["tenants"] * d["cold_per_tenant"])]
-        t0 = time.perf_counter()
         for r in range(d["cold_per_tenant"]):
             for t in range(d["tenants"]):
                 await serve(t, cold_recs[r * d["tenants"] + t], d["osl"])
-        cold_wall = time.perf_counter() - t0
-        cold_tokens = tokens_served[0] - tok0
         holder = {  # tenant -> worker index that served it cold
             rec["tenant"]: served.get(rec["request_id"])
             for rec in cold_recs
@@ -273,16 +232,12 @@ async def run_scenario(**overrides) -> dict:
 
         # ---- phase 2: warm — fresh suffixes on the same prefixes; the
         # router must send each tenant back to its holder
-        tok0 = tokens_served[0]
         warm_recs = [
             dict() for _ in range(d["tenants"] * d["warm_per_tenant"])
         ]
-        t0 = time.perf_counter()
         for r in range(d["warm_per_tenant"]):
             for t in range(d["tenants"]):
                 await serve(t, warm_recs[r * d["tenants"] + t], d["osl"])
-        warm_wall = time.perf_counter() - t0
-        warm_tokens = tokens_served[0] - tok0
         to_holder = sum(
             1 for rec in warm_recs
             if served.get(rec["request_id"]) == holder.get(rec["tenant"])
@@ -323,15 +278,9 @@ async def run_scenario(**overrides) -> dict:
                 break
             await asyncio.sleep(d["poll_interval"] / 2)
         pull_recs = [dict() for _ in range(d["pull_requests"])]
-        t0 = time.perf_counter()
         for rec in pull_recs:
             await serve(victim_tenant, rec, d["osl"])
-        pull_wall = time.perf_counter() - t0
         await asyncio.gather(*held)
-
-        total_wall = time.perf_counter() - t_total0
-        total_tokens = tokens_served[0] - tok_total0
-        usd = d["chip_hour_usd"]
 
         def p50(recs):
             return round(
@@ -347,7 +296,7 @@ async def run_scenario(**overrides) -> dict:
         pulls["tokens_moved"] = sum(p.pull_tokens for p in pullers)
         # zero-orphan quiesce census (engine/kv_ledger.py): every page
         # the phases touched must be back to free/cached custody before
-        # teardown — a leak here fails the bench, not just a dashboard
+        # teardown — a leak here fails the proof, not just a dashboard
         census = await asyncio.to_thread(quiesce_census, engines)
         return {
             "scenario": {
@@ -355,10 +304,7 @@ async def run_scenario(**overrides) -> dict:
                 for k in ("tenants", "page", "prefix_pages", "suffix",
                           "osl", "warm_per_tenant", "pull_requests",
                           "max_batch")
-                # kv_quant joins the descriptor ONLY when set: the
-                # bench-history context must stay byte-identical for
-                # the existing unquantized baselines
-            } | ({"kv_quant": d["kv_quant"]} if d["kv_quant"] else {}),
+            },
             "ttft_cold_p50_s": p50(cold_recs),
             "ttft_warm_p50_s": p50(warm_recs),
             "ttft_pull_p50_s": p50(pull_recs),
@@ -369,13 +315,6 @@ async def run_scenario(**overrides) -> dict:
             "warm_reuse_frac": round(warm_reused / len(warm_recs), 3),
             "router_blocks": router.indexer.tree.num_blocks,
             "pulls": pulls,
-            "dollars": {
-                "chip_hour_usd": usd,
-                "cold": _phase_dollars(cold_tokens, cold_wall, usd),
-                "warm": _phase_dollars(warm_tokens, warm_wall, usd),
-                "pull_phase_wall_s": round(pull_wall, 4),
-                **_phase_dollars(total_tokens, total_wall, usd),
-            },
             "kv_census": census,
         }
     finally:

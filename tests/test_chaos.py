@@ -284,7 +284,7 @@ async def test_chaos_slo_breach_dumps_one_forensic_artifact(tmp_path):
 # indistinguishable from a SIGKILLed process — and the frontend must
 # resume the stream on the healthy worker with zero duplicated or
 # skipped tokens. The real-JaxEngine SSE variant of this proof is
-# scripts/failover_chaos.py (the `failover` BENCH_OUT section).
+# scripts/failover_chaos.py.
 
 
 def _arith_next(t: int) -> int:

@@ -427,7 +427,7 @@ async def test_sse_event_ids_and_reconnect_resume():
     uninterrupted one — no repeats, no gaps."""
     import aiohttp
 
-    from dynamo_tpu.loadgen.http import engine_http_service
+    from dynamo_tpu.llm.http.engine_service import engine_http_service
 
     class SlowArith(ArithEngine):
         async def generate(self, ctx):
@@ -508,7 +508,7 @@ async def test_sse_event_ids_and_reconnect_resume():
 async def test_sse_reconnect_expired_window_410():
     import aiohttp
 
-    from dynamo_tpu.loadgen.http import engine_http_service
+    from dynamo_tpu.llm.http.engine_service import engine_http_service
 
     async with engine_http_service(ArithEngine(0)) as svc:
         svc.sse_relay = SseRelay(grace_s=30.0)

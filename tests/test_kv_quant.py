@@ -1,8 +1,8 @@
 """int8 KV cache: quantized page pools + f32 scale pools end to end.
 
-The decode phase streams every live KV page per step — at B=256 decode
-attention was 71% of the int8-weights step (KERNEL_TPU r3), all of it
-bf16 page bandwidth. int8 pages halve that traffic. These tests pin the
+The decode phase streams every live KV page per step — at wide batches
+decode attention is most of an int8-weights step (PERF.md section 5),
+all of it page bandwidth. int8 pages halve that traffic. These tests pin the
 scheme (ops/quant.quantize_kv_rows: per-token-per-kv-head symmetric
 absmax) against the jnp oracle, the three pallas kernels (interpret
 mode), the serving engine, the offload tier, the disagg wire (including
@@ -441,7 +441,7 @@ def test_pack_unpack_roundtrip():
         np.asarray(unpack_kv_slots(packed)), np.asarray(rows)
     )
     # int32 row t must hold token rows 4t..4t+3 as little-endian bytes
-    # (the probed pltpu.bitcast order — scripts/probe_bitcast.py)
+    # (pltpu.bitcast's order on the chip; chip_smoke.py's int8 phase holds it)
     u = np.asarray(packed).view(np.uint32)
     for j in range(4):
         np.testing.assert_array_equal(

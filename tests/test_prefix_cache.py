@@ -142,11 +142,14 @@ async def test_prefix_hit_greedy_stream_byte_identical():
     tokens = rng.randint(1, TINY.vocab_size, size=3 * PAGE + 3).tolist()
     try:
         cold, meta_c = await collect(engine, tokens, max_tokens=8)
+        cold_prefilled = engine.phase_stats["prefill_tokens"]
         warm, meta_w = await collect(engine, tokens, max_tokens=8)
         assert meta_c["prefix_cached_tokens"] == 0
         assert meta_w["prefix_cached_tokens"] == 3 * PAGE
         assert warm == cold
         st = engine.phase_stats
+        # the warm serve dispatched fewer prefill tokens than the cold one
+        assert 0 < st["prefill_tokens"] - cold_prefilled < cold_prefilled
         assert st["prefix_hits"] == 1
         assert st["prefix_reused_tokens"] == 3 * PAGE
         assert st["prefix_tail_tokens"] == 3
