@@ -3183,7 +3183,9 @@ class JaxEngine:
         where it is read: `_phase_stats`, collective bytes, the flight
         digest, the ring. `rec`: rows, tokens, phys_rows (token rows
         through the layer stack, padding included), optionally budget,
-        build_s, span (more ring attributes); `_enqueue` adds starved. A body that raises books nothing. The
+        build_s, span (more ring attributes), kv_pages_streamed /
+        kv_pages_held (decode: `_kv_pages`); `_enqueue` adds starved. A
+        body that raises books nothing. The
         wall is a dispatch-CALL wall (a jit call returns once the work
         is enqueued); the counts are the load-bearing part."""
         with profiler.step_annotation(self._step_count), \
@@ -3209,6 +3211,8 @@ class JaxEngine:
             kind, t1 - t0, rows=rows, tokens=tokens,
             budget=rec.get("budget", 0), build_s=rec.get("build_s", 0.0),
             starved=rec.get("starved", 0),
+            kv_pages_streamed=rec.get("kv_pages_streamed", 0),
+            kv_pages_held=rec.get("kv_pages_held", 0),
         )
         if tracing.enabled():
             tracing.complete(
@@ -4538,6 +4542,10 @@ class JaxEngine:
                 phys_rows=bld.width * bld.steps,
                 span={"steps": bld.steps},
             )
+            if self._attn_pallas:
+                rec["kv_pages_streamed"], rec["kv_pages_held"] = (
+                    self._kv_pages(bld)
+                )
         rec["build_s"] = bld.build_s
         wd = self._op_begin("spec.dispatch" if bld.spec else "decode.dispatch")
         try:
@@ -4552,6 +4560,23 @@ class JaxEngine:
                 return self._run_decode_dispatch_locked(bld, rec)
         finally:
             self._op_end(wd)
+
+    def _kv_pages(self, bld: "_DecodeBuild") -> tuple[int, int]:
+        """KV pages one layer's decode kernel copies in over this
+        dispatch's steps (`ops.pallas_attention.streamed_pages`, the rule
+        its work list is built to) and the pages those rows hold: the
+        attended lengths are `_decode_multi`'s, from the build's
+        positions. Equal while the kernel reads only what a sequence
+        holds; the digest keeps both so a reader sees when it does not."""
+        from dynamo_tpu.ops.pallas_attention import streamed_pages
+
+        pos = bld.rows_i[[i for i, _ in bld.active], 0]
+        lengths = np.minimum(
+            pos[None, :] + 1 + np.arange(bld.steps)[:, None],
+            self.config.max_model_len,
+        )
+        ps = self.page_size
+        return streamed_pages(lengths, ps), int(np.sum(-(-lengths // ps)))
 
     def _run_spec_dispatch_locked(
         self, bld: "_DecodeBuild", rec: dict

@@ -77,6 +77,9 @@ FIELDS = (
     "emit_s",        # sync/overlap rows: landing the tokens after the fetch
     "starved",       # 1 = the device had drained when the jit call was made
     "preempted",     # the engine's cumulative preemption count
+    # pallas decode rows (PR 32), per layer over the dispatch's steps:
+    "kv_pages_streamed",  # KV pages the decode kernel copies in
+    "kv_pages_held",      # KV pages the rows' attended lengths hold
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

@@ -15,6 +15,22 @@ KV page exactly once. Design points (measured on v5e):
   streams stay full across sequence boundaries. A (batch,) grid paid
   ~20 us of pipeline overhead per program; per-program double buffering
   stalled at every sequence switch.
+- **a work item covers only the pages its sequence holds**
+  (`live_pages`: `pages_per_block` in every block but a sequence's last,
+  there what is left of `ceil(length / page_size)`, computed from the
+  lengths the kernel already has). The item's copies are started and
+  waited for page by page under that count, and its convert / QK /
+  softmax / PV run over `live_pages * page_size` positions under a
+  `lax.switch` on it: shapes are static, so each count is a branch over
+  statically sliced buffers with the same (m, l, acc) carry. A copy
+  skipped WITHOUT its compute skipped is wrong, not slow: the ring's
+  buffers start uninitialised, the mask is `exp(-inf) = 0`, and a
+  float32 scale tile (or a bf16 page) of garbage times 0 is NaN. Before
+  this rule a 512-token item read, converted and multiplied up to three
+  trash pages past each sequence's end: 1.17x the pages held at the
+  benchmark's decode-saturate contexts (PERF.md section 6, PR 32).
+  `streamed_pages` is the same rule on the host: the engine's digests
+  and the benchmark's `decode_kv_read_amp` count by it.
 - **fused cache write**: XLA lowers `pool.at[slots].set(rows)` to a
   scatter the TPU backend serializes (~20 us/row); instead the kernel
   injects the new token's K/V into its page while that page sits in VMEM
@@ -30,8 +46,21 @@ KV page exactly once. Design points (measured on v5e):
   physically contiguous — XLA lays [N, K, Hd] out slot-minor, which turns
   page DMA into a strided scatter (~15x slower).
 
-VMEM budget: q/out [B, H, K*Hd] + NBUF block buffers; at B=128, H=32,
-K*Hd=512, page 64 x ppb 4 x NBUF 4 that is ~10 MB.
+VMEM budget: q/out [B, H, K*Hd] + NBUF block buffers. At the deployment
+the benchmark runs (Mistral-7B: H=32, K*Hd=1024; int32-packed int8 pages
+of 128, decode width 64, ppb 4 = a 512-token item, NBUF 4): two 2 MiB
+page rings (4 x 4 x 32 packed rows x 1024 x 4 B, K and V), two 4 MiB
+query / output blocks (64 x 32 x 1024 bf16), under 0.3 MiB of scale
+rings, staging tiles and new rows. bf16 pools at page 64 (256-token
+items): two 4 MiB rings at the same widths. The block was tuned at 256
+tokens and doubled with the deployment's page; smaller blocks were timed
+again on the chip in PR 32 and are slower (PERF.md section 6): an item's
+fixed cost (semaphore waits, the accumulator's rescale, two MXU
+fill / drains in a serial QK -> softmax -> PV chain that nothing overlaps
+across loop iterations) is paid per item, and the page count per item
+takes the saving with no more items. What bounds the kernel there is the
+vector unit (int8 -> f32 of every K and V tile), not its copies: with
+the convert ablated it runs at its DMA floor.
 
 Sharding: KV heads are the tp axis. The kernel is written for the
 per-shard view (local K heads); `shard_map` wrapping happens in the
@@ -44,6 +73,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,6 +103,79 @@ def hbm_out(pool: jax.Array):
     """The `out_shape` entry of a pool a kernel returns (aliased onto its
     `in_hbm` operand): same shape and dtype, memory space HBM."""
     return pltpu.HBM(pool.shape, pool.dtype)
+
+
+# the serving call site passes neither: a work item is this many pages
+# (512 tokens at the benchmark's page 128, 256 at page 64), the DMA ring
+# this many items deep. Timed alone on the v5e at the decode-saturate
+# cell's shape (scripts/kernel_check_tpu.py --cell-shape; PERF.md section
+# 6, PR 32): blocks of 1 / 2 / 8 pages and rings of 2 / 3 / 6 / 8 items
+# are all slower.
+PAGES_PER_BLOCK = 4
+NBUF = 4
+
+
+def live_pages(length, blk, page_size: int, pages_per_block: int, xp=jnp):
+    """Pages of work item (sequence, block `blk`) that the sequence holds:
+    `pages_per_block` in every block but the last, there what is left of
+    `ceil(length / page_size)`. The ONE rule of what a work item copies
+    in, waits for and computes over; `streamed_pages` sums it (`xp=np`:
+    host arrays; the kernels pass traced int32 scalars)."""
+    div = np.floor_divide if xp is np else jax.lax.div
+    pages = div(length + (page_size - 1), page_size)
+    return xp.minimum(pages - blk * pages_per_block, pages_per_block)
+
+
+def switch_live_pages(item, n_live, pages_per_block: int, *carry):
+    """`item(n, *carry)` under a branch on the work item's live page count
+    (1..pages_per_block): shapes in a kernel are static, so each count is
+    its own branch over statically sliced buffers, all returning the same
+    carry."""
+    if pages_per_block == 1:
+        return item(1, *carry)
+    return jax.lax.switch(
+        n_live - 1,
+        [functools.partial(item, n) for n in range(1, pages_per_block + 1)],
+        *carry,
+    )
+
+
+def work_list(lengths: jax.Array, t_blk: int, max_blocks: int):
+    """The kernels' flat work list: (sequence, page-block) pairs in
+    sequence order, `ceil(length / t_blk)` a sequence, empty rows skipped
+    — the DMA ring stays full across sequence boundaries. Returns
+    (work_seq [B * max_blocks], work_blk, n_work); entries from `n_work`
+    on are 0 and never walked."""
+    b = lengths.shape[0]
+    bps = (lengths + t_blk - 1) // t_blk                   # blocks per seq
+    csum = jnp.cumsum(bps)
+    n_work = csum[-1]
+    widx = jnp.arange(b * max_blocks, dtype=jnp.int32)
+    work_seq = jnp.searchsorted(csum, widx, side="right").astype(jnp.int32)
+    safe_seq = jnp.minimum(work_seq, b - 1)
+    work_blk = widx - (csum[safe_seq] - bps[safe_seq])
+    work_seq = jnp.where(widx < n_work, safe_seq, 0)
+    work_blk = jnp.where(widx < n_work, work_blk, 0).astype(jnp.int32)
+    return work_seq, work_blk, n_work
+
+
+def streamed_pages(lengths, page_size: int,
+                   pages_per_block: int = PAGES_PER_BLOCK) -> int:
+    """KV pages ONE layer's decode kernel copies in for these attended
+    lengths (any shape; 0 = a row with no work): the work list's
+    `ceil(length / block)` items a sequence, each its `live_pages`. The
+    engine books it per decode dispatch beside the pages held (digest
+    columns `kv_pages_streamed` / `kv_pages_held`), so a change of page
+    size or block that makes the kernel read past a sequence's end shows
+    as a ratio above 1. Host-side numpy; exact on any backend."""
+    lengths = np.asarray(lengths, np.int64).ravel()
+    items = -(-lengths // (page_size * pages_per_block))
+    return sum(
+        int(live_pages(
+            lengths[blk < items], blk, page_size, pages_per_block, xp=np
+        ).sum())
+        for blk in range(int(items.max(initial=0)))
+    )
 
 
 def _decode_kernel(
@@ -114,20 +217,29 @@ def _decode_kernel(
     n_work = n_work_ref[0]
 
     def start_work_dma(w, slot):
+        # the item's LIVE pages only (`live_pages`): a table entry past
+        # the sequence's end names the trash page, and nothing reads it
         seq = work_seq_ref[w]
         blk = work_blk_ref[w]
+        n_live = live_pages(lengths_ref[seq], blk, page_size, pages_per_block)
         for p in range(pages_per_block):
-            page_id = tables_ref[seq, blk * pages_per_block + p]
-            pltpu.make_async_copy(
-                k_pages_hbm.at[page_id], k_buf.at[slot, p], k_sems.at[slot]
-            ).start()
-            pltpu.make_async_copy(
-                v_pages_hbm.at[page_id], v_buf.at[slot, p], v_sems.at[slot]
-            ).start()
 
-    def wait_work_dma(slot):
-        # one wait per started copy: semaphores count completions
-        for _ in range(pages_per_block):
+            @pl.when(p < n_live)
+            def _start(p=p):
+                page_id = tables_ref[seq, blk * pages_per_block + p]
+                pltpu.make_async_copy(
+                    k_pages_hbm.at[page_id], k_buf.at[slot, p],
+                    k_sems.at[slot],
+                ).start()
+                pltpu.make_async_copy(
+                    v_pages_hbm.at[page_id], v_buf.at[slot, p],
+                    v_sems.at[slot],
+                ).start()
+
+    def wait_work_dma(slot, n):
+        # one wait per started copy: semaphores count completions, so the
+        # item's `n` live pages (static: inside its branch) and no more
+        for _ in range(n):
             pltpu.make_async_copy(
                 k_pages_hbm.at[0], k_buf.at[slot, 0], k_sems.at[slot]
             ).wait()
@@ -166,8 +278,7 @@ def _decode_kernel(
         length = lengths_ref[seq]
         wpos = wpos_ref[seq]
         slot = jax.lax.rem(w, nbuf)
-
-        wait_work_dma(slot)
+        n_live = live_pages(length, blk, page_size, pages_per_block)
 
         # fresh sequence: reset the flash state
         is_first = blk == 0
@@ -175,17 +286,31 @@ def _decode_kernel(
         l_prev = jnp.where(is_first, jnp.zeros_like(l_prev), l_prev)
         acc = jnp.where(is_first, jnp.zeros_like(acc), acc)
 
-        kb = k_buf[slot].reshape(t_blk, kw)
-        vb = v_buf[slot].reshape(t_blk, kw)
+        def item(n, m_prev, l_prev, acc):
+            # the whole item over its first `n` pages (static): fused
+            # write, then one online-softmax step. Pages past them were
+            # never copied in: the ring's buffers start uninitialised,
+            # and a NaN there times a masked probability of 0 is NaN, so
+            # the compute is sliced with the copies, not masked after
+            # them. All of it sits in the branch: a value made outside
+            # one and used inside goes through VMEM on its way
+            t = n * page_size
+            wait_work_dma(slot, n)
+            kb = k_buf[slot, :n].reshape(t, kw)
+            vb = v_buf[slot, :n].reshape(t, kw)
+            if ablate == "nocompute":
+                touch = jnp.sum(kb.astype(jnp.float32)) * 0.0
+                return m_prev, l_prev, acc + touch
 
-        if ablate == "nocompute":
-            acc = acc + jnp.sum(kb.astype(jnp.float32)) * 0.0
-        else:
             # fused cache update: inject the new token's K/V row into the
-            # block that owns position `wpos` (the final block), store the
+            # block that owns position `wpos` (the final block; the
+            # position is attended, so its page is live), store the
             # block back and write just that page to HBM
-            do_write = (wpos >= 0) & (blk == jax.lax.div(wpos, t_blk))
-            row = jax.lax.broadcasted_iota(jnp.int32, (t_blk, kw), 0)
+            do_write = (
+                (wpos >= 0) & (wpos < length)
+                & (blk == jax.lax.div(wpos, t_blk))
+            )
+            row = jax.lax.broadcasted_iota(jnp.int32, (t, kw), 0)
             off = wpos - blk * t_blk
             inject = do_write & (row == off)
             kb = jnp.where(inject, knew_ref[seq], kb)
@@ -193,8 +318,8 @@ def _decode_kernel(
 
             @pl.when(do_write)
             def _store_back():
-                k_buf[slot] = kb.reshape(pages_per_block, page_size, kw)
-                v_buf[slot] = vb.reshape(pages_per_block, page_size, kw)
+                k_buf[slot, :n] = kb.reshape(n, page_size, kw)
+                v_buf[slot, :n] = vb.reshape(n, page_size, kw)
                 p_local = jax.lax.div(off, page_size)
                 page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
                 pltpu.make_async_copy(
@@ -211,36 +336,39 @@ def _decode_kernel(
                 qb_ref[seq].astype(jnp.float32), kb.astype(jnp.float32),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [H, T_blk]
+            )  # [H, t]
 
             pos = blk * t_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(pos < length, s, _NEG_INF)
 
             m_curr = jnp.max(s, axis=-1, keepdims=True)            # [H, 1]
             m_next = jnp.maximum(m_prev, m_curr)
-            p_blk = jnp.exp(s - m_next)                             # [H, T]
+            p_blk = jnp.exp(s - m_next)                             # [H, t]
             l_curr = jnp.sum(p_blk, axis=-1, keepdims=True)
             alpha = jnp.exp(m_prev - m_next)
             l_next = alpha * l_prev + l_curr
 
-            # ONE PV dot: [H, T] @ [T, K*Hd]; the caller keeps only each
+            # ONE PV dot: [H, t] @ [t, K*Hd]; the caller keeps only each
             # row's own head-column block
             o_curr = jax.lax.dot_general(
                 p_blk, vb.astype(jnp.float32),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            acc = acc * alpha + o_curr
-            m_prev, l_prev = m_next, l_next
+            return m_next, l_next, acc * alpha + o_curr
 
-            # last block of this sequence: emit the normalized output
-            n_blocks = lax_cdiv(length, t_blk)
+        m_prev, l_prev, acc = switch_live_pages(
+            item, n_live, pages_per_block, m_prev, l_prev, acc
+        )
 
-            @pl.when(blk == n_blocks - 1)
-            def _emit():
-                o_ref[seq] = (
-                    acc / jnp.maximum(l_prev, 1e-30)
-                ).astype(o_ref.dtype)
+        # last block of this sequence: emit the normalized output
+        n_blocks = lax_cdiv(length, t_blk)
+
+        @pl.when(blk == n_blocks - 1)
+        def _emit():
+            o_ref[seq] = (
+                acc / jnp.maximum(l_prev, 1e-30)
+            ).astype(o_ref.dtype)
 
         # refill the ring with the work item NBUF ahead
         nxt = w + nbuf
@@ -362,33 +490,41 @@ def _decode_kernel_q(
         return lo, hi
 
     def start_work_dma(w, slot):
+        # the item's LIVE pages only (`live_pages`): data and scale tile
         seq = work_seq_ref[w]
         blk = work_blk_ref[w]
+        n_live = live_pages(lengths_ref[seq], blk, page_size, pages_per_block)
         for p in range(pages_per_block):
-            page_id = tables_ref[seq, blk * pages_per_block + p]
-            pltpu.make_async_copy(
-                k_pages_hbm.at[page_id], k_buf.at[slot, p], k_sems.at[slot]
-            ).start()
-            pltpu.make_async_copy(
-                v_pages_hbm.at[page_id], v_buf.at[slot, p], v_sems.at[slot]
-            ).start()
-            if ablate != "noscale_dma":
+
+            @pl.when(p < n_live)
+            def _start(p=p):
+                page_id = tables_ref[seq, blk * pages_per_block + p]
                 pltpu.make_async_copy(
-                    ks_pages_hbm.at[page_id],
-                    ks_buf.at[slot, :, p * page_size:(p + 1) * page_size],
+                    k_pages_hbm.at[page_id], k_buf.at[slot, p],
                     k_sems.at[slot],
                 ).start()
                 pltpu.make_async_copy(
-                    vs_pages_hbm.at[page_id],
-                    vs_buf.at[slot, :, p * page_size:(p + 1) * page_size],
+                    v_pages_hbm.at[page_id], v_buf.at[slot, p],
                     v_sems.at[slot],
                 ).start()
+                if ablate != "noscale_dma":
+                    pltpu.make_async_copy(
+                        ks_pages_hbm.at[page_id],
+                        ks_buf.at[slot, :, p * page_size:(p + 1) * page_size],
+                        k_sems.at[slot],
+                    ).start()
+                    pltpu.make_async_copy(
+                        vs_pages_hbm.at[page_id],
+                        vs_buf.at[slot, :, p * page_size:(p + 1) * page_size],
+                        v_sems.at[slot],
+                    ).start()
 
-    def wait_work_dma(slot):
-        # one wait per started copy, with a descriptor matching each
-        # enqueued copy's SIZE — TPU DMA semaphores count bytes, so a
+    def wait_work_dma(slot, n):
+        # one wait per started copy — the item's `n` live pages (static:
+        # inside its branch) and no more — with a descriptor matching
+        # each enqueued copy's SIZE: TPU DMA semaphores count bytes, so a
         # data-page wait cannot stand in for a scale-tile copy
-        for _ in range(pages_per_block):
+        for _ in range(n):
             pltpu.make_async_copy(
                 k_pages_hbm.at[0], k_buf.at[slot, 0], k_sems.at[slot]
             ).wait()
@@ -438,179 +574,190 @@ def _decode_kernel_q(
         length = lengths_ref[seq]
         wpos = wpos_ref[seq]
         slot = jax.lax.rem(w, nbuf)
-
-        wait_work_dma(slot)
+        n_live = live_pages(length, blk, page_size, pages_per_block)
 
         is_first = blk == 0
         m_prev = jnp.where(is_first, jnp.full_like(m_prev, _NEG_INF), m_prev)
         l_prev = jnp.where(is_first, jnp.zeros_like(l_prev), l_prev)
         acc = jnp.where(is_first, jnp.zeros_like(acc), acc)
 
-        ksb = ks_buf[slot]                       # [SUBL, t_blk]
-        vsb = vs_buf[slot]
+        def item(n, m_prev, l_prev, acc):
+            # the whole item over its first `n` pages (static): fused
+            # write, then one online-softmax step. Pages past them were
+            # never copied in: the ring's buffers start uninitialised,
+            # and a float32 scale tile of garbage times a masked
+            # probability of 0 is NaN, so the compute is sliced with the
+            # copies, not masked after them. All of it sits in the
+            # branch: a value made outside one and used inside goes
+            # through VMEM on its way
+            t = n * page_size
+            wait_work_dma(slot, n)
+            ksb = ks_buf[slot, :, :t]                # [SUBL, t]
+            vsb = vs_buf[slot, :, :t]
 
-        # fused cache update: inject the new token's int8 K/V row into its
-        # data page and its scale column into the block-wide scale buffer,
-        # store both back and write just that page pair to HBM
-        do_write = (wpos >= 0) & (blk == jax.lax.div(wpos, t_blk))
-        off = wpos - blk * t_blk
-        if packed:
-            # int32 domain: the token's row is byte lane off%4 of packed
-            # row off//4; mask-merge the new int8 row's bytes in place
-            kb32 = k_buf[slot].reshape(t_blk // 4, kwp)
-            vb32 = v_buf[slot].reshape(t_blk // 4, kwp)
-            shift = jax.lax.rem(off, 4) * 8
-            mask = 0xFF << shift
-            row32 = jax.lax.broadcasted_iota(jnp.int32, (t_blk // 4, kwp), 0)
-            inj = do_write & (row32 == jax.lax.div(off, 4))
-            nk32 = (knew_ref[seq].astype(jnp.int32) & 0xFF) << shift
-            nv32 = (vnew_ref[seq].astype(jnp.int32) & 0xFF) << shift
-            kb32 = jnp.where(inj, (kb32 & ~mask) | nk32, kb32)
-            vb32 = jnp.where(inj, (vb32 & ~mask) | nv32, vb32)
-            kb = pltpu.bitcast(kb32, jnp.int8)   # [t_blk, kwp]
-            vb = pltpu.bitcast(vb32, jnp.int8)
-        else:
-            kb = k_buf[slot].reshape(t_blk, kwp)
-            vb = v_buf[slot].reshape(t_blk, kwp)
-            row = jax.lax.broadcasted_iota(jnp.int32, (t_blk, kwp), 0)
-            kb = jnp.where(do_write & (row == off), knew_ref[seq], kb)
-            vb = jnp.where(do_write & (row == off), vnew_ref[seq], vb)
-        p_loc = jax.lax.div(off, page_size)
-        slane = jax.lax.broadcasted_iota(jnp.int32, (subl, t_blk), 1)
-        sc_mask = do_write & (slane == off)
-        ksb = jnp.where(sc_mask, ksnew_ref[seq].reshape(subl, 1), ksb)
-        vsb = jnp.where(sc_mask, vsnew_ref[seq].reshape(subl, 1), vsb)
-
-        @pl.when(do_write)
-        def _store_back():
+            # fused cache update: inject the new token's int8 K/V row into
+            # its data page and its scale column into the block-wide scale
+            # buffer, store both back and write just that page pair to HBM
+            # (the position is attended, so its page is live)
+            do_write = (
+                (wpos >= 0) & (wpos < length)
+                & (blk == jax.lax.div(wpos, t_blk))
+            )
+            off = wpos - blk * t_blk
             if packed:
-                k_buf[slot] = kb32.reshape(pages_per_block, page_size // 4, kwp)
-                v_buf[slot] = vb32.reshape(pages_per_block, page_size // 4, kwp)
+                # int32 domain: the token's row is byte lane off%4 of
+                # packed row off//4; mask-merge the new int8 row's bytes
+                kb32 = k_buf[slot, :n].reshape(t // 4, kwp)
+                vb32 = v_buf[slot, :n].reshape(t // 4, kwp)
+                shift = jax.lax.rem(off, 4) * 8
+                mask = 0xFF << shift
+                row32 = jax.lax.broadcasted_iota(jnp.int32, (t // 4, kwp), 0)
+                inj = do_write & (row32 == jax.lax.div(off, 4))
+                nk32 = (knew_ref[seq].astype(jnp.int32) & 0xFF) << shift
+                nv32 = (vnew_ref[seq].astype(jnp.int32) & 0xFF) << shift
+                kb32 = jnp.where(inj, (kb32 & ~mask) | nk32, kb32)
+                vb32 = jnp.where(inj, (vb32 & ~mask) | nv32, vb32)
+                kb = pltpu.bitcast(kb32, jnp.int8)   # [t, kwp]
+                vb = pltpu.bitcast(vb32, jnp.int8)
             else:
-                k_buf[slot] = kb.reshape(pages_per_block, page_size, kwp)
-                v_buf[slot] = vb.reshape(pages_per_block, page_size, kwp)
-            ks_buf[slot] = ksb
-            vs_buf[slot] = vsb
-            # select the written page's [SUBL, S] scale tile (static
-            # slices + runtime select: lane offsets must be static)
-            kt = jnp.zeros((subl, page_size), jnp.float32)
-            vt = jnp.zeros((subl, page_size), jnp.float32)
-            for p in range(pages_per_block):
-                sel = p_loc == p
-                kt = jnp.where(
-                    sel, ksb[:, p * page_size:(p + 1) * page_size], kt
-                )
-                vt = jnp.where(
-                    sel, vsb[:, p * page_size:(p + 1) * page_size], vt
-                )
-            ks_stage[slot] = kt
-            vs_stage[slot] = vt
-            page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
-            pltpu.make_async_copy(
-                k_buf.at[slot, p_loc], ko_pages_hbm.at[page_id], w_sem
-            ).start()
-            pltpu.make_async_copy(
-                ks_stage.at[slot], kso_pages_hbm.at[page_id], w_sem
-            ).start()
-            pltpu.make_async_copy(
-                v_buf.at[slot, p_loc], vo_pages_hbm.at[page_id], w_sem
-            ).start()
-            pltpu.make_async_copy(
-                vs_stage.at[slot], vso_pages_hbm.at[page_id], w_sem
-            ).start()
-            wb_pending[slot] = 1
+                kb = k_buf[slot, :n].reshape(t, kwp)
+                vb = v_buf[slot, :n].reshape(t, kwp)
+                row = jax.lax.broadcasted_iota(jnp.int32, (t, kwp), 0)
+                kb = jnp.where(do_write & (row == off), knew_ref[seq], kb)
+                vb = jnp.where(do_write & (row == off), vnew_ref[seq], vb)
+            p_loc = jax.lax.div(off, page_size)
+            slane = jax.lax.broadcasted_iota(jnp.int32, (subl, t), 1)
+            sc_mask = do_write & (slane == off)
+            ksb = jnp.where(sc_mask, ksnew_ref[seq].reshape(subl, 1), ksb)
+            vsb = jnp.where(sc_mask, vsnew_ref[seq].reshape(subl, 1), vsb)
 
-        if ablate in ("nocompute", "noconvert"):
-            # DMA + loop floor: "nocompute" converts the full buffers
-            # (mirrors the bf16 kernel's ablation), "noconvert" touches
-            # 8 rows only — the delta isolates the int8->f32 VPU cost
-            if ablate == "nocompute":
-                touch = (
-                    jnp.sum(kb.astype(jnp.float32))
-                    + jnp.sum(vb.astype(jnp.float32))
+            @pl.when(do_write)
+            def _store_back():
+                if packed:
+                    k_buf[slot, :n] = kb32.reshape(n, page_size // 4, kwp)
+                    v_buf[slot, :n] = vb32.reshape(n, page_size // 4, kwp)
+                else:
+                    k_buf[slot, :n] = kb.reshape(n, page_size, kwp)
+                    v_buf[slot, :n] = vb.reshape(n, page_size, kwp)
+                ks_buf[slot, :, :t] = ksb
+                vs_buf[slot, :, :t] = vsb
+                # select the written page's [SUBL, S] scale tile (static
+                # slices + runtime select: lane offsets must be static)
+                kt = jnp.zeros((subl, page_size), jnp.float32)
+                vt = jnp.zeros((subl, page_size), jnp.float32)
+                for p in range(n):
+                    sel = p_loc == p
+                    kt = jnp.where(
+                        sel, ksb[:, p * page_size:(p + 1) * page_size], kt
+                    )
+                    vt = jnp.where(
+                        sel, vsb[:, p * page_size:(p + 1) * page_size], vt
+                    )
+                ks_stage[slot] = kt
+                vs_stage[slot] = vt
+                page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
+                pltpu.make_async_copy(
+                    k_buf.at[slot, p_loc], ko_pages_hbm.at[page_id], w_sem
+                ).start()
+                pltpu.make_async_copy(
+                    ks_stage.at[slot], kso_pages_hbm.at[page_id], w_sem
+                ).start()
+                pltpu.make_async_copy(
+                    v_buf.at[slot, p_loc], vo_pages_hbm.at[page_id], w_sem
+                ).start()
+                pltpu.make_async_copy(
+                    vs_stage.at[slot], vso_pages_hbm.at[page_id], w_sem
+                ).start()
+                wb_pending[slot] = 1
+
+            if ablate in ("nocompute", "noconvert"):
+                # DMA + loop floor: "nocompute" converts the full buffers
+                # (mirrors the bf16 kernel's ablation), "noconvert"
+                # touches 8 rows only — the delta isolates the int8->f32
+                # VPU cost
+                if ablate == "nocompute":
+                    touch = (
+                        jnp.sum(kb.astype(jnp.float32))
+                        + jnp.sum(vb.astype(jnp.float32))
+                    )
+                else:
+                    touch = (
+                        jnp.sum(kb[0:8, :].astype(jnp.float32))
+                        + jnp.sum(vb[0:8, :].astype(jnp.float32))
+                    )
+                return m_prev, l_prev, acc + touch * 0.0
+
+            # int8 values are exact in bf16, so the data dot needs no
+            # HIGHEST; K-scales fold into the score lanes afterwards (one
+            # VPU repeat). (probed: casting to bf16 instead of f32 here
+            # is ~4% SLOWER — int8->bf16 goes through f32 plus a truncate
+            # on the VPU)
+            if int4:
+                klo, khi = nibbles(kb)               # [t, kwp] planes
+                qbs = qb_ref[seq].astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    qbs[:, :kwp], klo,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) + jax.lax.dot_general(
+                    qbs[:, kwp:], khi,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [HK, t]
+            else:
+                s = jax.lax.dot_general(
+                    qb_ref[seq].astype(jnp.float32), kb.astype(jnp.float32),
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [HK, t]
+            if ablate != "noscale_mul":
+                s = s * pltpu.repeat(ksb, g, 0)
+
+            pos = blk * t_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < length, s, _NEG_INF)
+
+            m_curr = jnp.max(s, axis=-1, keepdims=True)            # [HK, 1]
+            m_next = jnp.maximum(m_prev, m_curr)
+            p_blk = jnp.exp(s - m_next)                             # [HK, t]
+            l_curr = jnp.sum(p_blk, axis=-1, keepdims=True)
+            alpha = jnp.exp(m_prev - m_next)
+            l_next = alpha * l_prev + l_curr
+
+            # V-scales fold into the probs: (p * vs) @ v_int == p @ dequant(v)
+            pv_in = (
+                p_blk if ablate == "noscale_mul"
+                else p_blk * pltpu.repeat(vsb, g, 0)
+            )
+            if int4:
+                # planar accumulator: lo-plane columns first, hi after —
+                # the caller un-permutes to natural feature order
+                vlo, vhi = nibbles(vb)
+                o_curr = jnp.concatenate(
+                    [
+                        jax.lax.dot_general(
+                            pv_in, vlo,
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        ),
+                        jax.lax.dot_general(
+                            pv_in, vhi,
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        ),
+                    ],
+                    axis=1,
                 )
             else:
-                touch = (
-                    jnp.sum(kb[0:8, :].astype(jnp.float32))
-                    + jnp.sum(vb[0:8, :].astype(jnp.float32))
+                o_curr = jax.lax.dot_general(
+                    pv_in, vb.astype(jnp.float32),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
                 )
-            acc = acc + touch * 0.0
-            nxt = w + nbuf
+            return m_next, l_next, acc * alpha + o_curr
 
-            @pl.when(nxt < n_work)
-            def _refill_ablate():
-                drain_wb(slot)
-                start_work_dma(nxt, slot)
-
-            return m_prev, l_prev, acc
-
-        # int8 values are exact in bf16, so the data dot needs no HIGHEST;
-        # K-scales fold into the score lanes afterwards (one VPU repeat).
-        # (probed: casting to bf16 instead of f32 here is ~4% SLOWER —
-        # int8->bf16 goes through f32 plus a truncate on the VPU)
-        if int4:
-            klo, khi = nibbles(kb)               # [t_blk, kwp] planes
-            qbs = qb_ref[seq].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qbs[:, :kwp], klo,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) + jax.lax.dot_general(
-                qbs[:, kwp:], khi,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [HK, T_blk]
-        else:
-            s = jax.lax.dot_general(
-                qb_ref[seq].astype(jnp.float32), kb.astype(jnp.float32),
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [HK, T_blk]
-        if ablate != "noscale_mul":
-            s = s * pltpu.repeat(ksb, g, 0)
-
-        pos = blk * t_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, _NEG_INF)
-
-        m_curr = jnp.max(s, axis=-1, keepdims=True)            # [HK, 1]
-        m_next = jnp.maximum(m_prev, m_curr)
-        p_blk = jnp.exp(s - m_next)                             # [HK, T]
-        l_curr = jnp.sum(p_blk, axis=-1, keepdims=True)
-        alpha = jnp.exp(m_prev - m_next)
-        l_next = alpha * l_prev + l_curr
-
-        # V-scales fold into the probs: (p * vs) @ v_int == p @ dequant(v)
-        pv_in = (
-            p_blk if ablate == "noscale_mul"
-            else p_blk * pltpu.repeat(vsb, g, 0)
+        m_prev, l_prev, acc = switch_live_pages(
+            item, n_live, pages_per_block, m_prev, l_prev, acc
         )
-        if int4:
-            # planar accumulator: lo-plane columns first, hi after — the
-            # caller un-permutes to natural feature order
-            vlo, vhi = nibbles(vb)
-            o_curr = jnp.concatenate(
-                [
-                    jax.lax.dot_general(
-                        pv_in, vlo,
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ),
-                    jax.lax.dot_general(
-                        pv_in, vhi,
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ),
-                ],
-                axis=1,
-            )
-        else:
-            o_curr = jax.lax.dot_general(
-                pv_in, vb.astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        acc = acc * alpha + o_curr
-        m_prev, l_prev = m_next, l_next
 
         n_blocks = lax_cdiv(length, t_blk)
 
@@ -659,8 +806,8 @@ def fused_paged_decode_attention(
     new_vs: jax.Array = None,
     *,
     page_size: int,
-    pages_per_block: int = 4,
-    nbuf: int = 8,
+    pages_per_block: int = PAGES_PER_BLOCK,
+    nbuf: int = NBUF,
     interpret: bool = False,
     ablate: str = "",
     alias_caches: bool = True,
@@ -699,18 +846,8 @@ def fused_paged_decode_attention(
         block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
     max_blocks = block_tables.shape[1] // pages_per_block
 
-    # flat work list: (sequence, page-block) pairs, empty rows skipped —
-    # the kernel's DMA ring stays full across sequence boundaries
     lengths = lengths.astype(jnp.int32)
-    bps = (lengths + t_blk - 1) // t_blk                   # blocks per seq
-    csum = jnp.cumsum(bps)
-    n_work = csum[-1]
-    widx = jnp.arange(b * max_blocks, dtype=jnp.int32)
-    work_seq = jnp.searchsorted(csum, widx, side="right").astype(jnp.int32)
-    safe_seq = jnp.minimum(work_seq, b - 1)
-    work_blk = widx - (csum[safe_seq] - bps[safe_seq])
-    work_seq = jnp.where(widx < n_work, safe_seq, 0)
-    work_blk = jnp.where(widx < n_work, work_blk, 0).astype(jnp.int32)
+    work_seq, work_blk, n_work = work_list(lengths, t_blk, max_blocks)
 
     # free bitcast: [N, K*Hd] row-major -> page-major view
     page_rows = page_size // 4 if packed else page_size
@@ -935,7 +1072,7 @@ def paged_decode_attention(
     v_scales: jax.Array = None,
     *,
     page_size: int,
-    pages_per_block: int = 4,
+    pages_per_block: int = PAGES_PER_BLOCK,
     interpret: bool = False,
     int4: bool = False,
 ) -> jax.Array:

@@ -10,6 +10,13 @@ Timing methodology: each config is timed as N chained kernel calls
 cost of one dispatch is amortized over N and the fetch is the fence.
 No copy of KERNEL_TPU.json is kept in the tree (it is gitignored): the
 serving path's kernel rates are the ledger's `decode_attn_roofline`.
+
+`--cell-shape [--kernel-file <another tree's ops/pallas_attention.py>]...`
+runs only `cell_shape`: the decode kernel ALONE at the shape of the
+benchmark's `mistral-7b-int8.decode-saturate` cell, one line per block
+size and ablation, written to chiprun_out/kernel_cell_shape.json. It is
+how a change to the kernel's block or ring is decided (PERF.md section 6,
+PR 32).
 """
 
 from __future__ import annotations
@@ -52,7 +59,127 @@ def oracle(q, k_cache, v_cache, tables, lengths, page_size):
     return out
 
 
+def cell_shape(kernel_files: tuple[str, ...] = (), *, b: int = 64,
+               layers: int = 32, reps: int = 12, interpret: bool = False,
+               ) -> None:
+    """The decode kernel alone where the benchmark runs it: 64 rows, a
+    row's context a prompt U[256, 768] plus its progress through an
+    answer U[768, 1280], Mistral-7B's heads (32 x 128 over 8 kv heads),
+    int32-packed int8 pages of 128. Times 32 chained calls (a scan that
+    carries the pools, as the 32 layers of a step do) x `reps` and prints
+    ms per 32 calls = the kernel's share of one decode step. Each of
+    `kernel_files` (another tree's ops/pallas_attention.py) runs first on
+    the same inputs, and its outputs are compared with this tree's bit
+    for bit. (`interpret` with a small `b`
+    and `layers` walks the same code on a CPU: no time means anything.)"""
+    import importlib.util
+
+    import dynamo_tpu.ops.pallas_attention as PA
+    from dynamo_tpu.ops.quant import init_kv_scale_pool, kv_scale_subl
+
+    if jax.default_backend() != "tpu" and not interpret:
+        raise SystemExit("kernel_check_tpu: no TPU")
+    rng = np.random.RandomState(32)
+    h, kh, hd, page = 32, 8, 128, 128
+    kw = kh * hd
+    answers = rng.randint(768, 1281, size=b)
+    lengths = (rng.randint(256, 769, size=b)
+               + (rng.rand(b) * answers).astype(np.int64)).astype(np.int32)
+    pages = -(-lengths // page)
+    w = 32                                   # max_model_len 4096 / 128
+    tables = np.zeros((b, w), np.int32)
+    nxt = 1
+    for i in range(b):
+        tables[i, :pages[i]] = np.arange(nxt, nxt + pages[i])
+        nxt += pages[i]
+    num_pages = 807                          # the cell's pool
+    assert nxt <= num_pages
+    pool = lambda seed: jnp.asarray(np.random.RandomState(seed).randint(
+        -2 ** 31, 2 ** 31, size=(num_pages * page // 4, kw),
+        dtype=np.int64).astype(np.int32))
+    scales = lambda seed: init_kv_scale_pool(num_pages, page, kh) * (
+        0.01 + 0.01 * seed)
+    subl = kv_scale_subl(kh)
+    q = jnp.asarray(rng.randn(b, h, hd), jnp.bfloat16)
+    new_kv = jnp.asarray(rng.randint(-127, 128, size=(b, kw)), jnp.int8)
+    new_sc = jnp.full((b, subl), 0.02, jnp.float32)
+    fixed = (jnp.asarray(tables), jnp.asarray(lengths),
+             jnp.asarray(lengths - 1))
+    held = int(pages.sum())
+    need_bytes = int(lengths.sum()) * (2 * kw + 2 * 4 * kh)
+
+    def timed(mod, **kw_static):
+        def step(pools, _):
+            out, *pools = mod.fused_paged_decode_attention(
+                q, new_kv, new_kv, pools[0], pools[1], *fixed,
+                pools[2], pools[3], new_sc, new_sc, page_size=page,
+                interpret=interpret, **kw_static)
+            return tuple(pools), out[:, :, 0]
+
+        f = jax.jit(
+            lambda pools: jax.lax.scan(step, pools, None, length=layers),
+            donate_argnums=(0,))
+        pools = (pool(1), pool(2), scales(1), scales(2))
+        pools, out = f(pools)
+        first = np.asarray(out, np.float32)
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            pools, out = f(pools)
+        _ = np.asarray(out)
+        return (time.perf_counter() - t0) / reps * 1e3, first
+
+    def var(ppb, nbuf, ablate=""):
+        name = f"ppb{ppb}_nbuf{nbuf}" + (f"_{ablate}" if ablate else "")
+        return name, {"pages_per_block": ppb, "nbuf": nbuf, "ablate": ablate}
+
+    variants = [var(4, 4), var(4, 8), var(2, 8), var(1, 8), var(8, 4),
+                var(4, 2), var(4, 3), var(4, 6),
+                var(4, 8, "nocompute"), var(4, 8, "noconvert")]
+    mods = []
+    for i, path in enumerate(kernel_files):
+        spec = importlib.util.spec_from_file_location(f"pa_other{i}", path)
+        other = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(other)
+        mods.append((path, other))
+    mods.append(("this", PA))
+    rows, outs = [], {}
+    for tree, mod in mods:
+        for name, kw_static in variants:
+            ms, first = timed(mod, **kw_static)
+            outs[tree, name] = first
+            ppb = kw_static["pages_per_block"]
+            # a tree from before PR 32 has no counter and copied in whole
+            # blocks: every item `ppb` pages
+            streamed = (
+                mod.streamed_pages(lengths, page, ppb)
+                if hasattr(mod, "streamed_pages")
+                else PA.streamed_pages(lengths, page * ppb, 1) * ppb)
+            rows.append({
+                "tree": tree, "variant": name, "calls": layers, "ms": ms,
+                "pages_streamed": streamed, "pages_held": held,
+                "roofline_pct": 100 * need_bytes * layers / 819e9 / (ms / 1e3),
+            })
+            print(json.dumps(rows[-1]), flush=True)
+    record = {"rows": b, "tokens": int(lengths.sum()), "pages_held": held,
+              "need_bytes_per_call": need_bytes, "timings": rows}
+    if kernel_files:
+        record["max_abs_diff"] = {
+            f"{tree}:{n}": float(np.abs(outs[tree, n] - outs["this", n]).max())
+            for tree in kernel_files for n, _ in variants[:5]}
+        record["bit_equal"] = not any(record["max_abs_diff"].values())
+    out_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "kernel_cell_shape.json"), "w") as f:
+        json.dump(record, f, indent=1)
+    print(json.dumps({k: v for k, v in record.items() if k != "timings"}))
+
+
 def main() -> None:
+    if "--cell-shape" in sys.argv:
+        return cell_shape(tuple(
+            sys.argv[i + 1] for i, a in enumerate(sys.argv)
+            if a == "--kernel-file"))
     backend = jax.default_backend()
     record: dict = {"backend": backend, "configs": []}
     if backend != "tpu":

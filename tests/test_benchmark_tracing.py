@@ -223,6 +223,45 @@ def test_recording_scopes_cover_the_decode_program(libs, recording):
     assert 0.75 < named / sum(dec.values()) < 0.9
 
 
+# ------------------------------------------- the decode kernel's page count
+
+
+def _digest(kind, streamed=None, held=None):
+    d = {"kind": kind, "rows": 4, "tokens": 32}
+    if streamed is not None:
+        d.update(kv_pages_streamed=streamed, kv_pages_held=held)
+    return d
+
+
+@pytest.mark.parametrize("digests,want", [
+    # sums over the window's decode rows, not a mean of ratios
+    ([_digest("decode", 1356, 1164), _digest("decode", 300, 300),
+      _digest("prefill", 0, 0), _digest("sync", 0, 0)], 1656 / 1464),
+    ([_digest("decode", 512, 512), _digest("mixed", 0, 0)], 1.0),
+    # the gather path books 0 pages: nothing to read
+    ([_digest("decode", 0, 0), _digest("prefill", 0, 0)], None),
+    # a program from before the columns: nothing to read, nothing raised
+    ([_digest("decode"), _digest("prefill"), _digest("sync")], None),
+    ([], None),
+])
+def test_decode_kv_read_amp(libs, digests, want):
+    _, _, harness = libs
+    got = harness.read_metric(
+        "layer_metrics", "decode_kv_read_amp", {"digests": digests})
+    assert got == (want if want is None else pytest.approx(want, rel=1e-12))
+
+
+def test_decode_kv_read_amp_is_declared():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    m = bench["per_layer"][-1]
+    assert m == {
+        "name": "decode_kv_read_amp", "unit": "x", "better": "lower",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tpot_p95_ms",
+        "workloads": [w["name"] for w in bench["workloads"]]}
+
+
 # ------------------------------------------------------------- a rehearsal
 
 NEW_COUNTS = ("kv_preemptions", "true_compiles_in_window")

@@ -91,6 +91,28 @@ def test_digest_fields_round_trip(tmp_path):
     assert d["wall_s"] == pytest.approx(0.25)
 
 
+@pytest.mark.parametrize("kind,pages", [
+    ("decode", (1356, 1164)), ("decode", (0, 0)),
+    ("prefill", None), ("sync", None),
+])
+def test_kv_page_columns_round_trip(tmp_path, kind, pages):
+    """The decode digest's two page counts ride the row, the snapshot and
+    the artifact under their names; a row booked without them reads 0."""
+    rec = make_recorder(tmp_path)
+    host = {} if pages is None else dict(
+        kv_pages_streamed=pages[0], kv_pages_held=pages[1])
+    rec.record(kind, 0.01, rows=4, tokens=32, **host)
+    want = pages or (0, 0)
+    d = rec.snapshot()[-1]
+    assert (d["kv_pages_streamed"], d["kv_pages_held"]) == want
+    assert FIELDS[-2:] == ("kv_pages_streamed", "kv_pages_held")
+    with open(rec.trigger("manual")) as f:
+        art = json.load(f)
+    row = dict(zip(art["digest_fields"], art["digests"][-1]))
+    assert (row["kv_pages_streamed"], row["kv_pages_held"]) == want
+    assert row["rows"] == 4 and row["tokens"] == 32
+
+
 # --------------------------------------------------- trigger + rate limit
 
 

@@ -123,9 +123,9 @@ def _to_pool(dense, num_pages, page, kh):
     return scatter_kv_scales(pool, slots, dense, kh)
 
 
-def _quant_setup(seed=0):
+def _quant_setup(seed=0, W=4):
     key = jax.random.PRNGKey(seed)
-    B, H, KH, Hd, page, W = 3, 8, 4, 32, 8, 4
+    B, H, KH, Hd, page = 3, 8, 4, 32, 8
     kw = KH * Hd
     num_pages = B * W + 1
     num_slots = num_pages * page
@@ -498,6 +498,158 @@ def test_fused_decode_kernel_packed_matches_unpacked():
     )
     np.testing.assert_array_equal(np.asarray(ks_p2), np.asarray(ks_u))
     np.testing.assert_array_equal(np.asarray(vs_p2), np.asarray(vs_u))
+
+
+# (pages_per_block, pages the LAST block of a sequence holds): the first,
+# a middle and the last page of a block
+LIVE_CASES = [(2, 1), (2, 2), (4, 1), (4, 3), (4, 4)]
+
+
+def _new_rows(B, kw, KH, seed):
+    """Quantized new-token rows and their scale columns in the pool's
+    sublane-row layout."""
+    from dynamo_tpu.ops.quant import kv_scale_subl, _scale_rows
+
+    key = jax.random.PRNGKey(seed)
+    nkq, nks = quantize_kv_rows(jax.random.normal(key, (B, kw)), KH)
+    nvq, nvs = quantize_kv_rows(
+        jax.random.normal(jax.random.fold_in(key, 1), (B, kw)), KH
+    )
+    rows = _scale_rows(KH, 1)
+    pad = jnp.ones((B, kv_scale_subl(KH)), jnp.float32)
+    return (nkq, nks, pad.at[:, rows].set(nks),
+            nvq, nvs, pad.at[:, rows].set(nvs))
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@pytest.mark.parametrize("ppb,tail", LIVE_CASES)
+def test_decode_kernel_int8_never_touches_pages_not_held(ppb, tail, packed):
+    """Every page no sequence holds, the trash page included, carries NaN
+    scales: the output and the written pages must equal the oracle's on
+    clean pools. A scale tile of NaN times a masked probability of 0 is
+    NaN, so this holds only if the COMPUTE skips what the copy skipped."""
+    from dynamo_tpu.ops.attention import paged_attention, slots_from_pages
+    from dynamo_tpu.ops.pallas_attention import fused_paged_decode_attention
+    from dynamo_tpu.ops.quant import (
+        gather_kv_scales,
+        pack_kv_slots,
+        unpack_kv_slots,
+    )
+
+    B, H, KH, Hd, page, kw, q, kq, ks, vq, vs, tables = _quant_setup(
+        11, W=3 * ppb
+    )
+    t_blk = ppb * page
+    # the last block holds `tail` pages: inside the first block; one block
+    # in, ending on a page's end; two blocks in, the newest token the
+    # FIRST of its page
+    lengths = np.asarray([
+        (tail - 1) * page + 3,
+        t_blk + tail * page,
+        2 * t_blk + (tail - 1) * page + 1,
+    ], np.int32)
+    wpos = lengths - 1
+    nkq, nks, nks_p, nvq, nvs, nvs_p = _new_rows(B, kw, KH, 5)
+
+    tb = np.asarray(tables)
+    held = np.concatenate(
+        [tb[i, : -(-int(n) // page)] for i, n in enumerate(lengths)]
+    )
+    unheld = np.setdiff1d(np.arange(ks.shape[0]), held)
+    assert 0 in unheld
+    ks_nan = ks.at[unheld].set(jnp.nan)
+    vs_nan = vs.at[unheld].set(jnp.nan)
+
+    pools = (pack_kv_slots(kq), pack_kv_slots(vq)) if packed else (kq, vq)
+    out, k2, v2, ks2, vs2 = fused_paged_decode_attention(
+        q, nkq, nvq, *pools, tables, jnp.asarray(lengths), jnp.asarray(wpos),
+        ks_nan, vs_nan, nks_p, nvs_p,
+        page_size=page, pages_per_block=ppb, nbuf=2, interpret=True,
+    )
+    if packed:
+        k2, v2 = unpack_kv_slots(k2), unpack_kv_slots(v2)
+
+    all_slots = jnp.arange(kq.shape[0], dtype=jnp.int32)
+    slots = jnp.asarray([
+        int(tb[b, wpos[b] // page]) * page + int(wpos[b]) % page
+        for b in range(B)
+    ])
+    kd = dequantize_kv_rows(kq, gather_kv_scales(ks, all_slots, KH))
+    vd = dequantize_kv_rows(vq, gather_kv_scales(vs, all_slots, KH))
+    kd = kd.at[slots].set(dequantize_kv_rows(nkq, nks))
+    vd = vd.at[slots].set(dequantize_kv_rows(nvq, nvs))
+    ref = paged_attention(
+        q[:, None], kd, vd, slots_from_pages(tables, page),
+        jnp.asarray(wpos)[:, None],
+    )[:, 0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-3)
+    # data pages: only the written rows changed; scale pages: the written
+    # columns landed, held pages are otherwise as they were, the rest
+    # still NaN (never written back)
+    np.testing.assert_array_equal(
+        np.asarray(k2), np.asarray(kq.at[slots].set(nkq))
+    )
+    np.testing.assert_array_equal(
+        np.asarray(v2), np.asarray(vq.at[slots].set(nvq))
+    )
+    np.testing.assert_allclose(
+        np.asarray(gather_kv_scales(ks2, slots, KH)), np.asarray(nks)
+    )
+    np.testing.assert_allclose(
+        np.asarray(gather_kv_scales(vs2, slots, KH)), np.asarray(nvs)
+    )
+    assert not np.isnan(np.asarray(ks2)[held]).any()
+    assert np.isnan(np.asarray(ks2)[unheld]).all()
+    assert np.isnan(np.asarray(vs2)[unheld]).all()
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@pytest.mark.parametrize("ppb,tail", LIVE_CASES)
+def test_decode_kernel_int8_write_that_opens_a_page_lands(ppb, tail, packed):
+    """`length - 1` a multiple of the page size: the new token is the
+    first of the item's LAST live page, which held nothing before. Rows
+    and scale columns are bit-equal to the scatter oracle's."""
+    from dynamo_tpu.ops.pallas_attention import fused_paged_decode_attention
+    from dynamo_tpu.ops.quant import (
+        pack_kv_slots,
+        scatter_kv_scales,
+        unpack_kv_slots,
+    )
+
+    B, H, KH, Hd, page, kw, q, kq, ks, vq, vs, tables = _quant_setup(
+        13, W=3 * ppb
+    )
+    t_blk = ppb * page
+    wpos = np.asarray([
+        (tail - 1) * page,
+        t_blk + (tail - 1) * page,
+        2 * t_blk + (tail - 1) * page,
+    ], np.int32)
+    nkq, nks, nks_p, nvq, nvs, nvs_p = _new_rows(B, kw, KH, 6)
+    pools = (pack_kv_slots(kq), pack_kv_slots(vq)) if packed else (kq, vq)
+    _, k2, v2, ks2, vs2 = fused_paged_decode_attention(
+        q, nkq, nvq, *pools, tables, jnp.asarray(wpos + 1),
+        jnp.asarray(wpos), ks, vs, nks_p, nvs_p,
+        page_size=page, pages_per_block=ppb, nbuf=2, interpret=True,
+    )
+    if packed:
+        k2, v2 = unpack_kv_slots(k2), unpack_kv_slots(v2)
+    tb = np.asarray(tables)
+    slots = jnp.asarray(
+        [int(tb[b, wpos[b] // page]) * page for b in range(B)]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(k2), np.asarray(kq.at[slots].set(nkq))
+    )
+    np.testing.assert_array_equal(
+        np.asarray(v2), np.asarray(vq.at[slots].set(nvq))
+    )
+    np.testing.assert_array_equal(
+        np.asarray(ks2), np.asarray(scatter_kv_scales(ks, slots, nks, KH))
+    )
+    np.testing.assert_array_equal(
+        np.asarray(vs2), np.asarray(scatter_kv_scales(vs, slots, nvs, KH))
+    )
 
 
 def test_flash_prefill_kernel_packed_matches_unpacked():

@@ -157,7 +157,7 @@ async def test_digest_columns():
         collect(engine, greedy_request([9, 8, 7], max_tokens=20)))
     rows = engine.flight.snapshot()
     await engine.close()
-    assert flightmod.FIELDS[-4:] == (
+    assert flightmod.FIELDS[12:16] == (
         "build_s", "emit_s", "starved", "preempted")
     assert flightmod.FIELDS[:12] == (  # the accepted columns, in place
         "ts_unix", "step", "kind", "rows", "tokens", "wall_s", "budget_fill",
@@ -171,6 +171,45 @@ async def test_digest_columns():
     assert landed and all(r["emit_s"] > 0 for r in landed)
     assert all(r["emit_s"] == 0 for r in by["decode"])
     assert all(r["preempted"] == 0 for r in rows)
+
+
+async def test_decode_digests_count_the_kv_pages(tmp_path):
+    """A pallas engine books, on every decode row, the KV pages one
+    layer's kernel copies in over the dispatch's steps beside the pages
+    its rows hold; the two are equal (the kernel reads what a sequence
+    holds), 0 on every other row, and 0 where the gather path serves."""
+    assert flightmod.FIELDS[-2:] == ("kv_pages_streamed", "kv_pages_held")
+    engine = make_engine(attn_backend="pallas")
+    ps, steps = engine.page_size, engine.config.decode_steps
+    # one request alone: its first decode dispatch attends 4, 5, ...
+    # positions over the scan's steps
+    await collect(engine, greedy_request([5, 6, 7], max_tokens=12))
+    first = next(
+        r for r in engine.flight.snapshot() if r["kind"] == "decode")
+    assert first["rows"] == 1
+    assert first["kv_pages_held"] == sum(
+        -(-(4 + j) // ps) for j in range(steps))
+    await asyncio.gather(
+        collect(engine, greedy_request(list(range(3, 43)), max_tokens=20)),
+        collect(engine, greedy_request([9, 8, 7], max_tokens=20)))
+    rows = engine.flight.snapshot()
+    await engine.close()
+    decodes = [r for r in rows if r["kind"] == "decode"]
+    assert max(r["rows"] for r in decodes) == 2
+    for r in decodes:
+        assert r["kv_pages_streamed"] == r["kv_pages_held"] > 0
+        # a row attends 1..max_model_len positions in each of the steps
+        assert r["rows"] * steps <= r["kv_pages_held"]
+        assert r["kv_pages_held"] <= r["rows"] * steps * -(-128 // ps)
+    assert all(r["kv_pages_streamed"] == r["kv_pages_held"] == 0
+               for r in rows if r["kind"] != "decode")
+
+    gather = make_engine()
+    await collect(gather, greedy_request([5, 6, 7], max_tokens=12))
+    rows = gather.flight.snapshot()
+    await gather.close()
+    assert any(r["kind"] == "decode" for r in rows)
+    assert all(r["kv_pages_held"] == 0 for r in rows)
 
 
 def test_amend_fills_the_newest_digest_of_its_kind():

@@ -276,130 +276,6 @@ def test_tp_overlap_forward_refuses_moe_and_sp_ring():
         )
 
 
-def test_forward_overlap_int8_kv_matches_tp1():
-    """int8 dense KV (gather read path) under the overlap executor: the
-    shard-local spec rebuild (kv_tp=1 over local scale channels) must
-    reproduce the tp=1 quantized forward — same greedy argmax, hidden
-    within manual-tp float tolerance."""
-    mesh = _mesh()
-    b, t = 4, 16
-    tokens, positions, wslots, smat = _inputs(b, t)
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-
-    kv1 = llama.init_kv_cache(CFG, 512, kv_quant="int8", page_size=8, tp=1)
-    ref_hidden, ref_kv = llama.forward(
-        params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv1,
-        jnp.asarray(wslots.reshape(-1)),
-        llama.AttnSpec.gather(jnp.asarray(smat), page_size=8, kv_tp=1),
-    )
-
-    # tp=8 pools carry the tp-blocked scale layout (ops/quant.kv_scale_subl)
-    kv8 = llama.init_kv_cache(CFG, 512, kv_quant="int8", page_size=8, tp=TP)
-    spec8 = llama.AttnSpec.gather(jnp.asarray(smat), page_size=8, kv_tp=TP)
-    with jax.set_mesh(mesh):
-        hidden, kv_out = ov.tp_overlap_forward(
-            params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
-            jnp.asarray(wslots.reshape(-1)), spec8, mesh,
-        )
-    assert kv_out.k[0].dtype == jnp.int8
-    assert kv_out.ks[0].shape[1] == TP * 8  # tp-blocked scale sublanes
-    np.testing.assert_allclose(np.asarray(hidden), np.asarray(ref_hidden),
-                               rtol=2e-4, atol=2e-4)
-    lg_ref = llama.logits(params, CFG, ref_hidden[:, -1])
-    lg_ov = llama.logits(params, CFG, hidden[:, -1])
-    assert np.array_equal(
-        np.asarray(jnp.argmax(lg_ref, -1)), np.asarray(jnp.argmax(lg_ov, -1))
-    )
-    # the written slots actually hold quantized rows (not pool zeros)
-    w0 = np.asarray(kv_out.k[0])[wslots.reshape(-1)]
-    assert np.any(w0 != 0)
-    # dequantized written rows agree with the tp=1 reference within one
-    # int8 bucket (a 1-ULP pre-quant diff may flip a rounding boundary)
-    from dynamo_tpu.ops.quant import dequantize_kv_rows, gather_kv_scales
-
-    flat = jnp.asarray(wslots.reshape(-1))
-    for layer in (0, CFG.num_layers - 1):
-        got = dequantize_kv_rows(
-            kv_out.k[layer][flat],
-            gather_kv_scales(kv_out.ks[layer], flat, CFG.num_kv_heads, TP),
-        )
-        want = dequantize_kv_rows(
-            ref_kv.k[layer][flat],
-            gather_kv_scales(ref_kv.ks[layer], flat, CFG.num_kv_heads, 1),
-        )
-        scale = float(jnp.max(jnp.abs(want))) / 127.0
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), atol=2.5 * scale, rtol=0
-        )
-
-
-@pytest.mark.parametrize("tier", ["int8", "int4"])
-def test_forward_overlap_packed_pallas_prefill_matches_tp1(tier):
-    """The pallas serving combination the executor was extended for:
-    int32-PACKED quantized pools + the pallas page-scatter write + flash
-    prefill kernels (interpret mode on CPU), tp=8 overlap vs tp=1. The
-    kernels' per-layer shard_maps collapse into the executor's single
-    one; block tables, packed pools and scale tiles ride shard-local."""
-    mesh = _mesh()
-    b, t, page = 4, 16, 8
-    tokens, positions, wslots, smat = _inputs(b, t, page=page)
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-    quant = tier
-
-    # _inputs rows write slots [page*(1+8i), page*(1+8i)+t): pages
-    # 1+8i, 2+8i per sequence — contiguous, page-aligned, trash-free
-    ppseq = t // page
-    btables = np.stack(
-        [np.arange(1 + 8 * i, 1 + 8 * i + ppseq) for i in range(b)]
-    ).astype(np.int32)
-    wtables = btables.reshape(-1).astype(np.int32)
-    q_pos0 = np.zeros(b, np.int32)
-    lens = np.full(b, t, np.int32)
-
-    def spec(kv_tp):
-        return llama.AttnSpec.gather(
-            jnp.asarray(smat), write_tables=jnp.asarray(wtables),
-            page_size=page, interpret=True,
-            block_tables=jnp.asarray(btables),
-            q_pos0=jnp.asarray(q_pos0), lengths=jnp.asarray(lens),
-            kv_tp=kv_tp,
-            # int4 pools are nibble-packed at half width, so the kernels
-            # need the static tier flag (pallas requires groups == 1)
-            int4_groups=1 if tier == "int4" else 0,
-        )
-
-    kv1 = llama.init_kv_cache(
-        CFG, 512, kv_quant=quant, page_size=page, tp=1, packed=True
-    )
-    assert kv1.k[0].dtype == jnp.int32
-    ref_hidden, ref_kv = llama.forward(
-        params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv1,
-        jnp.asarray(wslots.reshape(-1)), spec(1),
-    )
-
-    kv8 = llama.init_kv_cache(
-        CFG, 512, kv_quant=quant, page_size=page, tp=TP, packed=True
-    )
-    with jax.set_mesh(mesh):
-        hidden, kv_out = ov.tp_overlap_forward(
-            params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
-            jnp.asarray(wslots.reshape(-1)), spec(TP), mesh,
-        )
-    assert kv_out.k[0].dtype == jnp.int32
-    np.testing.assert_allclose(np.asarray(hidden), np.asarray(ref_hidden),
-                               rtol=3e-4, atol=3e-4)
-    # the serving property that gates the engine dispatch: greedy streams
-    # byte-identical to tp=1
-    lg_ref = llama.logits(params, CFG, ref_hidden[:, -1])
-    lg_ov = llama.logits(params, CFG, hidden[:, -1])
-    assert np.array_equal(
-        np.asarray(jnp.argmax(lg_ref, -1)), np.asarray(jnp.argmax(lg_ov, -1))
-    )
-    # packed page writes landed (row group of the first written page)
-    g0 = int(wslots[0, 0]) // 4
-    assert np.any(np.asarray(kv_out.k[0])[g0] != 0)
-
-
 def test_forward_overlap_quantized_weights_matches_tp1_bitwise():
     """int8 quantized WEIGHTS under the executor: ring_rs_matmul carries
     the row-parallel projections' int32 accumulator across the ring
