@@ -143,12 +143,15 @@ class _Dispatch:
     """One in-flight dispatch (decode scan, spec verify, or a pipelined
     mixed step): device tokens + the slot snapshot it was built from."""
 
-    __slots__ = ("out_dev", "snapshot", "steps", "spec", "pos0",
+    __slots__ = ("out_dev", "snapshot", "steps", "spec", "pos0", "moe",
                  "draft_lens", "mixed", "bld")
 
     def __init__(self, out_dev, snapshot, steps, spec=False, pos0=None,
-                 draft_lens=None, mixed=False, bld=None):
+                 draft_lens=None, mixed=False, bld=None, moe=None):
         self.out_dev = out_dev          # [steps, B] device array
+        # an expert model's decode dispatch: its load, [2] on the device
+        # (experts with a token, most tokens on one expert; means)
+        self.moe = moe
         self.snapshot = snapshot        # list[(slot_index, Sequence)]
         self.steps = steps
         # speculative verify dispatch: out_dev is (tokens [B, T],
@@ -219,8 +222,11 @@ class JaxEngine:
         # Mosaic needs the folded KV width lane-aligned per tp shard (the
         # kernels slice [*, K*Hd] refs); tiny test models fall back
         kw_ok = (
-            self.model_cfg.num_kv_heads * self.model_cfg.head_dim
+            self.model_cfg.latent_pool_width if self.model_cfg.latent
+            else self.model_cfg.num_kv_heads * self.model_cfg.head_dim
         ) % (128 * mc.tp) == 0
+        if self.model_cfg.latent:
+            self._refuse_latent_config()
         if config.attn_backend == "auto":
             self._attn_pallas = backend == "tpu" and tp_only and kw_ok
             self._attn_interpret = False
@@ -1011,6 +1017,12 @@ class JaxEngine:
             )
 
         self._inject_fn = jax.jit(_inject, donate_argnums=(0,))
+        if self.model_cfg.latent:
+            def _refuse(*_a, **_k):
+                self._refuse_latent_plane("KV page inject / extract")
+
+            self._inject_fn = self._extract_fn = _refuse
+            return
 
         def _extract(kv, slots):
             if _eng_packed:
@@ -1059,6 +1071,45 @@ class JaxEngine:
             )
 
     @property
+    def _returns_expert_load(self) -> bool:
+        """An expert model's decode program returns its expert load after
+        its tokens (`_decode_multi`); the pp stage executor has no expert
+        layer."""
+        return bool(self.model_cfg.num_experts) and not self._pp
+
+    def _refuse_latent_plane(self, plane: str) -> None:
+        """A plane that moves or converts K and V pools, asked of a
+        model whose cache is one latent pool a layer: refused with the
+        reason, never run on half a cache."""
+        if self.model_cfg.latent:
+            raise ValueError(
+                f"{plane} is not served with latent attention "
+                f"('{self.model_cfg.name}'): it is written for a K pool and "
+                "a V pool a layer, and a latent cache is ONE pool of "
+                "[c ; k_r] rows"
+            )
+
+    def _refuse_latent_config(self) -> None:
+        """What a latent-attention model cannot be combined with yet,
+        each refused at construction (docs/kv_cache.md "Latent pools")."""
+        cfg, mc = self.config, self.config.mesh
+        asked = {
+            f"kv_quantization={cfg.kv_quantization!r} (the latent pool is "
+            "served in the model's dtype; no quantized latent rows yet)":
+                cfg.kv_quantization is not None,
+            f"quantization={cfg.quantization!r} (int8 weights)":
+                cfg.quantization is not None,
+            "a mesh of more than one device (the latent pool has no head "
+            "axis to shard; tp / pp / sp / ep / dp all refuse)":
+                mc.num_devices > 1,
+            "host KV offload (host_kv_pages)": bool(cfg.host_kv_pages),
+            "spec_decode": bool(cfg.spec_decode),
+        }
+        for what, on in asked.items():
+            if on:
+                self._refuse_latent_plane(what)
+
+    @property
     def attention_backend(self) -> dict:
         """What this engine's attention actually runs — `attn_backend=
         "auto"` resolved: `kind` is "pallas" or "gather", `interpret`
@@ -1082,7 +1133,13 @@ class JaxEngine:
     def _auto_num_pages(self, params) -> int:
         cfg, m = self.config, self.model_cfg
         tp = self.config.mesh.tp
-        if self._kv_quant:
+        if m.latent:
+            # one pool a layer, a row the lanes it occupies (576 -> 640)
+            page_bytes = (
+                m.num_layers * cfg.page_size * m.latent_pool_width
+                * self._dtype.dtype.itemsize
+            )
+        elif self._kv_quant:
             # quantized data pages (int8: 1 byte/feature; int4: packed
             # nibbles, 1 byte per TWO features — exactly a quarter of
             # bf16) + [SUBL, S] f32 scale tiles per pool
@@ -1372,7 +1429,7 @@ class JaxEngine:
         return hidden, (k_st, v_st)
 
     def _forward(self, params, kv, tokens, positions, write_slots, attn,
-                 embeds=None, embeds_mask=None):
+                 embeds=None, embeds_mask=None, moe_stats=None):
         """llama.forward, rerouted through the latency-hiding manual-TP
         executor on engines that selected it. The executor serves every
         dispatch family's AttnSpec shape on tp-only engines — gather
@@ -1392,6 +1449,7 @@ class JaxEngine:
         return llama.forward(
             params, self.model_cfg, tokens, positions, kv, write_slots,
             attn, embeds=embeds, embeds_mask=embeds_mask,
+            moe_stats=moe_stats,
         )
 
     def _model_step(self, params, kv, state, tokens, positions, write_slots,
@@ -1604,6 +1662,7 @@ class JaxEngine:
                     smat, page_size=s, kv_tp=self.config.mesh.tp,
                     int4_groups=self._kv_int4_groups,
                 )
+            moe = [] if self._returns_expert_load else None
             if self._pp:
                 hidden, kv = self._pp_forward(
                     params, kv, tokens[:, None], positions[:, None],
@@ -1612,7 +1671,7 @@ class JaxEngine:
             else:
                 hidden, kv = self._forward(
                     params, kv, tokens[:, None], positions[:, None],
-                    wslots, attn,
+                    wslots, attn, moe_stats=moe,
                 )
             lg = llama.logits(params, self.model_cfg, hidden[:, 0])
 
@@ -1636,6 +1695,12 @@ class JaxEngine:
                 counts = bump_counts(counts, ys[0], active)
             else:
                 ys = _sample()
+            if moe:
+                # this step's expert load, mean over its expert layers:
+                # [experts with a token, most tokens on one expert]
+                ys = ys + (jnp.mean(
+                    jnp.asarray(moe, jnp.float32), axis=0
+                ),)
             return (ys[0], positions + 1, kv, key, counts), ys
 
         (_, _, kv, _, counts), out_t = jax.lax.scan(
@@ -1671,6 +1736,11 @@ class JaxEngine:
                 tid=keep(state.tid, out_t[2][-1]),
                 tlp=keep(state.tlp, out_t[3][-1]),
             )
+        if self._returns_expert_load:
+            # an expert model's dispatch ends in its load, [2] float32
+            # (mean over the steps): fetched with the tokens, booked on
+            # the sync digest (`_land`)
+            S = S + (jnp.mean(out_t[-1], axis=0),)
         return S, kv, self._pin_state(state)
 
     def _spec_verify_step(self, params, kv, state, tokens, positions,
@@ -2015,6 +2085,7 @@ class JaxEngine:
         injection converts a bf16/int8 mix to this engine's KV dtype as
         needed, while cross-tier quantized mixes raise
         KvQuantMismatchError (see _convert_wire_kv)."""
+        self._refuse_latent_plane("disaggregated decode (generate_remote)")
         payload = request.payload
         pre = (
             PreprocessedRequest.from_dict(payload)
@@ -2061,6 +2132,10 @@ class JaxEngine:
         `device_arrays=True` skips the host copy and returns jax arrays
         — the send side of the device-path transfer
         (engine/xproc_kv.py / engine/kv_transfer.py)."""
+        self._refuse_latent_plane(
+            "disaggregated prefill (prefill_only: the send side of the "
+            "host-staged and device-path planes)"
+        )
         if self._pp:
             raise ValueError("disagg prefill_only unsupported with pp>1 (v1)")
         ctx = ctx or Context(pre.to_dict())
@@ -2126,6 +2201,9 @@ class JaxEngine:
         (quantize/dequantize on injection); cross-tier quantized mixes
         raise KvQuantMismatchError (_convert_wire_kv) — packed bytes are
         quantized exactly once and never requantized pool-to-pool."""
+        self._refuse_latent_plane(
+            "prefix ingest (ingest_prefix: the device-path landing side)"
+        )
         full_pages = len(token_ids) // self.page_size
         if full_pages == 0:
             return 0
@@ -2204,6 +2282,7 @@ class JaxEngine:
         gather cannot race an eviction; pins drop before returning (the
         pages stay cached). Blocking (jit dispatch + device fetch):
         callers run it in a worker thread."""
+        self._refuse_latent_plane("prefix export (export_prefix)")
         if hashes is None:
             from dynamo_tpu.llm.tokens import compute_block_hashes
 
@@ -3678,6 +3757,11 @@ class JaxEngine:
         logs it once and keeps the normal paths. spec_decode COMPOSES
         (spec-eligible decode rows ride mixed steps as ragged q_len=1+k
         verify rows — see _build_mixed); it is no longer an exclusion."""
+        if self.model_cfg.latent:
+            return (
+                "mixed_batching unsupported with latent attention: the "
+                "ragged kernel reads a K pool and a V pool"
+            )
         if self._pp:
             return "mixed_batching unsupported with pp>1 (v1)"
         if self._sp:
@@ -4616,6 +4700,8 @@ class JaxEngine:
         self._step_count += 1
         for arr in S:
             arr.copy_to_host_async()
+        if self._returns_expert_load:
+            return _Dispatch(S[:-1], bld.active, bld.steps, moe=S[-1])
         return _Dispatch(S, bld.active, bld.steps)
 
     async def _sync_dispatch(self, d: _Dispatch, overlapped: bool = False) -> None:
@@ -4668,10 +4754,13 @@ class JaxEngine:
             else:
                 self._sync_decode(d, arrs)
         if self.flight is not None:
-            self.flight.amend(
-                "overlap" if overlapped else "sync",
-                emit_s=time.perf_counter() - t1,
-            )
+            host = {"emit_s": time.perf_counter() - t1}
+            if d.moe is not None:
+                # the same program made it: ready since the tokens were
+                host["moe_experts_hit"], host["moe_load_max"] = (
+                    np.asarray(d.moe).tolist()
+                )
+            self.flight.amend("overlap" if overlapped else "sync", **host)
 
     def _sync_decode(self, d: _Dispatch, arrs) -> None:
         """Land a decode scan: row 0 first tokens, then the steps."""

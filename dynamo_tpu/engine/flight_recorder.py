@@ -80,6 +80,10 @@ FIELDS = (
     # pallas decode rows (PR 32), per layer over the dispatch's steps:
     "kv_pages_streamed",  # KV pages the decode kernel copies in
     "kv_pages_held",      # KV pages the rows' attended lengths hold
+    # sync / overlap rows of an expert model's decode dispatch (PR 34),
+    # means over its steps and expert layers, from the device:
+    "moe_experts_hit",    # distinct experts with at least one token
+    "moe_load_max",       # most tokens routed to one expert
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

@@ -1,4 +1,6 @@
-"""Model configurations for the Llama family (Llama 2/3, Mistral, Qwen2).
+"""Model configurations: the Llama family (Llama 2/3, Mistral, Qwen2,
+Gemma), Mixtral-style expert models and the DeepSeek-V2 family (latent
+attention, softmax-routed experts beside shared ones, leading dense layers).
 
 One config dataclass covers the architectures the reference serves through
 vLLM/sglang (reference: examples/llm/configs/*.yaml serve Llama/DeepSeek
@@ -8,7 +10,16 @@ owns the model natively, so the config is ours, not an engine passthrough.
 Conventions:
 - `head_dim` is explicit (Llama3 keeps hidden/heads, but e.g. Qwen2-0.5B
   differs), GQA via `num_kv_heads < num_heads`.
-- `rope_scaling` carries the Llama-3.1 long-context NTK scaling dict.
+- `rope_scaling` carries the Llama-3.1 long-context NTK scaling dict, or
+  the `deepseek_v2` YaRN dict (ops/rope.py reads both).
+- latent attention (`kv_lora_rank > 0`): queries are `num_heads x
+  (qk_nope_head_dim + qk_rope_head_dim)`, the cache keeps ONE row of
+  `kv_lora_rank + qk_rope_head_dim` values a token a layer (`latent_width`)
+  and `head_dim` is the query/key head size (nope + rope).
+- expert layers: `num_experts` routed experts of `moe_intermediate_size`,
+  `num_shared_experts` shared ones on every token, the first
+  `first_dense_layers` layers dense at `intermediate_size`; the router's
+  scoring and renormalisation are read here, never assumed (models/moe.py).
 - dtypes: weights/activations bfloat16 on TPU (MXU-native), float32 for
   norms/softmax accumulation inside the ops.
 """
@@ -44,7 +55,19 @@ class ModelConfig:
     # sparse MoE (mixtral-style): 0 experts = dense FFN
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    expert_capacity_factor: float = 1.25
+    # expert width (0 = intermediate_size, mixtral), shared experts on
+    # every token, leading dense layers, and the router as published:
+    # renormalise the top-k weights (mixtral: yes) and scale them
+    moe_intermediate_size: int = 0
+    num_shared_experts: int = 0
+    first_dense_layers: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # latent attention (deepseek_v2): 0 = plain / grouped-query heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def q_size(self) -> int:
@@ -54,12 +77,42 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def latent(self) -> bool:
+        """Latent attention: one cached row a token, no K/V heads."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token keeps in a layer's latent pool: [c ; k_r]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_pool_width(self) -> int:
+        """Lanes a latent row OCCUPIES: `latent_width` rounded up to the
+        128-lane tile (576 -> 640). HBM arrays are tiled by 128 lanes, so
+        a [N, 576] pool takes 640 a row whatever its declared shape, and
+        the page DMAs of a kernel must be whole tiles (Mosaic refuses a
+        576-wide slice). The pool is declared at the width it occupies;
+        the pad lanes are zero and sized into the pool's budget."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    def is_moe_layer(self, layer: int) -> bool:
+        return bool(self.num_experts) and layer >= self.first_dense_layers
+
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
 
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "hf-model") -> "ModelConfig":
-        """Build from a HuggingFace config.json dict (llama/mistral/qwen2)."""
+        """Build from a HuggingFace config.json dict (llama / mistral /
+        qwen2 / gemma / mixtral / deepseek_v2)."""
+        if hf.get("model_type") == "deepseek_v2":
+            return cls._from_deepseek_v2(hf, name)
         num_heads = hf["num_attention_heads"]
         head_dim = hf.get("head_dim") or hf["hidden_size"] // num_heads
         return cls(
@@ -91,6 +144,56 @@ class ModelConfig:
             norm_weight_offset=1.0 if hf.get("model_type") == "gemma" else 0.0,
             num_experts=hf.get("num_local_experts", 0),
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+        )
+
+
+    @classmethod
+    def _from_deepseek_v2(cls, hf: dict, name: str) -> "ModelConfig":
+        """The `deepseek_v2` keys. What this build cannot run is refused
+        here, by name, rather than read as something else."""
+        unsupported = {
+            "q_lora_rank": hf.get("q_lora_rank") is not None,
+            "scoring_func": hf.get("scoring_func", "softmax") != "softmax",
+            "topk_method": hf.get("topk_method", "greedy") != "greedy",
+            "n_group": hf.get("n_group", 1) not in (None, 1),
+            "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+            "attention_bias": bool(hf.get("attention_bias")),
+        }
+        bad = [k for k, v in unsupported.items() if v]
+        if bad:
+            raise ValueError(
+                f"deepseek_v2 config: {bad[0]}={hf.get(bad[0])!r} is not "
+                "served (low-rank queries, sigmoid or group-limited routing, "
+                "expert layers at a period other than 1, attention bias)"
+            )
+        nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+        return cls(
+            name=name,
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf.get(
+                "num_key_value_heads", hf["num_attention_heads"]
+            ),
+            head_dim=nope + rope,
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            rope_scaling=hf.get("rope_scaling"),
+            num_experts=hf.get("n_routed_experts") or 0,
+            num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+            moe_intermediate_size=hf.get("moe_intermediate_size", 0),
+            num_shared_experts=hf.get("n_shared_experts") or 0,
+            first_dense_layers=hf.get("first_k_dense_replace", 0),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=hf["v_head_dim"],
         )
 
 
@@ -259,6 +362,69 @@ _preset(ModelConfig(
     max_position_embeddings=32768,
     num_experts=8,
     num_experts_per_tok=2,
+))
+
+
+# DeepSeek-V2 family: latent attention over one cached row a token, 64
+# softmax-routed experts top-6 (weights as they are) beside two shared
+# ones, layer 0 dense; YaRN rope on a 64-wide slice shared by all heads.
+_DEEPSEEK_V2_YARN = {
+    "beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707,
+    "mscale_all_dim": 0.707, "original_max_position_embeddings": 4096,
+    "type": "yarn",
+}
+
+_preset(ModelConfig(
+    name="deepseek-v2-lite",
+    vocab_size=102400,
+    hidden_size=2048,
+    intermediate_size=10944,
+    num_layers=27,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=192,
+    rope_theta=10000,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=163840,
+    rope_scaling=_DEEPSEEK_V2_YARN,
+    num_experts=64,
+    num_experts_per_tok=6,
+    moe_intermediate_size=1408,
+    num_shared_experts=2,
+    first_dense_layers=1,
+    norm_topk_prob=False,
+    routed_scaling_factor=1.0,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+))
+
+# the same family at a size the CPU tests finish in seconds: 2 layers of
+# which 1 dense, 8 experts top-2, 1 shared, latent rank 32, rope 16
+TINY_MLA = _preset(ModelConfig(
+    name="tiny-mla",
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=2,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=48,
+    rope_theta=10000.0,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=2048,
+    rope_scaling={**_DEEPSEEK_V2_YARN, "original_max_position_embeddings": 64},
+    num_experts=8,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    num_shared_experts=1,
+    first_dense_layers=1,
+    norm_topk_prob=False,
+    kv_lora_rank=32,
+    qk_nope_head_dim=32,
+    qk_rope_head_dim=16,
+    v_head_dim=32,
 ))
 
 

@@ -23,7 +23,11 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.ops.attention import paged_attention, write_kv_slots
+from dynamo_tpu.ops.attention import (
+    latent_attention,
+    paged_attention,
+    write_kv_slots,
+)
 from dynamo_tpu.ops.norm import rms_norm
 from dynamo_tpu.ops.quant import (
     dequantize_kv_rows,
@@ -32,13 +36,23 @@ from dynamo_tpu.ops.quant import (
     quant_matmul,
     quantize_kv_rows,
 )
-from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
+from dynamo_tpu.ops.rope import (
+    apply_rope,
+    pairs_to_halves,
+    rope_cos_sin,
+    rope_inv_freq,
+    softmax_scale,
+)
 
 # Names on the device's operations: `jax.named_scope` puts its name into
 # every traced operation's HLO metadata (`op_name`), where a profile
 # finds it (benchmark/lib/trace_host.py: attn.qkv / attn.rope /
 # attn.kv_write / attn.kernel / attn.o, mlp.gate_up / mlp.down, norm,
-# head; the engine adds sample). Metadata only: the compiled code is
+# head; the engine adds sample; latent attention is attn.mla_q /
+# attn.mla_kv_a / attn.rope / attn.kv_write / attn.mla_absorb /
+# attn.mla_kernel / attn.mla_o, an expert layer mlp.moe_router /
+# mlp.moe_dispatch / mlp.moe_experts / mlp.moe_combine / mlp.moe_shared,
+# models/moe.py). Metadata only: the compiled code is
 # the same without it. The persistent compile cache's key is the same
 # only for a program without a pallas kernel: a kernel's serialized body
 # keeps its own source locations, which the key does not strip, so a
@@ -204,10 +218,19 @@ class KVCache(NamedTuple):
     attention streams every live page per step, so int8 pages halve the
     decode phase's dominant HBM traffic; the scale page adds SUBL*S*4
     bytes per K*Hd*S-byte page (~6% at 8B dims). ks/vs are None in
-    unquantized mode."""
+    unquantized mode.
+
+    Latent cache (`cfg.latent`, docs/kv_cache.md "Latent pools"): ONE
+    pool a layer, `k`, whose row is a token's `[c ; k_r]` (the normed
+    latent, then the rotated shared key: 512 + 64 values for
+    DeepSeek-V2, in the 640 lanes such a row occupies:
+    `ModelConfig.latent_pool_width`), no head axis; `v` is None: the values ARE the first
+    `kv_lora_rank` columns of the same row, and the decode kernel reads
+    each row once (ops/pallas_mla.py). Pages, block tables and slots are
+    the same as for K/V pools."""
 
     k: tuple
-    v: tuple
+    v: tuple | None
     ks: tuple | None = None
     vs: tuple | None = None
 
@@ -218,6 +241,10 @@ class KVCache(NamedTuple):
     @property
     def quantized(self) -> bool:
         return self.ks is not None
+
+    @property
+    def latent(self) -> bool:
+        return self.v is None
 
     def stacked(self) -> tuple[jnp.ndarray, jnp.ndarray]:
         """[L, N, K*Hd] copies (host extraction / wire format only)."""
@@ -234,6 +261,21 @@ def init_kv_cache(
     by shard on their devices: the engine sizes the pool to each
     device's free memory, so a layer's whole unsharded pool is tp times
     what one device can hold and must never be built in one place."""
+    if cfg.latent:
+        if kv_quant is not None:
+            raise ValueError(
+                f"kv_quantization={kv_quant!r} with latent attention "
+                f"('{cfg.name}'): the latent pool is served in the model's "
+                "dtype only (no quantized latent rows yet)"
+            )
+        return KVCache(
+            k=tuple(
+                jnp.zeros((num_slots, cfg.latent_pool_width), dtype,
+                          device=sharding)
+                for _ in range(cfg.num_layers)
+            ),
+            v=None,
+        )
     shape = (num_slots, cfg.num_kv_heads * cfg.head_dim)
     if kv_quant is not None:
         if kv_quant not in ("int8", "int4"):
@@ -780,6 +822,104 @@ def _attn_block(
     return proj, kv_k, kv_v, kv_ks, kv_vs
 
 
+def _mla_attn_block(
+    lp: Params,
+    cfg: ModelConfig,
+    x: jnp.ndarray,          # [B, T, D]
+    cos: jnp.ndarray,        # [B, T, rope width]
+    sin: jnp.ndarray,
+    pool: jnp.ndarray,       # [N, latent_pool_width] this layer's pool
+    write_slots: jnp.ndarray,   # [B*T] int32
+    attn: "AttnSpec",
+    positions: jnp.ndarray,     # [B, T]
+):
+    """Latent attention (DeepSeek-V2) in the ABSORBED form on every path:
+    the per-head key expansion W_uk is folded into the query and the
+    value expansion W_uv applied after the softmax, so attention is
+    multi-query over the cached rows themselves, `score = (q_n W_uk . c
+    + q_r . k_r) x s`, `o = (softmax . c) W_uv`. Decode reads the rows
+    through the paged kernel (ops/pallas_mla.py, the row written by the
+    same kernel); a prefill chunk writes its pages and attends the rows
+    gathered through its block table (ops/attention.latent_attention):
+    at <= 4,096 tokens the absorbed form's extra flops are a few ms a
+    chunk, and one form means prefill and decode cannot disagree about
+    the cache. The expanded form is the benchmark's reference.
+
+    Returns (projection [B, T, D], pool)."""
+    b, t, _ = x.shape
+    h, rank = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    width = pool.shape[1]   # rank + rope, padded to whole lane tiles
+    lane_pad = [(0, 0)] * 2 + [(0, width - rank - rope)]
+    with jax.named_scope("attn.mla_q"):
+        q = mm(x, lp["wq"]).reshape(b, t, h, nope + rope)
+    with jax.named_scope("attn.mla_kv_a"):
+        kva = mm(x, lp["w_kva"])                              # [B, T, W]
+        c = rms_norm(kva[..., :rank], lp["kv_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn.rope"):
+        q_r = apply_rope(pairs_to_halves(q[..., nope:]), cos, sin)
+        k_r = apply_rope(
+            pairs_to_halves(kva[..., None, rank:]), cos, sin
+        )[..., 0, :]
+    with jax.named_scope("attn.mla_absorb"):
+        w_kvb = lp["w_kvb"].reshape(rank, h, nope + vd)
+        q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :nope], w_kvb[..., :nope])
+        # the softmax scale rides the query: the kernel multiplies nothing
+        qa = jnp.pad(
+            jnp.concatenate([q_abs, q_r], axis=-1) * softmax_scale(cfg),
+            [(0, 0)] + lane_pad,
+        ).astype(x.dtype)                                     # [B, T, H, W]
+        rows = jnp.pad(
+            jnp.concatenate([c, k_r], axis=-1), lane_pad
+        ).astype(pool.dtype)                                  # [B, T, W]
+    kernel = jax.named_scope("attn.mla_kernel")
+
+    if attn.block_tables is not None and attn.write_pos is not None:
+        from dynamo_tpu.ops.pallas_mla import mla_paged_decode_attention
+
+        o_lat, pool = kernel(mla_paged_decode_attention)(
+            qa[:, 0], rows[:, 0], pool, attn.block_tables, attn.lengths,
+            attn.write_pos, rank=rank, page_size=attn.page_size,
+            interpret=attn.interpret,
+        )
+        o_lat = o_lat[:, None]                                # [B, 1, H, rank]
+    else:
+        ps = attn.page_size
+        if attn.write_tables is not None:
+            # whole pages through the page writer (chunk starts are
+            # page-aligned; the tail of a last page is the sequence's own
+            # not-yet-valid positions or the trash page)
+            from dynamo_tpu.ops.pallas_mla import latent_page_write
+
+            t_pad = -(-t // ps) * ps
+            pages = jnp.pad(rows, ((0, 0), (0, t_pad - t), (0, 0)))
+            pool = jax.named_scope("attn.kv_write")(latent_page_write)(
+                pool, attn.write_tables,
+                pages.reshape(b * (t_pad // ps), ps, width),
+                page_size=ps, interpret=attn.interpret,
+            )
+        else:
+            with jax.named_scope("attn.kv_write"):
+                pool = pool.at[write_slots].set(rows.reshape(b * t, width))
+        if attn.block_tables is not None:
+            seen = pool.reshape(-1, ps, width)[attn.block_tables].reshape(
+                b, -1, width
+            )
+        else:
+            seen = pool[attn.slot_matrix]                     # [B, C, W]
+        q_lens = None if attn.write_tables is not None else attn.lengths
+        o_lat = kernel(latent_attention)(
+            qa, seen, positions, rank, q_lens=q_lens
+        )
+    with jax.named_scope("attn.mla_absorb"):
+        out = jnp.einsum(
+            "bthr,rhv->bthv", o_lat.astype(x.dtype), w_kvb[..., nope:]
+        )
+    with jax.named_scope("attn.mla_o"):
+        proj = mm(out.reshape(b, t, h * vd), lp["wo"])
+    return proj, pool
+
+
 _ACTIVATIONS = {
     "silu": jax.nn.silu,
     "gelu": lambda x: jax.nn.gelu(x, approximate=False),
@@ -827,6 +967,8 @@ def forward(
     attn,                      # AttnSpec, or a raw [B, C] slot matrix (gather mode)
     embeds: jnp.ndarray | None = None,       # [B, T, D] multimodal injections
     embeds_mask: jnp.ndarray | None = None,  # [B, T] bool: use embeds row
+    moe_stats: list | None = None,  # receives each expert layer's load
+    # (models/moe.py `stats`), for a caller that returns it with its tokens
 ) -> tuple[jnp.ndarray, KVCache]:
     """One model step. Returns (hidden [B, T, D] after final norm, updated kv).
 
@@ -835,9 +977,9 @@ def forward(
     """
     if not isinstance(attn, AttnSpec):
         attn = AttnSpec.gather(attn)
-    # genuine-token mask for MoE capacity (padding must not evict real
-    # tokens): fused decode marks inactive rows by write_pos == -1; every
-    # other path routes padding's writes to trash slot 0
+    # genuine-token mask for the expert layers (padding routes nowhere):
+    # fused decode marks inactive rows by write_pos == -1; every other
+    # path routes padding's writes to trash slot 0
     real_mask = None
     if cfg.num_experts:
         b_, t_ = tokens.shape
@@ -865,10 +1007,11 @@ def forward(
     new_vs_layers = []
     for l, lp in enumerate(params["layers"]):
         x, layer_k, layer_v, layer_ks, layer_vs = layer_step(
-            lp, cfg, x, cos, sin, kv.k[l], kv.v[l],
+            lp, cfg, x, cos, sin, kv.k[l], None if kv.latent else kv.v[l],
             write_slots, attn, positions, real_mask=real_mask,
             kv_ks=kv.ks[l] if kv.quantized else None,
             kv_vs=kv.vs[l] if kv.quantized else None,
+            moe_stats=moe_stats,
         )
         new_k_layers.append(layer_k)
         new_v_layers.append(layer_v)
@@ -877,7 +1020,7 @@ def forward(
 
     kv = KVCache(
         k=tuple(new_k_layers),
-        v=tuple(new_v_layers),
+        v=None if kv.latent else tuple(new_v_layers),
         ks=tuple(new_ks_layers) if kv.quantized else None,
         vs=tuple(new_vs_layers) if kv.quantized else None,
     )
@@ -890,7 +1033,8 @@ def forward(
 
 def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
                positions, real_mask=None, kv_ks=None, kv_vs=None,
-               tp_axis=None, tp_overlap: bool = False, bt_shape=None):
+               tp_axis=None, tp_overlap: bool = False, bt_shape=None,
+               moe_stats=None):
     """One transformer layer (attention + FFN, pre-norm residuals) over
     the paged pools — shared by `forward` and the pipeline-parallel
     stage executor (parallel/pipeline.py). `tp_axis` enables manual-tp
@@ -900,22 +1044,34 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
     and leaves ROW-SCATTERED [ceil(b*t/tp), D] — norms and residual
     adds run on the scattered view and every collective is a chunked
     `lax.ppermute` ring (parallel/tp_overlap.py). kv_ks/kv_vs are the
-    int8-KV scale pools (None in unquantized mode; returned as-is)."""
+    int8-KV scale pools (None in unquantized mode; returned as-is).
+
+    What a layer IS comes from its parameters: a latent-attention layer
+    holds `w_kva` (then `kv_k` is its latent pool and `kv_v` None), an
+    expert layer holds `router` (a model's leading dense layers hold
+    `w_gate` like any dense model's)."""
     if tp_overlap and cfg.num_experts:
         raise ValueError("tp_overlap layer executor covers dense models")
     w_off = cfg.norm_weight_offset
     attn_in = _norm(x, lp["attn_norm"], cfg.rms_norm_eps, weight_offset=w_off)
-    attn_out, kv_k, kv_v, kv_ks, kv_vs = _attn_block(
-        lp, cfg, attn_in, cos, sin, kv_k, kv_v, write_slots, attn, positions,
-        kv_ks=kv_ks, kv_vs=kv_vs, tp_axis=tp_axis,
-        tp_overlap=tp_overlap, bt_shape=bt_shape,
-    )
+    if "w_kva" in lp:
+        attn_out, kv_k = _mla_attn_block(
+            lp, cfg, attn_in, cos, sin, kv_k, write_slots, attn, positions,
+        )
+    else:
+        attn_out, kv_k, kv_v, kv_ks, kv_vs = _attn_block(
+            lp, cfg, attn_in, cos, sin, kv_k, kv_v, write_slots, attn,
+            positions, kv_ks=kv_ks, kv_vs=kv_vs, tp_axis=tp_axis,
+            tp_overlap=tp_overlap, bt_shape=bt_shape,
+        )
     x = x + attn_out
     mlp_in = _norm(x, lp["mlp_norm"], cfg.rms_norm_eps, weight_offset=w_off)
-    if cfg.num_experts:
+    if "router" in lp:
         from dynamo_tpu.models.moe import moe_block
 
-        x = x + moe_block(lp, cfg, mlp_in, real_mask=real_mask)
+        x = x + moe_block(
+            lp, cfg, mlp_in, real_mask=real_mask, stats=moe_stats
+        )
     else:
         x = x + _mlp_block(
             lp, mlp_in, tp_axis=tp_axis, act=cfg.hidden_act,
@@ -977,6 +1133,11 @@ def init_params(
     d, f = cfg.hidden_size, cfg.intermediate_size
     qs, kvs = cfg.q_size, cfg.kv_size
     keys = iter(jax.random.split(key, 4 + 9 * cfg.num_layers))
+    if quantize and (cfg.latent or cfg.num_shared_experts):
+        raise ValueError(
+            f"quantization with '{cfg.name}': int8 weights are not served "
+            "for latent attention or shared experts yet"
+        )
     if quantize:
         from dynamo_tpu.ops.quant import QUANT_KEYS, quantize_weight
 
@@ -989,15 +1150,31 @@ def init_params(
     layers = []
     for i in range(cfg.num_layers):
         sh = shardings["layers"][i] if shardings else {}
-        lp = {
-            "attn_norm": jnp.ones((d,), dtype),
-            "wq": dense(next(keys), (d, qs), sharding=sh.get("wq")),
-            "wk": dense(next(keys), (d, kvs), sharding=sh.get("wk")),
-            "wv": dense(next(keys), (d, kvs), sharding=sh.get("wv")),
-            "wo": dense(next(keys), (qs, d), sharding=sh.get("wo")),
-            "mlp_norm": jnp.ones((d,), dtype),
-        }
-        if cfg.num_experts:
+        if cfg.latent:
+            rank, hv = cfg.kv_lora_rank, cfg.num_heads * cfg.v_head_dim
+            lp = {
+                "attn_norm": jnp.ones((d,), dtype),
+                "wq": dense(next(keys), (d, qs)),
+                "w_kva": dense(next(keys), (d, cfg.latent_width)),
+                "kv_norm": jnp.ones((rank,), dtype),
+                "w_kvb": dense(
+                    next(keys),
+                    (rank, cfg.num_heads * (cfg.qk_nope_head_dim
+                                            + cfg.v_head_dim)),
+                ),
+                "wo": dense(next(keys), (hv, d)),
+                "mlp_norm": jnp.ones((d,), dtype),
+            }
+        else:
+            lp = {
+                "attn_norm": jnp.ones((d,), dtype),
+                "wq": dense(next(keys), (d, qs), sharding=sh.get("wq")),
+                "wk": dense(next(keys), (d, kvs), sharding=sh.get("wk")),
+                "wv": dense(next(keys), (d, kvs), sharding=sh.get("wv")),
+                "wo": dense(next(keys), (qs, d), sharding=sh.get("wo")),
+                "mlp_norm": jnp.ones((d,), dtype),
+            }
+        if cfg.is_moe_layer(i):
             from dynamo_tpu.models.moe import init_moe_params
 
             lp.update(init_moe_params(cfg, next(keys), dtype=dtype))

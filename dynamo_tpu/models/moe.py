@@ -1,101 +1,174 @@
-"""Sparse mixture-of-experts FFN (Mixtral-style) with expert parallelism.
+"""Sparse mixture-of-experts FFN, drop-free: every routed (token, expert)
+pair is computed, at any imbalance, with static shapes.
 
-The reference serves MoE checkpoints (DeepSeek-R1, Mixtral) through its
-engines' fused MoE kernels + expert-parallel process groups (SURVEY §2.4
-— EP is an engine concern there). TPU-native, experts are one more mesh
-axis: expert weights live as [E, ...] arrays sharded P('ep', ...), the
-router's dispatch/combine are one-hot einsums (the GShard/Switch
-formulation), and GSPMD inserts the all-to-alls over the ep axis — no
-hand-written token shuffling.
+The reference serves MoE checkpoints (DeepSeek, Mixtral) through its
+engines' fused MoE kernels (SURVEY §2.4). Here the layer is four steps,
+each under the `jax.named_scope` a profile finds it by:
 
-Capacity-based routing (GShard): each expert processes at most
-`capacity = ceil(k * N / E * capacity_factor)` tokens per step; overflow
-tokens fall through that expert (their combine weight is zero) —
-degraded quality, never a crash, and every shape stays static for XLA.
-Top-k weights are renormalized over the selected experts (Mixtral
-convention).
+- `mlp.moe_router`: scores over the experts in float32 (bf16 logits flip
+  near-tie top-k membership), softmax, the k largest. What happens to the
+  k weights is the CONFIGURATION's: renormalised over the selected experts
+  (`norm_topk_prob`, Mixtral) or used as they are (DeepSeek-V2), times
+  `routed_scaling_factor`.
+- `mlp.moe_dispatch`: the N x k pairs sorted by expert (stable, so a
+  token's rows keep their order inside an expert) and the tokens gathered
+  into that order: rows [0, g0) belong to expert 0, the next g1 to expert
+  1, ... Padding rows (bucket pad, inactive decode slots) are given the
+  sentinel expert E: they sort last, belong to no group and are computed
+  by nobody.
+- `mlp.moe_experts`: three grouped matmuls (`grouped_matmul`: rows x
+  [E, in, out] under the group sizes) around the SwiGLU: ONE kernel on
+  every platform, megablox (`jax.experimental.pallas.ops.tpu.megablox.gmm`),
+  which visits an expert's weights once per 128-row tile that holds one
+  of its rows; compiled where the program is lowered for a TPU, in the
+  pallas interpreter elsewhere (CPU tests run the tiling, the sentinel
+  rows and the unwritten tail the chip runs). Timed alone on the v5e at
+  DeepSeek-V2-Lite's widths (8 expert layers, 10.8 ms of weights at the
+  HBM peak; `scripts/moe_layer_tpu.py`, PERF.md section 6, PR 34), decode
+  (768 rows) / a prefill chunk (3,072 rows): megablox at tiles of 128 x
+  2,048 x 1,408 13.8 / 16.6 ms, smaller or wider tiles slower; XLA:TPU's
+  `jax.lax.ragged_dot` 35.0 / 47.1 ms (why it is not the layer's form);
+  every expert over every token (no sort, a batched matmul) 12.7 / 28.2.
+- `mlp.moe_combine`: each pair's output times its weight, un-sorted back
+  to token order, the k rows of a token added up.
+
+Shared experts (`num_shared_experts`, one SwiGLU of that many expert
+widths on EVERY token) are `mlp.moe_shared`. Expert weights live as
+[E, ...] arrays, sharded P('ep', ...) on a mesh that has the axis.
+
+`stats` (a list the caller passes) receives this layer's load as two
+int32 scalars, (distinct experts with a token, most tokens on one expert):
+the decode program returns their means with the tokens (engine.py).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.ops.quant import mm
+
+# rows a grouped-matmul tile holds, and the most bytes of an expert's
+# weights a tile may take (two such tiles are in flight): 2,048 x 1,408
+# bf16 at DeepSeek-V2-Lite's widths, the fastest tiling timed
+GMM_ROWS = 128
+GMM_WEIGHT_TILE_BYTES = 6 << 20
+
 
 def init_moe_params(cfg, key, dtype=jnp.bfloat16) -> dict:
-    """Per-layer MoE params: router [D, E] + expert FFNs [E, D, F]/[E, F, D]."""
-    d, f, e = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
-    k_router, k_gate, k_up, k_down = jax.random.split(key, 4)
+    """Per-layer MoE params: router [D, E], expert FFNs [E, D, F] /
+    [E, F, D], and the shared experts as one FFN of width S x F."""
+    d, f, e = cfg.hidden_size, cfg.expert_width, cfg.num_experts
+    k_router, k_gate, k_up, k_down, k_shared = jax.random.split(key, 5)
 
     def dense(k, shape, scale):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
-    return {
+    lp = {
         "router": dense(k_router, (d, e), d ** -0.5),
         "we_gate": dense(k_gate, (e, d, f), d ** -0.5),
         "we_up": dense(k_up, (e, d, f), d ** -0.5),
         "we_down": dense(k_down, (e, f, d), f ** -0.5),
     }
+    if cfg.num_shared_experts:
+        fs = cfg.num_shared_experts * f
+        ks = jax.random.split(k_shared, 3)
+        lp.update({
+            "ws_gate": dense(ks[0], (d, fs), d ** -0.5),
+            "ws_up": dense(ks[1], (d, fs), d ** -0.5),
+            "ws_down": dense(ks[2], (fs, d), fs ** -0.5),
+        })
+    return lp
 
 
-def expert_capacity(cfg, n_tokens: int) -> int:
-    """Static per-expert token budget, padded to a TPU-friendly multiple."""
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    cap = int(k * n_tokens / e * cfg.expert_capacity_factor) + 1
-    return -(-cap // 8) * 8
+@jax.named_scope("mlp.moe_router")
+def route(lp: dict, cfg, xf: jnp.ndarray):
+    """xf [N, D] -> (weights [N, k] float32, experts [N, k] int32)."""
+    logits = xf.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        top_w = top_w * cfg.routed_scaling_factor
+    return top_w, top_i.astype(jnp.int32)
 
 
-def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None) -> jnp.ndarray:
-    """x [B, T, D] -> [B, T, D]. Router top-k -> capacity-bounded one-hot
-    dispatch -> per-expert SwiGLU -> weighted combine.
+def grouped_matmul(xs, w, group_sizes, out_dtype=None):
+    """xs [M, in] (rows sorted by group, M a multiple of GMM_ROWS) x
+    w [E, in, out] -> [M, out]: rows [0, g0) times w[0], the next g1 times
+    w[1], ... Rows past the groups' end come out as whatever was there
+    (the caller selects them out). The megablox kernel: compiled where
+    this is lowered for a TPU (a described one too), interpreted on any
+    other platform."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    `real_mask` [B, T] bool marks genuine tokens: padding rows (bucket
-    pad, inactive decode slots) must not consume expert capacity — a pad
-    row ahead of a real token in batch order would otherwise evict it."""
+    k, n = w.shape[1], w.shape[2]
+    tk = min(k, 2048)
+    tn = min(n, max(GMM_WEIGHT_TILE_BYTES // (tk * w.dtype.itemsize)
+                    // 128 * 128, 128))
+    kernel = functools.partial(
+        gmm, preferred_element_type=out_dtype or xs.dtype,
+        tiling=(GMM_ROWS, tk, tn),
+    )
+    return jax.lax.platform_dependent(
+        xs, w, group_sizes, tpu=kernel,
+        default=functools.partial(kernel, interpret=True),
+    )
+
+
+def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None,
+              stats: list | None = None) -> jnp.ndarray:
+    """x [B, T, D] -> [B, T, D]; `real_mask` [B, T] bool marks genuine
+    tokens (others route nowhere and come out as the shared experts'
+    output alone, which no real row reads)."""
     b, t, d = x.shape
     n = b * t
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    cap = expert_capacity(cfg, n)
     xf = x.reshape(n, d)
-    real = (
-        jnp.ones((n,), jnp.float32)
-        if real_mask is None
-        else real_mask.reshape(n).astype(jnp.float32)
-    )
+    top_w, top_i = route(lp, cfg, xf)
 
-    # fp32 routing: bf16 logits flip near-tie top-k membership
-    logits = xf.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                 # [N, E]
-    top_w, top_i = jax.lax.top_k(probs, k)                  # [N, k]
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)  # mixtral renorm
+    with jax.named_scope("mlp.moe_dispatch"):
+        expert_of = top_i.reshape(n * k)
+        if real_mask is not None:
+            expert_of = jnp.where(
+                jnp.repeat(real_mask.reshape(n), k), expert_of, e
+            )
+        # the grouped matmul works on whole tiles of rows: the pairs are
+        # padded with sentinel rows up to a multiple of GMM_ROWS
+        m = -(-n * k // GMM_ROWS) * GMM_ROWS
+        expert_of = jnp.pad(expert_of, (0, m - n * k), constant_values=e)
+        pair = jnp.arange(m, dtype=jnp.int32)
+        sorted_expert, order = jax.lax.sort(
+            (expert_of, pair), num_keys=1, is_stable=True
+        )
+        # rows per expert; the sentinel's bin is cut off
+        group_sizes = jnp.zeros((e + 1,), jnp.int32).at[expert_of].add(1)[:e]
+        xs = xf[jnp.minimum(order // k, n - 1)]              # [M, D]
+    if stats is not None:
+        stats.append((jnp.sum(group_sizes > 0), jnp.max(group_sizes)))
 
-    # position of each (token, slot) within its expert: slot-major cumsum
-    # so slot 0 assignments win capacity over slot 1 (GShard priority);
-    # pad rows are zeroed out of the count entirely
-    onehot = jax.nn.one_hot(top_i, e, dtype=jnp.float32)    # [N, k, E]
-    onehot = onehot * real[:, None, None]
-    flat = onehot.transpose(1, 0, 2).reshape(k * n, e)      # [kN, E]
-    pos = jnp.cumsum(flat, axis=0) - 1.0                    # [kN, E]
-    pos_in_e = jnp.sum(pos * flat, axis=-1)                 # [kN]
-    keep = (pos_in_e < cap) & (jnp.sum(flat, axis=-1) > 0)  # pads drop here
+    with jax.named_scope("mlp.moe_experts"):
+        gate = grouped_matmul(xs, lp["we_gate"], group_sizes)
+        up = grouped_matmul(xs, lp["we_up"], group_sizes)
+        ys = grouped_matmul(
+            jax.nn.silu(gate) * up, lp["we_down"], group_sizes, jnp.float32
+        )                                                    # [M, D]
 
-    slot_w = top_w.T.reshape(k * n)                         # [kN]
-    expert_of = top_i.T.reshape(k * n)                      # [kN]
-    pos_oh = jax.nn.one_hot(
-        pos_in_e.astype(jnp.int32), cap, dtype=xf.dtype
-    )  # [kN, C]
-    exp_oh = jax.nn.one_hot(expert_of, e, dtype=xf.dtype)   # [kN, E]
-    keep_f = keep.astype(xf.dtype)
+    with jax.named_scope("mlp.moe_combine"):
+        # a row past the groups' end holds whatever the grouped matmul
+        # left there: selected out, never multiplied by a zero
+        w_sorted = jnp.pad(top_w.reshape(n * k), (0, m - n * k))[order]
+        ys = jnp.where(
+            (sorted_expert < e)[:, None], ys * w_sorted[:, None], 0.0
+        )
+        back = jnp.zeros((m,), jnp.int32).at[order].set(pair)
+        out = ys[back[: n * k]].reshape(n, k, d).sum(axis=1).astype(x.dtype)
 
-    # dispatch [kN, E, C] (0/1), combine adds the routing weight
-    dispatch = exp_oh[:, :, None] * pos_oh[:, None, :] * keep_f[:, None, None]
-    combine = dispatch * slot_w.astype(xf.dtype)[:, None, None]
-
-    tok = jnp.tile(xf, (k, 1))                              # [kN, D]
-    expert_in = jnp.einsum("sec,sd->ecd", dispatch, tok)    # [E, C, D]
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, lp["we_gate"]))
-    up = jnp.einsum("ecd,edf->ecf", expert_in, lp["we_up"])
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up, lp["we_down"])
-    out = jnp.einsum("sec,ecd->sd", combine, expert_out)    # [kN, D]
-    out = out.reshape(k, n, d).sum(axis=0)                  # slots add up
+    if cfg.num_shared_experts:
+        with jax.named_scope("mlp.moe_shared"):
+            hidden = jax.nn.silu(mm(xf, lp["ws_gate"])) * mm(xf, lp["ws_up"])
+            out = out + mm(hidden, lp["ws_down"])
     return out.reshape(b, t, d)

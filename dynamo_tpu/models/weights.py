@@ -81,13 +81,24 @@ def load_params(
         "mlp.gate_proj.weight": ("w_gate", True),
         "mlp.up_proj.weight": ("w_up", True),
         "mlp.down_proj.weight": ("w_down", True),
+        # deepseek_v2: latent attention, the router, the shared experts
+        # (one FFN of n_shared_experts expert widths in the checkpoint too)
+        "self_attn.kv_a_proj_with_mqa.weight": ("w_kva", True),
+        "self_attn.kv_a_layernorm.weight": ("kv_norm", False),
+        "self_attn.kv_b_proj.weight": ("w_kvb", True),
+        "mlp.gate.weight": ("router", True),
+        "mlp.shared_experts.gate_proj.weight": ("ws_gate", True),
+        "mlp.shared_experts.up_proj.weight": ("ws_up", True),
+        "mlp.shared_experts.down_proj.weight": ("ws_down", True),
     }
 
     # mixtral MoE tensors stage per (layer, matrix) and flush to device
     # the moment all E experts arrived — staging stays bounded at one
     # [E, ...] group, keeping the one-tensor(-group) streaming invariant
     moe_stage: dict[tuple[int, str], dict[int, np.ndarray]] = {}
-    moe_map = {"w1": "we_gate", "w3": "we_up", "w2": "we_down"}
+    moe_map = {"w1": "we_gate", "w3": "we_up", "w2": "we_down",
+               "gate_proj": "we_gate", "up_proj": "we_up",
+               "down_proj": "we_down"}
 
     def stage_moe(idx: int, ours: str, e_idx: int, tensor: np.ndarray) -> None:
         group = moe_stage.setdefault((idx, ours), {})
@@ -114,9 +125,14 @@ def load_params(
             if sub == "block_sparse_moe.gate.weight":
                 layers[idx]["router"] = convert(name, tensor, transpose=True)
                 continue
-            if sub.startswith("block_sparse_moe.experts."):
-                # block_sparse_moe.experts.{e}.{w1|w2|w3}.weight
-                e_s, _, w_name = sub[len("block_sparse_moe.experts."):].partition(".")
+            experts = next(
+                (p for p in ("block_sparse_moe.experts.", "mlp.experts.")
+                 if sub.startswith(p)), None,
+            )
+            if experts:
+                # mixtral: block_sparse_moe.experts.{e}.{w1|w2|w3}.weight;
+                # deepseek_v2: mlp.experts.{e}.{gate|up|down}_proj.weight
+                e_s, _, w_name = sub[len(experts):].partition(".")
                 ours = moe_map.get(w_name.split(".")[0])
                 if ours is not None:
                     stage_moe(idx, ours, int(e_s), tensor)
@@ -135,15 +151,20 @@ def load_params(
         raise ValueError(
             f"checkpoint {model_dir} has incomplete expert groups: {short[:5]}"
         )
-    required = ["wq"]
-    if cfg.num_experts:
-        required += ["router", "we_gate", "we_up", "we_down"]
+    def required(i: int) -> list[str]:
+        need = ["wq"] + (["w_kva", "kv_norm", "w_kvb"] if cfg.latent else [])
+        if cfg.is_moe_layer(i):
+            need += ["router", "we_gate", "we_up", "we_down"]
+            if cfg.num_shared_experts:
+                need += ["ws_gate", "ws_up", "ws_down"]
+        return need
+
     missing = [
         k for k in ("embed", "final_norm") if k not in params
     ] + [
         f"layers[{i}].{r}"
         for i, lp in enumerate(layers)
-        for r in required
+        for r in required(i)
         if r not in lp
     ]
     if missing:

@@ -155,3 +155,30 @@ def paged_attention(
     probs = _masked_softmax(logits, mask)
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
     return out.reshape(b, t, h, hd)
+
+
+def latent_attention(
+    qa: jnp.ndarray,         # [B, T, H, W] absorbed, pre-scaled queries
+    rows: jnp.ndarray,       # [B, C, W] the sequence's latent rows, by position
+    positions: jnp.ndarray,  # [B, T] int32 absolute position of each query
+    rank: int,               # the first `rank` columns of a row are its value
+    q_lens: jnp.ndarray | None = None,  # [B] valid query rows per row
+) -> jnp.ndarray:
+    """Absorbed latent attention over gathered rows (models/llama.py
+    `_mla_attn_block`): every head scores against the ONE row a token
+    keeps, `[c ; k_r]`, and the value is that row's `c`. Row j holds
+    position j, so the mask is `paged_attention`'s. Returns the latent
+    output [B, T, H, rank] in the rows' dtype; the caller applies W_uv."""
+    c = rows.shape[1]
+    logits = jnp.einsum(
+        "bthw,bcw->bhtc", qa, rows, preferred_element_type=jnp.float32
+    )
+    mask = jnp.arange(c)[None, None, :] <= positions[:, :, None]  # [B, T, C]
+    if q_lens is not None:
+        mask = mask & (
+            jnp.arange(qa.shape[1])[None, :, None] < q_lens[:, None, None]
+        )
+    probs = _masked_softmax(logits, mask[:, None])
+    return jnp.einsum(
+        "bhtc,bcr->bthr", probs.astype(rows.dtype), rows[..., :rank]
+    )

@@ -1,4 +1,5 @@
-"""Rotary position embeddings, HF rotate-half convention, Llama-3.1 scaling.
+"""Rotary position embeddings, HF rotate-half convention; Llama-3.1 and
+YaRN (deepseek_v2) frequency scaling.
 
 HF convention (first-half/second-half pairing) is used so HF safetensors
 weights load without permutation. Frequencies are computed in float32 and
@@ -8,6 +9,8 @@ compounds at long context.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -15,11 +18,15 @@ from dynamo_tpu.models.config import ModelConfig
 
 
 def rope_inv_freq(cfg: ModelConfig) -> np.ndarray:
-    """Per-pair inverse frequencies [head_dim//2], with optional llama3
-    NTK-by-parts scaling (matches HF `Llama3RotaryEmbedding`)."""
-    half = cfg.head_dim // 2
+    """Per-pair inverse frequencies [rope width // 2] (the rope width is
+    `qk_rope_head_dim` under latent attention, else `head_dim`), with
+    optional llama3 NTK-by-parts scaling (matches HF
+    `Llama3RotaryEmbedding`) or YaRN (`yarn_inv_freq`)."""
+    half = (cfg.qk_rope_head_dim or cfg.head_dim) // 2
     inv = 1.0 / (cfg.rope_theta ** (np.arange(0, half, dtype=np.float64) / half))
     sc = cfg.rope_scaling
+    if sc and sc.get("type", sc.get("rope_type")) == "yarn":
+        return yarn_inv_freq(inv, cfg.rope_theta, sc).astype(np.float32)
     if sc and sc.get("rope_type") in ("llama3",):
         factor = sc["factor"]
         low = sc["low_freq_factor"]
@@ -37,6 +44,60 @@ def rope_inv_freq(cfg: ModelConfig) -> np.ndarray:
             inv,
         )
     return inv.astype(np.float32)
+
+
+def yarn_inv_freq(inv: np.ndarray, theta: float, sc: dict) -> np.ndarray:
+    """YaRN (as `DeepseekV2YarnRotaryEmbedding`): a blend of the plain
+    frequencies `inv` and the same / `factor`, by a linear ramp over the
+    pair index between the dimensions that turn `beta_fast` and
+    `beta_slow` times within the original context. Fast pairs (short
+    wavelengths) keep their frequency, slow ones are interpolated."""
+    half = inv.shape[0]
+    dim, orig = 2 * half, sc["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(sc["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(sc["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    return inv / sc["factor"] * ramp + inv * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term: 0.1 x mscale x ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """What scores are multiplied by before the softmax: head_dim^-0.5,
+    times YaRN's `mscale_all_dim` term squared where the configuration
+    has one (deepseek_v2: 192^-0.5 x 1.2608^2 at the published values).
+    The factor on cos / sin, mscale / mscale_all_dim, is 1 for every
+    published deepseek_v2 configuration and is refused otherwise."""
+    scale = cfg.head_dim ** -0.5
+    sc = cfg.rope_scaling
+    if sc and sc.get("type", sc.get("rope_type")) == "yarn":
+        if sc.get("mscale", 1.0) != sc.get("mscale_all_dim", 0.0):
+            raise ValueError(
+                "yarn rope_scaling with mscale != mscale_all_dim (a factor "
+                "on cos / sin other than 1) is not served"
+            )
+        scale *= yarn_mscale(sc["factor"], sc["mscale_all_dim"]) ** 2
+    return scale
+
+
+def pairs_to_halves(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., d] with rotary pairs as adjacent values (x[2i], x[2i+1]), the
+    deepseek_v2 checkpoint layout, to the half-split layout `apply_rope`
+    rotates (the published modelling code makes the same permutation).
+    Applied to queries and keys alike, so their dot product is the one of
+    the adjacent-pair rotation."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 def rope_cos_sin(inv_freq: jnp.ndarray, positions: jnp.ndarray):

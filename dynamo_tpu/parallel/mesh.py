@@ -126,38 +126,54 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    layer = {
-        "attn_norm": ns(),
-        "wq": ns(None, "tp"),
-        "wk": ns(None, "tp"),
-        "wv": ns(None, "tp"),
-        "wo": ns("tp", None),
-        "mlp_norm": ns(),
-    }
-    if cfg.num_experts:
-        # sparse MoE: experts over ep, each expert's FFN column/row
-        # parallel over tp (models/moe.py; GSPMD inserts the dispatch/
-        # combine all-to-alls over ep)
-        layer.update({
-            "router": ns(),
-            "we_gate": ns("ep", None, "tp"),
-            "we_up": ns("ep", None, "tp"),
-            "we_down": ns("ep", "tp", None),
-        })
-    else:
-        layer.update({
-            "w_gate": ns(None, "tp"),
-            "w_up": ns(None, "tp"),
-            "w_down": ns("tp", None),
-        })
-    if cfg.attn_bias:
-        layer["bq"] = ns("tp")
-        layer["bk"] = ns("tp")
-        layer["bv"] = ns("tp")
+    def layer(i: int) -> dict:
+        if cfg.latent:
+            # latent attention is served on one device (the engine
+            # refuses a larger mesh): every leaf whole
+            lp = {k: ns() for k in (
+                "attn_norm", "wq", "w_kva", "kv_norm", "w_kvb", "wo",
+                "mlp_norm",
+            )}
+        else:
+            lp = {
+                "attn_norm": ns(),
+                "wq": ns(None, "tp"),
+                "wk": ns(None, "tp"),
+                "wv": ns(None, "tp"),
+                "wo": ns("tp", None),
+                "mlp_norm": ns(),
+            }
+        if cfg.is_moe_layer(i):
+            # sparse MoE: experts over ep, each expert's FFN column/row
+            # parallel over tp (models/moe.py; GSPMD inserts the
+            # dispatch/combine collectives over ep)
+            lp.update({
+                "router": ns(),
+                "we_gate": ns("ep", None, "tp"),
+                "we_up": ns("ep", None, "tp"),
+                "we_down": ns("ep", "tp", None),
+            })
+            if cfg.num_shared_experts:
+                lp.update({
+                    "ws_gate": ns(None, "tp"),
+                    "ws_up": ns(None, "tp"),
+                    "ws_down": ns("tp", None),
+                })
+        else:
+            lp.update({
+                "w_gate": ns(None, "tp"),
+                "w_up": ns(None, "tp"),
+                "w_down": ns("tp", None),
+            })
+        if cfg.attn_bias:
+            lp["bq"] = ns("tp")
+            lp["bk"] = ns("tp")
+            lp["bv"] = ns("tp")
+        return lp
 
     out = {
         "embed": ns("tp", None),  # vocab-sharded; lookup all-gathers over tp
-        "layers": [dict(layer) for _ in range(cfg.num_layers)],
+        "layers": [layer(i) for i in range(cfg.num_layers)],
         "final_norm": ns(),
     }
     if not cfg.tie_word_embeddings:

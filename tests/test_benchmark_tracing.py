@@ -254,7 +254,8 @@ def test_decode_kv_read_amp(libs, digests, want):
 def test_decode_kv_read_amp_is_declared():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    m = bench["per_layer"][-1]
+    m = next(m for m in bench["per_layer"]
+             if m["name"] == "decode_kv_read_amp")
     assert m == {
         "name": "decode_kv_read_amp", "unit": "x", "better": "lower",
         "source": "program_counter", "layer": "kernels",

@@ -1,4 +1,6 @@
-"""Sparse MoE (mixtral-style) + expert parallelism: block oracle match,
+"""Sparse MoE, drop-free (models/moe.py): block oracle match for each
+published router (renormalised top-k, softmax scores as they are, scaled,
+beside shared experts), no token dropped at any imbalance, padding rows,
 sharded forward equivalence on the ep axis, engine e2e serving."""
 
 from __future__ import annotations
@@ -7,121 +9,143 @@ import jax
 import jax.numpy as jnp
 
 import numpy as np
+import pytest
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import get_config
-from dynamo_tpu.models.moe import expert_capacity, init_moe_params, moe_block
+from dynamo_tpu.models.moe import init_moe_params, moe_block
 from dynamo_tpu.parallel import mesh as meshmod
 
 CFG = get_config("tiny-moe").with_(dtype="float32")
 
 
 def moe_oracle(lp, cfg, x):
-    """Per-token loop: route to top-k experts, weighted SwiGLU sum —
-    assumes capacity is never exceeded."""
+    """Per-token loop: route to the top-k experts, weighted SwiGLU sum
+    (weights renormalised or as they are, by the configuration), plus the
+    shared experts on every token."""
     b, t, d = x.shape
     out = np.zeros((b, t, d), np.float32)
-    router = np.asarray(lp["router"], np.float32)
+    w32 = {k: np.asarray(v, np.float32) for k, v in lp.items()}
+
+    def swiglu(h, gate, up, down):
+        g = h @ gate
+        return ((g / (1 + np.exp(-g))) * (h @ up)) @ down
+
     for bi in range(b):
         for ti in range(t):
             h = np.asarray(x[bi, ti], np.float32)
-            logits = h @ router
+            logits = h @ w32["router"]
             probs = np.exp(logits - logits.max())
             probs /= probs.sum()
-            top = np.argsort(-probs)[: cfg.num_experts_per_tok]
-            w = probs[top] / probs[top].sum()
+            top = np.argsort(-probs, kind="stable")[: cfg.num_experts_per_tok]
+            w = probs[top]
+            if cfg.norm_topk_prob:
+                w = w / w.sum()
+            w = w * cfg.routed_scaling_factor
             for wi, e in zip(w, top):
-                gate = np.asarray(lp["we_gate"], np.float32)[e]
-                up = np.asarray(lp["we_up"], np.float32)[e]
-                down = np.asarray(lp["we_down"], np.float32)[e]
-                g = h @ gate
-                silu = g / (1 + np.exp(-g))
-                out[bi, ti] += wi * ((silu * (h @ up)) @ down)
+                out[bi, ti] += wi * swiglu(
+                    h, w32["we_gate"][e], w32["we_up"][e], w32["we_down"][e])
+            if cfg.num_shared_experts:
+                out[bi, ti] += swiglu(
+                    h, w32["ws_gate"], w32["ws_up"], w32["ws_down"])
     return out
 
 
-def test_moe_block_matches_oracle():
-    key = jax.random.PRNGKey(0)
-    lp = init_moe_params(CFG, key, dtype=jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, CFG.hidden_size))
-    got = np.asarray(moe_block(lp, CFG, x))
-    ref = moe_oracle(lp, CFG, np.asarray(x))
+# the router as each family publishes it: Mixtral renormalises its top-2;
+# DeepSeek-V2 uses the softmax scores as they are, beside shared experts
+ROUTERS = {
+    "renormalised": CFG,
+    "as-is": CFG.with_(norm_topk_prob=False),
+    "as-is-scaled-shared": CFG.with_(
+        norm_topk_prob=False, routed_scaling_factor=2.5, num_shared_experts=2,
+        moe_intermediate_size=32),
+}
+
+
+def _layer(cfg, seed=0):
+    return init_moe_params(cfg, jax.random.PRNGKey(seed), dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_moe_block_matches_oracle(router):
+    cfg = ROUTERS[router]
+    lp = _layer(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.hidden_size))
+    got = np.asarray(moe_block(lp, cfg, x))
+    ref = moe_oracle(lp, cfg, np.asarray(x))
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
-def test_capacity_drops_overflow_deterministically():
-    # force every token's top-1 to expert 0 via a huge router column; with
-    # N tokens > cap, tokens at batch positions >= cap lose their expert-0
-    # slot (GShard priority: earlier rows win) and keep ONLY their
-    # second-choice expert's weighted contribution
-    cfg = CFG.with_(expert_capacity_factor=0.1)
-    lp = init_moe_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    lp["router"] = lp["router"].at[:, 0].set(100.0)
+@pytest.mark.parametrize("routing", ["uniform", "one-expert"])
+def test_no_token_is_dropped_at_any_imbalance(routing):
+    """Drop-free: every routed (token, expert) pair is computed, whether
+    the tokens spread evenly or ALL of them pick the same expert first
+    (a capacity-bounded layer drops most of them there)."""
+    cfg = ROUTERS["as-is"]
+    lp = _layer(cfg)
     n = 64
-    cap = expert_capacity(cfg, n)
-    assert cap < n
     x = jax.random.normal(jax.random.PRNGKey(2), (1, n, cfg.hidden_size))
-    out = np.asarray(moe_block(lp, cfg, x))
-    assert np.isfinite(out).all()
-
-    # replicate the GShard priority exactly: slot-major (all first
-    # choices, row order, then all second choices); an assignment past
-    # `cap` in its expert contributes nothing
-    router = np.asarray(lp["router"], np.float32)
-    counters = {e: 0 for e in range(cfg.num_experts)}
-    per_tok = []
-    for ti in range(n):
-        h = np.asarray(x[0, ti], np.float32)
-        logits = h @ router
-        probs = np.exp(logits - logits.max())
-        probs /= probs.sum()
-        top = np.argsort(-probs)[:2]
-        w = probs[top] / probs[top].sum()
-        per_tok.append((h, top, w))
-    assignments = [[None, None] for _ in range(n)]
-    for slot in range(2):
-        for ti in range(n):
-            e = int(per_tok[ti][1][slot])
-            kept = counters[e] < cap
-            counters[e] += 1
-            assignments[ti][slot] = kept
-    dropped = [ti for ti in range(n) if not all(assignments[ti])]
-    assert dropped, "test setup must overflow some expert"
-    for ti in range(n):
-        h, top, w = per_tok[ti]
-        expected = np.zeros(cfg.hidden_size, np.float32)
-        for slot in range(2):
-            if not assignments[ti][slot]:
-                continue
-            e = int(top[slot])
-            g = h @ np.asarray(lp["we_gate"], np.float32)[e]
-            silu = g / (1 + np.exp(-g))
-            expected += w[slot] * (
-                (silu * (h @ np.asarray(lp["we_up"], np.float32)[e]))
-                @ np.asarray(lp["we_down"], np.float32)[e]
-            )
-        np.testing.assert_allclose(out[0, ti], expected, rtol=2e-4, atol=2e-4)
-
-
-def test_padding_rows_do_not_consume_capacity():
-    """With a real_mask, pad rows ahead of real tokens must not evict
-    them from their routed expert."""
-    cfg = CFG.with_(expert_capacity_factor=0.1)
-    lp = init_moe_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    lp["router"] = lp["router"].at[:, 0].set(100.0)
-    n = 64
-    cap = expert_capacity(cfg, n)
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, n, cfg.hidden_size))
-    # first half pads: without the mask they'd eat expert-0 capacity
-    mask = jnp.arange(n)[None, :] >= (n - cap)
-    out = np.asarray(moe_block(lp, cfg, x, real_mask=mask))
-    # all real tokens (the last cap rows) got their full two-expert sum
+    if routing == "one-expert":
+        # positive inputs on a huge router column: expert 0 wins every row
+        lp["router"] = lp["router"].at[:, 0].set(100.0)
+        x = jnp.abs(x)
+    if routing == "uniform":
+        # a router of zeros scores every expert alike: top-k takes the
+        # first k for every token, each with weight 1/E
+        lp["router"] = jnp.zeros_like(lp["router"])
+    stats = []
+    got = np.asarray(moe_block(lp, cfg, x, stats=stats))
     ref = moe_oracle(lp, cfg, np.asarray(x))
+    np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+    (hit, load_max), = stats
+    assert int(load_max) == n  # every token reached its first expert
+    assert int(hit) == cfg.num_experts_per_tok if routing == "uniform" \
+        else 2 <= int(hit) <= cfg.num_experts
+
+
+def test_grouped_matmul_kernel_matches_ragged_dot():
+    """The layer's grouped matmul (megablox; off a TPU it runs in the
+    pallas interpreter) against `jax.lax.ragged_dot` on rows sorted by
+    group: uneven groups, an empty one, and rows past the groups' end
+    (which nobody reads)."""
+    from dynamo_tpu.models.moe import GMM_ROWS, grouped_matmul
+
+    rng = np.random.RandomState(0)
+    xs = jnp.asarray(rng.randn(2 * GMM_ROWS, 64).astype(np.float32))
+    w = jnp.asarray(rng.randn(4, 64, 256).astype(np.float32))
+    sizes = jnp.asarray([70, 0, 129, 31], jnp.int32)
+    used = int(sizes.sum())
+    got = grouped_matmul(xs, w, sizes)
+    want = jax.lax.ragged_dot(xs, w, sizes)
     np.testing.assert_allclose(
-        out[0, n - cap:], ref[0, n - cap:], rtol=2e-4, atol=2e-4
-    )
-    # pad rows contribute nothing
-    np.testing.assert_allclose(out[0, : n - cap], 0.0, atol=1e-6)
+        np.asarray(got)[:used], np.asarray(want)[:used], rtol=1e-5, atol=1e-4)
+
+
+def test_padding_rows_route_nowhere_and_change_no_real_row():
+    """Rows outside `real_mask` (bucket pad, inactive decode slots) belong
+    to no expert: the real rows come out as if the pads were not there,
+    whatever the pads hold, and the load counts real rows only."""
+    cfg = ROUTERS["as-is-scaled-shared"]
+    lp = _layer(cfg)
+    n, real = 48, 20
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, n, cfg.hidden_size))
+    mask = (jnp.arange(n) % 3 == 0)[None, :] & (jnp.arange(n) < 3 * real)
+    stats = []
+    out = np.asarray(moe_block(lp, cfg, x, real_mask=mask, stats=stats))
+    ref = moe_oracle(lp, cfg, np.asarray(x))
+    keep = np.asarray(mask[0])
+    np.testing.assert_allclose(out[0, keep], ref[0, keep], rtol=2e-4, atol=2e-4)
+    # other values in the pad rows, the same real rows
+    x2 = jnp.where(mask[..., None], x, 1e3 * x + 7.0)
+    out2 = np.asarray(moe_block(lp, cfg, x2, real_mask=mask))
+    np.testing.assert_array_equal(out2[0, keep], out[0, keep])
+    # a pad row carries the shared experts' output alone
+    pad_only = moe_oracle(
+        {k: v for k, v in lp.items()},
+        cfg.with_(num_experts_per_tok=0), np.asarray(x))
+    np.testing.assert_allclose(
+        out[0, ~keep], pad_only[0, ~keep], rtol=2e-4, atol=2e-4)
+    assert int(stats[0][1]) <= int(keep.sum())
 
 
 def test_sharded_forward_matches_single_device():

@@ -373,3 +373,67 @@ def test_sampling_shortlist_compiles_at_full_vocab(one_chip,
     ).compile()
     # approx_max_k lowers to the TPU's PartialReduce custom call
     assert "PartialReduce" in compiled.as_text()
+
+
+def test_latent_decode_scan_compiles_at_the_benchmark_shape(
+        one_chip, no_persistent_cache):
+    """DeepSeek-V2-Lite at its published widths, the leading dense layer
+    and one expert layer through a two-step decode scan at the
+    `decode-wide` cell's shape (width 128, pages of 128, bf16): the latent
+    decode kernel (rows of 640 lanes: Mosaic refuses a 576-wide page
+    slice), the grouped expert matmuls, and no copy of a latent pool
+    anywhere in the step."""
+    cfg = PRESETS["deepseek-v2-lite"].with_(num_layers=2)
+    page, num_pages, width, max_len = 128, 2048, 128, 4096
+    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+                         page=page, num_pages=num_pages)
+    assert kv.v is None and kv.k[0].shape == (num_pages * page, 640)
+    step = _decode_fn(cfg, page)
+
+    def dispatch(params, kv, tokens, positions, tables):
+        def body(carry, _):
+            tokens, positions, kv = carry
+            lg, kv = step(params, kv, tokens, positions, tables,
+                          positions + 1, positions)
+            return (jnp.argmax(lg, -1).astype(jnp.int32), positions + 1,
+                    kv), None
+
+        (tokens, _, kv), _ = jax.lax.scan(
+            body, (tokens, positions, kv), None, length=2)
+        return tokens, kv
+
+    compiled = jax.jit(dispatch, donate_argnums=(1,)).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((width,), one_chip), _i32((width,), one_chip),
+        _i32((width, max_len // page), one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # the latent kernel in both layers + three grouped matmuls
+    assert text.count("tpu_custom_call") >= 5
+    # the grouped matmul picks compiled / interpreted by the platform it
+    # is lowered for: for a TPU that choice leaves nothing in the program
+    assert "conditional(" not in text
+    pool = re.compile(r"= bf16\[\d+,(?:128,)?640\]\S* (copy|copy-start)\(")
+    moved = [ln.strip()[:160] for ln in text.splitlines() if pool.search(ln)]
+    assert not moved, "latent pools copied inside the step:\n" + "\n".join(moved)
+
+
+@pytest.mark.parametrize("rows,bucket,wb", [(1, 128, 1), (1, 512, 32)],
+                         ids=["n1-t128", "n1-t512-w32"])
+def test_latent_prefill_layer_compiles(one_chip, no_persistent_cache,
+                                       rows, bucket, wb):
+    """The latent page writer + absorbed attention over the rows gathered
+    through the block table, an expert layer at published widths: a
+    one-page chunk, and a whole chunk at the longest attended bucket."""
+    cfg = PRESETS["deepseek-v2-lite"].with_(num_layers=2)
+    page = 128
+    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+                         page=page, num_pages=512)
+    compiled = _prefill_step(cfg, page).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((rows, bucket), one_chip), _i32((rows, bucket), one_chip),
+        _i32((rows * (bucket // page),), one_chip),
+        _i32((rows, wb), one_chip), _i32((rows,), one_chip),
+    ).compile()
+    # a page write a layer + three grouped matmuls
+    _assert_kernel(compiled, at_least=5)
