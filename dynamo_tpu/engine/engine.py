@@ -52,6 +52,7 @@ import logging
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import AsyncIterator, Callable, NamedTuple, Optional
 
@@ -805,6 +806,14 @@ class JaxEngine:
         # + concurrent prefill_only dispatches) — guard the RMWs
         self._phase_lock = threading.Lock()
         self._preemptions = 0  # sequences preempted for want of KV pages
+        # frames put on out_queues and the tokens in them (`_emit`): a
+        # landing puts ONE frame per sequence, so tokens / frames is
+        # `decode_steps` in steady decode and 1 for a first-token emit
+        self._frames = self._frame_tokens = 0
+        # the collector's passes, and the freeze of what set-up built
+        # (telemetry.HeapWatch; docs/observability.md "The collector")
+        self._heap = telemetry.HeapWatch()
+        weakref.finalize(self, self._heap.detach)
         # newest dispatch's first output (under _kv_lock): ready = the
         # device has drained all that was queued
         self._last_out = None
@@ -1284,6 +1293,11 @@ class JaxEngine:
             # sequences preempted for want of KV pages (cumulative; the
             # flight digests carry it per step as `preempted`)
             "preemptions_total": self._preemptions,
+            # what `_emit` put on the out_queues (one frame per sequence
+            # per landing), and the collector's full passes
+            "frames_total": self._frames,
+            "tokens_total": self._frame_tokens,
+            **self._heap.stats(),
             # speculative decode health (ForwardPassMetrics.from_dict
             # drops unknown keys, so the router wire stays compatible)
             "spec_acceptance_rate": (
@@ -2537,6 +2551,7 @@ class JaxEngine:
     async def close(self) -> None:
         self._closed = True
         self._wake.set()
+        self._heap.close()
         if self.flight is not None:
             # freeze the final context snapshot and drop the bound
             # provider: the flight-recorder registry keeps the RING
@@ -2680,6 +2695,7 @@ class JaxEngine:
         if new_task is not None:
             self._inflight = await new_task
         if progressed:
+            self._heap.settle()
             # yield so producers/consumers interleave with the loop
             await asyncio.sleep(0)
             return False
@@ -3441,10 +3457,8 @@ class JaxEngine:
             # decode program as an override — emit immediately, no fetch
             self._overrides[seq.slot] = int(tok)
             seq.num_computed = seq.total_tokens
-            self._stamp_first_meta(seq)
             self._t_fetched = time.perf_counter()
-            self._append_token(seq, int(tok), extra_meta=seq.first_meta)
-            seq.first_meta = None
+            self._emit(seq, [int(tok)], first=True)
 
     def _start_first_emit(self, finals, S) -> None:
         """One async host fetch per prefill GROUP that emits the group's
@@ -3493,19 +3507,14 @@ class JaxEngine:
                 continue  # preempted/finished meanwhile; normal paths own it
             seq.carry_pending = False
             seq.num_computed = seq.total_tokens
-            tops = None
-            if tid is not None and seq.top_logprobs:
-                tops = [
-                    [int(tid[row, j]), float(tlp[row, j])]
-                    for j in range(seq.top_logprobs)
-                ]
-            self._stamp_first_meta(seq)
-            self._append_token(
-                seq, int(toks[row]),
-                logprob=float(lps[row]) if lps is not None else None,
-                tops=tops, extra_meta=seq.first_meta,
+            k = seq.top_logprobs if tid is not None else 0
+            self._emit(
+                seq, [int(toks[row])],
+                [float(lps[row])] if lps is not None else None,
+                ([tid[row, :k].tolist()], [tlp[row, :k].tolist()])
+                if k else None,
+                first=True,
             )
-            seq.first_meta = None
 
     def _prefill_group_dispatch(self, seqs: list[Sequence], bucket: int):
         """Dispatch one chunk for each sequence in ONE [n, bucket] model
@@ -4303,9 +4312,7 @@ class JaxEngine:
                     # deterministic-advance contract); serialized steps
                     # advance here at sync
                     seq.device_pos += 1
-                seq.num_computed += 1
-                self._register_full_pages(seq)
-                self._append_token(seq, tok)
+                self._emit(seq, [tok])
                 if self.slots[slot] is seq:
                     self._overrides[slot] = tok
                 continue
@@ -4328,9 +4335,7 @@ class JaxEngine:
                         "seq.first_dispatch", cat="lifecycle",
                         req=seq.ctx.id, ts=now,
                     )
-                self._stamp_first_meta(seq)
-                self._append_token(seq, tok, extra_meta=seq.first_meta)
-                seq.first_meta = None
+                self._emit(seq, [tok], first=True)
                 if self.slots[slot] is seq:
                     self._overrides[slot] = tok
             else:
@@ -4746,6 +4751,7 @@ class JaxEngine:
             len(d.bld["entries"]) if d.mixed else len(d.snapshot),
             t0, t1, overlapped, bld_t0=d.bld["t0"] if d.mixed else None,
         )
+        frames, tokens = self._frames, self._frame_tokens
         with profiler.phase("eng.emit"):
             if d.mixed:
                 self._sync_mixed(d.bld, arrs)
@@ -4754,7 +4760,13 @@ class JaxEngine:
             else:
                 self._sync_decode(d, arrs)
         if self.flight is not None:
-            host = {"emit_s": time.perf_counter() - t1}
+            host = {
+                "emit_s": time.perf_counter() - t1,
+                "frames": self._frames - frames,
+                "tokens": self._frame_tokens - tokens,
+                # collector passes since the landing before this one
+                "gc_s": self._heap.lap(),
+            }
             if d.moe is not None:
                 # the same program made it: ready since the tokens were
                 host["moe_experts_hit"], host["moe_load_max"] = (
@@ -4763,42 +4775,29 @@ class JaxEngine:
             self.flight.amend("overlap" if overlapped else "sync", **host)
 
     def _sync_decode(self, d: _Dispatch, arrs) -> None:
-        """Land a decode scan: row 0 first tokens, then the steps."""
+        """Land a decode scan, a sequence at a time: its column of the
+        fetched arrays, read off once, is one frame. Row 0 is the
+        dispatch's input carry: a sequence that entered with a
+        freshly-prefilled first token emits it here, in stream order
+        before its decode tokens (one fetch covers everything)."""
         out, out_lps = arrs[0], arrs[1]
         tops = arrs[2:] if len(arrs) == 4 else None
-
-        def top_list(seq, step, i):
-            if tops is None or not seq.top_logprobs:
-                return None
-            return [
-                [int(tops[0][step, i, j]), float(tops[1][step, i, j])]
-                for j in range(seq.top_logprobs)
-            ]
-
-        # row 0 is the dispatch's input carry: sequences that entered with
-        # a freshly-prefilled first token emit it here, in stream order
-        # before their decode tokens — one fetch covers everything
         for i, seq in d.snapshot:
-            if self.slots[i] is seq and seq.carry_pending:
+            if self.slots[i] is not seq:
+                continue  # finished/preempted earlier: overshoot discarded
+            first = seq.carry_pending
+            if first:
                 seq.carry_pending = False
                 seq.num_computed = seq.total_tokens  # prefill KV all valid
-                self._stamp_first_meta(seq)
-                self._append_token(
-                    seq, int(out[0, i]), logprob=float(out_lps[0, i]),
-                    tops=top_list(seq, 0, i), extra_meta=seq.first_meta,
-                )
-                seq.first_meta = None
-        for step in range(1, out.shape[0]):
-            for i, seq in d.snapshot:
-                if self.slots[i] is not seq:
-                    # finished/preempted earlier: overshoot discarded
-                    continue
-                seq.num_computed += 1
-                self._register_full_pages(seq)
-                self._append_token(
-                    seq, int(out[step, i]), logprob=float(out_lps[step, i]),
-                    tops=top_list(seq, step, i),
-                )
+            lo = 0 if first else 1
+            k = seq.top_logprobs if tops is not None else 0
+            self._emit(
+                seq, out[lo:, i].tolist(),
+                out_lps[lo:, i].tolist() if seq.want_logprobs else None,
+                (tops[0][lo:, i, :k].tolist(), tops[1][lo:, i, :k].tolist())
+                if k else None,
+                first=first,
+            )
 
     def _emit_verify_row(self, slot: int, seq: Sequence, out_row,
                          n: int, drafted: int, base: int,
@@ -4821,16 +4820,10 @@ class JaxEngine:
         always lands). Rows with real drafts advance data-dependently,
         are never carried into a following build, and keep the
         rewind."""
-        emitted = 0
-        for j in range(n):
-            if self.slots[slot] is not seq:
-                break  # EOS/length mid-window: the tail is discarded
-            seq.num_computed += 1
-            if not keep_pos:
-                seq.device_pos = base + j + 1
-            self._register_full_pages(seq)
-            self._append_token(seq, int(out_row[j]))
-            emitted += 1
+        # EOS/length mid-window: the tail is discarded
+        emitted = self._emit(seq, out_row[:n].tolist())
+        if not keep_pos:
+            seq.device_pos = base + emitted
         # counters reflect what actually LANDED: when an emitted draft
         # finished the stream (EOS) the discarded tail — and the
         # never-emitted bonus — must not inflate acceptance
@@ -5170,16 +5163,34 @@ class JaxEngine:
         self._bg_tasks.add(task)
         task.add_done_callback(self._bg_tasks.discard)
 
-    def _append_token(
-        self, seq: Sequence, token: int,
-        logprob: Optional[float] = None, tops: Optional[list] = None,
-        extra_meta: Optional[dict] = None,
-    ) -> None:
-        seq.blocks.extend([token])
+    def _emit(
+        self, seq: Sequence, toks: list, lps: Optional[list] = None,
+        alts: Optional[tuple] = None, first: bool = False,
+    ) -> int:
+        """The ONE emit path: what one fetch brought for one sequence
+        (`toks`, in order; `lps` its log-probabilities when asked for,
+        `alts` = (ids, lps) of the alternatives, a row per token)
+        becomes ONE frame on its out_queue. The sequence keeps tokens up
+        to the one that finishes it; the rest lie past its end and are
+        discarded, and the final frame follows. `first`: toks[0] is the
+        prompt's first token (its KV is the prefill's, so `num_computed`
+        stands for it) and `first_meta` rides in the frame. Returns the
+        number of tokens kept."""
+        if not toks:
+            return 0
+        base, reason = seq.generated, None
+        for n, tok in enumerate(toks, 1):
+            seq.generated = base + n
+            reason = seq.check_finish(tok)
+            if reason:
+                break
+        toks = toks[:n]
+        seq.blocks.extend(toks)
         if seq.spec is not None:
-            seq.spec.extend([token])
-        seq.generated += 1
-        if seq.generated == 1:
+            seq.spec.extend(toks)
+        seq.num_computed += n - first
+        self._register_full_pages(seq)
+        if base == 0:
             seq.t_first_emit = time.perf_counter()
             if seq.t_admit:
                 # ttft_s = queue_wait_s + prefill_s + first_emit_s
@@ -5190,25 +5201,35 @@ class JaxEngine:
                     "seq.first_token", cat="lifecycle", req=seq.ctx.id,
                     ts=seq.t_first_emit,
                 )
-        frame = EngineOutput(token_ids=[token])
+        frame = EngineOutput(token_ids=toks)
         if seq.want_logprobs:
             # NaN = no local logprob (disagg remotely-sampled first token)
-            lp = None if logprob is None or logprob != logprob else logprob
-            if lp is not None:
-                seq.cum_logprob += lp
-            frame.log_probs = [lp]
+            lps = [
+                None if lp is None or lp != lp else lp
+                for lp in (lps[:n] if lps is not None else [None] * n)
+            ]
+            for lp in lps:
+                if lp is not None:
+                    seq.cum_logprob += lp
+            frame.log_probs = lps
             frame.cum_log_probs = seq.cum_logprob
-            if tops is not None:
+            if alts is not None:
                 # NaN alternatives (disagg first token) are dropped
                 frame.top_log_probs = [
-                    [e for e in tops if e[1] == e[1]]
+                    [[t, lp] for t, lp in zip(ids, vals) if lp == lp]
+                    for ids, vals in zip(alts[0][:n], alts[1][:n])
                 ]
-        if extra_meta:
-            frame.meta = extra_meta
+        if first:
+            self._stamp_first_meta(seq)
+            if seq.first_meta:
+                frame.meta = seq.first_meta
+            seq.first_meta = None
+        self._frames += 1
+        self._frame_tokens += n
         seq.out_queue.put_nowait(frame.to_dict())
-        reason = seq.check_finish(token)
         if reason:
             self._finish(seq, reason)
+        return n
 
     def _finish(self, seq: Sequence, reason: str) -> None:
         self._register_full_pages(seq)

@@ -64,7 +64,7 @@ FIELDS = (
     "step",          # engine _step_count at record time
     "kind",          # index into KINDS
     "rows",          # rows in the dispatch
-    "tokens",        # budget tokens the dispatch carried
+    "tokens",        # budget tokens the dispatch carried; sync: landed
     "wall_s",        # dispatch wall (dispatch kinds) or fetch wall (sync)
     "budget_fill",   # tokens / step budget (mixed steps; else 0)
     "queue_depth",   # sequences waiting for a slot
@@ -84,6 +84,9 @@ FIELDS = (
     # means over its steps and expert layers, from the device:
     "moe_experts_hit",    # distinct experts with at least one token
     "moe_load_max",       # most tokens routed to one expert
+    # sync / overlap rows (PR 35); `tokens` there = tokens the landing kept:
+    "frames",        # frames it put on out_queues: ONE per sequence
+    "gc_s",          # collector passes since the landing before (HeapWatch)
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

@@ -12,6 +12,11 @@ module surfaces both:
   there, and `Engine.metrics()` simply omits the series (the Prometheus
   checker treats absent-on-CPU as fine, zero-series rules apply to
   registered counters, not platform-gated gauges).
+- **`HeapWatch`** books the seconds CPython's cyclic collector takes
+  from the host (a `gc.callbacks` hook: a full pass over the ~375k
+  objects of a warm engine's compiled programs stops the loop's thread
+  for 0.2-0.4 s) and, once the step programs stand still, moves what
+  set-up built out of the collector's reach (`gc.freeze`).
 - **`install_compile_listener()`** registers a process-wide
   `jax.monitoring` duration listener counting XLA backend compiles and
   their wall time, and — when tracing is armed — records each one as an
@@ -23,9 +28,10 @@ module surfaces both:
 
 from __future__ import annotations
 
+import gc
+import logging
 import threading
 import time
-from typing import Optional
 
 import jax
 
@@ -40,6 +46,8 @@ _COMPILE_KEY = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_KEY = "/jax/compilation_cache/cache_hits"
 # wall of reading one such program back (a duration event)
 _CACHE_READ_KEY = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+log = logging.getLogger("dynamo_tpu.engine")
 
 _lock = threading.Lock()
 _installed = False
@@ -105,6 +113,93 @@ def compile_stats() -> dict:
             "backend_compiles": max(_compile_events - _cache_hits, 0),
             "cache_read_s": round(_cache_read_s, 4),
         }
+
+
+# ticks of the engine's loop over which `compile_events` must stand still
+# before the heap is frozen: 5 s of a 155 ms tick, well inside a warm-up
+HEAP_QUIET_TICKS = 32
+
+
+class HeapWatch:
+    """One engine's view of the cyclic collector. `gc_s` sums the seconds
+    of every pass since construction (`lap()`: its growth since the call
+    before, which the engine books on each landing's digest),
+    `full_passes` / `full_pass_s` the generation-2 passes alone. `settle()` is the cure for their length:
+    a full pass walks every tracked object, and nearly all of a warm
+    engine's are its compiled programs, which never die. The rule is one
+    the engine can observe: `compile_events` (compiled or read back from
+    the persistent cache) unchanged over `HEAP_QUIET_TICKS` ticks that
+    dispatched or landed something -> `gc.collect()` then `gc.freeze()`,
+    once, and again only after the count moves. The heap is the
+    process's, so an embedding process gets the freeze too; `close()`
+    gives it back (`gc.unfreeze`)."""
+
+    def __init__(self):
+        self.gc_s = 0.0
+        self.full_passes = 0
+        self.full_pass_s = 0.0
+        self._t0 = self._lap = 0.0
+        self._compiles = -1  # compile_events when the quiet stretch began
+        self._quiet = 0      # ticks since; -1 = frozen and nothing moved
+        self._froze = False
+        gc.callbacks.append(self._on_pass)
+
+    def _on_pass(self, phase: str, info: dict) -> None:
+        # passes never nest (the collector refuses to re-enter)
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        self.gc_s += dt
+        if info.get("generation") == 2:
+            self.full_passes += 1
+            self.full_pass_s += dt
+
+    def lap(self) -> float:
+        """Seconds of collector passes since the call before."""
+        was, self._lap = self._lap, self.gc_s
+        return self._lap - was
+
+    def settle(self) -> bool:
+        """Between two ticks of the loop; True when it froze the heap."""
+        n = _compile_events
+        if n != self._compiles:
+            self._compiles, self._quiet = n, 0
+        elif self._quiet >= 0:
+            self._quiet += 1
+            if self._quiet >= HEAP_QUIET_TICKS:
+                t0 = time.perf_counter()
+                gc.collect()
+                gc.freeze()
+                self._quiet, self._froze = -1, True
+                log.info(
+                    "programs warm (%d compiled or loaded): heap frozen, "
+                    "%d objects, %.3f s", n, gc.get_freeze_count(),
+                    time.perf_counter() - t0,
+                )
+                return True
+        return False
+
+    def stats(self) -> dict:
+        return {
+            "gc_full_passes_total": self.full_passes,
+            "gc_full_pass_s_total": round(self.full_pass_s, 4),
+            "gc_frozen_objects": gc.get_freeze_count(),
+        }
+
+    def detach(self) -> None:
+        """Stop counting (an engine dropped without `close()`: its
+        finalizer; no unfreeze from inside a collector pass)."""
+        try:
+            gc.callbacks.remove(self._on_pass)
+        except ValueError:
+            pass
+
+    def close(self) -> None:
+        self.detach()
+        if self._froze:
+            self._froze = False
+            gc.unfreeze()
 
 
 def device_memory_stats(device=None) -> dict:

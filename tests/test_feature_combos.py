@@ -93,8 +93,11 @@ async def test_quant_with_logprobs_and_penalties():
         engine, req([5, 6, 7], logprobs=True, top_logprobs=2)
     )
     tf = [f for f in frames if f.get("token_ids")]
-    assert all(f["log_probs"][0] <= 0.0 for f in tf)
-    assert all(len(f["top_log_probs"][0]) == 2 for f in tf)
+    # a frame carries what one landing brought: a column per token
+    assert all(len(f["log_probs"]) == len(f["token_ids"])
+               == len(f["top_log_probs"]) for f in tf)
+    assert all(lp <= 0.0 for f in tf for lp in f["log_probs"])
+    assert all(len(alts) == 2 for f in tf for alts in f["top_log_probs"])
 
     tokens2, _ = await collect(
         engine, req([20, 21, 22], max_tokens=8, frequency_penalty=100.0)

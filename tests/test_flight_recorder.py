@@ -105,8 +105,9 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
     want = pages or (0, 0)
     d = rec.snapshot()[-1]
     assert (d["kv_pages_streamed"], d["kv_pages_held"]) == want
-    assert FIELDS[-4:] == ("kv_pages_streamed", "kv_pages_held",
-                           "moe_experts_hit", "moe_load_max")
+    assert FIELDS[-6:] == ("kv_pages_streamed", "kv_pages_held",
+                           "moe_experts_hit", "moe_load_max",
+                           "frames", "gc_s")
     assert d["moe_experts_hit"] == d["moe_load_max"] == 0
     with open(rec.trigger("manual")) as f:
         art = json.load(f)

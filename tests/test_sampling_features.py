@@ -112,7 +112,8 @@ async def test_engine_logprobs_stream():
     )
     assert len(tokens) == 5
     token_frames = [f for f in frames if f.get("token_ids")]
-    lps = [f["log_probs"][0] for f in token_frames]
+    lps = [lp for f in token_frames for lp in f["log_probs"]]
+    assert len(lps) == 5
     assert all(isinstance(lp, float) and lp <= 0.0 for lp in lps)
     np.testing.assert_allclose(
         token_frames[-1]["cum_log_probs"], sum(lps), rtol=1e-5
@@ -133,7 +134,8 @@ async def test_engine_logprobs_match_manual_forward():
     tokens, frames = await collect(
         engine, request(prompt, max_tokens=3, greedy=True, logprobs=True)
     )
-    lps = [f["log_probs"][0] for f in frames if f.get("token_ids")]
+    lps = [lp for f in frames for lp in f.get("log_probs") or []]
+    assert len(lps) == len(tokens) == 3
 
     # manual: same params, full-context forward per step
     params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
@@ -278,15 +280,21 @@ async def test_engine_top_logprobs():
                 top_logprobs=3),
     )
     token_frames = [f for f in frames if f.get("token_ids")]
-    assert len(token_frames) == 4
-    for f in token_frames:
-        alts = f["top_log_probs"][0]
+    # a frame is what one landing brought: each of its tokens is paired
+    # with its own log-probability and its own row of alternatives
+    per_token = [
+        row for f in token_frames
+        for row in zip(f["token_ids"], f["log_probs"], f["top_log_probs"],
+                       strict=True)
+    ]
+    assert len(per_token) == 4
+    for tok, lp, alts in per_token:
         assert len(alts) == 3
         # alternatives sorted descending; greedy sampled token == argmax
-        lps = [lp for _, lp in alts]
+        lps = [a for _, a in alts]
         assert lps == sorted(lps, reverse=True)
-        assert alts[0][0] == f["token_ids"][0]
-        np.testing.assert_allclose(alts[0][1], f["log_probs"][0], rtol=1e-5)
+        assert alts[0][0] == tok
+        np.testing.assert_allclose(alts[0][1], lp, rtol=1e-5)
     await engine.close()
 
 

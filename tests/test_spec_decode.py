@@ -340,13 +340,19 @@ async def test_rejected_tail_never_registered_in_prefix_cache():
 
 
 async def test_spec_frames_stream_in_order():
-    """Multi-token emits arrive as one frame per token, in sequence
-    order, with the finish frame last (SSE framing downstream relies on
-    this invariant)."""
+    """Multi-token emits arrive as ONE frame per landing (a verify row's
+    accepted drafts and its corrected or bonus token together, at most
+    spec_k_max + 1), in sequence order, with the finish frame last (SSE
+    framing downstream relies on this invariant)."""
     spec = make_engine(spec_decode=True)
     tokens, frames = await collect(spec, request(REPETITIVE, max_tokens=24))
     assert len(tokens) == 24
-    assert all(len(f["token_ids"]) == 1 for f in frames if f.get("token_ids"))
+    sizes = [len(f["token_ids"]) for f in frames if f.get("token_ids")]
+    assert max(sizes) > 1  # an accepted draft rode with its verify token
+    assert max(sizes) <= max(
+        spec.config.spec_k_max + 1, spec.config.decode_steps + 1)
+    assert spec.metrics()["frames_total"] == len(sizes)
+    assert spec.metrics()["tokens_total"] == 24
     assert frames[-1].get("finish_reason") == "length"
     assert all(not f.get("finish_reason") for f in frames[:-1])
     await spec.close()
