@@ -88,6 +88,7 @@ from dynamo_tpu.ops.sampling import (
 )
 from dynamo_tpu.engine import flight_recorder as flightmod
 from dynamo_tpu.engine import kv_ledger as kvledgermod
+from dynamo_tpu.models.config import FULL, WINDOW
 from dynamo_tpu.engine import profiler, telemetry
 from dynamo_tpu.parallel import mesh as meshmod
 from dynamo_tpu.runtime.pipeline.context import Context
@@ -226,6 +227,19 @@ class JaxEngine:
             self.model_cfg.latent_pool_width if self.model_cfg.latent
             else self.model_cfg.num_kv_heads * self.model_cfg.head_dim
         ) % (128 * mc.tp) == 0
+        # window beside full attention: pools, page ids and block tables
+        # per kind of layer (docs/kv_cache.md "Window pools")
+        self._hybrid = self.model_cfg.hybrid
+        # (bucket, page width, statics) whose wider groups are loaded
+        self._tail_groups_loaded: set[tuple] = set()
+        if self._hybrid:
+            kw_ok = all(
+                w % 128 == 0
+                for kind in (FULL, WINDOW)
+                for w in (self.model_cfg.attn_kind(kind).k_width,
+                          self.model_cfg.attn_kind(kind).v_width)
+            )
+            self._refuse_hybrid_config()
         if self.model_cfg.latent:
             self._refuse_latent_config()
         if config.attn_backend == "auto":
@@ -537,8 +551,12 @@ class JaxEngine:
                 )
             self.param_count = logical_param_count(params, self.model_cfg)
 
-        self.num_pages = config.num_pages or self._auto_num_pages(params)
         self.page_size = config.page_size
+        # the window kind's pool (0 pages for every other model): what
+        # `max_batch_size` rows can hold at once; the full kind takes the
+        # rest of the memory (`_auto_num_pages`)
+        self.win_num_pages = self._win_pool_pages() if self._hybrid else 0
+        self.num_pages = config.num_pages or self._auto_num_pages(params)
         num_slots = self.num_pages * self.page_size
         # the pools are created UNDER their shardings (pp keeps its own
         # stage-stacked placement): the pool is sized to each device's
@@ -555,6 +573,7 @@ class JaxEngine:
             scale_sharding=None if self._pp else jax.sharding.NamedSharding(
                 self.mesh, jax.sharding.PartitionSpec(None, "tp", None)
             ),
+            win_slots=self.win_num_pages * self.page_size,
         )
         if self._pp:
             from dynamo_tpu.parallel.pipeline import (
@@ -592,6 +611,18 @@ class JaxEngine:
             on_leak=self._on_kv_leak,
         )
         self.allocator.ledger = self.kv_ledger
+        # the window kind's pages: their own ids, free list and ledger
+        # (the same owners; both audits run, both must close)
+        self.win_allocator = self.kv_ledger_win = None
+        self._win_released = 0  # window pages released behind the window
+        if self._hybrid:
+            self.win_allocator = PageAllocator(
+                self.win_num_pages, self.page_size
+            )
+            self.kv_ledger_win = kvledgermod.KvLedger(
+                allocator=self.win_allocator, on_leak=self._on_kv_leak,
+            )
+            self.win_allocator.ledger = self.kv_ledger_win
         # HBM->host offload tier (engine/offload.py); None when disabled
         self.host_pool = None
         # pause switch: a D2H page gather holds _kv_lock for its whole
@@ -662,7 +693,10 @@ class JaxEngine:
         # write the trash page).
         _B = config.max_batch_size
         _W = config.max_pages_per_seq
-        self._host_rows_i = np.zeros((_B, 2 + _W), np.int32)
+        # a hybrid model's row carries two tables: [full | window]
+        self._host_rows_i = np.zeros(
+            (_B, 2 + _W * (2 if self._hybrid else 1)), np.int32
+        )
         self._host_samp_i = self._host_rows_i[:, :2]  # views of one
         self._host_tables = self._host_rows_i[:, 2:]  # upload block
         self._host_samp_i[:, 1] = -1  # seed sentinel
@@ -1026,9 +1060,10 @@ class JaxEngine:
             )
 
         self._inject_fn = jax.jit(_inject, donate_argnums=(0,))
-        if self.model_cfg.latent:
+        if self.model_cfg.latent or self._hybrid:
             def _refuse(*_a, **_k):
                 self._refuse_latent_plane("KV page inject / extract")
+                self._refuse_hybrid_plane("KV page inject / extract")
 
             self._inject_fn = self._extract_fn = _refuse
             return
@@ -1098,6 +1133,60 @@ class JaxEngine:
                 "[c ; k_r] rows"
             )
 
+    def _refuse_hybrid_plane(self, plane: str) -> None:
+        """A plane that moves, shares or re-reads pages by ONE list of
+        page ids, asked of a model whose layers keep two kinds of page
+        (full-attention pools beside window pools that release behind
+        the window): refused with the reason."""
+        if self._hybrid:
+            raise ValueError(
+                f"{plane} is not served with window beside full attention "
+                f"('{self.model_cfg.name}'): it is written for one list of "
+                "page ids a sequence, and this cache has two kinds of "
+                "page, of which the window kind releases behind the window"
+            )
+
+    def _refuse_hybrid_config(self) -> None:
+        """What a window-and-full-attention model cannot be combined
+        with yet, each refused at construction (docs/kv_cache.md
+        "Window pools")."""
+        cfg, mc = self.config, self.config.mesh
+        asked = {
+            f"kv_quantization={cfg.kv_quantization!r} (the two kinds of "
+            "pool are served in the model's dtype)":
+                cfg.kv_quantization is not None,
+            f"quantization={cfg.quantization!r} (int8 weights)":
+                cfg.quantization is not None,
+            "a mesh of more than one device (tp / pp / sp / ep / dp: the "
+            "layer holds its share of the experts without an exchange)":
+                mc.num_devices > 1,
+            "host KV offload (host_kv_pages)": bool(cfg.host_kv_pages),
+            "spec_decode (the verify step)": bool(cfg.spec_decode),
+            "mixed_batching": bool(cfg.mixed_batching),
+        }
+        for what, on in asked.items():
+            if on:
+                self._refuse_hybrid_plane(what)
+
+    def _win_pool_pages(self) -> int:
+        """Pages of the window kind's pool, from what the configuration
+        and the flags say: every one of `max_batch_size` rows may hold
+        the pages its window, the tokens of the dispatches in flight and
+        the next dispatch touch, plus one it is about to release; a
+        quarter of the rows may sit in prefill holding a chunk's pages
+        besides (a chunk at a time, whatever the prompt's length:
+        `_reserve_window_pages`); and the trash page."""
+        cfg, ps = self.config, self.config.page_size
+        per_row = -(-(self.model_cfg.sliding_window - 1
+                      + 3 * cfg.decode_steps) // ps) + 1
+        chunk = -(-cfg.prefill_chunk // ps)
+        return min(
+            1 + cfg.max_batch_size * per_row
+            + max(cfg.max_batch_size // 4, 1) * chunk,
+            # never more than every row's whole context
+            1 + cfg.max_batch_size * cfg.max_pages_per_seq,
+        )
+
     def _refuse_latent_config(self) -> None:
         """What a latent-attention model cannot be combined with yet,
         each refused at construction (docs/kv_cache.md "Latent pools")."""
@@ -1142,7 +1231,22 @@ class JaxEngine:
     def _auto_num_pages(self, params) -> int:
         cfg, m = self.config, self.model_cfg
         tp = self.config.mesh.tp
-        if m.latent:
+        reserved = 0  # bytes of what is sized before the pages (below)
+        if self._hybrid:
+            # the window kind's pool is sized from the rows it must hold
+            # (`_win_pool_pages`); a page of the full kind's takes what
+            # is left
+            def kind_bytes(kind):
+                spec = m.attn_kind(kind)
+                return (
+                    m.layers_of(kind) * cfg.page_size
+                    * (spec.k_width + spec.v_width)
+                    * self._dtype.dtype.itemsize
+                )
+
+            page_bytes = kind_bytes(FULL)
+            reserved = self.win_num_pages * kind_bytes(WINDOW)
+        elif m.latent:
             # one pool a layer, a row the lanes it occupies (576 -> 640)
             page_bytes = (
                 m.num_layers * cfg.page_size * m.latent_pool_width
@@ -1189,7 +1293,7 @@ class JaxEngine:
         free = min(
             s["bytes_limit"] * cfg.hbm_utilization - s["bytes_in_use"]
             for s in stats
-        )
+        ) - reserved
         n = int(free // max(page_bytes, 1))
         if n < cfg.max_pages_per_seq + 1:
             raise RuntimeError(
@@ -1268,8 +1372,12 @@ class JaxEngine:
             # custody ledger (engine/kv_ledger.py): cumulative violations
             # by the audit + release misuse, pages currently attributed
             # to orphans, completed audit passes, open in-flight windows
-            "kv_ledger_violations": self.kv_ledger.violations_total,
-            "kv_ledger_orphan_pages": len(self.kv_ledger.last_orphans),
+            "kv_ledger_violations": self.kv_ledger.violations_total + (
+                self.kv_ledger_win.violations_total if self._hybrid else 0
+            ),
+            "kv_ledger_orphan_pages": len(self.kv_ledger.last_orphans) + (
+                len(self.kv_ledger_win.last_orphans) if self._hybrid else 0
+            ),
             "kv_ledger_audits": self.kv_ledger.audits_total,
             "kv_ledger_inflight": len(self.kv_ledger._inflight),
             "slot_occupancy": (
@@ -1293,6 +1401,12 @@ class JaxEngine:
             # sequences preempted for want of KV pages (cumulative; the
             # flight digests carry it per step as `preempted`)
             "preemptions_total": self._preemptions,
+            # a hybrid model's window kind: pages in use, and pages
+            # released behind the window since start (0 for any other)
+            "kv_window_pages_used": (
+                self.win_allocator.pages_used if self._hybrid else 0
+            ),
+            "kv_window_pages_released_total": self._win_released,
             # what `_emit` put on the out_queues (one frame per sequence
             # per landing), and the collector's full passes
             "frames_total": self._frames,
@@ -1502,6 +1616,20 @@ class JaxEngine:
             hidden, kv = self._pp_forward(
                 params, kv, tokens, positions, write_slots, slot_matrix
             )
+        elif self._hybrid:
+            # every per-kind input arrives as (full, window)
+            full, win = (
+                self._prefill_attn(
+                    slot_matrix[k],
+                    None if wtables is None else wtables[k],
+                    None if btables is None else btables[k],
+                    positions, last_idx, sp_cached,
+                ) for k in (FULL, WINDOW)
+            )
+            hidden, kv = self._forward(
+                params, kv, tokens, positions, write_slots[FULL],
+                self._hybrid_spec(full, win, write_slots[WINDOW]),
+            )
         else:
             hidden, kv = self._forward(
                 params, kv, tokens, positions, write_slots,
@@ -1579,6 +1707,15 @@ class JaxEngine:
             int4_groups=self._kv_int4_groups,
         )
 
+    @staticmethod
+    def _hybrid_spec(full, win, win_write_slots):
+        """The full kind's AttnSpec carrying the window kind's (its own
+        tables, its own write slots): `llama.forward` hands each layer
+        its kind's."""
+        win.write_slots = win_write_slots
+        full.win = win
+        return full
+
     def _pin_state(self, state: StepState) -> StepState:
         """A step program returns the state replicated over the mesh, as
         it took it: the next program's input sharding never changes."""
@@ -1615,6 +1752,13 @@ class JaxEngine:
         ovr = rows_i[:, 3].astype(bool)
         topk, seeds = rows_i[:, 4], rows_i[:, 5]
         block_tables = rows_i[:, 6:]
+        win_tables = None
+        if self._hybrid:
+            # [full | window]: each kind's page ids
+            half = block_tables.shape[1] // 2
+            block_tables, win_tables = (
+                block_tables[:, :half], block_tables[:, half:]
+            )
         temp, topp = rows_f[:, 0], rows_f[:, 1]
         fp, prp, rp = rows_f[:, 2], rows_f[:, 3], rows_f[:, 4]
         state, key = state.split()
@@ -1623,11 +1767,16 @@ class JaxEngine:
         carry_lps = jnp.where(ovr, jnp.nan, state.lps[:w])
         s = self.page_size
         w_pages = block_tables.shape[1]
-        smat = None
+        smat = win_smat = None
         if not self._attn_pallas:
             smat = (
                 block_tables[:, :, None] * s + jnp.arange(s, dtype=jnp.int32)
             ).reshape(w, -1)
+            if self._hybrid:
+                win_smat = (
+                    win_tables[:, :, None] * s
+                    + jnp.arange(s, dtype=jnp.int32)
+                ).reshape(w, -1)
 
         counts = state.counts
         use_pen = counts is not None
@@ -1659,23 +1808,37 @@ class JaxEngine:
                     kv_tp=self.config.mesh.tp,
                     int4_groups=self._kv_int4_groups,
                 )
+                if self._hybrid:
+                    attn = self._hybrid_spec(attn, llama.AttnSpec.pallas_decode(
+                        win_tables, attn.lengths, s,
+                        write_pos=attn.write_pos,
+                        interpret=self._attn_interpret,
+                    ), None)
             else:
                 page_idx = jnp.minimum(positions // s, w_pages - 1)
-                wslots = (
-                    jnp.take_along_axis(
-                        block_tables, page_idx[:, None], axis=1
-                    )[:, 0] * s
-                    + positions % s
-                )
-                # inactive rows and positions past a finished sequence's
-                # budget must write the trash page, never a valid slot
-                wslots = jnp.where(
-                    active & (positions < max_len), wslots, 0
-                ).astype(jnp.int32)
+
+                def slots_in(tables):
+                    # inactive rows and positions past a finished
+                    # sequence's budget must write the trash page, never
+                    # a valid slot
+                    return jnp.where(
+                        active & (positions < max_len),
+                        jnp.take_along_axis(
+                            tables, page_idx[:, None], axis=1
+                        )[:, 0] * s + positions % s,
+                        0,
+                    ).astype(jnp.int32)
+
+                wslots = slots_in(block_tables)
                 attn = llama.AttnSpec.gather(
                     smat, page_size=s, kv_tp=self.config.mesh.tp,
                     int4_groups=self._kv_int4_groups,
                 )
+                if self._hybrid:
+                    attn = self._hybrid_spec(
+                        attn, llama.AttnSpec.gather(win_smat, page_size=s),
+                        slots_in(win_tables),
+                    )
             moe = [] if self._returns_expert_load else None
             if self._pp:
                 hidden, kv = self._pp_forward(
@@ -2100,6 +2263,7 @@ class JaxEngine:
         needed, while cross-tier quantized mixes raise
         KvQuantMismatchError (see _convert_wire_kv)."""
         self._refuse_latent_plane("disaggregated decode (generate_remote)")
+        self._refuse_hybrid_plane("disaggregated decode (generate_remote)")
         payload = request.payload
         pre = (
             PreprocessedRequest.from_dict(payload)
@@ -2147,6 +2311,10 @@ class JaxEngine:
         — the send side of the device-path transfer
         (engine/xproc_kv.py / engine/kv_transfer.py)."""
         self._refuse_latent_plane(
+            "disaggregated prefill (prefill_only: the send side of the "
+            "host-staged and device-path planes)"
+        )
+        self._refuse_hybrid_plane(
             "disaggregated prefill (prefill_only: the send side of the "
             "host-staged and device-path planes)"
         )
@@ -2216,6 +2384,9 @@ class JaxEngine:
         raise KvQuantMismatchError (_convert_wire_kv) — packed bytes are
         quantized exactly once and never requantized pool-to-pool."""
         self._refuse_latent_plane(
+            "prefix ingest (ingest_prefix: the device-path landing side)"
+        )
+        self._refuse_hybrid_plane(
             "prefix ingest (ingest_prefix: the device-path landing side)"
         )
         full_pages = len(token_ids) // self.page_size
@@ -2297,6 +2468,7 @@ class JaxEngine:
         pages stay cached). Blocking (jit dispatch + device fetch):
         callers run it in a worker thread."""
         self._refuse_latent_plane("prefix export (export_prefix)")
+        self._refuse_hybrid_plane("prefix export (export_prefix)")
         if hashes is None:
             from dynamo_tpu.llm.tokens import compute_block_hashes
 
@@ -2808,8 +2980,13 @@ class JaxEngine:
         i = seq.slot
         row = self._host_tables[i]
         row[:] = 0
-        n = min(len(seq.page_ids), row.shape[0])
+        w = self.config.max_pages_per_seq
+        n = min(len(seq.page_ids), w)
         row[:n] = seq.page_ids[:n]
+        if self._hybrid:
+            # released pages read 0: the trash page, never attended
+            n = min(len(seq.win_page_ids), w)
+            row[w:w + n] = seq.win_page_ids[:n]
         self._host_samp_f[i] = (
             seq.temperature, seq.top_p, seq.frequency_penalty,
             seq.presence_penalty, seq.repetition_penalty,
@@ -2892,6 +3069,8 @@ class JaxEngine:
             # embed sequences: only the text prefix below embeds_offset
             # has sound hashes (placeholder ids don't cover the image)
             hashes = hashes[:cap]
+        if self._hybrid:
+            hashes = []  # nothing is registered: no in-engine prefix cache
         matched = self.allocator.match_prefix(hashes)
         host_run: list[int] = []
         if self.host_pool is not None and hashes:
@@ -2907,6 +3086,11 @@ class JaxEngine:
         fresh = self.allocator.allocate(need) if need else []
         if fresh is None:
             self.allocator.release(matched)
+            return False
+        if self._hybrid and not self._reserve_window_pages(
+            seq, -(-min(t, self.config.prefill_chunk) // self.page_size)
+        ):
+            self.allocator.release(fresh)
             return False
         if host_run and not self._restore_worthwhile(len(host_run)):
             # cost gate: on this deployment restoring would be slower
@@ -2981,6 +3165,59 @@ class JaxEngine:
                 )
         return True
 
+    # ---- window pages (hybrid models) ----------------------------------
+
+    def _reserve_window_pages(self, seq: Sequence, n: int) -> bool:
+        """Window-kind pages for the `n` logical pages of a fresh
+        reservation's FIRST chunk: a window layer's pages come a chunk at
+        a time (`_pick_prefill_groups` grows the list through the next
+        chunk once `_release_window_pages` has handed back what fell
+        behind the window), so a row in prefill holds its chunk's pages
+        and the one before, however long its prompt. Admission leaves one
+        page a live row free, so that a decode row's growth never waits
+        on a prompt."""
+        wa = self.win_allocator
+        if wa.num_free - n < sum(s is not None for s in self.slots):
+            return False
+        got = wa.allocate(n)
+        if got is None:
+            return False
+        seq.win_page_ids = got
+        seq.win_first = 0
+        self.kv_ledger_win.hold(got, seq.ctx.id, tenant=seq.tenant)
+        return True
+
+    def _release_window_pages(self, seq: Sequence) -> bool:
+        """Give back the window-kind pages that lie WHOLLY behind the
+        window of every position still to be computed: a query at `p >=
+        num_computed` reads positions above `p - window`, so logical
+        page i goes once `(i + 1) * page_size <= num_computed - window +
+        1`. Counted on what has LANDED: a dispatch in flight starts at or
+        after it. The list keeps its positions (a released entry reads 0,
+        the trash page), so tables are built as for the full kind."""
+        ps = self.page_size
+        first = max(
+            seq.num_computed - self.model_cfg.sliding_window + 1, 0
+        ) // ps
+        first = min(first, len(seq.win_page_ids))
+        if first <= seq.win_first:
+            return False
+        gone = seq.win_page_ids[seq.win_first:first]
+        self.kv_ledger_win.drop(gone, seq.ctx.id)
+        self.win_allocator.release(gone)
+        seq.win_page_ids[seq.win_first:first] = [0] * len(gone)
+        seq.win_first = first
+        self._win_released += len(gone)
+        return True
+
+    def _drop_window_pages(self, seq: Sequence) -> None:
+        """All of a sequence's window-kind pages, at preemption / finish."""
+        held = seq.win_page_ids[seq.win_first:]
+        self.kv_ledger_win.drop(held, seq.ctx.id)
+        self.win_allocator.release(held)
+        seq.win_page_ids = []
+        seq.win_first = 0
+
     # ---- prefill ------------------------------------------------------
 
     def _bucket_for(self, n: int) -> int:
@@ -2989,9 +3226,10 @@ class JaxEngine:
                 return b
         return self.config.prefill_chunk
 
-    def _slot_matrix_row(self, seq: Sequence) -> np.ndarray:
+    def _slot_matrix_row(self, seq: Sequence, page_ids=None) -> np.ndarray:
+        page_ids = seq.page_ids if page_ids is None else page_ids
         table = np.zeros(self.config.max_pages_per_seq, np.int32)
-        table[: len(seq.page_ids)] = seq.page_ids
+        table[: len(page_ids)] = page_ids
         return (
             table[:, None] * self.page_size + np.arange(self.page_size, dtype=np.int32)
         ).reshape(-1)
@@ -3048,6 +3286,22 @@ class JaxEngine:
             chunk = min(
                 seq.total_tokens - seq.num_computed, self.config.prefill_chunk
             )
+            if self._hybrid:
+                # this chunk's window-kind pages (the full kind's were all
+                # reserved at admission); a pool that is out preempts, as
+                # for a decode row's growth: this row, or one picked before
+                n_pre = self._preemptions
+                alive = self._ensure_pages_through(
+                    seq, seq.num_computed + chunk - 1
+                )
+                if self._preemptions != n_pre:
+                    groups = {
+                        b: kept for b, ss in groups.items()
+                        if (kept := [s for s in ss if s.slot >= 0])
+                    }
+                if not alive:
+                    progressed = True
+                    continue
             bucket = self._bucket_for(chunk)
             groups.setdefault(bucket, []).append(seq)
             if padded_cost() > budget:
@@ -3218,6 +3472,8 @@ class JaxEngine:
         """One ledger audit pass; forensics must never break serving."""
         try:
             violations = self.kv_ledger.audit()
+            if self._hybrid:
+                violations = violations + self.kv_ledger_win.audit()
         except Exception:
             log.debug("kv ledger audit failed", exc_info=True)
             return
@@ -3256,12 +3512,16 @@ class JaxEngine:
         if fr is None:
             return
         try:
+            used = {"kv_frac_full": round(self.allocator.usage(), 4)}
+            if self._hybrid:
+                used["kv_frac_win"] = round(self.win_allocator.usage(), 4)
             fr.record(
                 kind, wall_s, rows=rows, tokens=tokens,
                 budget_fill=round(tokens / budget, 4) if budget else 0.0,
                 queue_depth=len(self.waiting),
                 slots_active=sum(1 for s in self.slots if s is not None),
-                kv_frac=round(self.allocator.usage(), 4),
+                # the kind that runs out first (a hybrid model has two)
+                kv_frac=max(used.values()), **(used if self._hybrid else {}),
                 degrade_mask=self._degrade.mask(),
                 step=self._step_count,
                 preempted=self._preemptions, **host,
@@ -3308,6 +3568,10 @@ class JaxEngine:
             starved=rec.get("starved", 0),
             kv_pages_streamed=rec.get("kv_pages_streamed", 0),
             kv_pages_held=rec.get("kv_pages_held", 0),
+            **{k: rec[k] for k in (
+                "kv_pages_held_full", "kv_win_pages_held",
+                "kv_win_pages_released",
+            ) if k in rec},
         )
         if tracing.enabled():
             tracing.complete(
@@ -3576,6 +3840,10 @@ class JaxEngine:
                 1 << (w_need - 1).bit_length(), self.config.max_pages_per_seq
             )
             btables = np.zeros((n, w_b), np.int32)
+            if self._hybrid:
+                # the window kind's copies of the four per-kind inputs
+                win = [np.zeros_like(a) for a in
+                       (smat, wslots, wtables, btables)]
             for j, seq in enumerate(seqs):
                 tokens = seq.tokens
                 start = seq.num_computed
@@ -3593,6 +3861,15 @@ class JaxEngine:
                 wtables[j, :n_pages_used] = pages[start // ps : start // ps + n_pages_used]
                 npg = min(len(pages), w_b)
                 btables[j, :npg] = pages[:npg]
+                if self._hybrid:
+                    wp = np.asarray(seq.win_page_ids, np.int32)
+                    win[0][j] = self._slot_matrix_row(seq, wp)
+                    win[1][j, :chunk] = wp[idx // ps] * ps + idx % ps
+                    win[2][j, :n_pages_used] = wp[
+                        start // ps : start // ps + n_pages_used
+                    ]
+                    nwp = min(len(wp), w_b)
+                    win[3][j, :nwp] = wp[:nwp]
                 if has_embeds and seq.prompt_embeds is not None:
                     # overlap of [start, start+chunk) with the embed span
                     e0 = seq.embeds_offset
@@ -3634,13 +3911,26 @@ class JaxEngine:
                     if max_cached:
                         spc = 1 << (max_cached - 1).bit_length()
                         spc = min(spc, self.config.max_pages_per_seq)
+                kinds = [(smat, wslots, wtables, btables)]
+                if self._hybrid:
+                    kinds.append(win)
+
+                def per_kind(i, flat=False, on=True):
+                    # input `i` of every kind of page: one array, or a
+                    # hybrid model's (full, window)
+                    if not on:
+                        return None
+                    up = [jnp.asarray(k[i].reshape(-1) if flat else k[i])
+                          for k in kinds]
+                    return tuple(up) if self._hybrid else up[0]
+
                 args = (
                     jnp.asarray(tok_arr), jnp.asarray(pos_arr),
-                    jnp.asarray(wslots.reshape(-1)),
-                    jnp.asarray(smat), jnp.asarray(rows_i),
+                    per_kind(1, flat=True),
+                    per_kind(0), jnp.asarray(rows_i),
                     jnp.asarray(rows_f),
-                    jnp.asarray(wtables.reshape(-1)) if self._attn_pallas else None,
-                    jnp.asarray(btables) if self._attn_pallas else None,
+                    per_kind(2, flat=True, on=self._attn_pallas),
+                    per_kind(3, on=self._attn_pallas),
                     jnp.asarray(emb) if has_embeds else None,
                     jnp.asarray(emb_mask) if has_embeds else None,
                     bool((rows_f[:, 0] <= 0.0).all()),
@@ -3650,6 +3940,9 @@ class JaxEngine:
             S = self._enqueue(
                 rec, self._step_fn, *args, counts=use_ext, sp_cached=spc
             )
+            if (self._hybrid and len(seqs) == 1 and not has_embeds
+                    and w_b * ps > self.config.prefill_chunk):
+                self._load_tail_groups(bucket, w_b, args, use_ext, spc)
         now = time.perf_counter()
         for seq in seqs:
             if seq.num_computed + min(
@@ -3683,6 +3976,41 @@ class JaxEngine:
         # loop's emission/finish callbacks
         return S if len(S) == 4 else (S[0], S[1], None, None)
 
+    def _load_tail_groups(self, bucket: int, w_b: int, args: tuple,
+                          counts: bool, spc: int) -> None:
+        """The [2, bucket], [4, bucket], ... siblings of a one-row tail
+        program, loaded with it (worker thread, under `_kv_lock`). The
+        last chunk of a prompt longer than `prefill_chunk` attends more
+        pages than a one-chunk prompt can, and under load such tails share
+        a tick with one another and with fresh prompts of their bucket: a
+        group no single request decides, so no request sent alone reaches
+        its program, and one first met under load holds every stream for
+        as long as it takes to load. So the first one-row dispatch of a
+        (bucket, page width, statics) runs each wider program that the
+        group budget and the slots allow once, on rows of padding (no
+        slot, the trash page), as the padding rows of any group."""
+        key = (bucket, w_b, *args[10:], counts, spc)
+        if key in self._tail_groups_loaded:
+            return
+        self._tail_groups_loaded.add(key)
+        n = 2
+        while (n * bucket <= self.config.prefill_group_tokens
+               and n < 2 * self.config.max_batch_size):
+            pad = list(jax.tree.map(
+                lambda a: jnp.zeros((n * a.shape[0], *a.shape[1:]), a.dtype),
+                args[:10],
+            ))
+            rows_i = np.zeros((n, 5), np.int32)
+            rows_i[:, 2] = rows_i[:, 4] = -1
+            rows_f = np.zeros((n, 5), np.float32)
+            rows_f[:, 1] = rows_f[:, 4] = 1.0
+            pad[4], pad[5] = jnp.asarray(rows_i), jnp.asarray(rows_f)
+            self._enqueue(
+                {}, self._step_fn, *pad, *args[10:], counts=counts,
+                sp_cached=spc,
+            )
+            n *= 2
+
     def _note_prefilled(self, seqs: list[Sequence], bucket: int) -> None:
         """Post-dispatch bookkeeping (loop thread only): advance computed
         counts and register full pages in the prefix cache."""
@@ -3691,6 +4019,8 @@ class JaxEngine:
             seq.num_computed += chunk
             seq.prefill_chunks += 1
             self._register_full_pages(seq)
+            if self._hybrid and self._release_window_pages(seq):
+                self._mark_slot_state(seq)
 
     def _prefill_chunk_dispatch(self, seq: Sequence):
         """Single-sequence chunk dispatch (disagg prefill_only path;
@@ -3770,6 +4100,11 @@ class JaxEngine:
             return (
                 "mixed_batching unsupported with latent attention: the "
                 "ragged kernel reads a K pool and a V pool"
+            )
+        if self._hybrid:
+            return (
+                "mixed_batching unsupported with window beside full "
+                "attention: the ragged step takes one block table a row"
             )
         if self._pp:
             return "mixed_batching unsupported with pp>1 (v1)"
@@ -4635,6 +4970,19 @@ class JaxEngine:
                 rec["kv_pages_streamed"], rec["kv_pages_held"] = (
                     self._kv_pages(bld)
                 )
+            if self._hybrid:
+                # the full kind's pages stay in kv_pages_held_full; the
+                # two counters then count BOTH kinds (a layer of each)
+                rec["kv_pages_held_full"] = (
+                    rec["kv_pages_held"] if self._attn_pallas
+                    else self._kv_pages(bld)[1]
+                )
+                streamed, held = self._kv_window_pages(bld)
+                rec["kv_win_pages_held"] = held
+                rec["kv_win_pages_released"] = self._win_released
+                if self._attn_pallas:
+                    rec["kv_pages_streamed"] += streamed
+                    rec["kv_pages_held"] += held
         rec["build_s"] = bld.build_s
         wd = self._op_begin("spec.dispatch" if bld.spec else "decode.dispatch")
         try:
@@ -4659,13 +5007,34 @@ class JaxEngine:
         holds; the digest keeps both so a reader sees when it does not."""
         from dynamo_tpu.ops.pallas_attention import streamed_pages
 
+        lengths, ps = self._attended_lengths(bld), self.page_size
+        return streamed_pages(lengths, ps), int(np.sum(-(-lengths // ps)))
+
+    def _attended_lengths(self, bld: "_DecodeBuild") -> np.ndarray:
+        """[steps, active rows]: the KV count each step of this dispatch
+        attends, as `_decode_multi` derives it from the build's positions."""
         pos = bld.rows_i[[i for i, _ in bld.active], 0]
-        lengths = np.minimum(
+        return np.minimum(
             pos[None, :] + 1 + np.arange(bld.steps)[:, None],
             self.config.max_model_len,
         )
-        ps = self.page_size
-        return streamed_pages(lengths, ps), int(np.sum(-(-lengths // ps)))
+
+    def _kv_window_pages(self, bld: "_DecodeBuild") -> tuple[int, int]:
+        """A hybrid model's window kind, for the same rows and steps:
+        (pages ONE window layer's kernel copies in, by the rule its work
+        list is built to, from the window starts `_attn_block` hands it;
+        pages the rows hold in the window pool, over the steps)."""
+        from dynamo_tpu.ops.pallas_attention import streamed_pages
+
+        lengths = self._attended_lengths(bld)
+        starts = np.maximum(lengths - self.model_cfg.sliding_window, 0)
+        held = sum(
+            len(s.win_page_ids) - s.win_first for _, s in bld.active
+        )
+        return (
+            streamed_pages(lengths, self.page_size, starts=starts),
+            held * bld.steps,
+        )
 
     def _run_spec_dispatch_locked(
         self, bld: "_DecodeBuild", rec: dict
@@ -4862,12 +5231,27 @@ class JaxEngine:
             self._phase_stats["spec_emitted"] += emitted_total
 
     def _ensure_pages_through(self, seq: Sequence, upto_pos: int) -> bool:
-        grew = False
-        while upto_pos // self.page_size >= len(seq.page_ids):
-            got = self.allocator.allocate(1)
+        # a hybrid model first hands back the window pages that fell
+        # behind the window, then grows BOTH kinds' lists through
+        # `upto_pos`; whichever pool runs out preempts
+        grew = self._hybrid and self._release_window_pages(seq)
+        while True:
+            if upto_pos // self.page_size >= len(seq.page_ids):
+                alloc, ledger, ids = (
+                    self.allocator, self.kv_ledger, seq.page_ids
+                )
+            elif self._hybrid and (
+                upto_pos // self.page_size >= len(seq.win_page_ids)
+            ):
+                alloc, ledger, ids = (
+                    self.win_allocator, self.kv_ledger_win, seq.win_page_ids
+                )
+            else:
+                break
+            got = alloc.allocate(1)
             if got is not None:
-                seq.page_ids.extend(got)
-                self._kv_hold(got, seq.ctx.id, tenant=seq.tenant)
+                ids.extend(got)
+                ledger.hold(got, seq.ctx.id, tenant=seq.tenant)
                 grew = True
                 continue
             live = [s for s in self.slots if s is not None]
@@ -4894,6 +5278,8 @@ class JaxEngine:
         self._register_full_pages(seq)
         self._kv_drop(seq.page_ids, seq.ctx.id)
         self.allocator.release(seq.page_ids)
+        if self._hybrid:
+            self._drop_window_pages(seq)
         self.slots[seq.slot] = None
         self._overrides.pop(seq.slot, None)
         # the slot may be reused: a preempted row mid-pipeline must not
@@ -4916,6 +5302,10 @@ class JaxEngine:
     # ---- bookkeeping --------------------------------------------------
 
     def _register_full_pages(self, seq: Sequence) -> None:
+        if self._hybrid:
+            # no in-engine prefix cache over two kinds of page: a hash
+            # would name a full-kind page whose window-kind twin is gone
+            return
         full = seq.num_computed // self.page_size
         cap = seq.cacheable_pages(self.page_size)
         if cap is not None:
@@ -5247,6 +5637,8 @@ class JaxEngine:
         else:
             self._kv_drop(seq.page_ids, seq.ctx.id)
             self.allocator.release(seq.page_ids)
+            if self._hybrid:
+                self._drop_window_pages(seq)
         if seq.slot >= 0:
             self._overrides.pop(seq.slot, None)
             self._carry_ok[seq.slot] = False
@@ -5316,6 +5708,8 @@ class JaxEngine:
         # release path ran (a skipped release, a lost frame), the next
         # ledger audit attributes the leak to this request id
         self.kv_ledger.request_finished(seq.ctx.id)
+        if self._hybrid:
+            self.kv_ledger_win.request_finished(seq.ctx.id)
         for cb in self._request_observers:
             try:
                 cb(summary)

@@ -87,6 +87,16 @@ FIELDS = (
     # sync / overlap rows (PR 35); `tokens` there = tokens the landing kept:
     "frames",        # frames it put on out_queues: ONE per sequence
     "gc_s",          # collector passes since the landing before (HeapWatch)
+    # a model with window beside full attention (two kinds of pool):
+    "kv_frac_full",  # occupancy of the full-attention kind's pool
+    "kv_frac_win",   # ... of the window kind's (kv_frac is the larger)
+    # its decode rows: `kv_pages_streamed` / `kv_pages_held` there count a
+    # layer of EACH kind; the full kind's share of `kv_pages_held`, the
+    # window-pool pages the rows hold (x the dispatch's steps, like it),
+    # and the engine's cumulative count of window pages released
+    "kv_pages_held_full",
+    "kv_win_pages_held",
+    "kv_win_pages_released",
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

@@ -44,6 +44,11 @@ class Sequence:
 
     prompt_len: int = 0
     page_ids: list[int] = field(default_factory=list)
+    # a hybrid model's window-kind pages, by logical page like `page_ids`
+    # (their own ids, the window pool's): entries below `win_first` were
+    # released behind the window and read 0, the trash page
+    win_page_ids: list[int] = field(default_factory=list)
+    win_first: int = 0
     num_cached: int = 0        # prefix-cache tokens reused at admission
     num_computed: int = 0      # tokens whose KV is valid in pages
     registered_pages: int = 0  # leading pages whose hashes are registered

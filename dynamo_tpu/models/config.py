@@ -1,6 +1,9 @@
 """Model configurations: the Llama family (Llama 2/3, Mistral, Qwen2,
-Gemma), Mixtral-style expert models and the DeepSeek-V2 family (latent
-attention, softmax-routed experts beside shared ones, leading dense layers).
+Gemma), Mixtral-style expert models, the DeepSeek-V2 family (latent
+attention, softmax-routed experts beside shared ones, leading dense layers)
+and the MiMo-V2 family (window layers with a learned sink beside
+full-attention layers, keys wider than values, sigmoid-routed experts of
+which a deployment's chip holds a share).
 
 One config dataclass covers the architectures the reference serves through
 vLLM/sglang (reference: examples/llm/configs/*.yaml serve Llama/DeepSeek
@@ -20,6 +23,14 @@ Conventions:
   `num_shared_experts` shared ones on every token, the first
   `first_dense_layers` layers dense at `intermediate_size`; the router's
   scoring and renormalisation are read here, never assumed (models/moe.py).
+- a layer pattern (`layer_kinds`, one entry a layer: 0 full attention, 1
+  window attention) makes a model HYBRID: each kind has its own KV heads,
+  key width, value width and rope base (`attn_kind`), its own pools, page
+  ids and block tables (docs/kv_cache.md "Window pools"). An empty
+  pattern is every other model: one kind, `num_kv_heads` x `head_dim`.
+- an expert layer may hold a SHARE of the experts: the router scores all
+  `num_experts`, the layer holds `experts_held` of them from
+  `expert_offset` (0 held = all of them, every other preset).
 - dtypes: weights/activations bfloat16 on TPU (MXU-native), float32 for
   norms/softmax accumulation inside the ops.
 """
@@ -27,7 +38,29 @@ Conventions:
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
+
+FULL, WINDOW = 0, 1  # the kinds a layer pattern names
+
+
+class AttnKind(NamedTuple):
+    """What one kind of attention layer keeps a token and how it reads:
+    the cache specification the pools are sized from."""
+
+    kv_heads: int
+    k_dim: int        # key (and query) head width
+    v_dim: int        # value head width
+    rope_theta: float
+    window: int       # tokens a query sees, itself included; 0 = all
+    sink: bool        # a learned per-head logit in the softmax
+
+    @property
+    def k_width(self) -> int:
+        return self.kv_heads * self.k_dim
+
+    @property
+    def v_width(self) -> int:
+        return self.kv_heads * self.v_dim
 
 
 @dataclass(frozen=True)
@@ -68,6 +101,58 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # hybrid attention (mimo_v2_flash): the kind of each layer (empty =
+    # one kind), the window kind's heads / widths / rope base, the share
+    # of a head's dimensions that rotate, the window kind's learned sink
+    # and the factor on every value row. `v_head_dim` above is then the
+    # full kind's value width.
+    layer_kinds: tuple = ()
+    sliding_window: int = 0
+    swa_num_kv_heads: int = 0
+    swa_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    rotary_dim: int = 0           # 0 = the whole head
+    swa_sink: bool = False
+    attn_value_scale: float = 1.0
+    # the router as published: score function (softmax | sigmoid) and a
+    # learned bias added to the scores for SELECTION only (noaux_tc)
+    scoring_func: str = "softmax"
+    router_bias: bool = False
+    # the share of the experts this layer holds (0 = all `num_experts`)
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def hybrid(self) -> bool:
+        """More than one kind of attention layer: pools, page ids and
+        block tables per kind."""
+        return bool(self.layer_kinds)
+
+    def layer_kind(self, layer: int) -> int:
+        return self.layer_kinds[layer] if self.layer_kinds else FULL
+
+    def attn_kind(self, kind: int) -> AttnKind:
+        if kind == WINDOW:
+            return AttnKind(
+                self.swa_num_kv_heads, self.swa_head_dim,
+                self.swa_v_head_dim, self.swa_rope_theta,
+                self.sliding_window, self.swa_sink,
+            )
+        return AttnKind(
+            self.num_kv_heads, self.head_dim,
+            self.v_head_dim if self.hybrid else self.head_dim,
+            self.rope_theta, 0, False,
+        )
+
+    def layers_of(self, kind: int) -> int:
+        if not self.hybrid:
+            return self.num_layers if kind == FULL else 0
+        return sum(1 for k in self.layer_kinds if k == kind)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def q_size(self) -> int:
@@ -110,9 +195,11 @@ class ModelConfig:
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "hf-model") -> "ModelConfig":
         """Build from a HuggingFace config.json dict (llama / mistral /
-        qwen2 / gemma / mixtral / deepseek_v2)."""
+        qwen2 / gemma / mixtral / deepseek_v2 / mimo_v2_flash)."""
         if hf.get("model_type") == "deepseek_v2":
             return cls._from_deepseek_v2(hf, name)
+        if hf.get("model_type") == "mimo_v2_flash":
+            return cls._from_mimo_v2_flash(hf, name)
         num_heads = hf["num_attention_heads"]
         head_dim = hf.get("head_dim") or hf["hidden_size"] // num_heads
         return cls(
@@ -163,8 +250,9 @@ class ModelConfig:
         if bad:
             raise ValueError(
                 f"deepseek_v2 config: {bad[0]}={hf.get(bad[0])!r} is not "
-                "served (low-rank queries, sigmoid or group-limited routing, "
-                "expert layers at a period other than 1, attention bias)"
+                "served for this model_type (low-rank queries, a router "
+                "other than greedy softmax, group-limited routing, expert "
+                "layers at a period other than 1, attention bias)"
             )
         nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
         return cls(
@@ -194,6 +282,92 @@ class ModelConfig:
             qk_nope_head_dim=nope,
             qk_rope_head_dim=rope,
             v_head_dim=hf["v_head_dim"],
+        )
+
+    @classmethod
+    def _from_mimo_v2_flash(cls, hf: dict, name: str) -> "ModelConfig":
+        """The `mimo_v2_flash` keys: a layer pattern of window (1) and
+        full (0) attention layers, each kind with its own KV heads and
+        rope base, 192-wide keys over 128-wide values, sigmoid-routed
+        experts chosen with a correction bias. What is not served is
+        refused by name.
+
+        A deployment's chip holds a SHARE of the experts: the file it
+        runs states the experts it holds as `n_routed_experts` and the
+        router's width as `router_width` (absent: the same), from
+        `expert_offset` (absent: 0)."""
+        freq = list(hf.get("moe_layer_freq") or [])
+        n_layers = hf["num_hidden_layers"]
+        first_dense = next((i for i, f in enumerate(freq) if f), len(freq))
+        pattern = tuple(hf["hybrid_layer_pattern"])
+        unsupported = {
+            "n_group": hf.get("n_group", 1) not in (None, 1),
+            "topk_group": hf.get("topk_group", 1) not in (None, 1),
+            "attention_bias": bool(hf.get("attention_bias")),
+            "add_full_attention_sink_bias":
+                bool(hf.get("add_full_attention_sink_bias")),
+            "routed_scaling_factor":
+                hf.get("routed_scaling_factor") not in (None, 1, 1.0),
+            "n_shared_experts": bool(hf.get("n_shared_experts")),
+            "scoring_func": hf.get("scoring_func") != "sigmoid",
+            "topk_method": hf.get("topk_method") != "noaux_tc",
+            "moe_layer_freq":
+                len(freq) != n_layers or not all(freq[first_dense:]),
+            "hybrid_layer_pattern":
+                len(pattern) != n_layers or set(pattern) - {FULL, WINDOW},
+            "swa_num_attention_heads":
+                hf.get("swa_num_attention_heads", hf["num_attention_heads"])
+                != hf["num_attention_heads"],
+            "sliding_window_size":
+                hf.get("sliding_window_size", hf["sliding_window"])
+                != hf["sliding_window"],
+        }
+        bad = [k for k, v in unsupported.items() if v]
+        if bad:
+            raise ValueError(
+                f"mimo_v2_flash config: {bad[0]}={hf.get(bad[0])!r} is not "
+                "served (group-limited routing, attention bias, a sink in "
+                "full-attention layers, a routed scaling factor, shared "
+                "experts beside a sigmoid router, a router other than "
+                "sigmoid with a noaux_tc bias, dense layers after the first "
+                "expert layer, a layer pattern that does not name every "
+                "layer, window layers with another head count or window)"
+            )
+        held = hf.get("n_routed_experts") or 0
+        return cls(
+            name=name,
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            head_dim=hf["head_dim"],
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_norm_eps=hf.get("layernorm_epsilon", 1e-5),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            num_experts=hf.get("router_width", held),
+            num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+            moe_intermediate_size=hf.get("moe_intermediate_size", 0),
+            first_dense_layers=first_dense,
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+            v_head_dim=hf["v_head_dim"],
+            layer_kinds=pattern,
+            sliding_window=hf["sliding_window"],
+            swa_num_kv_heads=hf["swa_num_key_value_heads"],
+            swa_head_dim=hf["swa_head_dim"],
+            swa_v_head_dim=hf["swa_v_head_dim"],
+            swa_rope_theta=hf.get("swa_rope_theta", 10000.0),
+            rotary_dim=int(
+                hf["head_dim"] * hf.get("partial_rotary_factor", 1.0)
+            ),
+            swa_sink=bool(hf.get("add_swa_attention_sink_bias")),
+            attn_value_scale=float(hf.get("attention_value_scale") or 1.0),
+            scoring_func="sigmoid",
+            router_bias=True,
+            experts_held=held,
+            expert_offset=hf.get("expert_offset", 0),
         )
 
 
@@ -425,6 +599,80 @@ TINY_MLA = _preset(ModelConfig(
     qk_nope_head_dim=32,
     qk_rope_head_dim=16,
     v_head_dim=32,
+))
+
+# MiMo-V2 family: five window layers (128 tokens, a learned sink a head, 8
+# KV heads) to one full-attention layer (4 KV heads), 192-wide keys over
+# 128-wide values with rope on the first 64 dimensions, layer 0 dense, 256
+# sigmoid-routed experts top-8 chosen with a correction bias, no shared one.
+_MIMO_PERIOD = (FULL,) + (WINDOW,) * 4 + (FULL,) + (WINDOW,) * 5 + (FULL,)
+
+_preset(ModelConfig(
+    name="mimo-v2-flash",
+    vocab_size=152576,
+    hidden_size=4096,
+    intermediate_size=16384,
+    num_layers=48,
+    num_heads=64,
+    num_kv_heads=4,
+    head_dim=192,
+    rope_theta=5000000,
+    rms_norm_eps=1e-5,
+    max_position_embeddings=262144,
+    num_experts=256,
+    num_experts_per_tok=8,
+    moe_intermediate_size=2048,
+    first_dense_layers=1,
+    norm_topk_prob=True,
+    v_head_dim=128,
+    layer_kinds=_MIMO_PERIOD[:6] + (_MIMO_PERIOD[6:] * 7),
+    sliding_window=128,
+    swa_num_kv_heads=8,
+    swa_head_dim=192,
+    swa_v_head_dim=128,
+    swa_rope_theta=10000,
+    rotary_dim=64,
+    swa_sink=True,
+    attn_value_scale=0.707,
+    scoring_func="sigmoid",
+    router_bias=True,
+    experts_held=256,
+))
+
+# the same family at a size the CPU tests finish in seconds: the dense
+# layer and two periods (window x2, full), window 16 over pages of 8, 8
+# experts of which this "chip" holds 4, keys 24 wide (8 rotate) over
+# values 16 wide
+TINY_MIMO = _preset(ModelConfig(
+    name="tiny-mimo",
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=7,
+    num_heads=4,
+    num_kv_heads=1,
+    head_dim=24,
+    rope_theta=5000000,
+    rms_norm_eps=1e-5,
+    max_position_embeddings=2048,
+    num_experts=8,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    first_dense_layers=1,
+    norm_topk_prob=True,
+    v_head_dim=16,
+    layer_kinds=(FULL, WINDOW, WINDOW, FULL, WINDOW, WINDOW, FULL),
+    sliding_window=16,
+    swa_num_kv_heads=2,
+    swa_head_dim=24,
+    swa_v_head_dim=16,
+    swa_rope_theta=10000,
+    rotary_dim=8,
+    swa_sink=True,
+    attn_value_scale=0.707,
+    scoring_func="sigmoid",
+    router_bias=True,
+    experts_held=4,
 ))
 
 

@@ -22,7 +22,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import FULL, WINDOW, ModelConfig
 from dynamo_tpu.ops.attention import (
     latent_attention,
     paged_attention,
@@ -92,7 +92,16 @@ class AttnSpec:
     def __init__(self, slot_matrix=None, block_tables=None, lengths=None,
                  write_pos=None, page_size: int = 16, interpret: bool = False,
                  mesh=None, write_tables=None, q_pos0=None, ring: bool = False,
-                 kv_tp: int = 1, prefix_cols: int = 0, int4_groups: int = 0):
+                 kv_tp: int = 1, prefix_cols: int = 0, int4_groups: int = 0,
+                 win=None, write_slots=None):
+        # a hybrid model's WINDOW layers read their own pools through
+        # their own page ids: `win` is a second AttnSpec holding that
+        # kind's slot matrix / block tables / write tables (lengths,
+        # write_pos and q_pos0 are the sequence's, shared) and its
+        # `write_slots` (gather-mode row scatter; the full kind's ride
+        # `forward`'s argument as ever). None for every other model.
+        self.win = win
+        self.write_slots = write_slots
         self.slot_matrix = slot_matrix
         self.block_tables = block_tables
         self.lengths = lengths
@@ -167,13 +176,14 @@ jax.tree_util.register_pytree_node(
     AttnSpec,
     lambda s: (
         (s.slot_matrix, s.block_tables, s.lengths, s.write_pos,
-         s.write_tables, s.q_pos0),
+         s.write_tables, s.q_pos0, s.win, s.write_slots),
         (s.page_size, s.interpret, s.mesh, s.ring, s.kv_tp, s.prefix_cols,
          s.int4_groups),
     ),
     lambda aux, children: AttnSpec(
         slot_matrix=children[0], block_tables=children[1], lengths=children[2],
         write_pos=children[3], write_tables=children[4], q_pos0=children[5],
+        win=children[6], write_slots=children[7],
         page_size=aux[0], interpret=aux[1], mesh=aux[2], ring=aux[3],
         kv_tp=aux[4], prefix_cols=aux[5], int4_groups=aux[6],
     ),
@@ -227,7 +237,13 @@ class KVCache(NamedTuple):
     `ModelConfig.latent_pool_width`), no head axis; `v` is None: the values ARE the first
     `kv_lora_rank` columns of the same row, and the decode kernel reads
     each row once (ops/pallas_mla.py). Pages, block tables and slots are
-    the same as for K/V pools."""
+    the same as for K/V pools.
+
+    Hybrid cache (`cfg.hybrid`, docs/kv_cache.md "Window pools"): a
+    layer's pools have its KIND's shape (`ModelConfig.attn_kind`: keys K
+    x Kd wide, values K x Vd) and its kind's number of slots: window
+    layers' pools are smaller and indexed by their own page ids, so
+    `num_slots` is the full kind's and nothing stacks."""
 
     k: tuple
     v: tuple | None
@@ -255,12 +271,32 @@ def init_kv_cache(
     cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16,
     kv_quant: str | None = None, page_size: int = 16, tp: int = 1,
     packed: bool = False, kv_quant_group: int | None = None,
-    sharding=None, scale_sharding=None,
+    sharding=None, scale_sharding=None, win_slots: int | None = None,
 ) -> KVCache:
     """`sharding` / `scale_sharding` create the data / scale pools shard
     by shard on their devices: the engine sizes the pool to each
     device's free memory, so a layer's whole unsharded pool is tp times
     what one device can hold and must never be built in one place."""
+    if cfg.hybrid:
+        # pools per kind: `num_slots` rows in a full-attention layer,
+        # `win_slots` in a window layer, each its kind's widths
+        if kv_quant is not None:
+            raise ValueError(
+                f"kv_quantization={kv_quant!r} with window and full "
+                f"attention layers ('{cfg.name}'): the two kinds of pool "
+                "are served in the model's dtype only"
+            )
+        kinds = [cfg.attn_kind(cfg.layer_kind(l)) for l in range(cfg.num_layers)]
+        rows = [
+            (win_slots or num_slots) if kd.window else num_slots
+            for kd in kinds
+        ]
+        return KVCache(
+            k=tuple(jnp.zeros((n, kd.k_width), dtype, device=sharding)
+                    for n, kd in zip(rows, kinds)),
+            v=tuple(jnp.zeros((n, kd.v_width), dtype, device=sharding)
+                    for n, kd in zip(rows, kinds)),
+        )
     if cfg.latent:
         if kv_quant is not None:
             raise ValueError(
@@ -352,13 +388,18 @@ def _attn_block(
     lp: Params,
     cfg: ModelConfig,
     x: jnp.ndarray,          # [B, T, D]
-    cos: jnp.ndarray,        # [B, T, Hd]
+    cos: jnp.ndarray,        # [B, T, rotary width] at the kind's rope base
     sin: jnp.ndarray,
-    kv_k: jnp.ndarray,       # [N, K*Hd] this layer's pools (int8 when quantized)
-    kv_v: jnp.ndarray,
+    kv_k: jnp.ndarray,       # [N, K*Kd] this layer's pools (int8 when quantized)
+    kv_v: jnp.ndarray,       # [N, K*Vd]
     write_slots: jnp.ndarray,   # [B*T] int32
     attn: "AttnSpec",
     positions: jnp.ndarray,     # [B, T]
+    kind: int = FULL,        # the layer's kind (`cfg.layer_kind`): its KV
+    # heads, key width Kd, value width Vd, window and sink are
+    # `cfg.attn_kind(kind)`. A model of one kind has Kd == Vd == head_dim,
+    # no window and no sink; in a hybrid model cos / sin, `attn` and
+    # `write_slots` are the kind's own
     kv_ks=None,              # [N, K] f32 scale pools (int8 KV mode)
     kv_vs=None,
     tp_axis=None,  # set when running INSIDE a shard_map (manual tp):
@@ -373,7 +414,18 @@ def _attn_block(
         b, t = bt_shape
     else:
         b, t, _ = x.shape
-    h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    spec = cfg.attn_kind(kind)
+    h, kh, hd, vd = cfg.num_heads, spec.kv_heads, spec.k_dim, spec.v_dim
+    sink = lp["sink"].astype(jnp.float32) if spec.sink else None
+
+    def one_kind_only(path: str) -> None:
+        if spec.window or spec.sink or vd != hd:
+            raise ValueError(
+                f"{path} is written for one width of key and value and "
+                "every position attended: no window, sink or narrower "
+                f"values ('{cfg.name}')"
+            )
+
     if tp_axis is not None:
         # manual tp: this shard holds its local slice of the heads
         tpn = jax.lax.axis_size(tp_axis)
@@ -450,14 +502,18 @@ def _attn_block(
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
+        if cfg.attn_value_scale != 1.0:
+            v = (v * cfg.attn_value_scale).astype(v.dtype)
         q = q.reshape(b, t, h, hd)
         k = k.reshape(b, t, kh, hd)
-        v = v.reshape(b, t, kh, hd)
+        v = v.reshape(b, t, kh, vd)
 
     with jax.named_scope("attn.rope"):
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    kernel = jax.named_scope("attn.kernel")
+    kernel = jax.named_scope(
+        "attn.swa_kernel" if spec.window else "attn.kernel"
+    )
 
     if attn.block_tables is not None and attn.write_pos is not None:
         from dynamo_tpu.ops.pallas_attention import fused_paged_decode_attention
@@ -467,9 +523,16 @@ def _attn_block(
             page_size=attn.page_size,
             interpret=attn.interpret,
             int4=int4,
+            # the last attended position is `lengths - 1`: a window
+            # starts `window` positions at or before it
+            starts=(
+                jnp.maximum(attn.lengths - spec.window, 0) if spec.window
+                else None
+            ),
+            sink=sink,
         )
         new_k = k[:, 0].reshape(b, kh * hd)
-        new_v = v[:, 0].reshape(b, kh * hd)
+        new_v = v[:, 0].reshape(b, kh * vd)
         if quant:
             # quantize the new rows at trace time; the kernel injects the
             # quantized rows + scale columns into their pages in VMEM.
@@ -537,7 +600,7 @@ def _attn_block(
         ps = attn.page_size
         t_pad = -(-t // ps) * ps
         k2 = k.reshape(b, t, kh * hd)
-        v2 = v.reshape(b, t, kh * hd)
+        v2 = v.reshape(b, t, kh * vd)
         ks2 = vs2 = None
         if quant:
             k2, ks2 = _quant_rows(k2)
@@ -552,11 +615,11 @@ def _attn_block(
                 vs2 = jnp.pad(vs2, ((0, 0), (0, t_pad - t), (0, 0)),
                               constant_values=1.0)
         n_pg = b * (t_pad // ps)
-        # row width is kh*hd, except the int4 tier nibble-packs rows to
-        # half width at quantize time — read it off the rows themselves
-        row_w = k2.shape[-1]
-        k_pages = k2.reshape(n_pg, ps, row_w)
-        v_pages = v2.reshape(n_pg, ps, row_w)
+        # row width is kh*hd (values kh*vd), except the int4 tier
+        # nibble-packs rows to half width at quantize time — read it off
+        # the rows themselves
+        k_pages = k2.reshape(n_pg, ps, k2.shape[-1])
+        v_pages = v2.reshape(n_pg, ps, v2.shape[-1])
         if quant and kv_k.dtype == jnp.int32:
             # int32-packed pools: pack the chunk's source pages to match
             # (4 token rows per int32 row, ops/quant.pack_kv_slots)
@@ -615,6 +678,7 @@ def _attn_block(
             fl = functools.partial(
                 flash_prefill_attention,
                 page_size=ps, interpret=attn.interpret, int4=int4,
+                window=spec.window, sink=sink,
             )
             if attn.mesh is not None:
                 P = jax.sharding.PartitionSpec
@@ -647,8 +711,10 @@ def _attn_block(
                 q, kv_k, kv_v, attn.slot_matrix, positions,
                 k_scales=kv_ks, v_scales=kv_vs, scale_tp=attn.kv_tp,
                 int4_groups=attn.int4_groups or None,
+                window=spec.window, sink=sink,
             )
     elif attn.ring and attn.mesh is not None:
+        one_kind_only("ring attention")
         # sp-sharded long-context prefill: KV lands in the (sp-replicated)
         # pool for later decode; attention rings the fresh chunk blocks
         # around the sp axis (ops/ring_attention.py). With q_pos0 set the
@@ -664,7 +730,7 @@ def _attn_block(
 
         kv_k, kv_v, kv_ks, kv_vs = _write_rows(
             kv_k, kv_v, kv_ks, kv_vs,
-            k.reshape(b * t, kh * hd), v.reshape(b * t, kh * hd),
+            k.reshape(b * t, kh * hd), v.reshape(b * t, kh * vd),
         )
         if attn.q_pos0 is not None:
             # bounded gather: only the page bucket that actually holds
@@ -715,7 +781,7 @@ def _attn_block(
     else:
         kv_k, kv_v, kv_ks, kv_vs = _write_rows(
             kv_k, kv_v, kv_ks, kv_vs,
-            k.reshape(b * t, kh * hd), v.reshape(b * t, kh * hd),
+            k.reshape(b * t, kh * hd), v.reshape(b * t, kh * vd),
         )
         if attn.block_tables is not None and attn.q_pos0 is not None:
             # mixed prefill+decode and spec-verify steps on the pallas
@@ -726,6 +792,7 @@ def _attn_block(
             # verify rows q_len=1+k, chunk rows causal inside the chunk)
             from dynamo_tpu.ops.pallas_attention import ragged_paged_attention
 
+            one_kind_only("the ragged kernel of mixed and verify steps")
             rg = functools.partial(
                 ragged_paged_attention,
                 page_size=attn.page_size, interpret=attn.interpret,
@@ -760,6 +827,7 @@ def _attn_block(
         elif attn.block_tables is not None:
             from dynamo_tpu.ops.pallas_attention import paged_decode_attention
 
+            one_kind_only("the read-only decode kernel")
             ro = functools.partial(
                 paged_decode_attention,
                 page_size=attn.page_size,
@@ -800,6 +868,7 @@ def _attn_block(
                 k_scales=kv_ks, v_scales=kv_vs, scale_tp=attn.kv_tp,
                 q_lens=attn.lengths,
                 int4_groups=attn.int4_groups or None,
+                window=spec.window, sink=sink,
             )
     with jax.named_scope("attn.o"):
         if tp_overlap:
@@ -811,10 +880,10 @@ def _attn_block(
             from dynamo_tpu.parallel import tp_overlap as _ov
 
             proj = _ov.ring_rs_matmul(
-                out.reshape(b * t, h * hd), lp["wo"], tp_axis
+                out.reshape(b * t, h * vd), lp["wo"], tp_axis
             )
         else:
-            proj = mm(out.reshape(b, t, h * hd), lp["wo"])
+            proj = mm(out.reshape(b, t, h * vd), lp["wo"])
             if tp_axis is not None:
                 from dynamo_tpu.parallel.tp_overlap import psum_allreduce
 
@@ -1000,18 +1069,34 @@ def forward(
 
     inv_freq = jnp.asarray(rope_inv_freq(cfg))
     cos, sin = rope_cos_sin(inv_freq, positions)  # [B, T, Hd]
+    by_kind = None
+    if cfg.hybrid:
+        # per kind of layer: its rope base's tables, its AttnSpec (its
+        # own page ids) and its write slots
+        wcos, wsin = rope_cos_sin(
+            jnp.asarray(rope_inv_freq(cfg, cfg.swa_rope_theta)), positions
+        )
+        by_kind = {
+            FULL: (cos, sin, attn, write_slots),
+            WINDOW: (wcos, wsin, attn.win, attn.win.write_slots),
+        }
 
     new_k_layers = []
     new_v_layers = []
     new_ks_layers = []
     new_vs_layers = []
     for l, lp in enumerate(params["layers"]):
+        l_cos, l_sin, l_attn, l_slots = (
+            by_kind[cfg.layer_kind(l)] if by_kind
+            else (cos, sin, attn, write_slots)
+        )
         x, layer_k, layer_v, layer_ks, layer_vs = layer_step(
-            lp, cfg, x, cos, sin, kv.k[l], None if kv.latent else kv.v[l],
-            write_slots, attn, positions, real_mask=real_mask,
+            lp, cfg, x, l_cos, l_sin, kv.k[l],
+            None if kv.latent else kv.v[l],
+            l_slots, l_attn, positions, real_mask=real_mask,
             kv_ks=kv.ks[l] if kv.quantized else None,
             kv_vs=kv.vs[l] if kv.quantized else None,
-            moe_stats=moe_stats,
+            moe_stats=moe_stats, layer=l,
         )
         new_k_layers.append(layer_k)
         new_v_layers.append(layer_v)
@@ -1034,7 +1119,7 @@ def forward(
 def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
                positions, real_mask=None, kv_ks=None, kv_vs=None,
                tp_axis=None, tp_overlap: bool = False, bt_shape=None,
-               moe_stats=None):
+               moe_stats=None, *, layer: int):
     """One transformer layer (attention + FFN, pre-norm residuals) over
     the paged pools — shared by `forward` and the pipeline-parallel
     stage executor (parallel/pipeline.py). `tp_axis` enables manual-tp
@@ -1046,15 +1131,17 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
     `lax.ppermute` ring (parallel/tp_overlap.py). kv_ks/kv_vs are the
     int8-KV scale pools (None in unquantized mode; returned as-is).
 
-    What a layer IS comes from its parameters: a latent-attention layer
-    holds `w_kva` (then `kv_k` is its latent pool and `kv_v` None), an
-    expert layer holds `router` (a model's leading dense layers hold
-    `w_gate` like any dense model's)."""
+    What layer `layer` IS comes from the configuration: its attention
+    kind from the pattern (`cfg.layer_kind`; in a hybrid model cos / sin,
+    `attn` and `write_slots` are that kind's), latent attention from
+    `cfg.latent` (then `kv_k` is its latent pool and `kv_v` None), an
+    expert layer from `cfg.is_moe_layer`. The stage executors run dense
+    models of one kind, whose layers are all alike, and say 0."""
     if tp_overlap and cfg.num_experts:
         raise ValueError("tp_overlap layer executor covers dense models")
     w_off = cfg.norm_weight_offset
     attn_in = _norm(x, lp["attn_norm"], cfg.rms_norm_eps, weight_offset=w_off)
-    if "w_kva" in lp:
+    if cfg.latent:
         attn_out, kv_k = _mla_attn_block(
             lp, cfg, attn_in, cos, sin, kv_k, write_slots, attn, positions,
         )
@@ -1063,10 +1150,11 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
             lp, cfg, attn_in, cos, sin, kv_k, kv_v, write_slots, attn,
             positions, kv_ks=kv_ks, kv_vs=kv_vs, tp_axis=tp_axis,
             tp_overlap=tp_overlap, bt_shape=bt_shape,
+            kind=cfg.layer_kind(layer),
         )
     x = x + attn_out
     mlp_in = _norm(x, lp["mlp_norm"], cfg.rms_norm_eps, weight_offset=w_off)
-    if "router" in lp:
+    if cfg.is_moe_layer(layer):
         from dynamo_tpu.models.moe import moe_block
 
         x = x + moe_block(
@@ -1133,10 +1221,11 @@ def init_params(
     d, f = cfg.hidden_size, cfg.intermediate_size
     qs, kvs = cfg.q_size, cfg.kv_size
     keys = iter(jax.random.split(key, 4 + 9 * cfg.num_layers))
-    if quantize and (cfg.latent or cfg.num_shared_experts):
+    if quantize and (cfg.latent or cfg.num_shared_experts or cfg.hybrid):
         raise ValueError(
             f"quantization with '{cfg.name}': int8 weights are not served "
-            "for latent attention or shared experts yet"
+            "for latent attention, shared experts or window beside full "
+            "attention yet"
         )
     if quantize:
         from dynamo_tpu.ops.quant import QUANT_KEYS, quantize_weight
@@ -1165,6 +1254,24 @@ def init_params(
                 "wo": dense(next(keys), (hv, d)),
                 "mlp_norm": jnp.ones((d,), dtype),
             }
+        elif cfg.hybrid:
+            spec = cfg.attn_kind(cfg.layer_kind(i))
+            hv = cfg.num_heads * spec.v_dim
+            lp = {
+                "attn_norm": jnp.ones((d,), dtype),
+                "wq": dense(next(keys), (d, cfg.num_heads * spec.k_dim)),
+                "wk": dense(next(keys), (d, spec.k_width)),
+                "wv": dense(next(keys), (d, spec.v_width)),
+                "wo": dense(next(keys), (hv, d)),
+                "mlp_norm": jnp.ones((d,), dtype),
+            }
+            if spec.sink:
+                # N(0, 1) so that the sink is judged (a checkpoint's
+                # come from training)
+                lp["sink"] = jax.random.normal(
+                    jax.random.fold_in(key, 7000 + i), (cfg.num_heads,),
+                    jnp.float32,
+                )
         else:
             lp = {
                 "attn_norm": jnp.ones((d,), dtype),

@@ -5,11 +5,19 @@ The reference serves MoE checkpoints (DeepSeek, Mixtral) through its
 engines' fused MoE kernels (SURVEY §2.4). Here the layer is four steps,
 each under the `jax.named_scope` a profile finds it by:
 
-- `mlp.moe_router`: scores over the experts in float32 (bf16 logits flip
-  near-tie top-k membership), softmax, the k largest. What happens to the
-  k weights is the CONFIGURATION's: renormalised over the selected experts
-  (`norm_topk_prob`, Mixtral) or used as they are (DeepSeek-V2), times
-  `routed_scaling_factor`.
+- `mlp.moe_router`: scores over ALL `num_experts` in float32 (bf16 logits
+  flip near-tie top-k membership), softmax or sigmoid (`scoring_func`),
+  the k largest, chosen by score + a learned correction bias where the
+  configuration has one (`router_bias`, noaux_tc: the bias selects, the
+  weights are the scores without it). What happens to the k weights is
+  the CONFIGURATION's: renormalised over the selected experts
+  (`norm_topk_prob`, Mixtral, MiMo-V2) or used as they are (DeepSeek-V2),
+  times `routed_scaling_factor`.
+- a layer may hold a SHARE of the experts (`experts_held` from
+  `expert_offset`: one chip of an expert-parallel group): pairs routed to
+  an expert it does not hold get the sentinel below, and the layer's
+  output is its own experts' part of the sum (the group's exchange adds
+  the parts up; on one chip nothing stands in for the absent chips).
 - `mlp.moe_dispatch`: the N x k pairs sorted by expert (stable, so a
   token's rows keep their order inside an expert) and the tokens gathered
   into that order: rows [0, g0) belong to expert 0, the next g1 to expert
@@ -36,8 +44,9 @@ Shared experts (`num_shared_experts`, one SwiGLU of that many expert
 widths on EVERY token) are `mlp.moe_shared`. Expert weights live as
 [E, ...] arrays, sharded P('ep', ...) on a mesh that has the axis.
 
-`stats` (a list the caller passes) receives this layer's load as two
-int32 scalars, (distinct experts with a token, most tokens on one expert):
+`stats` (a list the caller passes) receives this layer's load over the
+experts it HOLDS as two int32 scalars, (distinct experts with a token,
+most tokens on one expert):
 the decode program returns their means with the tokens (engine.py).
 """
 
@@ -58,20 +67,43 @@ GMM_WEIGHT_TILE_BYTES = 6 << 20
 
 
 def init_moe_params(cfg, key, dtype=jnp.bfloat16) -> dict:
-    """Per-layer MoE params: router [D, E], expert FFNs [E, D, F] /
-    [E, F, D], and the shared experts as one FFN of width S x F."""
+    """Per-layer MoE params: router [D, E], expert FFNs [held, D, F] /
+    [held, F, D], and the shared experts as one FFN of width S x F.
+
+    A configuration that states the experts it holds (`experts_held`)
+    draws each expert from its own key (the layer key folded with the
+    expert's id), so a share holds exactly the rows the whole layer
+    would: the shares of a layer add up (tests/test_mimo_v2_flash.py)."""
     d, f, e = cfg.hidden_size, cfg.expert_width, cfg.num_experts
     k_router, k_gate, k_up, k_down, k_shared = jax.random.split(key, 5)
 
     def dense(k, shape, scale):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
+    if cfg.experts_held:
+        ids = cfg.expert_offset + jnp.arange(cfg.experts_held)
+
+        def experts(k, shape, scale):
+            return jax.vmap(
+                lambda i: dense(jax.random.fold_in(k, i), shape, scale)
+            )(ids)
+    else:
+        def experts(k, shape, scale):
+            return dense(k, (e, *shape), scale)
+
     lp = {
         "router": dense(k_router, (d, e), d ** -0.5),
-        "we_gate": dense(k_gate, (e, d, f), d ** -0.5),
-        "we_up": dense(k_up, (e, d, f), d ** -0.5),
-        "we_down": dense(k_down, (e, f, d), f ** -0.5),
+        "we_gate": experts(k_gate, (d, f), d ** -0.5),
+        "we_up": experts(k_up, (d, f), d ** -0.5),
+        "we_down": experts(k_down, (f, d), f ** -0.5),
     }
+    if cfg.router_bias:
+        # N(0, 0.02) so that the selection bias is judged (HF initialises it
+        # to zero) and the load stays near even, as the trained bias of a
+        # checkpoint keeps it: at 0.05 the draw starves 2-3 experts in 16
+        lp["router_bias"] = 0.02 * jax.random.normal(
+            jax.random.fold_in(k_router, 1), (e,), jnp.float32
+        )
     if cfg.num_shared_experts:
         fs = cfg.num_shared_experts * f
         ks = jax.random.split(k_shared, 3)
@@ -87,8 +119,18 @@ def init_moe_params(cfg, key, dtype=jnp.bfloat16) -> dict:
 def route(lp: dict, cfg, xf: jnp.ndarray):
     """xf [N, D] -> (weights [N, k] float32, experts [N, k] int32)."""
     logits = xf.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.scoring_func == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    if cfg.router_bias:
+        # the bias chooses; the weights are the scores without it
+        _, top_i = jax.lax.top_k(
+            probs + lp["router_bias"], cfg.num_experts_per_tok
+        )
+        top_w = jnp.take_along_axis(probs, top_i, axis=-1)
+    else:
+        top_w, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
     if cfg.norm_topk_prob:
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     if cfg.routed_scaling_factor != 1.0:
@@ -126,12 +168,20 @@ def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None,
     output alone, which no real row reads)."""
     b, t, d = x.shape
     n = b * t
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    # `e` experts are HELD here, ids [offset, offset + e) of the
+    # `num_experts` the router scores; every other preset holds them all
+    e, k = cfg.held_experts, cfg.num_experts_per_tok
     xf = x.reshape(n, d)
     top_w, top_i = route(lp, cfg, xf)
 
     with jax.named_scope("mlp.moe_dispatch"):
         expert_of = top_i.reshape(n * k)
+        if e != cfg.num_experts:
+            # a pair routed to an expert another chip holds: the sentinel
+            expert_of = expert_of - cfg.expert_offset
+            expert_of = jnp.where(
+                (expert_of >= 0) & (expert_of < e), expert_of, e
+            )
         if real_mask is not None:
             expert_of = jnp.where(
                 jnp.repeat(real_mask.reshape(n), k), expert_of, e

@@ -63,12 +63,19 @@ def slots_from_pages(block_tables: jnp.ndarray, page_size: int) -> jnp.ndarray:
     return s.reshape(*block_tables.shape[:-1], -1)
 
 
-def _masked_softmax(logits: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    """Softmax over the last axis in f32; fully-masked rows yield zeros."""
+def _masked_softmax(logits: jnp.ndarray, mask: jnp.ndarray,
+                    sink: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Softmax over the last axis in f32; fully-masked rows yield zeros.
+    `sink` (broadcastable to logits[..., :1]) is one more logit a row
+    whose weight is not returned."""
     logits = jnp.where(mask, logits, _NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
+    if sink is not None:
+        m = jnp.maximum(m, sink)
     p = jnp.exp(logits - m) * mask
     denom = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        denom = denom + jnp.exp(sink - m)
     return p / (denom + 1e-30)
 
 
@@ -83,6 +90,8 @@ def paged_attention(
     scale_tp: int = 1,
     q_lens: jnp.ndarray | None = None,    # [B] valid query rows per row
     int4_groups: int | None = None,       # int4 pools: scale groups per head
+    window: int = 0,                      # tokens a query sees (0 = all)
+    sink: jnp.ndarray | None = None,      # [H] f32 learned sink logits
 ) -> jnp.ndarray:
     """Gathered-slot attention. Gathered slot j holds absolute position j of
     the sequence, so causality is `j <= positions[b, t]`; padded queries and
@@ -99,6 +108,12 @@ def paged_attention(
     (ops/quant.quantize_kv_rows; pool layout ops/quant.init_kv_scale_pool);
     rows are dequantized after the gather — this path is the correctness
     oracle for the int8 pallas kernels.
+
+    Keys and values may differ in width (`v_cache` rows are K x Vd): the
+    output is [B, T, H, Vd]. `window` adds `j > position - window` to the
+    mask (slots behind it may name released pages: never read into a
+    weight). `sink` [H] joins each head's softmax as one more column
+    whose weight is dropped, so a row of weights sums to less than 1.
 
     `int4_groups` switches the pools to the nibble-packed int4 tier
     (ops/quant.quantize_kv_rows_int4): the caches hold HALF-width packed
@@ -130,7 +145,7 @@ def paged_attention(
         ).reshape(b, c, kh, hd)
     else:
         k = k_cache[slot_matrix].reshape(b, c, kh, hd)  # [B, C, K, Hd]
-        v = v_cache[slot_matrix].reshape(b, c, kh, hd)
+        v = v_cache[slot_matrix].reshape(b, c, kh, -1)  # [B, C, K, Vd]
     if not int4 and k_scales is not None:
         from dynamo_tpu.ops.quant import gather_kv_scales
 
@@ -146,15 +161,20 @@ def paged_attention(
 
     j = jnp.arange(c)
     mask = j[None, None, :] <= positions[:, :, None]  # [B, T, C]
+    if window:
+        mask = mask & (j[None, None, :] > positions[:, :, None] - window)
     if q_lens is not None:
         mask = mask & (
             jnp.arange(t)[None, :, None] < q_lens[:, None, None]
         )
     mask = mask[:, None, None, :, :]
 
-    probs = _masked_softmax(logits, mask)
+    probs = _masked_softmax(
+        logits, mask,
+        None if sink is None else sink.reshape(1, kh, g, 1, 1),
+    )
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
-    return out.reshape(b, t, h, hd)
+    return out.reshape(b, t, h, v.shape[-1])
 
 
 def latent_attention(

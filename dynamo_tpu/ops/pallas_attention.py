@@ -113,6 +113,10 @@ def hbm_out(pool: jax.Array):
 # are all slower.
 PAGES_PER_BLOCK = 4
 NBUF = 4
+# scoped VMEM the unquantized decode kernel may take (the v5e has 128 MiB):
+# at 256 sequences of 64 heads the queries, the output and the two page
+# rings of a 1,536 / 1,024-wide layer pass the 16 MiB default together
+DECODE_VMEM_LIMIT = 64 << 20
 
 
 def live_pages(length, blk, page_size: int, pages_per_block: int, xp=jnp):
@@ -160,15 +164,20 @@ def work_list(lengths: jax.Array, t_blk: int, max_blocks: int):
 
 
 def streamed_pages(lengths, page_size: int,
-                   pages_per_block: int = PAGES_PER_BLOCK) -> int:
+                   pages_per_block: int = PAGES_PER_BLOCK, starts=None) -> int:
     """KV pages ONE layer's decode kernel copies in for these attended
     lengths (any shape; 0 = a row with no work): the work list's
-    `ceil(length / block)` items a sequence, each its `live_pages`. The
+    `ceil(length / block)` items a sequence, each its `live_pages`; with
+    `starts` (a window's first attended positions, the same shape) the
+    list begins at the page that holds the start, as the kernel's does. The
     engine books it per decode dispatch beside the pages held (digest
     columns `kv_pages_streamed` / `kv_pages_held`), so a change of page
     size or block that makes the kernel read past a sequence's end shows
     as a ratio above 1. Host-side numpy; exact on any backend."""
     lengths = np.asarray(lengths, np.int64).ravel()
+    if starts is not None:
+        starts = np.minimum(np.asarray(starts, np.int64).ravel(), lengths)
+        lengths = lengths - starts // page_size * page_size
     items = -(-lengths // (page_size * pages_per_block))
     return sum(
         int(live_pages(
@@ -186,47 +195,76 @@ def _decode_kernel(
     work_seq_ref,      # [MAXW] i32 sequence of each work item
     work_blk_ref,      # [MAXW] i32 page-block index of each work item
     n_work_ref,        # [1] i32 number of valid work items
+    start_ref,         # [B] i32 first position the sequence attends (0 = all)
     # inputs (VMEM)
-    qb_ref,            # [B, H, K*Hd] block-diagonal queries (pre-scaled)
-    knew_ref,          # [B, 1, K*Hd] new-token key rows
-    vnew_ref,
-    # inputs (HBM)
-    k_pages_hbm,       # [num_pages, page_size, K*Hd]
-    v_pages_hbm,
-    # outputs
-    o_ref,             # [B, H, K*Hd] VMEM (block-diag slice taken outside)
-    ko_pages_hbm,      # aliased k_pages_hbm
-    vo_pages_hbm,
-    # scratch
-    k_buf,             # [NBUF, ppb, page_size, K*Hd] VMEM
-    v_buf,
-    k_sems,            # DMA sems [NBUF]
-    v_sems,
-    w_sem,             # DMA sem for page write-backs
-    wb_pending,        # SMEM [NBUF]: write-back in flight from this slot
-    *,
+    q_ref,             # [B, H, Kd] queries (pre-scaled), one row a head
+    knew_ref,          # [B, 1, K*Kd] new-token key rows
+    vnew_ref,          # [B, 1, K*Vd]
+    ek_ref,            # [Kd, K*Kd] 0/1: a head's query tiled over the blocks
+    ev_ref,            # [K*Vd, Vd] 0/1: the blocks of an output row added up
+    *rest,             # [sink_ref [H, 1] f32,] then HBM inputs, outputs, scratch
     batch: int,
     page_size: int,
     pages_per_block: int,
     nbuf: int,
+    sink: bool = False,
     ablate: str = "",   # perf bisection: "nocompute" | "empty"
 ):
+    """Keys `Kd` and values `Vd` wide (equal in every model but one whose
+    keys are wider). The block-diagonal operand of the score dot is made
+    HERE, once a sequence, from the head's own [H, Kd] rows (a 0/1 matmul
+    and a mask: exact), and the output's diagonal blocks are added up here
+    too: at 256 sequences of 64 heads over 1,536-wide keys the expanded
+    queries would be 50 MB of VMEM, or of HBM traffic, a layer.
+
+    `start_ref` is a window: the work list of a sequence begins at the
+    page that holds its first attended position (its items count blocks
+    from THAT page) and the mask cuts inside it; pages before it are
+    never copied in (the engine has released them). `sink`: a learned
+    logit a head that joins the softmax's denominator when a sequence's
+    last item is done, so the weights sum to less than 1."""
+    if sink:
+        sink_ref, *rest = rest
+    (k_pages_hbm,      # [num_pages, page_size, K*Kd]
+     v_pages_hbm,      # [num_pages, page_size, K*Vd]
+     o_ref,            # [B, H, Vd] VMEM
+     ko_pages_hbm,     # aliased k_pages_hbm
+     vo_pages_hbm,
+     k_buf,            # [NBUF, ppb, page_size, K*Kd] VMEM
+     v_buf,            # [NBUF, ppb, page_size, K*Vd]
+     qb_buf,           # [H, K*Kd] VMEM: this sequence's block-diagonal queries
+     k_sems,           # DMA sems [NBUF]
+     v_sems,
+     w_sem,            # DMA sem for page write-backs
+     wb_pending,       # SMEM [NBUF]: write-back in flight from this slot
+     ) = rest
     t_blk = pages_per_block * page_size
-    h = qb_ref.shape[1]
-    kw = qb_ref.shape[2]
+    h, kd = q_ref.shape[1], q_ref.shape[2]
+    kw = knew_ref.shape[2]
+    vw = vnew_ref.shape[2]
+    kh = kw // kd
+    vd = vw // kh
+    g = h // kh
     n_work = n_work_ref[0]
+
+    def first_page(seq):
+        return jax.lax.div(start_ref[seq], page_size)
 
     def start_work_dma(w, slot):
         # the item's LIVE pages only (`live_pages`): a table entry past
         # the sequence's end names the trash page, and nothing reads it
         seq = work_seq_ref[w]
         blk = work_blk_ref[w]
-        n_live = live_pages(lengths_ref[seq], blk, page_size, pages_per_block)
+        page0 = first_page(seq)
+        n_live = live_pages(
+            lengths_ref[seq] - page0 * page_size, blk, page_size,
+            pages_per_block,
+        )
         for p in range(pages_per_block):
 
             @pl.when(p < n_live)
             def _start(p=p):
-                page_id = tables_ref[seq, blk * pages_per_block + p]
+                page_id = tables_ref[seq, page0 + blk * pages_per_block + p]
                 pltpu.make_async_copy(
                     k_pages_hbm.at[page_id], k_buf.at[slot, p],
                     k_sems.at[slot],
@@ -277,14 +315,31 @@ def _decode_kernel(
         blk = work_blk_ref[w]
         length = lengths_ref[seq]
         wpos = wpos_ref[seq]
+        start = start_ref[seq]
+        base = first_page(seq) * page_size   # position of the list's page 0
         slot = jax.lax.rem(w, nbuf)
-        n_live = live_pages(length, blk, page_size, pages_per_block)
+        n_live = live_pages(length - base, blk, page_size, pages_per_block)
 
         # fresh sequence: reset the flash state
         is_first = blk == 0
         m_prev = jnp.where(is_first, jnp.full_like(m_prev, _NEG_INF), m_prev)
         l_prev = jnp.where(is_first, jnp.zeros_like(l_prev), l_prev)
         acc = jnp.where(is_first, jnp.zeros_like(acc), acc)
+
+        @pl.when(is_first)
+        def _expand_queries():
+            # row r (a query head) carries its values in its kv head's
+            # column block, zeros elsewhere: one MXU dot then computes
+            # every head's scores with no cross-head leakage
+            tiled = jax.lax.dot_general(
+                q_ref[seq], ek_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                  # [H, K*Kd]
+            own = (
+                jax.lax.broadcasted_iota(jnp.int32, (h, kw), 1) // kd
+                == jax.lax.broadcasted_iota(jnp.int32, (h, kw), 0) // g
+            )
+            qb_buf[...] = jnp.where(own, tiled, 0.0).astype(qb_buf.dtype)
 
         def item(n, m_prev, l_prev, acc):
             # the whole item over its first `n` pages (static): fused
@@ -297,7 +352,7 @@ def _decode_kernel(
             t = n * page_size
             wait_work_dma(slot, n)
             kb = k_buf[slot, :n].reshape(t, kw)
-            vb = v_buf[slot, :n].reshape(t, kw)
+            vb = v_buf[slot, :n].reshape(t, vw)
             if ablate == "nocompute":
                 touch = jnp.sum(kb.astype(jnp.float32)) * 0.0
                 return m_prev, l_prev, acc + touch
@@ -308,18 +363,18 @@ def _decode_kernel(
             # block back and write just that page to HBM
             do_write = (
                 (wpos >= 0) & (wpos < length)
-                & (blk == jax.lax.div(wpos, t_blk))
+                & (blk == jax.lax.div(wpos - base, t_blk))
             )
-            row = jax.lax.broadcasted_iota(jnp.int32, (t, kw), 0)
-            off = wpos - blk * t_blk
-            inject = do_write & (row == off)
-            kb = jnp.where(inject, knew_ref[seq], kb)
-            vb = jnp.where(inject, vnew_ref[seq], vb)
+            off = wpos - base - blk * t_blk
+            krow = jax.lax.broadcasted_iota(jnp.int32, (t, kw), 0)
+            vrow = jax.lax.broadcasted_iota(jnp.int32, (t, vw), 0)
+            kb = jnp.where(do_write & (krow == off), knew_ref[seq], kb)
+            vb = jnp.where(do_write & (vrow == off), vnew_ref[seq], vb)
 
             @pl.when(do_write)
             def _store_back():
                 k_buf[slot, :n] = kb.reshape(n, page_size, kw)
-                v_buf[slot, :n] = vb.reshape(n, page_size, kw)
+                v_buf[slot, :n] = vb.reshape(n, page_size, vw)
                 p_local = jax.lax.div(off, page_size)
                 page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
                 pltpu.make_async_copy(
@@ -333,13 +388,15 @@ def _decode_kernel(
             # ONE MXU dot for all kv heads: qb rows are zero outside their
             # head's column block, so cross-head terms vanish
             s = jax.lax.dot_general(
-                qb_ref[seq].astype(jnp.float32), kb.astype(jnp.float32),
+                qb_buf[...].astype(jnp.float32), kb.astype(jnp.float32),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [H, t]
 
-            pos = blk * t_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(pos < length, s, _NEG_INF)
+            pos = base + blk * t_blk + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            s = jnp.where((pos < length) & (pos >= start), s, _NEG_INF)
 
             m_curr = jnp.max(s, axis=-1, keepdims=True)            # [H, 1]
             m_next = jnp.maximum(m_prev, m_curr)
@@ -348,7 +405,7 @@ def _decode_kernel(
             alpha = jnp.exp(m_prev - m_next)
             l_next = alpha * l_prev + l_curr
 
-            # ONE PV dot: [H, t] @ [t, K*Hd]; the caller keeps only each
+            # ONE PV dot: [H, t] @ [t, K*Vd]; the emit keeps only each
             # row's own head-column block
             o_curr = jax.lax.dot_general(
                 p_blk, vb.astype(jnp.float32),
@@ -362,12 +419,27 @@ def _decode_kernel(
         )
 
         # last block of this sequence: emit the normalized output
-        n_blocks = lax_cdiv(length, t_blk)
+        n_blocks = lax_cdiv(length - base, t_blk)
 
         @pl.when(blk == n_blocks - 1)
         def _emit():
-            o_ref[seq] = (
-                acc / jnp.maximum(l_prev, 1e-30)
+            l_fin, a_fin = l_prev, acc
+            if sink:
+                # the sink's column joins the denominator and is dropped
+                m_fin = jnp.maximum(m_prev, sink_ref[...])
+                beta = jnp.exp(m_prev - m_fin)
+                l_fin = l_prev * beta + jnp.exp(sink_ref[...] - m_fin)
+                a_fin = acc * beta
+            full = (a_fin / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+            own = (
+                jax.lax.broadcasted_iota(jnp.int32, (h, vw), 1) // vd
+                == jax.lax.broadcasted_iota(jnp.int32, (h, vw), 0) // g
+            )
+            # block-diagonal slice: row r keeps its own head's column block
+            o_ref[seq] = jax.lax.dot_general(
+                jnp.where(own, full, jnp.zeros_like(full)), ev_ref[...],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             ).astype(o_ref.dtype)
 
         # refill the ring with the work item NBUF ahead
@@ -382,7 +454,7 @@ def _decode_kernel(
 
     m0 = jnp.full((h, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((h, 1), jnp.float32)
-    a0 = jnp.zeros((h, kw), jnp.float32)
+    a0 = jnp.zeros((h, vw), jnp.float32)
     jax.lax.fori_loop(0, n_work, body, (m0, l0, a0))
     for j in range(nbuf):
         drain_wb(j)
@@ -804,6 +876,9 @@ def fused_paged_decode_attention(
     v_scales: jax.Array = None,
     new_ks: jax.Array = None,    # [B, SUBL] f32 new-row scale columns
     new_vs: jax.Array = None,
+    starts: jax.Array = None,    # [B] i32 first attended position (a
+    # window; None = every position). Unquantized pools only
+    sink: jax.Array = None,      # [H] f32 learned sink logits (the same)
     *,
     page_size: int,
     pages_per_block: int = PAGES_PER_BLOCK,
@@ -815,11 +890,13 @@ def fused_paged_decode_attention(
 ):
     """Flash paged decode attention fused with the KV-cache update.
 
-    Returns (out [B, H, Hd], k_cache, v_cache[, k_scales, v_scales]); the
+    Returns (out [B, H, Vd], k_cache, v_cache[, k_scales, v_scales]); the
     caches are updated in place (aliased) — the new token's row is
     injected into its page in VMEM and only that page is written back, so
     there is no XLA scatter anywhere on the decode path. With scale pools
-    the pages are int8 (`_decode_kernel_q`)."""
+    the pages are int8 (`_decode_kernel_q`). Unquantized pools may keep
+    values narrower than keys (`v_cache` rows K x Vd), a window start a
+    sequence and a sink logit a head (`_decode_kernel`)."""
     b, h, hd = q.shape
     quant = k_scales is not None
     # int32-PACKED pools (quant.pack_kv_slots layout): 4 token rows per
@@ -847,17 +924,18 @@ def fused_paged_decode_attention(
     max_blocks = block_tables.shape[1] // pages_per_block
 
     lengths = lengths.astype(jnp.int32)
-    work_seq, work_blk, n_work = work_list(lengths, t_blk, max_blocks)
+    if quant:
+        work_seq, work_blk, n_work = work_list(lengths, t_blk, max_blocks)
 
     # free bitcast: [N, K*Hd] row-major -> page-major view
     page_rows = page_size // 4 if packed else page_size
     k_pages = k_cache.reshape(num_pages, page_rows, kw)
-    v_pages = v_cache.reshape(num_pages, page_rows, kw)
     new_k = new_k.reshape(b, 1, kw)
-    new_v = new_v.reshape(b, 1, kw)
 
     scale = hd ** -0.5
     if quant:
+        v_pages = v_cache.reshape(num_pages, page_rows, kw)
+        new_v = new_v.reshape(b, 1, kw)
         # scale pools arrive page-blocked [P, SUBL, S]
         k_pages, v_pages, ks_pages, vs_pages = (
             in_hbm(p, interpret)
@@ -993,33 +1071,50 @@ def fused_paged_decode_attention(
             vs2,
         )
 
-    # block-diagonal queries [B, H, K*Hd]: row r (a query head) carries its
-    # values in its kv head's column block, zeros elsewhere — one MXU dot
-    # then computes every head's scores with no cross-head leakage
+    # the kernel makes the block-diagonal operand itself from the heads'
+    # own rows: a 0/1 matrix that tiles a row over the K column blocks
+    # (and one that adds an output row's blocks up), then a mask
+    assert starts is None or not quant
+    vw = v_cache.shape[1]
+    vd = vw // kh
+    v_pages = v_cache.reshape(num_pages, page_size, vw)
+    new_v = new_v.reshape(b, 1, vw)
     qs = (q * scale).astype(q.dtype)
-    q_tiled = jnp.tile(qs, (1, 1, kh))                       # [B, H, K*Hd]
-    col_head = (jnp.arange(kw, dtype=jnp.int32) // hd)[None, None, :]
-    row_head = (jnp.arange(h, dtype=jnp.int32) // g)[None, :, None]
-    qb = jnp.where(col_head == row_head, q_tiled, 0).astype(q.dtype)
+    ek = (jnp.arange(kw)[None, :] % hd == jnp.arange(hd)[:, None]).astype(
+        q.dtype
+    )                                                        # [Kd, K*Kd]
+    ev = (jnp.arange(vw)[:, None] % vd == jnp.arange(vd)[None, :]).astype(
+        q.dtype
+    )                                                        # [K*Vd, Vd]
+    if starts is None:
+        starts = jnp.zeros((b,), jnp.int32)
+        from_page0 = lengths
+    else:
+        starts = jnp.minimum(starts.astype(jnp.int32), lengths)
+        from_page0 = lengths - starts // page_size * page_size
+    work_seq, work_blk, n_work = work_list(from_page0, t_blk, max_blocks)
+    extra = () if sink is None else (
+        sink.astype(jnp.float32).reshape(h, 1),
+    )
 
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(1,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
+            *[vmem] * (5 + len(extra)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
+            vmem,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((nbuf, pages_per_block, page_size, kw), k_cache.dtype),
-            pltpu.VMEM((nbuf, pages_per_block, page_size, kw), v_cache.dtype),
+            pltpu.VMEM((nbuf, pages_per_block, page_size, vw), v_cache.dtype),
+            pltpu.VMEM((h, kw), q.dtype),
             pltpu.SemaphoreType.DMA((nbuf,)),
             pltpu.SemaphoreType.DMA((nbuf,)),
             pltpu.SemaphoreType.DMA,
@@ -1033,32 +1128,35 @@ def fused_paged_decode_attention(
         page_size=page_size,
         pages_per_block=pages_per_block,
         nbuf=nbuf,
+        sink=sink is not None,
         ablate=ablate,
     )
-    out_full, k2, v2 = pl.pallas_call(
+    n_in = 7 + 5 + len(extra)   # scalar prefetch, VMEM inputs, then pools
+    out, k2, v2 = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, kw), q.dtype),
+            jax.ShapeDtypeStruct((b, h, vd), q.dtype),
             jax.ShapeDtypeStruct(k_pages.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_cache.dtype),
         ],
-        # inputs: 0..5 = scalar prefetch, 6 = qb, 7/8 = new_k/new_v,
-        # 9/10 = k_pages/v_pages — aliased onto outputs 1/2 (skipped for
-        # read-only callers that keep using their input caches: aliasing
-        # would force XLA to defensively copy both pools)
-        input_output_aliases={9: 1, 10: 2} if alias_caches else {},
+        # the pools are aliased onto outputs 1/2 (skipped for read-only
+        # callers that keep using their input caches: aliasing would
+        # force XLA to defensively copy both pools)
+        input_output_aliases=(
+            {n_in: 1, n_in + 1: 2} if alias_caches else {}
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=DECODE_VMEM_LIMIT
+        ),
         interpret=interpret,
     )(lengths, block_tables.astype(jnp.int32), write_pos.astype(jnp.int32),
-      work_seq, work_blk, n_work[None], qb, new_k, new_v, k_pages, v_pages)
-
-    # block-diagonal slice: row r keeps its own head's column block
-    out = out_full.astype(jnp.float32).reshape(b, kh, g, kh, hd)
-    out = jnp.einsum("bkgkd->bkgd", out).reshape(b, h, hd).astype(q.dtype)
+      work_seq, work_blk, n_work[None], starts, qs, new_k, new_v, ek, ev,
+      *extra, k_pages, v_pages)
     return (
         out,
         k2.reshape(num_slots, kw),
-        v2.reshape(num_slots, kw),
+        v2.reshape(num_slots, vw),
     )
 
 
@@ -1089,7 +1187,7 @@ def paged_decode_attention(
     res = fused_paged_decode_attention(
         q,
         jnp.zeros((b, kw), row_dtype),
-        jnp.zeros((b, kw), row_dtype),
+        jnp.zeros((b, v_cache.shape[1]), row_dtype),
         k_cache,
         v_cache,
         block_tables,

@@ -88,7 +88,8 @@ def paged_kv_write(
     num_pages = num_slots // page_size
     n = page_table.shape[0]
     kp = k_cache.reshape(num_pages, page_rows, kw)
-    vp = v_cache.reshape(num_pages, page_rows, kw)
+    if quant:
+        vp = v_cache.reshape(num_pages, page_rows, kw)
 
     def dst(i, tbl):
         return (tbl[i], 0, 0)
@@ -139,6 +140,8 @@ def paged_kv_write(
             ovs,
         )
 
+    vw = v_cache.shape[1]   # values may be narrower than keys
+    vp = v_cache.reshape(num_pages, page_rows, vw)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
@@ -146,11 +149,11 @@ def paged_kv_write(
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, page_size, kw), src),
-            pl.BlockSpec((1, page_size, kw), src),
+            pl.BlockSpec((1, page_size, vw), src),
         ],
         out_specs=[
             pl.BlockSpec((1, page_size, kw), dst),
-            pl.BlockSpec((1, page_size, kw), dst),
+            pl.BlockSpec((1, page_size, vw), dst),
         ],
     )
     ok, ov = pl.pallas_call(
@@ -166,4 +169,4 @@ def paged_kv_write(
         ),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kp, vp, new_k, new_v)
-    return ok.reshape(num_slots, kw), ov.reshape(num_slots, kw)
+    return ok.reshape(num_slots, kw), ov.reshape(num_slots, vw)

@@ -62,7 +62,15 @@ def _kernel(
     subl: int = 0,
     packed: bool = False,
     int4: bool = False,
+    vd: int = 0,
+    window: int = 0,
+    sink: bool = False,
 ):
+    vd = vd or hd   # values may be narrower than keys (unquantized pools)
+    if sink:
+        # [T_TILE*G, KH] f32: row r of kv head k holds the sink logit of
+        # query head k*G + r % G
+        sink_ref, *page_refs = page_refs
     k_refs = page_refs[:ppb]
     v_refs = page_refs[ppb:2 * ppb]
     off = 2 * ppb
@@ -119,9 +127,19 @@ def _kernel(
     ) // g
     k_pos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, (tg, blk), 1)
     valid = (k_pos <= q_pos) & (q_pos < pos0 + tlen)  # [TG, BLK]
+    in_reach = kb * blk <= pos0 + (tt + 1) * t_tile - 1
+    if window:
+        # a window: a query sees the `window` positions up to its own;
+        # page-blocks wholly behind the tile's first query's window are
+        # skipped like those above the causal line (their table entries
+        # may name pages the engine has released)
+        valid = valid & (k_pos > q_pos - window)
+        in_reach = in_reach & (
+            (kb + 1) * blk - 1 > pos0 + tt * t_tile - window
+        )
 
     # skip page-blocks entirely above the tile's causal line
-    @pl.when(kb * blk <= pos0 + (tt + 1) * t_tile - 1)
+    @pl.when(in_reach)
     def _work():
         if packed:
             # int32-packed pages (quant.pack_kv_slots): bitcast each
@@ -170,7 +188,7 @@ def _kernel(
             p = jnp.where(valid, p, 0.0)
             l_ref[:, k] = l_ref[:, k] * alpha + jnp.sum(p, axis=1)
             m_ref[:, k] = m_new
-            pv = jnp.zeros((tg, hd), jnp.float32)
+            pv = jnp.zeros((tg, vd), jnp.float32)
             for j in range(ppb):
                 p_j = p[:, j * page:(j + 1) * page]
                 if quant:
@@ -200,7 +218,7 @@ def _kernel(
                     if packed:
                         v_j = vbs[j][:, k * hd:(k + 1) * hd]   # [page, Hd]
                     else:
-                        v_j = v_refs[j][0, :, k * hd:(k + 1) * hd]
+                        v_j = v_refs[j][0, :, k * vd:(k + 1) * vd]
                     pv = pv + jax.lax.dot_general(
                         p_j, v_j.astype(jnp.float32),
                         (((1,), (0,)), ((), ())),
@@ -211,14 +229,23 @@ def _kernel(
     @pl.when(kb == wb - 1)
     def _emit():
         for k in range(kh):
-            denom = jnp.maximum(l_ref[:, k], 1e-30)
-            o_ref[0, k] = (acc_ref[k] / denom[:, None]).astype(o_ref.dtype)
+            l_fin, a_fin = l_ref[:, k], acc_ref[k]
+            if sink:
+                # the sink's column joins the denominator and is dropped
+                m_k, s_k = m_ref[:, k], sink_ref[:, k]
+                m_fin = jnp.maximum(m_k, s_k)
+                beta = jnp.exp(m_k - m_fin)
+                l_fin = l_fin * beta + jnp.exp(s_k - m_fin)
+                a_fin = a_fin * beta[:, None]
+            denom = jnp.maximum(l_fin, 1e-30)
+            o_ref[0, k] = (a_fin / denom[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "page_size", "t_tile", "pages_per_block", "interpret", "int4"
+        "page_size", "t_tile", "pages_per_block", "interpret", "int4",
+        "window",
     ),
 )
 def flash_prefill_attention(
@@ -234,15 +261,18 @@ def flash_prefill_attention(
     k_scales: jax.Array = None,  # [num_pages, SUBL, page_size] f32 scale
     # pools (ops/quant pool layout; SUBL >= 8, tokens in lanes)
     v_scales: jax.Array = None,
+    sink: jax.Array = None,   # [H] f32 learned sink logits (None = none)
     *,
     page_size: int,
     t_tile: int = 128,
     pages_per_block: int = 4,
     interpret: bool = False,
     int4: bool = False,
+    window: int = 0,          # tokens a query sees, itself included (0 = all)
 ) -> jax.Array:
     """Causal chunked-prefill attention over gathered pages; rows past
-    t_valid produce zeros. Returns [B, T, H, Hd] in q.dtype. With scale
+    t_valid produce zeros. Returns [B, T, H, Vd] in q.dtype (Vd = Hd
+    unless the unquantized value pool is narrower than the key pool). With scale
     pools the pages hold per-token-per-kv-head int8; scale blocks ride
     the same page routing and dequantization happens per head slice in
     VMEM (VPU-cheap next to the halved page DMA traffic).
@@ -263,6 +293,8 @@ def flash_prefill_attention(
     # cannot be derived from kw alone — hence the explicit static flag
     kh = (2 * kw if int4 else kw) // hd
     g = h // kh
+    vw = v_cache.shape[1]
+    vd = hd if quant else vw // kh
     if int4:
         assert quant, "int4 pools require scale pools"
     ppb = pages_per_block
@@ -273,12 +305,12 @@ def flash_prefill_attention(
         # scratch; Mosaic's scoped-VMEM stack is ~16 MB — 8B-class dims
         # blow it at the default tile, so shrink until it fits
         tg_ = tt * g
-        qo = 2 * 2 * kh * tg_ * hd * q.dtype.itemsize
-        pages = 2 * 2 * ppb * page_rows * kw * k_cache.dtype.itemsize
+        qo = 2 * kh * tg_ * (hd + vd) * q.dtype.itemsize
+        pages = 2 * ppb * page_rows * (kw + vw) * k_cache.dtype.itemsize
         if quant:
             pages += 2 * 2 * ppb * k_scales.shape[1] * page_size * 4
         scratch = (
-            kh * tg_ * hd * 4            # acc
+            kh * tg_ * vd * 4            # acc
             + tg_ * ppb * page_size * 4  # s
             + 2 * tg_ * kh * 4           # m, l
         )
@@ -304,7 +336,7 @@ def flash_prefill_attention(
         block_tables = jnp.pad(block_tables, ((0, 0), (0, wp - w)))
     num_pages = num_slots // page_size
     k_pages = k_cache.reshape(num_pages, page_rows, kw)
-    v_pages = v_cache.reshape(num_pages, page_rows, kw)
+    v_pages = v_cache.reshape(num_pages, page_rows, vw)
     tg = t_tile * g
     wb = wp // ppb
 
@@ -335,6 +367,14 @@ def flash_prefill_attention(
 
         scale_specs = [scale_spec(j) for j in range(ppb)] * 2
 
+    sink_inputs, sink_specs = [], []
+    if sink is not None:
+        # row r of a tile is query head k*G + r % G of kv head k
+        sink_inputs = [jnp.tile(
+            sink.astype(jnp.float32).reshape(kh, g).T, (t_tile, 1)
+        )]                                                    # [TG, KH]
+        sink_specs = [pl.BlockSpec((tg, kh), lambda *_: (0, 0))]
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, t_pad // t_tile, wb),
@@ -342,17 +382,18 @@ def flash_prefill_attention(
             pl.BlockSpec(
                 (1, kh, tg, hd), lambda bb, tt, kb, *_: (bb, 0, tt, 0)
             ),
+            *sink_specs,
             *[page_spec(j, kw) for j in range(ppb)],
-            *[page_spec(j, kw) for j in range(ppb)],
+            *[page_spec(j, vw) for j in range(ppb)],
             *scale_specs,
         ],
         out_specs=pl.BlockSpec(
-            (1, kh, tg, hd), lambda bb, tt, kb, *_: (bb, 0, tt, 0)
+            (1, kh, tg, vd), lambda bb, tt, kb, *_: (bb, 0, tt, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((tg, kh), jnp.float32),
             pltpu.VMEM((tg, kh), jnp.float32),
-            pltpu.VMEM((kh, tg, hd), jnp.float32),
+            pltpu.VMEM((kh, tg, vd), jnp.float32),
             pltpu.VMEM((tg, ppb * page_size), jnp.float32),
         ],
     )
@@ -360,10 +401,10 @@ def flash_prefill_attention(
         functools.partial(
             _kernel, t_tile=t_tile, page=page_size, kh=kh, g=g, hd=hd,
             wb=wb, ppb=ppb, quant=quant, subl=subl, packed=packed,
-            int4=int4,
+            int4=int4, vd=vd, window=window, sink=sink is not None,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, t_pad * g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kh, t_pad * g, vd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
@@ -373,10 +414,11 @@ def flash_prefill_attention(
         pos0.astype(jnp.int32),
         t_valid.astype(jnp.int32),
         qk,
+        *sink_inputs,
         *[k_pages] * ppb,
         *[v_pages] * ppb,
         *scale_inputs,
     )
     # [B, KH, T*G, Hd] -> [B, T, H, Hd]
-    out = out.reshape(b, kh, t_pad, g, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(b, t_pad, h, hd)[:, :t]
+    out = out.reshape(b, kh, t_pad, g, vd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, t_pad, h, vd)[:, :t]

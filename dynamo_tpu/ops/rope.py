@@ -17,13 +17,17 @@ import numpy as np
 from dynamo_tpu.models.config import ModelConfig
 
 
-def rope_inv_freq(cfg: ModelConfig) -> np.ndarray:
+def rope_inv_freq(cfg: ModelConfig, theta: float | None = None) -> np.ndarray:
     """Per-pair inverse frequencies [rope width // 2] (the rope width is
-    `qk_rope_head_dim` under latent attention, else `head_dim`), with
+    `qk_rope_head_dim` under latent attention, `rotary_dim` where only
+    the head's leading dimensions rotate, else `head_dim`), with
     optional llama3 NTK-by-parts scaling (matches HF
-    `Llama3RotaryEmbedding`) or YaRN (`yarn_inv_freq`)."""
-    half = (cfg.qk_rope_head_dim or cfg.head_dim) // 2
-    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, half, dtype=np.float64) / half))
+    `Llama3RotaryEmbedding`) or YaRN (`yarn_inv_freq`). `theta`: the
+    base of one kind of layer where the kinds differ (default
+    `rope_theta`)."""
+    half = (cfg.qk_rope_head_dim or cfg.rotary_dim or cfg.head_dim) // 2
+    theta = cfg.rope_theta if theta is None else theta
+    inv = 1.0 / (theta ** (np.arange(0, half, dtype=np.float64) / half))
     sc = cfg.rope_scaling
     if sc and sc.get("type", sc.get("rope_type")) == "yarn":
         return yarn_inv_freq(inv, cfg.rope_theta, sc).astype(np.float32)
@@ -117,6 +121,13 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     the whole rotation into a single pass over x — the full-width
     intermediate materialized f32 copies of every q/k tensor."""
     orig_dtype = x.dtype
+    rot = cos.shape[-1]
+    if rot < x.shape[-1]:
+        # partial rotary: the leading `rot` dimensions rotate (rotate-half
+        # over those), the rest pass through
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1
+        )
     half = x.shape[-1] // 2
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)

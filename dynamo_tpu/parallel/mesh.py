@@ -153,6 +153,8 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
                 "we_up": ns("ep", None, "tp"),
                 "we_down": ns("ep", "tp", None),
             })
+            if cfg.router_bias:
+                lp["router_bias"] = ns()
             if cfg.num_shared_experts:
                 lp.update({
                     "ws_gate": ns(None, "tp"),
@@ -165,6 +167,8 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
                 "w_up": ns(None, "tp"),
                 "w_down": ns("tp", None),
             })
+        if cfg.attn_kind(cfg.layer_kind(i)).sink:
+            lp["sink"] = ns()
         if cfg.attn_bias:
             lp["bq"] = ns("tp")
             lp["bk"] = ns("tp")

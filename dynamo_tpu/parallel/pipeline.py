@@ -168,7 +168,7 @@ def pp_forward(
                     lp, cfg, x, cos1, sin1, kvk, kvv,
                     ws1.reshape(-1), llama.AttnSpec.gather(sm1), pos1,
                     tp_axis="tp", tp_overlap=overlap,
-                    bt_shape=(mb, t) if overlap else None,
+                    bt_shape=(mb, t) if overlap else None, layer=0,
                 )
                 return x, (kvk, kvv)
 

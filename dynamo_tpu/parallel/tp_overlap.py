@@ -410,12 +410,12 @@ def single_layer_executor(
             xs = scatter_rows(pad_rows(x.reshape(b * t, -1), tp), "tp")
             xs, kv_k, kv_v, _, _ = llama.layer_step(
                 lp, cfg, xs, cos, sin, kv_k, kv_v, ws, attn, pos,
-                tp_axis="tp", tp_overlap=True, bt_shape=(b, t),
+                tp_axis="tp", tp_overlap=True, bt_shape=(b, t), layer=0,
             )
         else:
             xs, kv_k, kv_v, _, _ = llama.layer_step(
                 lp, cfg, x, cos, sin, kv_k, kv_v, ws, attn, pos,
-                tp_axis="tp",
+                tp_axis="tp", layer=0,
             )
         return xs, kv_k, kv_v
 
@@ -530,7 +530,7 @@ def tp_overlap_forward(
                 ws, local, pos,
                 kv_ks=ks_pools[i] if quantized else None,
                 kv_vs=vs_pools[i] if quantized else None,
-                tp_axis="tp", tp_overlap=True, bt_shape=(b, t),
+                tp_axis="tp", tp_overlap=True, bt_shape=(b, t), layer=i,
             )
             new_k.append(kp)
             new_v.append(vp)

@@ -252,3 +252,71 @@ def test_mixtral_weight_loading(tmp_path):
             np.asarray(loaded["layers"][0][key]), np.asarray(lp[key]),
             rtol=1e-6, atol=1e-6,
         )
+
+
+# ------------------- a layer that holds a share; every other preset unmoved
+
+# sha256 (first 16 hex) over the layer's output, the router's weights and
+# indices and every parameter, and the layer's load, computed with the
+# tree BEFORE `experts_held` / the sigmoid router existed (PR 35): the
+# softmax / all-held presets must not move by a bit
+ALL_HELD_GOLDEN = {
+    "tiny-moe": ("b6aaaf5b13a6f696", [4, 8]),
+    "tiny-mla": ("09c3922492ceda88", [8, 4]),
+    "mixtral-8x7b": ("2a07021484d6a4aa", [8, 4]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ALL_HELD_GOLDEN))
+def test_all_held_softmax_presets_are_bit_equal_to_before(name):
+    import hashlib
+
+    from dynamo_tpu.models.config import PRESETS
+    from dynamo_tpu.models.moe import init_moe_params, moe_block, route
+
+    cfg = PRESETS[name]
+    if name == "mixtral-8x7b":   # its router and counts, at a CPU's width
+        cfg = cfg.with_(hidden_size=64, intermediate_size=128)
+    assert cfg.held_experts == cfg.num_experts and cfg.expert_offset == 0
+    lp = init_moe_params(cfg, jax.random.PRNGKey(11), dtype=jnp.float32)
+    x = jax.random.normal(
+        jax.random.PRNGKey(12), (2, 7, cfg.hidden_size), jnp.float32)
+    mask = jnp.ones((2, 7), bool).at[1, 5:].set(False)
+    stats = []
+    y = moe_block(lp, cfg, x, real_mask=mask, stats=stats)
+    w, i = route(lp, cfg, x.reshape(-1, cfg.hidden_size))
+    h = hashlib.sha256()
+    for a in (y, w, i, *[v for _, v in sorted(lp.items())]):
+        h.update(np.asarray(a).tobytes())
+    assert (h.hexdigest()[:16], [int(s) for s in stats[0]]) == (
+        ALL_HELD_GOLDEN[name])
+
+
+def test_a_share_computes_only_its_own_experts():
+    """8 experts scored, 3 held from 2: pairs routed elsewhere are the
+    sentinel's (computed by nobody), the load counts held experts only,
+    and the output is exactly the held experts' part of the whole sum."""
+    from dynamo_tpu.models.config import PRESETS
+    from dynamo_tpu.models.moe import init_moe_params, moe_block, route
+
+    whole_cfg = PRESETS["tiny-mimo"].with_(experts_held=8)
+    cfg = whole_cfg.with_(experts_held=3, expert_offset=2)
+    key = jax.random.PRNGKey(4)
+    whole = init_moe_params(whole_cfg, key, dtype=jnp.float32)
+    lp = init_moe_params(cfg, key, dtype=jnp.float32)
+    assert lp["router"].shape[1] == 8 and lp["we_up"].shape[0] == 3
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 40, cfg.hidden_size))
+    stats = []
+    got = moe_block(lp, cfg, x, stats=stats)
+    w, idx = route(lp, cfg, x.reshape(40, -1))
+    held = (idx >= 2) & (idx < 5)
+    assert int(stats[0][0]) == len(np.unique(np.asarray(idx)[held]))
+    assert int(stats[0][1]) == np.bincount(
+        np.asarray(idx)[held], minlength=8).max()
+    xf = x.reshape(40, -1)
+    want = jnp.zeros_like(xf)
+    for e in range(2, 5):
+        y = (jax.nn.silu(xf @ whole["we_gate"][e]) * (xf @ whole["we_up"][e])
+             ) @ whole["we_down"][e]
+        want = want + y * jnp.sum(jnp.where(idx == e, w, 0.0), 1)[:, None]
+    np.testing.assert_allclose(got[0], want, atol=1e-5)

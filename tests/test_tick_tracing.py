@@ -178,7 +178,9 @@ async def test_decode_digests_count_the_kv_pages(tmp_path):
     layer's kernel copies in over the dispatch's steps beside the pages
     its rows hold; the two are equal (the kernel reads what a sequence
     holds), 0 on every other row, and 0 where the gather path serves."""
-    assert flightmod.FIELDS[-6:-4] == ("kv_pages_streamed", "kv_pages_held")
+    # columns are only ever appended: PR 36's five follow the six of which
+    # these were the first two
+    assert flightmod.FIELDS[-11:-9] == ("kv_pages_streamed", "kv_pages_held")
     engine = make_engine(attn_backend="pallas")
     ps, steps = engine.page_size, engine.config.decode_steps
     # one request alone: its first decode dispatch attends 4, 5, ...

@@ -437,3 +437,117 @@ def test_latent_prefill_layer_compiles(one_chip, no_persistent_cache,
     ).compile()
     # a page write a layer + three grouped matmuls
     _assert_kernel(compiled, at_least=5)
+
+
+# --------------------------------- window beside full attention (MiMo-V2)
+
+
+def _hybrid_cfg():
+    """MiMo-V2-Flash at published widths: the dense full-attention layer,
+    a window layer and a full layer, 16 of 256 experts held."""
+    return PRESETS["mimo-v2-flash"].with_(
+        num_layers=3, layer_kinds=(0, 1, 0), experts_held=16,
+        vocab_size=19072,
+    )
+
+
+def _hybrid_shapes(cfg, page, num_pages, win_pages):
+    params = jax.eval_shape(
+        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0))
+    kv = jax.eval_shape(functools.partial(
+        llama.init_kv_cache, cfg, num_pages * page, page_size=page,
+        win_slots=win_pages * page,
+    ))
+    return params, kv
+
+
+def test_hybrid_decode_scan_keeps_both_kinds_of_pool_in_hbm(
+        one_chip, no_persistent_cache):
+    """The `reason-wide` cell's decode dispatch (width 256, pages of 128,
+    bf16) through a two-step scan: ONE decode kernel serves the full kind
+    (K pool 768 lanes, V pool 512) and the window kind (1,536 / 1,024, a
+    window start a row, a sink a head), 64 heads over 192-wide keys at 256
+    rows fit its VMEM, the held experts' grouped matmuls compile, and no
+    pool of either kind is copied or prefetched anywhere in the step."""
+    cfg = _hybrid_cfg()
+    page, num_pages, win_pages, width, max_len = 128, 2048, 1025, 256, 4096
+    params, kv = _hybrid_shapes(cfg, page, num_pages, win_pages)
+    assert [p.shape for p in kv.k] == [
+        (num_pages * page, 768), (win_pages * page, 1536),
+        (num_pages * page, 768)]
+    assert [p.shape[1] for p in kv.v] == [512, 1024, 512]
+    assert params["layers"][1]["we_gate"].shape == (16, 4096, 2048)
+    assert params["layers"][1]["router"].shape == (4096, 256)
+
+    def dispatch(params, kv, tokens, positions, tables, win_tables):
+        def body(carry, _):
+            tokens, positions, kv = carry
+            attn = llama.AttnSpec.pallas_decode(
+                tables, positions + 1, page, write_pos=positions)
+            attn.win = llama.AttnSpec.pallas_decode(
+                win_tables, positions + 1, page, write_pos=positions)
+            hidden, kv = llama.forward(
+                params, cfg, tokens[:, None], positions[:, None], kv,
+                jnp.zeros_like(positions), attn)
+            lg = llama.logits(params, cfg, hidden[:, 0])
+            return (jnp.argmax(lg, -1).astype(jnp.int32), positions + 1,
+                    kv), None
+
+        (tokens, _, kv), _ = jax.lax.scan(
+            body, (tokens, positions, kv), None, length=2)
+        return tokens, kv
+
+    compiled = jax.jit(dispatch, donate_argnums=(1,)).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((width,), one_chip), _i32((width,), one_chip),
+        _i32((width, max_len // page), one_chip),
+        _i32((width, max_len // page), one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # a decode kernel a layer + three grouped matmuls in two layers
+    assert text.count("tpu_custom_call") >= 9
+    assert "conditional(" not in text
+    pool = re.compile(
+        r"= bf16\[\d+,(?:128,)?(?:768|512|1536|1024)\]\S* "
+        r"(copy|copy-start|slice-start)\(")
+    # a pool by its rows (slots, or pages once reshaped): the weights are
+    # 4,096 rows of the same widths
+    rows = {num_pages * page, win_pages * page, num_pages, win_pages}
+    moved = [ln.strip()[:160] for ln in text.splitlines() if pool.search(ln)
+             and int(re.search(r"bf16\[(\d+),", ln).group(1)) in rows]
+    assert not moved, "KV pools moved inside the step:\n" + "\n".join(moved)
+
+
+@pytest.mark.parametrize("rows,bucket,wb", [(1, 128, 1), (1, 512, 32),
+                                            (4, 128, 8)],
+                         ids=["n1-t128", "n1-t512-w32", "n4-t128-w8"])
+def test_hybrid_prefill_layers_compile(one_chip, no_persistent_cache,
+                                       rows, bucket, wb):
+    """Page writer + flash prefill of both kinds (192-wide keys sliced a
+    head at a time out of 768- and 1,536-lane pages, 128-wide values, the
+    window mask and the sink), at published widths."""
+    cfg = _hybrid_cfg()
+    page = 128
+    params, kv = _hybrid_shapes(cfg, page, 512, 256)
+
+    def step(params, kv, tokens, positions, wtables, btables, last_idx):
+        def spec():
+            return llama.AttnSpec.gather(
+                None, write_tables=wtables, page_size=page,
+                block_tables=btables, q_pos0=positions[:, 0],
+                lengths=last_idx + 1)
+
+        attn = spec()
+        attn.win = spec()
+        return llama.forward(
+            params, cfg, tokens, positions, kv,
+            jnp.zeros((rows * bucket,), jnp.int32), attn)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((rows, bucket), one_chip), _i32((rows, bucket), one_chip),
+        _i32((rows * (bucket // page),), one_chip),
+        _i32((rows, wb), one_chip), _i32((rows,), one_chip),
+    ).compile()
+    # a page write and a flash prefill a layer + three grouped matmuls x 2
+    _assert_kernel(compiled, at_least=12)
