@@ -3570,7 +3570,7 @@ class JaxEngine:
             kv_pages_held=rec.get("kv_pages_held", 0),
             **{k: rec[k] for k in (
                 "kv_pages_held_full", "kv_win_pages_held",
-                "kv_win_pages_released",
+                "kv_win_pages_released", "kv_win_items",
             ) if k in rec},
         )
         if tracing.enabled():
@@ -4977,12 +4977,13 @@ class JaxEngine:
                     rec["kv_pages_held"] if self._attn_pallas
                     else self._kv_pages(bld)[1]
                 )
-                streamed, held = self._kv_window_pages(bld)
+                streamed, items, held = self._kv_window_pages(bld)
                 rec["kv_win_pages_held"] = held
                 rec["kv_win_pages_released"] = self._win_released
                 if self._attn_pallas:
                     rec["kv_pages_streamed"] += streamed
                     rec["kv_pages_held"] += held
+                    rec["kv_win_items"] = items
         rec["build_s"] = bld.build_s
         wd = self._op_begin("spec.dispatch" if bld.spec else "decode.dispatch")
         try:
@@ -5019,20 +5020,25 @@ class JaxEngine:
             self.config.max_model_len,
         )
 
-    def _kv_window_pages(self, bld: "_DecodeBuild") -> tuple[int, int]:
+    def _kv_window_pages(self, bld: "_DecodeBuild") -> tuple[int, int, int]:
         """A hybrid model's window kind, for the same rows and steps:
         (pages ONE window layer's kernel copies in, by the rule its work
-        list is built to, from the window starts `_attn_block` hands it;
-        pages the rows hold in the window pool, over the steps)."""
-        from dynamo_tpu.ops.pallas_attention import streamed_pages
+        list is built to, from the static window `_attn_block` hands it;
+        that kernel's work items where an item holds several sequences'
+        windows, 0 where the window is too long for one; pages the rows
+        hold in the window pool, over the steps)."""
+        from dynamo_tpu.ops import pallas_attention as pa
 
         lengths = self._attended_lengths(bld)
-        starts = np.maximum(lengths - self.model_cfg.sliding_window, 0)
+        window, ps = self.model_cfg.sliding_window, self.page_size
+        items = 0
+        if pa.window_grouped(window, ps, pa.PAGES_PER_BLOCK):
+            items = int(np.sum(pa.window_items(lengths, xp=np)))
         held = sum(
             len(s.win_page_ids) - s.win_first for _, s in bld.active
         )
         return (
-            streamed_pages(lengths, self.page_size, starts=starts),
+            pa.streamed_pages(lengths, ps, window=window), items,
             held * bld.steps,
         )
 

@@ -97,6 +97,9 @@ FIELDS = (
     "kv_pages_held_full",
     "kv_win_pages_held",
     "kv_win_pages_released",
+    # work items of ONE window layer's decode kernel over the dispatch's
+    # steps (pallas decode rows; rows x steps / this = sequences an item)
+    "kv_win_items",
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

@@ -523,12 +523,10 @@ def _attn_block(
             page_size=attn.page_size,
             interpret=attn.interpret,
             int4=int4,
-            # the last attended position is `lengths - 1`: a window
-            # starts `window` positions at or before it
-            starts=(
-                jnp.maximum(attn.lengths - spec.window, 0) if spec.window
-                else None
-            ),
+            # the kind's STATIC window (the last attended position is
+            # `lengths - 1`; the kernel makes the starts): short enough,
+            # it takes the work item that holds several sequences
+            window=spec.window,
             sink=sink,
         )
         new_k = k[:, 0].reshape(b, kh * hd)

@@ -31,6 +31,20 @@ KV page exactly once. Design points (measured on v5e):
   benchmark's decode-saturate contexts (PERF.md section 6, PR 32).
   `streamed_pages` is the same rule on the host: the engine's digests
   and the benchmark's `decode_kv_read_amp` count by it.
+- **a window layer's work item holds several sequences**
+  (`_decode_window_kernel`, taken when the layer kind's static window
+  fits one item: `window_grouped`). A window of `window_pages` pages is
+  first, last and writing item at once: it needs no carried (m, l, acc),
+  no rescale and no branch a page count, so its body is a sibling, not a
+  branch of the long-context one. `WINDOW_GROUP` live sequences share a
+  loop iteration: one expand dot and one emit dot for all, score and PV
+  dots batched over the sequence axis (no cross-sequence products), and
+  the fused write sends back `WRITE_BACK_ROWS` rows, not a page. Measured
+  (PERF.md section 6, PR 37): such an item was never bound by its chain
+  of dots but by its bytes, two whole pages in and one whole page out for
+  one new row; the slab took a third of them away, the group a few
+  percent more. The ring's helpers (`_PageRing`), `live_pages`, the sink
+  and the float32 dots are shared with the per-sequence item.
 - **fused cache write**: XLA lowers `pool.at[slots].set(rows)` to a
   scatter the TPU backend serializes (~20 us/row); instead the kernel
   injects the new token's K/V into its page while that page sits in VMEM
@@ -113,6 +127,20 @@ def hbm_out(pool: jax.Array):
 # are all slower.
 PAGES_PER_BLOCK = 4
 NBUF = 4
+# sequences a WINDOW layer's work item holds (`_decode_window_kernel`),
+# and rows of a page its fused write merges and sends back: the aligned
+# slab around the new token's row, one packed bf16 tile (the whole page
+# where pages are shorter). Timed alone on the v5e at the reason-wide
+# cell's shape (scripts/hybrid_kernel_tpu.py: 5 window layers, 192 rows;
+# ~1.3 ms of every reading is launch and operand copies; PERF.md section
+# 6, PR 37): the per-sequence list 4.30 ms; this item with a whole page
+# sent back 3.95 / 3.91 / 3.88 / 3.92 at 1 / 2 / 4 / 8 sequences, with the
+# slab 3.08 / 3.01 / 3.01 / 3.06 (ring 2 or 4 deep: the same), its copies
+# and waits alone 2.83. The item is bound by the pages it moves, so the
+# slab is the gain and the group a small one: 4 fills the MXU's rows in
+# the two shared dots, 8 needs a ring of 2 to fit VMEM and is no faster.
+WINDOW_GROUP = 4
+WRITE_BACK_ROWS = 16
 # scoped VMEM the unquantized decode kernel may take (the v5e has 128 MiB):
 # at 256 sequences of 64 heads the queries, the output and the two page
 # rings of a 1,536 / 1,024-wide layer pass the 16 MiB default together
@@ -163,20 +191,74 @@ def work_list(lengths: jax.Array, t_blk: int, max_blocks: int):
     return work_seq, work_blk, n_work
 
 
+def window_pages(window: int, page_size: int) -> int:
+    """Pages a window of `window` positions can touch: it may begin
+    anywhere in its first page, so one more than it fills."""
+    return -(-window // page_size) + 1
+
+
+def window_grouped(window: int, page_size: int, pages_per_block: int) -> bool:
+    """Whether a layer with this (static) window takes the grouped work
+    item (`_decode_window_kernel`): a window that fits one item of the
+    per-sequence list needs none of that list's carried state. The ONE
+    rule of the path, decided from shapes alone: the kernel's wrapper,
+    `streamed_pages` and the engine's digest all ask it."""
+    return bool(window) and window_pages(window, page_size) <= pages_per_block
+
+
+def window_items(lengths, group: int = WINDOW_GROUP, xp=jnp):
+    """Work items of ONE window layer's grouped kernel for a call's
+    attended lengths (last axis: the call's rows, 0 = a row with no
+    work): its LIVE rows `group` at a time, the last item partial.
+    `window_work_list` walks this many; the engine books it per decode
+    dispatch (digest column `kv_win_items`, `xp=np`)."""
+    live = xp.sum(lengths > 0, axis=-1)
+    return (live + (group - 1)) // group
+
+
+def window_work_list(lengths: jax.Array, group: int):
+    """The grouped kernel's work list: the live rows compacted in row
+    order (padding rows of a wide decode program cost nothing), item `w`
+    holding entries [w * group, (w + 1) * group). Entries past the last
+    live row repeat it: every item copies and computes `group` whole
+    windows, and a repeat writes nothing, neither page nor output.
+    Returns (rows [ceil(B / group) * group], n [2] = items, live rows)."""
+    b = lengths.shape[0]
+    live = lengths > 0
+    n_live = jnp.sum(live).astype(jnp.int32)
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    idx = jnp.arange(-(-b // group) * group, dtype=jnp.int32)
+    rows = order[jnp.minimum(idx, jnp.maximum(n_live - 1, 0))]
+    return rows, jnp.stack([window_items(lengths, group), n_live]).astype(
+        jnp.int32)
+
+
 def streamed_pages(lengths, page_size: int,
-                   pages_per_block: int = PAGES_PER_BLOCK, starts=None) -> int:
+                   pages_per_block: int = PAGES_PER_BLOCK, starts=None,
+                   window: int = 0, group: int = WINDOW_GROUP) -> int:
     """KV pages ONE layer's decode kernel copies in for these attended
     lengths (any shape; 0 = a row with no work): the work list's
     `ceil(length / block)` items a sequence, each its `live_pages`; with
     `starts` (a window's first attended positions, the same shape) the
-    list begins at the page that holds the start, as the kernel's does. The
+    list begins at the page that holds the start, as the kernel's does.
+    With a static `window` that takes the grouped item (`window_grouped`;
+    lengths [..., rows], a call a row of the leading axes) it is that
+    item's rule: `window_pages` copies for each of an item's `group`
+    entries, a dead page slot and a partial item's repeats included. The
     engine books it per decode dispatch beside the pages held (digest
     columns `kv_pages_streamed` / `kv_pages_held`), so a change of page
     size or block that makes the kernel read past a sequence's end shows
     as a ratio above 1. Host-side numpy; exact on any backend."""
+    if window_grouped(window, page_size, pages_per_block):
+        items = window_items(np.asarray(lengths, np.int64), group, xp=np)
+        return int(np.sum(items)) * group * window_pages(window, page_size)
     lengths = np.asarray(lengths, np.int64).ravel()
     if starts is not None:
-        starts = np.minimum(np.asarray(starts, np.int64).ravel(), lengths)
+        starts = np.asarray(starts, np.int64).ravel()
+    if window:   # a window too long for one item: the per-sequence list
+        starts = np.maximum(0 if starts is None else starts, lengths - window)
+    if starts is not None:
+        starts = np.minimum(starts, lengths)
         lengths = lengths - starts // page_size * page_size
     items = -(-lengths // (page_size * pages_per_block))
     return sum(
@@ -185,6 +267,75 @@ def streamed_pages(lengths, page_size: int,
         ).sum())
         for blk in range(int(items.max(initial=0)))
     )
+
+
+class _PageRing:
+    """The unquantized decode kernels' DMA ring: the ONE set of copy /
+    wait / write-back helpers of both item bodies (`_decode_kernel`,
+    `_decode_window_kernel`). K and V pages are copied into page `p` of
+    ring slot `slot` on the slot's semaphores; a merged page goes back to
+    its pool page on `w_sem` under a mark in `wb_pending` (SMEM), which
+    is drained before the buffer it reads from is a copy's target again."""
+
+    def __init__(self, k_pages_hbm, v_pages_hbm, ko_pages_hbm, vo_pages_hbm,
+                 k_buf, v_buf, k_sems, v_sems, w_sem, wb_pending,
+                 wb_rows: int = 0):
+        self.k_pages_hbm, self.v_pages_hbm = k_pages_hbm, v_pages_hbm
+        self.ko_pages_hbm, self.vo_pages_hbm = ko_pages_hbm, vo_pages_hbm
+        self.k_buf, self.v_buf = k_buf, v_buf
+        self.k_sems, self.v_sems = k_sems, v_sems
+        self.w_sem, self.wb_pending = w_sem, wb_pending
+        # rows of a page a write-back moves (0 = the whole page)
+        self.wb_rows = wb_rows
+
+    def copy_in(self, page_id, slot, p):
+        pltpu.make_async_copy(
+            self.k_pages_hbm.at[page_id], self.k_buf.at[slot, p],
+            self.k_sems.at[slot],
+        ).start()
+        pltpu.make_async_copy(
+            self.v_pages_hbm.at[page_id], self.v_buf.at[slot, p],
+            self.v_sems.at[slot],
+        ).start()
+
+    def wait(self, slot, n: int):
+        # one wait per started copy: semaphores count completions, so the
+        # item's `n` copied pages (static) and no more
+        for _ in range(n):
+            pltpu.make_async_copy(
+                self.k_pages_hbm.at[0], self.k_buf.at[slot, 0],
+                self.k_sems.at[slot],
+            ).wait()
+            pltpu.make_async_copy(
+                self.v_pages_hbm.at[0], self.v_buf.at[slot, 0],
+                self.v_sems.at[slot],
+            ).wait()
+
+    def _wb_copies(self, slot, p, page_id, row0):
+        # the page, or its `wb_rows` rows from `row0`, buffer -> pool
+        rows = (pl.ds(row0, self.wb_rows),) if self.wb_rows else ()
+        return [
+            pltpu.make_async_copy(
+                buf.at[(slot, p, *rows)], pool.at[(page_id, *rows)],
+                self.w_sem,
+            )
+            for buf, pool in ((self.k_buf, self.ko_pages_hbm),
+                              (self.v_buf, self.vo_pages_hbm))
+        ]
+
+    def write_back(self, slot, p, page_id, mark, row0=0):
+        for copy in self._wb_copies(slot, p, page_id, row0):
+            copy.start()
+        self.wb_pending[mark] = 1
+
+    def drain(self, mark):
+        # a pending page write-back reads from k_buf / v_buf; it must land
+        # before that buffer is reused as a DMA-in target
+        @pl.when(self.wb_pending[mark] == 1)
+        def _():
+            for copy in self._wb_copies(0, 0, 0, 0):
+                copy.wait()
+            self.wb_pending[mark] = 0
 
 
 def _decode_kernel(
@@ -238,6 +389,8 @@ def _decode_kernel(
      w_sem,            # DMA sem for page write-backs
      wb_pending,       # SMEM [NBUF]: write-back in flight from this slot
      ) = rest
+    ring = _PageRing(k_pages_hbm, v_pages_hbm, ko_pages_hbm, vo_pages_hbm,
+                     k_buf, v_buf, k_sems, v_sems, w_sem, wb_pending)
     t_blk = pages_per_block * page_size
     h, kd = q_ref.shape[1], q_ref.shape[2]
     kw = knew_ref.shape[2]
@@ -250,9 +403,10 @@ def _decode_kernel(
     def first_page(seq):
         return jax.lax.div(start_ref[seq], page_size)
 
-    def start_work_dma(w, slot):
+    def start_work_dma(w, slot, settle=False):
         # the item's LIVE pages only (`live_pages`): a table entry past
         # the sequence's end names the trash page, and nothing reads it
+        # (`settle`: wait for those copies, not start them)
         seq = work_seq_ref[w]
         blk = work_blk_ref[w]
         page0 = first_page(seq)
@@ -264,39 +418,13 @@ def _decode_kernel(
 
             @pl.when(p < n_live)
             def _start(p=p):
-                page_id = tables_ref[seq, page0 + blk * pages_per_block + p]
-                pltpu.make_async_copy(
-                    k_pages_hbm.at[page_id], k_buf.at[slot, p],
-                    k_sems.at[slot],
-                ).start()
-                pltpu.make_async_copy(
-                    v_pages_hbm.at[page_id], v_buf.at[slot, p],
-                    v_sems.at[slot],
-                ).start()
-
-    def wait_work_dma(slot, n):
-        # one wait per started copy: semaphores count completions, so the
-        # item's `n` live pages (static: inside its branch) and no more
-        for _ in range(n):
-            pltpu.make_async_copy(
-                k_pages_hbm.at[0], k_buf.at[slot, 0], k_sems.at[slot]
-            ).wait()
-            pltpu.make_async_copy(
-                v_pages_hbm.at[0], v_buf.at[slot, 0], v_sems.at[slot]
-            ).wait()
-
-    def drain_wb(slot):
-        # a pending page write-back reads from k_buf/v_buf[slot]; it must
-        # land before that slot is reused as a DMA-in target
-        @pl.when(wb_pending[slot] == 1)
-        def _():
-            pltpu.make_async_copy(
-                k_buf.at[0, 0], ko_pages_hbm.at[0], w_sem
-            ).wait()
-            pltpu.make_async_copy(
-                v_buf.at[0, 0], vo_pages_hbm.at[0], w_sem
-            ).wait()
-            wb_pending[slot] = 0
+                if settle:
+                    ring.wait(slot, 1)
+                    return
+                ring.copy_in(
+                    tables_ref[seq, page0 + blk * pages_per_block + p],
+                    slot, p,
+                )
 
     o_ref[...] = jnp.zeros_like(o_ref)
     for j in range(nbuf):
@@ -307,6 +435,13 @@ def _decode_kernel(
             start_work_dma(j, j)
 
     if ablate == "empty":
+        # launch, operand copies, prologue: what it started is waited for
+        for j in range(nbuf):
+
+            @pl.when(j < n_work)
+            def _settle(j=j):
+                start_work_dma(j, j, settle=True)
+
         return
 
     def body(w, carry):
@@ -350,12 +485,16 @@ def _decode_kernel(
             # them. All of it sits in the branch: a value made outside
             # one and used inside goes through VMEM on its way
             t = n * page_size
-            wait_work_dma(slot, n)
+            ring.wait(slot, n)
             kb = k_buf[slot, :n].reshape(t, kw)
             vb = v_buf[slot, :n].reshape(t, vw)
             if ablate == "nocompute":
-                touch = jnp.sum(kb.astype(jnp.float32)) * 0.0
-                return m_prev, l_prev, acc + touch
+                # (times an iota's zeros: a splat accumulator is a layout
+                # Mosaic cannot carry into the emit's mask)
+                touch = jnp.sum(kb.astype(jnp.float32), axis=0, keepdims=True)
+                zeros = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0) < 0
+                return m_prev, l_prev, acc + touch[:, :vw] * zeros.astype(
+                    jnp.float32)
 
             # fused cache update: inject the new token's K/V row into the
             # block that owns position `wpos` (the final block; the
@@ -375,15 +514,10 @@ def _decode_kernel(
             def _store_back():
                 k_buf[slot, :n] = kb.reshape(n, page_size, kw)
                 v_buf[slot, :n] = vb.reshape(n, page_size, vw)
-                p_local = jax.lax.div(off, page_size)
-                page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
-                pltpu.make_async_copy(
-                    k_buf.at[slot, p_local], ko_pages_hbm.at[page_id], w_sem
-                ).start()
-                pltpu.make_async_copy(
-                    v_buf.at[slot, p_local], vo_pages_hbm.at[page_id], w_sem
-                ).start()
-                wb_pending[slot] = 1
+                ring.write_back(
+                    slot, jax.lax.div(off, page_size),
+                    tables_ref[seq, jax.lax.div(wpos, page_size)], slot,
+                )
 
             # ONE MXU dot for all kv heads: qb rows are zero outside their
             # head's column block, so cross-head terms vanish
@@ -447,7 +581,7 @@ def _decode_kernel(
 
         @pl.when(nxt < n_work)
         def _refill():
-            drain_wb(slot)
+            ring.drain(slot)
             start_work_dma(nxt, slot)
 
         return m_prev, l_prev, acc
@@ -457,11 +591,259 @@ def _decode_kernel(
     a0 = jnp.zeros((h, vw), jnp.float32)
     jax.lax.fori_loop(0, n_work, body, (m0, l0, a0))
     for j in range(nbuf):
-        drain_wb(j)
+        ring.drain(j)
 
 
 def lax_cdiv(a, b: int):
     return jax.lax.div(a + (b - 1), b)
+
+
+def _decode_window_kernel(
+    # scalar prefetch
+    lengths_ref,       # [B] i32: attended KV count per sequence (0 = inactive)
+    tables_ref,        # [B, W] i32 page ids
+    wpos_ref,          # [B] i32 position whose KV this step writes (-1 = none)
+    rows_ref,          # [ceil(B / G) * G] i32 `window_work_list`: the live
+    # sequences in row order, the last one repeated to the end
+    n_ref,             # [2] i32: work items, live sequences
+    start_ref,         # [B] i32 first position the sequence attends
+    # inputs (VMEM)
+    q_ref,             # [B, H, Kd] queries (pre-scaled), one row a head
+    knew_ref,          # [B, 1, K*Kd] new-token key rows
+    vnew_ref,          # [B, 1, K*Vd]
+    ek_ref,            # [Kd, K*Kd] 0/1: a head's query tiled over the blocks
+    ev_ref,            # [K*Vd, Vd] 0/1: the blocks of an output row added up
+    *rest,             # [sink_ref [H, 1] f32,] then HBM inputs, outputs, scratch
+    page_size: int,
+    win_pages: int,    # `window_pages`: page slots a sequence takes in an item
+    group: int,        # G: sequences an item holds
+    nbuf: int,
+    sink: bool = False,
+    ablate: str = "",   # perf bisection: "nocompute" | "empty"
+):
+    """The work item of a WINDOW layer: G sequences' whole windows in one
+    loop iteration, where `_decode_kernel` walks one (sequence, block) at
+    a time. A sibling body, not a branch of that one, and why: the
+    long-context item needs a carried (m, l, acc) and a live-page count
+    that varies by item (a `lax.switch`); a window of `win_pages` pages
+    is first, last and writing item at once, needs neither, and run
+    through that chain it pays four serial MXU fill / drains on H query
+    rows for every sequence with nothing overlapped across iterations
+    (PERF.md section 6, PR 37). Here the G query blocks are expanded in
+    ONE dot ([G*H, Kd] x [Kd, K*Kd]), scores and PV are dots batched over
+    the sequence axis ([G, H, K*Kd] x [G, T, K*Kd], [G, H, T] x
+    [G, T, K*Vd], T = `win_pages` pages: no cross-sequence products, so
+    no mask a sequence), the G outputs leave through ONE emit dot, there
+    is no rescale, and the G chains are independent.
+
+    Shared with `_decode_kernel`, unchanged: the ring's helpers
+    (`_PageRing`), `live_pages` as the rule of which pages a sequence
+    holds, the fused write (the new row is merged where it lies in VMEM
+    and goes back from there: here the `WRITE_BACK_ROWS` rows around it,
+    there its whole page), the sink in the denominator, float32 operands
+    in both dots; the arithmetic a sequence is that kernel's with one
+    item.
+
+    A copy skipped without its compute skipped is NaN, and a branch a
+    page count (`win_pages ** G`) is not an option, so every item copies
+    `G * win_pages` pages and computes over all of them: a page slot a
+    sequence does not hold (its context is under a page, or its window
+    starts on a page's first row) takes the sequence's FIRST page again,
+    and a partial last item's spare entries repeat the last live
+    sequence. Both are finite and the sequence's own, behind the
+    position mask (`exp(-inf) = 0`); a page no row holds is never read.
+    `streamed_pages(window=)` counts by the same rule."""
+    if sink:
+        sink_ref, *rest = rest
+    (k_pages_hbm,      # [num_pages, page_size, K*Kd]
+     v_pages_hbm,      # [num_pages, page_size, K*Vd]
+     o_ref,            # [B, H, Vd] VMEM
+     ko_pages_hbm,     # aliased k_pages_hbm
+     vo_pages_hbm,
+     k_buf,            # [NBUF, G * win_pages, page_size, K*Kd] VMEM
+     v_buf,            # [NBUF, G * win_pages, page_size, K*Vd]
+     k_sems,           # DMA sems [NBUF]
+     v_sems,
+     w_sem,            # DMA sem for page write-backs
+     wb_pending,       # SMEM [NBUF * G]: write-back in flight, an entry
+     ) = rest
+    wb_rows = min(WRITE_BACK_ROWS, page_size)
+    ring = _PageRing(k_pages_hbm, v_pages_hbm, ko_pages_hbm, vo_pages_hbm,
+                     k_buf, v_buf, k_sems, v_sems, w_sem, wb_pending,
+                     wb_rows=wb_rows)
+    t = win_pages * page_size
+    item_pages = group * win_pages
+    h, kd = q_ref.shape[1], q_ref.shape[2]
+    kw = knew_ref.shape[2]
+    vw = vnew_ref.shape[2]
+    kh = kw // kd
+    vd = vw // kh
+    gq = h // kh
+    n_items, n_live = n_ref[0], n_ref[1]
+
+    def first_page(seq):
+        return jax.lax.div(start_ref[seq], page_size)
+
+    def start_item(w, slot):
+        for g in range(group):
+            seq = rows_ref[w * group + g]
+            page0 = first_page(seq)
+            held = live_pages(
+                lengths_ref[seq] - page0 * page_size, 0, page_size, win_pages
+            )
+            for p in range(win_pages):
+                # a slot past the pages held: the first page again
+                ring.copy_in(
+                    tables_ref[seq, page0 + jnp.where(p < held, p, 0)],
+                    slot, g * win_pages + p,
+                )
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    for j in range(nbuf * group):
+        wb_pending[j] = 0
+    for j in range(nbuf):
+
+        @pl.when(j < n_items)
+        def _prologue(j=j):
+            start_item(j, j)
+
+    if ablate == "empty":
+        for j in range(nbuf):
+
+            @pl.when(j < n_items)
+            def _settle(j=j):
+                ring.wait(j, item_pages)
+
+        return
+
+    def own_block(width, block):
+        # [G*H, width]: row r (query head r % H of sequence r // H) against
+        # the columns of its kv head's block
+        head = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (group * h, width), 0), h)
+        col = jax.lax.broadcasted_iota(jnp.int32, (group * h, width), 1)
+        return col // block == head // gq
+
+    def attend(w, slot):
+        seqs = [rows_ref[w * group + g] for g in range(group)]
+        bases = [first_page(seq) * page_size for seq in seqs]
+        if ablate == "nocompute":
+            touch = jnp.sum(
+                k_buf[slot].astype(jnp.float32).reshape(
+                    item_pages * page_size, kw),
+                axis=0, keepdims=True,
+            )
+            o_ref[seqs[0]] = jnp.broadcast_to(
+                touch[:, :vd] * 0.0, (h, vd)
+            ).astype(o_ref.dtype)
+            return
+
+        # fused cache update, a sequence at a time: the new token's K/V
+        # row is merged into the `wb_rows` aligned rows around position
+        # `wpos` of the page that owns it and just those go back (not for
+        # a partial item's repeats)
+        for g, (seq, base) in enumerate(zip(seqs, bases)):
+            wpos = wpos_ref[seq]
+            off = wpos - base
+            do_write = (
+                (w * group + g < n_live) & (wpos >= 0)
+                & (wpos < lengths_ref[seq]) & (off >= 0)
+            )
+
+            @pl.when(do_write)
+            def _merge(g=g, seq=seq, wpos=wpos, off=off):
+                p = g * win_pages + jax.lax.div(off, page_size)
+                r = jax.lax.rem(off, page_size)
+                row0 = pl.multiple_of(
+                    jax.lax.div(r, wb_rows) * wb_rows, wb_rows)
+                slab = pl.ds(row0, wb_rows)
+                krow = jax.lax.broadcasted_iota(jnp.int32, (wb_rows, kw), 0)
+                vrow = jax.lax.broadcasted_iota(jnp.int32, (wb_rows, vw), 0)
+                k_buf[slot, p, slab] = jnp.where(
+                    krow == r - row0, knew_ref[seq], k_buf[slot, p, slab]
+                )
+                v_buf[slot, p, slab] = jnp.where(
+                    vrow == r - row0, vnew_ref[seq], v_buf[slot, p, slab]
+                )
+                ring.write_back(
+                    slot, p, tables_ref[seq, jax.lax.div(wpos, page_size)],
+                    slot * group + g, row0,
+                )
+
+        # a row carries its values in its kv head's column block, zeros
+        # elsewhere
+        tiled = jax.lax.dot_general(
+            jnp.concatenate([q_ref[seq] for seq in seqs], axis=0),
+            ek_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                  # [G*H, K*Kd]
+        qb = jnp.where(own_block(kw, kd), tiled, 0.0).reshape(group, h, kw)
+        kb = k_buf[slot].reshape(group, t, kw)
+        vb = v_buf[slot].reshape(group, t, vw)
+        s = jax.lax.dot_general(
+            qb, kb.astype(jnp.float32),
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                                  # [G, H, t]
+
+        # the attended positions of each sequence, counted from its page 0
+        gi = jax.lax.broadcasted_iota(jnp.int32, (group, 1, t), 0)
+        rel = jax.lax.broadcasted_iota(jnp.int32, (group, 1, t), 2)
+        lo = jnp.zeros((group, 1, t), jnp.int32)
+        hi = jnp.zeros((group, 1, t), jnp.int32)
+        for g, (seq, base) in enumerate(zip(seqs, bases)):
+            lo = jnp.where(gi == g, start_ref[seq] - base, lo)
+            hi = jnp.where(gi == g, lengths_ref[seq] - base, hi)
+        s = jnp.where((rel < hi) & (rel >= lo), s, _NEG_INF)
+
+        m = jnp.max(s, axis=-1, keepdims=True)                  # [G, H, 1]
+        p_blk = jnp.exp(s - m)
+        l_fin = jnp.sum(p_blk, axis=-1, keepdims=True)
+        a_fin = jax.lax.dot_general(
+            p_blk, vb.astype(jnp.float32),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                                  # [G, H, K*Vd]
+        if sink:
+            # the sink's column joins the denominator and is dropped
+            m_fin = jnp.maximum(m, sink_ref[...])
+            beta = jnp.exp(m - m_fin)
+            l_fin = l_fin * beta + jnp.exp(sink_ref[...] - m_fin)
+            a_fin = a_fin * beta
+        full = (a_fin / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+        full = full.reshape(group * h, vw)
+        # block-diagonal slice: row r keeps its own head's column block
+        out = jax.lax.dot_general(
+            jnp.where(own_block(vw, vd), full, jnp.zeros_like(full)),
+            ev_ref[...],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(o_ref.dtype)                              # [G*H, Vd]
+        for g, seq in enumerate(seqs):
+
+            @pl.when(w * group + g < n_live)
+            def _emit(g=g, seq=seq):
+                o_ref[seq] = out[g * h:(g + 1) * h]
+
+    def body(w, carry):
+        slot = jax.lax.rem(w, nbuf)
+        ring.wait(slot, item_pages)
+        attend(w, slot)
+
+        # refill the ring with the work item NBUF ahead
+        nxt = w + nbuf
+
+        @pl.when(nxt < n_items)
+        def _refill():
+            for g in range(group):
+                ring.drain(slot * group + g)
+            start_item(nxt, slot)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_items, body, 0)
+    for j in range(nbuf * group):
+        ring.drain(j)
 
 
 def _decode_kernel_q(
@@ -859,7 +1241,8 @@ def _decode_kernel_q(
 @functools.partial(
     jax.jit,
     static_argnames=["page_size", "pages_per_block", "nbuf", "interpret",
-                     "ablate", "alias_caches", "int4"],
+                     "ablate", "alias_caches", "int4", "window",
+                     "window_group"],
 )
 def fused_paged_decode_attention(
     q: jax.Array,             # [B, H, Hd] (rope applied, unscaled)
@@ -887,6 +1270,10 @@ def fused_paged_decode_attention(
     ablate: str = "",
     alias_caches: bool = True,
     int4: bool = False,
+    window: int = 0,             # the layer kind's STATIC window: no row
+    # attends more than its last `window` positions (`starts` is raised to
+    # `lengths - window`; None = exactly that). Unquantized pools only
+    window_group: int = WINDOW_GROUP,
 ):
     """Flash paged decode attention fused with the KV-cache update.
 
@@ -896,7 +1283,12 @@ def fused_paged_decode_attention(
     there is no XLA scatter anywhere on the decode path. With scale pools
     the pages are int8 (`_decode_kernel_q`). Unquantized pools may keep
     values narrower than keys (`v_cache` rows K x Vd), a window start a
-    sequence and a sink logit a head (`_decode_kernel`)."""
+    sequence and a sink logit a head (`_decode_kernel`). A static
+    `window` short enough for one work item (`window_grouped`: chosen
+    from `window`, `page_size` and `pages_per_block` alone) takes the
+    grouped item, `window_group` sequences' windows at a time
+    (`_decode_window_kernel`); without one the program is the
+    per-sequence list's, to the letter."""
     b, h, hd = q.shape
     quant = k_scales is not None
     # int32-PACKED pools (quant.pack_kv_slots layout): 4 token rows per
@@ -1074,7 +1466,7 @@ def fused_paged_decode_attention(
     # the kernel makes the block-diagonal operand itself from the heads'
     # own rows: a 0/1 matrix that tiles a row over the K column blocks
     # (and one that adds an output row's blocks up), then a mask
-    assert starts is None or not quant
+    assert (starts is None and not window) or not quant
     vw = v_cache.shape[1]
     vd = vw // kh
     v_pages = v_cache.reshape(num_pages, page_size, vw)
@@ -1086,20 +1478,57 @@ def fused_paged_decode_attention(
     ev = (jnp.arange(vw)[:, None] % vd == jnp.arange(vd)[None, :]).astype(
         q.dtype
     )                                                        # [K*Vd, Vd]
+    if window:
+        floor = jnp.maximum(lengths - window, 0)
+        starts = floor if starts is None else jnp.maximum(
+            starts.astype(jnp.int32), floor)
     if starts is None:
         starts = jnp.zeros((b,), jnp.int32)
         from_page0 = lengths
     else:
         starts = jnp.minimum(starts.astype(jnp.int32), lengths)
         from_page0 = lengths - starts // page_size * page_size
-    work_seq, work_blk, n_work = work_list(from_page0, t_blk, max_blocks)
+    tables = block_tables.astype(jnp.int32)
+    if window_grouped(window, page_size, pages_per_block):
+        # the work list and the ring of the grouped item: an item's
+        # buffer holds `window_group` sequences' page slots
+        win_pages = window_pages(window, page_size)
+        rows, n = window_work_list(lengths, window_group)
+        scalars = (lengths, tables, write_pos.astype(jnp.int32), rows, n,
+                   starts)
+        item_pages, marks = window_group * win_pages, nbuf * window_group
+        qb_scratch = []
+        kernel = functools.partial(
+            _decode_window_kernel,
+            page_size=page_size,
+            win_pages=win_pages,
+            group=window_group,
+            nbuf=nbuf,
+            sink=sink is not None,
+            ablate=ablate,
+        )
+    else:
+        work_seq, work_blk, n_work = work_list(from_page0, t_blk, max_blocks)
+        scalars = (lengths, tables, write_pos.astype(jnp.int32), work_seq,
+                   work_blk, n_work[None], starts)
+        item_pages, marks = pages_per_block, nbuf
+        qb_scratch = [pltpu.VMEM((h, kw), q.dtype)]
+        kernel = functools.partial(
+            _decode_kernel,
+            batch=b,
+            page_size=page_size,
+            pages_per_block=pages_per_block,
+            nbuf=nbuf,
+            sink=sink is not None,
+            ablate=ablate,
+        )
     extra = () if sink is None else (
         sink.astype(jnp.float32).reshape(h, 1),
     )
 
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=len(scalars),
         grid=(1,),
         in_specs=[
             *[vmem] * (5 + len(extra)),
@@ -1112,26 +1541,17 @@ def fused_paged_decode_attention(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((nbuf, pages_per_block, page_size, kw), k_cache.dtype),
-            pltpu.VMEM((nbuf, pages_per_block, page_size, vw), v_cache.dtype),
-            pltpu.VMEM((h, kw), q.dtype),
+            pltpu.VMEM((nbuf, item_pages, page_size, kw), k_cache.dtype),
+            pltpu.VMEM((nbuf, item_pages, page_size, vw), v_cache.dtype),
+            *qb_scratch,
             pltpu.SemaphoreType.DMA((nbuf,)),
             pltpu.SemaphoreType.DMA((nbuf,)),
             pltpu.SemaphoreType.DMA,
-            pltpu.SMEM((nbuf,), jnp.int32),
+            pltpu.SMEM((marks,), jnp.int32),
         ],
     )
-
-    kernel = functools.partial(
-        _decode_kernel,
-        batch=b,
-        page_size=page_size,
-        pages_per_block=pages_per_block,
-        nbuf=nbuf,
-        sink=sink is not None,
-        ablate=ablate,
-    )
-    n_in = 7 + 5 + len(extra)   # scalar prefetch, VMEM inputs, then pools
+    # scalar prefetch, VMEM inputs, then pools
+    n_in = len(scalars) + 5 + len(extra)
     out, k2, v2 = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1150,9 +1570,7 @@ def fused_paged_decode_attention(
             vmem_limit_bytes=DECODE_VMEM_LIMIT
         ),
         interpret=interpret,
-    )(lengths, block_tables.astype(jnp.int32), write_pos.astype(jnp.int32),
-      work_seq, work_blk, n_work[None], starts, qs, new_k, new_v, ek, ev,
-      *extra, k_pages, v_pages)
+    )(*scalars, qs, new_k, new_v, ek, ev, *extra, k_pages, v_pages)
     return (
         out,
         k2.reshape(num_slots, kw),

@@ -6,9 +6,16 @@ over 192-wide keys and 128-wide values in bf16 pages of 128; chained calls, one 
 kind's share of a decode step: 2 full-attention layers (4 KV heads: pools
 of 768 / 512 lanes, the whole context) and 5 window layers (8 KV heads:
 1,536 / 1,024 lanes, a 128-token window from a start a row, a sink a head).
-Variants: pages per work item and DMA ring depth for the window kind.
+Variants: the per-sequence work list (`group` null: the rule of every
+full-attention layer, and of a window layer before PR 37) with its
+`nocompute` (copies, waits and the loop) and `empty` (launch, operand
+copies and the prologue) ablations, and the grouped window item at 1 / 2 /
+4 / 8 sequences an item over ring depths, with the same ablations at the
+committed group and with a whole page sent back by the fused write.
+`check` compares one window layer's output and written pools under both
+rules on the chip.
 Exits non-zero without a TPU; results go to
-`chiprun_out/hybrid_kernel_cell_shape.json` (PERF.md section 6, PR 36).
+`chiprun_out/hybrid_kernel_cell_shape.json` (PERF.md section 6, PRs 36-37).
 
     chiprun -- python scripts/hybrid_kernel_tpu.py
 """
@@ -26,13 +33,47 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from dynamo_tpu.ops import pallas_attention  # noqa: E402
 from dynamo_tpu.ops.pallas_attention import (  # noqa: E402
+    WINDOW_GROUP,
     fused_paged_decode_attention,
 )
 
 WIDTH, LIVE, HEADS, KD, VD, PAGE, WINDOW = 256, 192, 64, 192, 128, 128, 128
 KINDS = {"full": (2, 4), "window": (5, 8)}   # layers, KV heads
 PEAK = 819e9
+
+
+def window_args(lens, key, group):
+    """A window layer's arguments: the per-sequence list takes the starts
+    alone (`group` None), the grouped item the static window."""
+    sink = jax.random.normal(key, (HEADS,), jnp.float32)
+    if group is None:
+        return dict(starts=jnp.maximum(lens - WINDOW, 0), sink=sink)
+    return dict(window=WINDOW, window_group=group, sink=sink)
+
+
+def check(q, lens, wpos, tables, num_pages, key):
+    """One window layer under both rules on the same pools: the largest
+    difference of the outputs, and whether the written pools are equal."""
+    kh = KINDS["window"][1]
+    kp = jax.random.normal(key, (num_pages * PAGE, kh * KD), jnp.bfloat16)
+    vp = jax.random.normal(key, (num_pages * PAGE, kh * VD), jnp.bfloat16)
+    nk = jax.random.normal(key, (WIDTH, kh * KD), jnp.bfloat16)
+    nv = jax.random.normal(key, (WIDTH, kh * VD), jnp.bfloat16)
+    res = [
+        fused_paged_decode_attention(
+            q, nk, nv, kp, vp, jnp.asarray(tables), lens, wpos,
+            page_size=PAGE, **window_args(lens, key, group))
+        for group in (None, WINDOW_GROUP)
+    ]
+    (o0, k0, v0), (o1, k1, v1) = res
+    diff = jnp.abs(o0.astype(jnp.float32) - o1.astype(jnp.float32))
+    return {"group": WINDOW_GROUP, "out_max_abs_diff": float(diff.max()),
+            "out_finite": bool(jnp.isfinite(o1.astype(jnp.float32)).all()),
+            "out_abs_mean": float(jnp.abs(o0.astype(jnp.float32)).mean()),
+            "pools_equal": bool((k0 == k1).all() & (v0 == v1).all()),
+            "rows_written": int((k1 != kp).any(axis=1).sum())}
 
 
 def main() -> int:
@@ -58,10 +99,26 @@ def main() -> int:
     wpos = jnp.where(lens > 0, lens - 1, -1)
     out = {"device": dev.device_kind, "rows": LIVE, "width": WIDTH,
            "resident_tokens": int(lengths.sum()), "variants": []}
-    cases = [("full", 4, 4), ("window", 4, 4), ("window", 2, 4),
-             ("window", 1, 8), ("window", 2, 8), ("full", 8, 4),
-             ("full", 2, 4)]
-    for kind, ppb, nbuf in cases:
+    # (kind, sequences an item: None = the per-sequence list, ring depth,
+    # ablation, rows a fused write sends back: 0 = the committed slab,
+    # scoped-VMEM MiB where the default is too little)
+    cases = [("full", None, 4, "", 0, 0), ("window", None, 4, "", 0, 0),
+             ("window", None, 4, "nocompute", 0, 0),
+             ("window", None, 4, "empty", 0, 0),
+             ("window", 1, 4, "", 0, 0), ("window", 2, 4, "", 0, 0),
+             ("window", 4, 2, "", 0, 0), ("window", 4, 4, "", 0, 0),
+             ("window", 8, 2, "", 0, 96),
+             ("window", WINDOW_GROUP, 4, "", PAGE, 0),
+             ("window", WINDOW_GROUP, 4, "nocompute", 0, 0),
+             ("window", WINDOW_GROUP, 4, "empty", 0, 0)]
+    out["check"] = check(q, lens, wpos, tables, num_pages, key)
+    print(json.dumps(out["check"]), flush=True)
+    slab = pallas_attention.WRITE_BACK_ROWS
+    for kind, group, nbuf, ablate, wb_rows, vmem_mib in cases:
+        # module constants the kernel reads as it is traced
+        jax.clear_caches()
+        pallas_attention.DECODE_VMEM_LIMIT = (vmem_mib or 64) << 20
+        pallas_attention.WRITE_BACK_ROWS = wb_rows or slab
         layers, kh = KINDS[kind]
         win = kind == "window"
         attended = np.minimum(lengths, WINDOW) if win else lengths
@@ -74,17 +131,15 @@ def main() -> int:
               for i in range(layers)]
         nk = jax.random.normal(key, (WIDTH, kh * KD), jnp.bfloat16)
         nv = jax.random.normal(key, (WIDTH, kh * VD), jnp.bfloat16)
-        extra = dict(
-            starts=jnp.maximum(lens - WINDOW, 0),
-            sink=jax.random.normal(key, (HEADS,), jnp.float32),
-        ) if win else {}
+        extra = window_args(lens, key, group) if win else {}
+        extra.update(nbuf=nbuf, ablate=ablate)
 
-        def step(kp, vp, q, ppb=ppb, nbuf=nbuf, extra=extra, nk=nk, nv=nv):
+        def step(kp, vp, q, extra=extra, nk=nk, nv=nv):
             ko, vo = [], []
             for k, v in zip(kp, vp):
                 o, k, v = fused_paged_decode_attention(
                     q, nk, nv, k, v, jnp.asarray(tables), lens, wpos,
-                    page_size=PAGE, pages_per_block=ppb, nbuf=nbuf, **extra)
+                    page_size=PAGE, **extra)
                 # chain the calls through the query
                 q = q + jnp.pad(o, ((0, 0), (0, 0), (0, KD - VD)))[..., :1] * 0
                 ko.append(k)
@@ -102,13 +157,15 @@ def main() -> int:
                 jax.block_until_ready((kp, vp, q2))
                 times.append(time.perf_counter() - t0)
             med = float(np.median(times))
-            row = {"kind": kind, "layers": layers, "pages_per_block": ppb,
-                   "nbuf": nbuf, "ms": med * 1e3,
+            row = {"kind": kind, "layers": layers, "group": group,
+                   "nbuf": nbuf, "ablate": ablate,
+                   "write_back_rows": min(wb_rows or slab, PAGE) if group else PAGE,
+                   "ms": med * 1e3,
                    "needed_bytes": need,
                    "roofline_pct": need / PEAK / med * 100}
         except Exception as e:  # noqa: BLE001 — a variant Mosaic refuses
-            row = {"kind": kind, "pages_per_block": ppb, "nbuf": nbuf,
-                   "error": f"{type(e).__name__}: {e}"[:300]}
+            row = {"kind": kind, "group": group, "nbuf": nbuf,
+                   "ablate": ablate, "error": f"{type(e).__name__}: {e}"[:300]}
         print(json.dumps(row), flush=True)
         out["variants"].append(row)
         del kp, vp

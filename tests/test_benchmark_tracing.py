@@ -263,6 +263,33 @@ def test_decode_kv_read_amp_is_declared():
         "workloads": [w["name"] for w in bench["workloads"]]}
 
 
+@pytest.mark.parametrize("digests,want", [
+    # rows x steps over a window layer's work items, summed over the
+    # window's decode rows: 186 rows in items of 4, the last one partial
+    ([dict(_digest("decode"), tokens=186 * 8, kv_win_items=47 * 8),
+      dict(_digest("decode"), tokens=32, kv_win_items=8),
+      dict(_digest("sync"), tokens=900)], (186 * 8 + 32) / (47 * 8 + 8)),
+    # a uniform model books 0, the parent no such column: nothing to read
+    ([dict(_digest("decode"), kv_win_items=0)], None),
+    ([_digest("decode"), _digest("prefill")], None),
+    ([], None),
+])
+def test_swa_rows_per_item(libs, digests, want):
+    _, _, harness = libs
+    got = harness.read_metric(
+        "layer_metrics", "swa_rows_per_item", {"digests": digests})
+    assert got == (want if want is None else pytest.approx(want, rel=1e-12))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    m = next(m for m in bench["per_layer"]
+             if m["name"] == "swa_rows_per_item")
+    assert m == {
+        "name": "swa_rows_per_item", "unit": "rows", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tpot_p95_ms",
+        "workloads": ["mimo-v2-flash-l7.reason-wide"]}
+
+
 # ------------------------------------------------------------- a rehearsal
 
 NEW_COUNTS = ("kv_preemptions", "true_compiles_in_window")

@@ -106,14 +106,16 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
     d = rec.snapshot()[-1]
     assert (d["kv_pages_streamed"], d["kv_pages_held"]) == want
     # columns are only ever appended: PR 36's five (a model with two
-    # kinds of pool) follow the six that were the tail before it
-    assert FIELDS[-11:] == ("kv_pages_streamed", "kv_pages_held",
+    # kinds of pool) follow the six that were the tail before it, PR 37's
+    # one (a window layer's work items) follows them
+    assert FIELDS[-12:] == ("kv_pages_streamed", "kv_pages_held",
                             "moe_experts_hit", "moe_load_max",
                             "frames", "gc_s",
                             "kv_frac_full", "kv_frac_win",
                             "kv_pages_held_full", "kv_win_pages_held",
-                            "kv_win_pages_released")
+                            "kv_win_pages_released", "kv_win_items")
     assert d["kv_frac_win"] == d["kv_win_pages_held"] == 0
+    assert d["kv_win_items"] == 0
     assert d["moe_experts_hit"] == d["moe_load_max"] == 0
     with open(rec.trigger("manual")) as f:
         art = json.load(f)

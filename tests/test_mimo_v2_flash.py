@@ -162,52 +162,139 @@ def _case(kh, h, kd, vd, ps, lens, seed=0, dtype=jnp.float32):
         sink=jnp.asarray(rng.normal(size=(h,)), jnp.float32), arr=arr)
 
 
-@pytest.mark.parametrize("kh,window,sink", [
-    (2, 16, True), (1, 0, False), (2, 16, False), (2, 0, True)])
+# contexts under the window (1, 5), of exactly a page (8), a window that
+# starts on a page's first row (24 at window 16, 16 and 64 at window 8: one
+# page slot dead) beside rows that fill every slot (17, 33, 40), a fused
+# write that opens a page (1, 17, 33), empty rows between live ones, and
+# nine live rows: not a multiple of any group
+GROUP_LENS = [1, 5, 0, 16, 17, 0, 0, 40, 64, 8, 24, 33]
+
+
+@pytest.mark.parametrize("kh,window,sink,group,ps,lens", [
+    (2, 16, True, 0, 8, None), (1, 0, False, 0, 8, None),
+    (2, 16, False, 0, 8, None), (2, 0, True, 0, 8, None),
+    # the grouped item (a window that fits one work item): three page
+    # slots a sequence at window 16 over pages of 8, two at window 8 (the
+    # benchmark's geometry: a window of one page); pages of 32: the fused
+    # write sends back the 16 rows around the new one, the first or the
+    # second half of a page
+    (2, 16, True, 4, 8, GROUP_LENS), (2, 8, True, 4, 8, GROUP_LENS),
+    (1, 32, False, 2, 32, [0, 9, 0, 32, 49, 130, 80]),
+])
 def test_decode_kernel_window_sink_and_widths_match_the_oracle(
-        kh, window, sink):
+        kh, window, sink, group, ps, lens):
     """Keys 24 wide over values 16 wide, a window start a sequence (the
-    pages behind it NAMED TRASH in the table, as after a release), a sink
-    logit a head: the fused kernel (interpret mode) against the gather
-    oracle, and the written rows land where the oracle's do."""
-    h, kd, vd, ps = 4, 24, 16, 8
-    c = _case(kh, h, kd, vd, ps, [1, 5, 16, 17, 40, 64])
+    pages behind it NAMED TRASH in the table AND poisoned in the pool, as
+    after a release), a sink logit a head: the fused kernel (interpret
+    mode) against the gather oracle, and the written rows land where the
+    oracle's do. `group`: the static window is handed over and the work
+    item holds that many sequences; its outputs are the per-sequence
+    list's, its pools to the bit, and no page a row does not hold is
+    read (NaN in, finite out) or written (NaN still)."""
+    h, kd, vd = 4, 24, 16
+    c = _case(kh, h, kd, vd, ps, lens or [1, 5, 16, 17, 40, 64])
     b, lens = len(c["lens"]), c["lens"]
     q, nk, nv = c["arr"](b, h, kd), c["arr"](b, kh * kd), c["arr"](b, kh * vd)
+    live = lens > 0
     wpos = lens - 1
     tb = c["tables"].copy()
+    held = np.zeros(c["kc"].shape[0], bool)
     for i in range(b):
-        tb[i, :max(lens[i] - window, 0) // ps if window else 0] = 0
-    out, k2, v2 = fused_paged_decode_attention(
-        q, nk, nv, c["kc"], c["vc"], jnp.asarray(tb), jnp.asarray(lens),
-        jnp.asarray(wpos),
-        starts=jnp.maximum(lens - window, 0) if window else None,
-        sink=c["sink"] if sink else None, page_size=ps, interpret=True)
-    ws = c["slots"][np.arange(b), wpos]
-    ko, vo = c["kc"].at[ws].set(nk), c["vc"].at[ws].set(nv)
+        first = max(lens[i] - window, 0) // ps if window else 0
+        tb[i, :first] = 0
+        pages = tb[i, first:-(-lens[i] // ps)]
+        held[(pages[:, None] * ps + np.arange(ps)).ravel()] = True
+    kc = jnp.where(held[:, None], c["kc"], jnp.nan)
+    vc = jnp.where(held[:, None], c["vc"], jnp.nan)
+    kwargs = dict(sink=c["sink"] if sink else None, page_size=ps,
+                  interpret=True)
+    starts = jnp.maximum(lens - window, 0) if window else None
+    args = (q, nk, nv, kc, vc, jnp.asarray(tb), jnp.asarray(lens),
+            jnp.asarray(wpos))
+    out, k2, v2 = fused_paged_decode_attention(*args, starts=starts, **kwargs)
+    if group:
+        per_sequence = out, k2, v2
+        out, k2, v2 = fused_paged_decode_attention(
+            *args, window=window, window_group=group, **kwargs)
+        # (a one-page row's dots run over its dead page slot too: the
+        # CPU's dot may add in another order there; the pools may not)
+        np.testing.assert_allclose(out, per_sequence[0], atol=1e-6)
+        np.testing.assert_array_equal(k2, per_sequence[1])
+        np.testing.assert_array_equal(v2, per_sequence[2])
+    ws = c["slots"][np.arange(b), wpos][live]
+    ko, vo = c["kc"].at[ws].set(nk[live]), c["vc"].at[ws].set(nv[live])
     want = paged_attention(
-        q[:, None], ko, vo, jnp.asarray(c["slots"]), jnp.asarray(wpos)[:, None],
+        q[:, None], ko, vo, jnp.asarray(c["slots"]),
+        jnp.asarray(np.maximum(wpos, 0))[:, None],
         window=window, sink=c["sink"] if sink else None)[:, 0]
-    np.testing.assert_allclose(out, want, atol=2e-6)
-    assert out.shape == (b, h, vd)
-    np.testing.assert_array_equal(k2[ws], nk)
-    np.testing.assert_array_equal(v2[ws], nv)
+    np.testing.assert_allclose(out[live], want[live], atol=2e-6)
+    assert out.shape == (b, h, vd) and not np.asarray(out[~live]).any()
+    np.testing.assert_array_equal(k2[ws], nk[live])
+    np.testing.assert_array_equal(v2[ws], nv[live])
+    np.testing.assert_array_equal(k2[held], ko[held])
+    assert np.isnan(np.asarray(k2)[~held]).all()
+    assert np.isnan(np.asarray(v2)[~held]).all()
+
+
+@pytest.mark.parametrize("kh,starts,sink,digest", [
+    (2, False, False, "b957f8c2d9ef4a2d"), (2, True, True, "d80f51a0a9653c29"),
+    (1, True, False, "7d51362a90a4a274")])
+def test_a_call_without_a_window_is_the_parent_s_to_the_bit(
+        kh, starts, sink, digest):
+    """Every model but the hybrid one calls the unquantized kernel with no
+    static window: outputs and pools hash to what the tree before the
+    grouped item (PR 36, f257dfe) gave on the same inputs."""
+    import hashlib
+
+    h, kd, vd, ps = 4, 24, 16, 8
+    c = _case(kh, h, kd, vd, ps, [1, 5, 0, 16, 17, 40, 64], seed=3)
+    b, lens = len(c["lens"]), c["lens"]
+    q, nk, nv = c["arr"](b, h, kd), c["arr"](b, kh * kd), c["arr"](b, kh * vd)
+    res = fused_paged_decode_attention(
+        q, nk, nv, c["kc"], c["vc"], jnp.asarray(c["tables"]),
+        jnp.asarray(lens), jnp.asarray(lens - 1),
+        starts=jnp.maximum(lens - 16, 0) if starts else None,
+        sink=c["sink"] if sink else None, page_size=ps, interpret=True)
+    m = hashlib.sha256()
+    for a in res:
+        m.update(np.asarray(a).tobytes())
+    assert m.hexdigest()[:16] == digest
 
 
 @pytest.mark.parametrize("window", [16, 128])
 def test_streamed_pages_with_a_window_counts_from_the_start_s_page(window):
     """The digest's count of the pages a window layer's decode kernel
-    copies in is the kernel's own rule: its work list begins at the page
-    that holds the window's first position."""
-    from dynamo_tpu.ops.pallas_attention import streamed_pages
+    copies in is the kernel's own rule. On the per-sequence list (a
+    window too long for one work item, or `starts` alone) the list begins
+    at the page that holds the window's first position; the grouped item
+    copies `window_pages` slots for each of its `group` entries, a dead
+    slot (the sequence's first page again) and a partial item's repeats
+    included, a call (a row of the leading axes) at a time."""
+    from dynamo_tpu.ops.pallas_attention import (
+        window_grouped,
+        window_items,
+        window_pages,
+        streamed_pages,
+    )
 
     ps = 8
-    lens = np.array([[1, 7, 8, 9], [16, 17, 130, 257]])
+    lens = np.array([[1, 7, 8, 9, 0], [16, 17, 130, 257, 44]])
     starts = np.maximum(lens - window, 0)
     want = int(np.sum(-(-lens // ps) - starts // ps))
     assert streamed_pages(lens, ps, starts=starts) == want
     assert streamed_pages(lens, ps, pages_per_block=1, starts=starts) == want
+    assert streamed_pages(lens, ps, pages_per_block=1, window=window) == want
     assert streamed_pages(lens, ps) == int(np.sum(-(-lens // ps)))
+    # 4 and 5 live rows, 2 an item: 2 + 3 items of 2 x 3 page slots
+    assert window_pages(16, ps) == 3 and window_pages(128, 128) == 2
+    assert list(window_items(lens, 2, xp=np)) == [2, 3]
+    assert int(window_items(jnp.asarray(lens[1]), 4)) == 2
+    if window_grouped(window, ps, 4):
+        assert streamed_pages(lens, ps, window=window, group=2) == 5 * 2 * 3
+        assert streamed_pages(lens, ps, window=window, group=4) == 3 * 4 * 3
+    else:   # 17 page slots: the per-sequence list
+        assert window == 128
+        assert streamed_pages(lens, ps, window=window) == want
 
 
 @pytest.mark.parametrize("kh,window,sink", [(2, 16, True), (1, 0, False)])
@@ -324,14 +411,20 @@ async def test_served_logprobs_match_the_reference(backend):
                for r in rows if r["kind"] in ("prefill", "decode"))
     if backend == "pallas":
         # the full kind streams what it holds; a window layer's kernel
-        # copies in the 2-3 pages its 16 tokens touch (its own work list's
-        # count), of the pages the row holds in that pool
+        # walks ceil(rows / group) work items a step (one row here), each
+        # a copy of every page slot (3 at window 16 over pages of 8) of
+        # every entry, a partial item's repeats included
+        from dynamo_tpu.ops.pallas_attention import WINDOW_GROUP, window_pages
+
         for r in decodes:
             assert r["kv_pages_held"] == (
                 r["kv_pages_held_full"] + r["kv_win_pages_held"])
-            win_streamed = r["kv_pages_streamed"] - r["kv_pages_held_full"]
-            assert 2 * r["tokens"] <= win_streamed <= 3 * r["tokens"]
-            assert win_streamed <= r["kv_win_pages_held"]
+            steps = r["tokens"] // r["rows"]
+            assert r["kv_win_items"] == -(-r["rows"] // WINDOW_GROUP) * steps
+            assert r["kv_pages_streamed"] - r["kv_pages_held_full"] == (
+                r["kv_win_items"] * WINDOW_GROUP * window_pages(16, 8))
+    assert all(r["kv_win_items"] == 0 for r in rows
+               if backend == "gather" or r["kind"] != "decode")
     loads = [r for r in rows if r["moe_experts_hit"]]
     assert loads and all(r["moe_experts_hit"] <= 4.0 for r in loads)
     await engine.close()
@@ -533,3 +626,25 @@ def test_engine_sizes_the_window_pool_from_rows_window_and_chunk():
     assert engine.kv.k[1].shape == (engine.win_num_pages * 8, 2 * 24)
     assert engine.kv.v[1].shape == (engine.win_num_pages * 8, 2 * 16)
     assert engine.kv.k[0].shape == (engine.num_pages * 8, 1 * 24)
+
+
+@pytest.mark.parametrize("rows,items_a_step", [(1, 1), (4, 1), (5, 2)])
+def test_the_digest_counts_a_window_layer_s_work_items(rows, items_a_step):
+    """`kv_win_items` is the kernel's own count: ceil(live rows / group)
+    a window layer and step, whatever slots the rows sit in; the pages
+    streamed are every page slot of every entry of those items."""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.ops.pallas_attention import WINDOW_GROUP, window_pages
+
+    engine = make_engine(model=CFG, max_batch_size=8, decode_steps=4,
+                         prefill_chunk=32)
+    seq = SimpleNamespace(win_page_ids=[0, 0, 7, 8, 9], win_first=2)
+    slots = [0, 2, 3, 5, 7][:rows]
+    bld = SimpleNamespace(
+        rows_i=np.arange(20, 28)[:, None], steps=4,
+        active=[(i, seq) for i in slots])
+    streamed, items, held = engine._kv_window_pages(bld)
+    assert items == items_a_step * 4
+    assert streamed == items * WINDOW_GROUP * window_pages(16, 8)
+    assert held == rows * 3 * 4

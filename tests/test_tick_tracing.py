@@ -178,9 +178,9 @@ async def test_decode_digests_count_the_kv_pages(tmp_path):
     layer's kernel copies in over the dispatch's steps beside the pages
     its rows hold; the two are equal (the kernel reads what a sequence
     holds), 0 on every other row, and 0 where the gather path serves."""
-    # columns are only ever appended: PR 36's five follow the six of which
-    # these were the first two
-    assert flightmod.FIELDS[-11:-9] == ("kv_pages_streamed", "kv_pages_held")
+    # columns are only ever appended: PR 36's five and PR 37's one follow
+    # the six of which these were the first two
+    assert flightmod.FIELDS[-12:-10] == ("kv_pages_streamed", "kv_pages_held")
     engine = make_engine(attn_backend="pallas")
     ps, steps = engine.page_size, engine.config.decode_steps
     # one request alone: its first decode dispatch attends 4, 5, ...
@@ -205,6 +205,8 @@ async def test_decode_digests_count_the_kv_pages(tmp_path):
         assert r["kv_pages_held"] <= r["rows"] * steps * -(-128 // ps)
     assert all(r["kv_pages_streamed"] == r["kv_pages_held"] == 0
                for r in rows if r["kind"] != "decode")
+    # a model of one kind of layer has no window layer's work items
+    assert all(r["kv_win_items"] == 0 for r in rows)
 
     gather = make_engine()
     await collect(gather, greedy_request([5, 6, 7], max_tokens=12))
