@@ -537,7 +537,10 @@ async def serve_once(model_dir: str, model_name: str, flags: list[str],
             first_only=first_only)
         c1 = telemetry.compile_stats()
         res["report"] = report
-        res["compiles"] = {k: round(c1[k] - c0[k], 3) for k in c1}
+        # the counters' growth (`phase_s` is a table by phase, `at_s` the
+        # snapshot's stamp: neither is a compile count)
+        res["compiles"] = {k: round(c1[k] - c0[k], 3) for k in c1
+                           if k not in ("phase_s", "at_s")}
         res["start_s"] = build_s + res["walls"]["first_request_s"]
         served = {
             "phase": "served", "label": label,

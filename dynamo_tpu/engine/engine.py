@@ -102,6 +102,10 @@ from dynamo_tpu.utils import (
 
 log = logging.getLogger("dynamo_tpu.engine")
 
+# a dispatch worker's phases, in the order of the digest's `lock_s`,
+# `upload_s`, `enqueue_s`
+_WORKER_PHASES = ("eng.lock", "eng.upload", "eng.enqueue")
+
 
 class StepState(NamedTuple):
     """What the decode path keeps on the device between dispatches. The
@@ -497,97 +501,107 @@ class JaxEngine:
                 "(tp=%d, exposed collective bytes/layer halved)", mc.tp
             )
 
-        if params is None:
-            if config.quantization and self._pp:
-                raise ValueError(
-                    "quantization unsupported with pp>1 (stage stacking)"
-                )
-            if config.checkpoint_dir:
-                from dynamo_tpu.models.weights import load_params
-
-                params = load_params(
-                    config.checkpoint_dir, self.model_cfg, dtype=self._dtype
-                )
-                # logical model size, before quantization adds scale
-                # vectors and a standalone int8 vocab head
-                self.param_count = llama.param_count(params)
-                if config.quantization:
-                    from dynamo_tpu.ops.quant import quantize_params
-
-                    params = quantize_params(
-                        params, self.model_cfg, mode=config.quantization
-                    )
-            else:
-                if config.quantization not in (None, "int8"):
+        # make or load, quantize, place; closed when the leaves are ready
+        with profiler.phase("eng.init.weights"):
+            if params is None:
+                if config.quantization and self._pp:
                     raise ValueError(
-                        f"unknown quantization {config.quantization!r}"
+                        "quantization unsupported with pp>1 (stage stacking)"
                     )
-                from dynamo_tpu.ops.quant import logical_param_count
+                if config.checkpoint_dir:
+                    from dynamo_tpu.models.weights import load_params
 
-                # every dense leaf is created under its target sharding
-                # (no device ever holds the whole tree), and quantized
-                # layers are quantized AS they are initialized: peak
-                # memory is "int8 so far + one bf16 layer", which lets
-                # 8B-class models random-init on a 16 GB chip
-                params = llama.init_params(
-                    self.model_cfg, jax.random.PRNGKey(config.seed),
-                    dtype=self._dtype, quantize=bool(config.quantization),
-                    shardings=None if self._pp else meshmod.param_shardings(
-                        self.model_cfg, self.mesh
-                    ),
+                    params = load_params(
+                        config.checkpoint_dir, self.model_cfg,
+                        dtype=self._dtype,
+                    )
+                    # logical model size, before quantization adds scale
+                    # vectors and a standalone int8 vocab head
+                    self.param_count = llama.param_count(params)
+                    if config.quantization:
+                        from dynamo_tpu.ops.quant import quantize_params
+
+                        params = quantize_params(
+                            params, self.model_cfg, mode=config.quantization
+                        )
+                else:
+                    if config.quantization not in (None, "int8"):
+                        raise ValueError(
+                            f"unknown quantization {config.quantization!r}"
+                        )
+                    from dynamo_tpu.ops.quant import logical_param_count
+
+                    # every dense leaf is created under its target sharding
+                    # (no device ever holds the whole tree), and quantized
+                    # layers are quantized AS they are initialized: peak
+                    # memory is "int8 so far + one bf16 layer", which lets
+                    # 8B-class models random-init on a 16 GB chip
+                    params = llama.init_params(
+                        self.model_cfg, jax.random.PRNGKey(config.seed),
+                        dtype=self._dtype, quantize=bool(config.quantization),
+                        shardings=None if self._pp
+                        else meshmod.param_shardings(self.model_cfg, self.mesh),
+                    )
+                    self.param_count = logical_param_count(
+                        params, self.model_cfg)
+                if not self._pp:
+                    params = meshmod.shard_params(
+                        params, self.model_cfg, self.mesh)
+            else:
+                from dynamo_tpu.ops.quant import (
+                    is_quantized,
+                    logical_param_count,
                 )
+
+                if config.quantization and not any(
+                    is_quantized(lp.get("wq")) for lp in params["layers"]
+                ):
+                    raise ValueError(
+                        "quantization set but caller-provided params are "
+                        "unquantized — pass ops.quant.quantize_params output"
+                    )
                 self.param_count = logical_param_count(params, self.model_cfg)
-            if not self._pp:
-                params = meshmod.shard_params(params, self.model_cfg, self.mesh)
-        else:
-            from dynamo_tpu.ops.quant import is_quantized, logical_param_count
-
-            if config.quantization and not any(
-                is_quantized(lp.get("wq")) for lp in params["layers"]
-            ):
-                raise ValueError(
-                    "quantization set but caller-provided params are "
-                    "unquantized — pass ops.quant.quantize_params output"
-                )
-            self.param_count = logical_param_count(params, self.model_cfg)
+            jax.block_until_ready(params)
 
         self.page_size = config.page_size
-        # the window kind's pool (0 pages for every other model): what
-        # `max_batch_size` rows can hold at once; the full kind takes the
-        # rest of the memory (`_auto_num_pages`)
-        self.win_num_pages = self._win_pool_pages() if self._hybrid else 0
-        self.num_pages = config.num_pages or self._auto_num_pages(params)
-        num_slots = self.num_pages * self.page_size
-        # the pools are created UNDER their shardings (pp keeps its own
-        # stage-stacked placement): the pool is sized to each device's
-        # free memory, so a layer's whole unsharded pool is tp times
-        # what one device can hold. Scale pools [P, SUBL, S] shard over
-        # tp on the sublane-row dim (each shard gets an aligned >=8-row
-        # block of its heads)
-        kv = llama.init_kv_cache(
-            self.model_cfg, num_slots, dtype=self._dtype,
-            kv_quant=self._kv_quant, page_size=self.page_size,
-            tp=config.mesh.tp, packed=self._kv_packed,
-            kv_quant_group=config.kv_quant_group,
-            sharding=None if self._pp else self._kv_sharding,
-            scale_sharding=None if self._pp else jax.sharding.NamedSharding(
-                self.mesh, jax.sharding.PartitionSpec(None, "tp", None)
-            ),
-            win_slots=self.win_num_pages * self.page_size,
-        )
-        if self._pp:
-            from dynamo_tpu.parallel.pipeline import (
-                pp_sharded_put,
-                stack_layer_params,
+        with profiler.phase("eng.init.pools"):
+            # the window kind's pool (0 pages for every other model): what
+            # `max_batch_size` rows can hold at once; the full kind takes the
+            # rest of the memory (`_auto_num_pages`)
+            self.win_num_pages = self._win_pool_pages() if self._hybrid else 0
+            self.num_pages = config.num_pages or self._auto_num_pages(params)
+            num_slots = self.num_pages * self.page_size
+            # the pools are created UNDER their shardings (pp keeps its own
+            # stage-stacked placement): the pool is sized to each device's
+            # free memory, so a layer's whole unsharded pool is tp times
+            # what one device can hold. Scale pools [P, SUBL, S] shard over
+            # tp on the sublane-row dim (each shard gets an aligned >=8-row
+            # block of its heads)
+            kv = llama.init_kv_cache(
+                self.model_cfg, num_slots, dtype=self._dtype,
+                kv_quant=self._kv_quant, page_size=self.page_size,
+                tp=config.mesh.tp, packed=self._kv_packed,
+                kv_quant_group=config.kv_quant_group,
+                sharding=None if self._pp else self._kv_sharding,
+                scale_sharding=None if self._pp else jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec(None, "tp", None)
+                ),
+                win_slots=self.win_num_pages * self.page_size,
             )
+            if self._pp:
+                from dynamo_tpu.parallel.pipeline import (
+                    pp_sharded_put,
+                    stack_layer_params,
+                )
 
-            k_st, v_st = kv.stacked()
-            params, k_st, v_st = pp_sharded_put(
-                self.mesh, stack_layer_params(params), k_st, v_st
-            )
-            self.kv = (k_st, v_st)  # stacked [L, N, KW] pair in pp mode
-        else:
-            self.kv = kv
+                k_st, v_st = kv.stacked()
+                params, k_st, v_st = pp_sharded_put(
+                    self.mesh, stack_layer_params(params), k_st, v_st
+                )
+                self.kv = (k_st, v_st)  # stacked [L, N, KW] pair in pp mode
+            else:
+                self.kv = kv
+            jax.block_until_ready(self.kv)
         self.params = params
 
         self._event_seq = 0
@@ -852,6 +866,11 @@ class JaxEngine:
         # device has drained all that was queued
         self._last_out = None
         self._t_fetched = 0.0  # when the newest fetch landed on the host
+        # the digest's tick columns (`_tick_lap`): when the newest landing
+        # ended (0 = none since the loop sat idle) and the loop thread's
+        # phase seconds then
+        self._t_landed = 0.0
+        self._phase_lap: dict[str, float] = {}
         # per-token exposed collective bytes across the layer stack (0
         # when tp collectives are absent or owned by another executor:
         # tp=1, sp ring prefill, pp stage rotation)
@@ -1340,6 +1359,8 @@ class JaxEngine:
         active = sum(1 for s in self.slots if s is not None)
         usable = self.num_pages - 1
         ps = self._phase_stats
+        compiles = telemetry.compile_stats()
+        compiles.pop("at_s")  # a snapshot's stamp, not a gauge
         return {
             "request_active_slots": active,
             "request_total_slots": len(self.slots),
@@ -1393,8 +1414,11 @@ class JaxEngine:
             "offload_restore_failed": self.offload_gate_stats["failed"],
             # jit compile telemetry (engine/telemetry.py, process-wide):
             # cache misses and the wall they burned — the silent
-            # multi-second stalls, now countable and traceable
-            **telemetry.compile_stats(),
+            # multi-second stalls, now countable and traceable; with
+            # them the host's clock by phase, a dict that EngineMetrics
+            # renders as one series labelled {phase=}
+            "phase_seconds_total": compiles.pop("phase_s"),
+            **compiles,
             # HBM gauges from device memory_stats(); absent on backends
             # that expose none (CPU)
             **telemetry.device_memory_stats(),
@@ -2865,7 +2889,8 @@ class JaxEngine:
                 )
                 progressed = True
         if new_task is not None:
-            self._inflight = await new_task
+            with profiler.phase("eng.join"):
+                self._inflight = await new_task
         if progressed:
             self._heap.settle()
             # yield so producers/consumers interleave with the loop
@@ -2876,6 +2901,7 @@ class JaxEngine:
             return True
         if self.waiting or self._prefilling or self._inflight:
             return False
+        self._t_landed = 0.0  # idle: the next landing opens no tick
         with profiler.phase("eng.wait"):
             if self._kv_audit_s > 0:
                 # idle must not stall the custody audit: a request
@@ -3365,9 +3391,10 @@ class JaxEngine:
                 # candidate.)
                 wd = self._op_begin("prefill.dispatch")
                 try:
-                    toks = await asyncio.to_thread(
-                        self._prefill_group_dispatch, seqs, bucket
-                    )
+                    with profiler.phase("eng.join"):
+                        toks = await asyncio.to_thread(
+                            self._prefill_group_dispatch, seqs, bucket
+                        )
                 finally:
                     self._op_end(wd)
                 with profiler.phase("eng.emit"):
@@ -3392,9 +3419,10 @@ class JaxEngine:
                         )
                     )
                     try:
-                        tok1 = await asyncio.to_thread(
-                            self._prefill_group_dispatch, [seq], b1
-                        )
+                        with profiler.phase("eng.join"):
+                            tok1 = await asyncio.to_thread(
+                                self._prefill_group_dispatch, [seq], b1
+                            )
                         self._note_prefilled([seq], b1)
                     except Exception:
                         log.exception("prefill of seq %s failed", seq.seq_id)
@@ -3539,10 +3567,14 @@ class JaxEngine:
         digest, the ring. `rec`: rows, tokens, phys_rows (token rows
         through the layer stack, padding included), optionally budget,
         build_s, span (more ring attributes), kv_pages_streamed /
-        kv_pages_held (decode: `_kv_pages`); `_enqueue` adds starved. A
+        kv_pages_held (decode: `_kv_pages`); `_enqueue` adds starved. The
+        digest's `lock_s` / `upload_s` / `enqueue_s` are this thread's
+        growth of those three phases in here (`tracing.phase_table`). A
         body that raises books nothing. The
         wall is a dispatch-CALL wall (a jit call returns once the work
         is enqueued); the counts are the load-bearing part."""
+        table = tracing.phase_table()  # this worker thread's
+        was = [table[n][0] if n in table else 0.0 for n in _WORKER_PHASES]
         with profiler.step_annotation(self._step_count), \
                 profiler.annotate(kind):
             with profiler.phase("eng.lock"):
@@ -3552,6 +3584,10 @@ class JaxEngine:
             finally:
                 self._kv_lock.release()
         t1 = time.perf_counter()
+        lock_s, upload_s, enqueue_s = (
+            table[n][0] - w if n in table else 0.0
+            for n, w in zip(_WORKER_PHASES, was)
+        )
         fam = "spec" if kind == "spec_verify" else kind
         rows, tokens = rec["rows"], rec["tokens"]
         with self._phase_lock:
@@ -3566,6 +3602,7 @@ class JaxEngine:
             kind, t1 - t0, rows=rows, tokens=tokens,
             budget=rec.get("budget", 0), build_s=rec.get("build_s", 0.0),
             starved=rec.get("starved", 0),
+            lock_s=lock_s, upload_s=upload_s, enqueue_s=enqueue_s,
             kv_pages_streamed=rec.get("kv_pages_streamed", 0),
             kv_pages_held=rec.get("kv_pages_held", 0),
             **{k: rec[k] for k in (
@@ -3993,23 +4030,25 @@ class JaxEngine:
         if key in self._tail_groups_loaded:
             return
         self._tail_groups_loaded.add(key)
-        n = 2
-        while (n * bucket <= self.config.prefill_group_tokens
-               and n < 2 * self.config.max_batch_size):
-            pad = list(jax.tree.map(
-                lambda a: jnp.zeros((n * a.shape[0], *a.shape[1:]), a.dtype),
-                args[:10],
-            ))
-            rows_i = np.zeros((n, 5), np.int32)
-            rows_i[:, 2] = rows_i[:, 4] = -1
-            rows_f = np.zeros((n, 5), np.float32)
-            rows_f[:, 1] = rows_f[:, 4] = 1.0
-            pad[4], pad[5] = jnp.asarray(rows_i), jnp.asarray(rows_f)
-            self._enqueue(
-                {}, self._step_fn, *pad, *args[10:], counts=counts,
-                sp_cached=spc,
-            )
-            n *= 2
+        with profiler.phase("eng.load_tail_groups"):
+            n = 2
+            while (n * bucket <= self.config.prefill_group_tokens
+                   and n < 2 * self.config.max_batch_size):
+                pad = list(jax.tree.map(
+                    lambda a: jnp.zeros(
+                        (n * a.shape[0], *a.shape[1:]), a.dtype),
+                    args[:10],
+                ))
+                rows_i = np.zeros((n, 5), np.int32)
+                rows_i[:, 2] = rows_i[:, 4] = -1
+                rows_f = np.zeros((n, 5), np.float32)
+                rows_f[:, 1] = rows_f[:, 4] = 1.0
+                pad[4], pad[5] = jnp.asarray(rows_i), jnp.asarray(rows_f)
+                self._enqueue(
+                    {}, self._step_fn, *pad, *args[10:], counts=counts,
+                    sp_cached=spc,
+                )
+                n *= 2
 
     def _note_prefilled(self, seqs: list[Sequence], bucket: int) -> None:
         """Post-dispatch bookkeeping (loop thread only): advance computed
@@ -4040,9 +4079,10 @@ class JaxEngine:
             # worker thread: the _kv_lock acquire can wait out a whole
             # in-flight decode dispatch — never block the event loop on
             # it. Bookkeeping stays HERE (event-loop thread).
-            tok, bucket = await asyncio.to_thread(
-                self._prefill_chunk_dispatch, seq
-            )
+            with profiler.phase("eng.join"):
+                tok, bucket = await asyncio.to_thread(
+                    self._prefill_chunk_dispatch, seq
+                )
             self._note_prefilled([seq], bucket)
             if seq.num_computed >= seq.total_tokens:
                 break
@@ -4385,14 +4425,16 @@ class JaxEngine:
                 # queued behind it — the zero-stall handoff
                 await self._sync_dispatch(old, overlapped=True)
             try:
-                S = await task
+                with profiler.phase("eng.join"):
+                    S = await task
             except Exception:
                 self._mixed_dispatch_failed(bld)
                 return None
             self._inflight = _Dispatch(S, [], 1, mixed=True, bld=bld)
             return "pipelined"
         try:
-            S = await asyncio.to_thread(self._run_mixed_dispatch, bld)
+            with profiler.phase("eng.join"):
+                S = await asyncio.to_thread(self._run_mixed_dispatch, bld)
             d = _Dispatch(S, [], 1, mixed=True, bld=bld)
             fetched = await self._fetch(d)
         except Exception:
@@ -5135,12 +5177,14 @@ class JaxEngine:
             else:
                 self._sync_decode(d, arrs)
         if self.flight is not None:
+            now = time.perf_counter()
             host = {
-                "emit_s": time.perf_counter() - t1,
+                "emit_s": now - t1,
                 "frames": self._frames - frames,
                 "tokens": self._frame_tokens - tokens,
                 # collector passes since the landing before this one
                 "gc_s": self._heap.lap(),
+                **self._tick_lap(now),
             }
             if d.moe is not None:
                 # the same program made it: ready since the tokens were
@@ -5148,6 +5192,32 @@ class JaxEngine:
                     np.asarray(d.moe).tolist()
                 )
             self.flight.amend("overlap" if overlapped else "sync", **host)
+
+    def _tick_lap(self, now: float) -> dict:
+        """The digest's tick columns (loop thread, at the end of a
+        landing): `tick_s` since the end of the landing before (0 on the
+        first, and on the first after the loop sat idle in ``eng.wait``),
+        and this thread's growth since then of ``eng.admit``, of
+        ``eng.join`` and (`unphased_s`) of no ``eng.*`` phase at all
+        under the parent ``eng.tick``. The ``fe.*`` phases of this thread
+        run inside the awaiting ``eng.*`` ones or in the unphased part,
+        so they are not taken off a second time."""
+        table = tracing.phase_table()
+        lap = {
+            n: c[0] for n, c in table.items()
+            if n.startswith("eng.") and n != "eng.tick"
+        }
+        was, self._phase_lap = self._phase_lap, lap
+        t0, self._t_landed = self._t_landed, now
+        if not t0:
+            return {}
+        grew = {n: v - was.get(n, 0.0) for n, v in lap.items()}
+        return {
+            "tick_s": now - t0,
+            "admit_s": grew.get("eng.admit", 0.0),
+            "join_s": grew.get("eng.join", 0.0),
+            "unphased_s": max(now - t0 - sum(grew.values()), 0.0),
+        }
 
     def _sync_decode(self, d: _Dispatch, arrs) -> None:
         """Land a decode scan, a sequence at a time: its column of the

@@ -28,9 +28,9 @@ is gone. This module is the black box:
   triggers are counted, not dumped.
 
 Module registry: engines register their recorder at init (bounded,
-strong refs — a closed scenario engine's ring stays dumpable) so the
-HTTP ``/debug/snapshot`` handler and a multi-process proof can
-dump without holding an engine reference. See docs/observability.md
+strong refs — a just-closed engine's ring stays dumpable) so the
+HTTP ``/debug/snapshot`` handler can dump without holding an engine
+reference. See docs/observability.md
 "Forensics plane".
 """
 
@@ -100,6 +100,19 @@ FIELDS = (
     # work items of ONE window layer's decode kernel over the dispatch's
     # steps (pallas decode rows; rows x steps / this = sequences an item)
     "kv_win_items",
+    # the host's clock by phase (`tracing.phase`, always on), as growth
+    # of the recording thread's own table. Every dispatch row: the worker
+    # inside `_dispatching`
+    "lock_s",        # waiting for `_kv_lock` (eng.lock)
+    "upload_s",      # the build's uploads (eng.upload)
+    "enqueue_s",     # the launch(es) (eng.enqueue)
+    # sync / overlap rows: the loop's thread since the landing before
+    "tick_s",        # end of that landing -> end of this one; 0 on the
+                     # first, and on the first after the loop sat idle
+    "admit_s",       # of it in eng.admit
+    "join_s",        # ... in eng.join, awaiting a dispatch worker
+    "unphased_s",    # ... in no eng.* phase under eng.tick: the event
+                     # loop elsewhere, or the process not running
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 
@@ -107,7 +120,7 @@ _COL = {f: i for i, f in enumerate(FIELDS)}
 # string "family:detail" counts under its family)
 TRIGGERS = (
     "slo_breach", "watchdog", "deadline_shed_burst", "anomaly",
-    "manual", "scenario", "kv_leak",
+    "manual", "kv_leak",
 )
 
 
@@ -211,8 +224,8 @@ class FlightRecorder:
         # bound methods are held via WeakMethod: the module registry
         # keeps recorders STRONGLY, and a bound engine method would pin
         # the engine's params + KV pools behind a ~100 KB ring if the
-        # engine is abandoned without close() (startup failure, dead
-        # scenario) — a dead provider just reads as empty context
+        # engine is abandoned without close() (startup failure) — a
+        # dead provider just reads as empty context
         self._context_ref: Optional[weakref.WeakMethod] = None
         self._context_fn: Optional[Callable[[], dict]] = None
         if context_fn is not None and hasattr(context_fn, "__self__"):
@@ -478,7 +491,7 @@ class FlightRecorder:
     def seal_context(self) -> None:
         """Freeze the live context into a final snapshot and drop the
         provider callable. Called at engine close: the module registry
-        holds recorders STRONGLY (a just-closed scenario engine's ring
+        holds recorders STRONGLY (a just-closed engine's ring
         is exactly what a postmortem wants) — sealing keeps the ~100 KB
         ring dumpable with its last context attached."""
         fn = self._context_provider()
@@ -522,7 +535,7 @@ def digest_to_dict(row: list) -> dict:
 
 # -------------------------------------------------------------- registry
 #
-# Strong refs, bounded: a scenario engine closed five seconds ago is
+# Strong refs, bounded: an engine closed five seconds ago is
 # exactly the one whose ring the postmortem wants, and the ring itself
 # is ~100 KB — keeping the last few alive is the point, not a leak.
 
@@ -536,20 +549,3 @@ def register(rec: FlightRecorder) -> None:
 
 def registered() -> list:
     return list(_registry)
-
-
-def dump_all(
-    reason: str, directory: Optional[str] = None, force: bool = True
-) -> list:
-    """Dump every registered recorder (manual/scenario triggers);
-    returns the artifact paths that were written."""
-    paths = []
-    for rec in registered():
-        try:
-            p = rec.trigger(reason, force=force, directory=directory)
-        except Exception:  # noqa: BLE001 — best-effort across recorders
-            log.exception("flight-recorder dump failed")
-            continue
-        if p is not None:
-            paths.append(p)
-    return paths

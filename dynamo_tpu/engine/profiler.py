@@ -15,12 +15,15 @@ device time went*. This module crosses that boundary two ways:
 - **Host phases.** `phase(name)` (`utils/tracing.py`'s, under this
   module's name too) is the one way to mark what the host is doing: it
   opens a `TraceAnnotation` (so the interval lies on the device trace's
-  clock during a capture) and on exit hands the same interval to the
-  trace ring when that is armed. The engine loop's tick (``eng.tick`` and its children), the
+  clock during a capture), on exit hands the same interval to the
+  trace ring when that is armed, and always adds its seconds to the
+  host's own clock (`tracing.phase_totals()`: what an untraced run
+  keeps). The engine loop's tick (``eng.tick`` and its children), the
   dispatch workers (``eng.lock`` / ``eng.upload`` / ``eng.enqueue``
   inside the dispatch annotation) and the frontend (``fe.*``) all use
   it; `benchmark/lib/trace_host.py` reads them back to say who owes the
-  device's idle time. Names: docs/observability.md.
+  device's idle time, `benchmark/lib/host_clock.py` the tick's length
+  and set-up's split. Names: docs/observability.md.
 - **On-demand capture.** ``POST /debug/profile?duration_ms=`` on a live
   engine runs `jax.profiler.start_trace` into ``DYN_PROFILE_DIR`` for
   the requested window and stops — replacing the ad-hoc one-off

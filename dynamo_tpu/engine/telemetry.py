@@ -19,7 +19,9 @@ module surfaces both:
   set-up built out of the collector's reach (`gc.freeze`).
 - **`install_compile_listener()`** registers a process-wide
   `jax.monitoring` duration listener counting XLA backend compiles and
-  their wall time, and — when tracing is armed — records each one as an
+  their wall time (and summing jax's walls of tracing, lowering and
+  reading the persistent cache: `compile_stats()`), and — when tracing
+  is armed — records each one as an
   ``engine.compile`` complete event on its own track, so the
   multi-second gaps in a step timeline finally carry a name. Idempotent;
   the listener is process-global because compilation is (one jit cache
@@ -37,10 +39,14 @@ import jax
 
 from dynamo_tpu.utils import tracing
 
-# jax monitoring event key for an XLA backend compile (jit cache miss).
-# The other /jax/core/compile/* keys (jaxpr trace, MLIR lowering) are
-# host-side and cheap; backend_compile is the multi-second one.
+# jax monitoring event key for an XLA backend compile (jit cache miss or
+# a read from the persistent cache): the multi-second one of a first run
 _COMPILE_KEY = "/jax/core/compile/backend_compile_duration"
+# tracing a function to a jaxpr and lowering the jaxpr to MLIR: host work
+# that every start pays for every program, cache or no cache (a step
+# program unrolls the model's depth, so neither is cheap: ROADMAP S10)
+_TRACE_KEY = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_KEY = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 # recorded once per program served from the persistent compilation cache
 # (utils/compile_cache.py) instead of being compiled
 _CACHE_HIT_KEY = "/jax/compilation_cache/cache_hits"
@@ -54,14 +60,15 @@ _installed = False
 _compile_events = 0
 _compile_time_s = 0.0
 _cache_hits = 0
-_cache_read_s = 0.0
+# walls that are only summed, by their event key
+_walls = {_CACHE_READ_KEY: 0.0, _TRACE_KEY: 0.0, _LOWER_KEY: 0.0}
 
 
 def _on_event_duration(name: str, duration_s: float, **_kw) -> None:
-    global _compile_events, _compile_time_s, _cache_read_s
-    if name == _CACHE_READ_KEY:
+    global _compile_events, _compile_time_s
+    if name in _walls:
         with _lock:
-            _cache_read_s += duration_s
+            _walls[name] += duration_s
         return
     if name != _COMPILE_KEY:
         return
@@ -104,14 +111,29 @@ def compile_stats() -> dict:
     ``backend_compile`` event for a program it reads back from the
     persistent cache too, so ``compile_events`` counts both;
     ``backend_compiles`` is what the compiler really built (events less
-    cache hits) and ``cache_read_s`` the wall of the reads."""
+    cache hits) and ``cache_read_s`` the wall of the reads. ``trace_s``
+    and ``lower_s`` sum jax's own walls of tracing to a jaxpr and of
+    lowering it to MLIR (a traced function that calls another counts the
+    inner trace in both). ``phase_s`` is the host's clock by phase
+    (`tracing.phase_totals()`, seconds alone) and ``at_s`` the
+    `time.monotonic()` of this snapshot: two snapshots give where the
+    host's time went between them. `Engine.metrics()` renders ``phase_s``
+    as one labelled series and leaves ``at_s`` out."""
+    phase_s = {
+        name: round(cell[0], 4)
+        for name, cell in sorted(tracing.phase_totals().items())
+    }
     with _lock:
         return {
             "compile_events": _compile_events,
             "compile_time_s": round(_compile_time_s, 4),
             "persistent_cache_hits": _cache_hits,
             "backend_compiles": max(_compile_events - _cache_hits, 0),
-            "cache_read_s": round(_cache_read_s, 4),
+            "cache_read_s": round(_walls[_CACHE_READ_KEY], 4),
+            "trace_s": round(_walls[_TRACE_KEY], 4),
+            "lower_s": round(_walls[_LOWER_KEY], 4),
+            "phase_s": phase_s,
+            "at_s": time.monotonic(),
         }
 
 

@@ -250,6 +250,18 @@ class EngineMetrics:
                 gauges = {}
             for key, val in gauges.items():
                 name = f"{self._prefix}_engine_{key}"
+                if isinstance(val, dict):
+                    # `phase_seconds_total`: the host's clock by phase
+                    # (utils/tracing.phase_totals), one series a phase; a
+                    # family with no sample yet is left out whole
+                    if val:
+                        yield f"# TYPE {name} counter"
+                    for phase, seconds in val.items():
+                        labels = {"phase": phase}
+                        if self._worker_id:
+                            labels["worker_id"] = self._worker_id
+                        yield f"{name}{_fmt_labels(labels)} {float(seconds)}"
+                    continue
                 yield f"# TYPE {name} gauge"
                 if key == "gspmd_fallback_dispatches":
                     # executor attribution: the refusal reason rides as

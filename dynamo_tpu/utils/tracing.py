@@ -14,6 +14,10 @@ Design:
   `enable()`) arms recording; every public helper first checks one module
   bool, and `span()` returns a shared no-op context manager when disarmed,
   so the hot paths pay a single attribute load + compare per call site.
+  The one thing that is always on is `phase`'s host clock: two
+  `perf_counter` reads and an add into the thread's own table per phase
+  (`phase_totals()`), which is what a run with no capture and no ring
+  keeps of where its host time went.
 - **Ring-buffered.** Completed events land in a bounded deque
   (`DYN_TRACE_BUFFER` events, default 65536, newest win) — tracing a
   long-running server can never grow without limit. `deque.append` is
@@ -60,6 +64,7 @@ import os
 import threading
 import time
 import uuid
+import weakref
 from collections import deque
 from typing import Callable, Iterator, Optional
 
@@ -84,6 +89,9 @@ __all__ = [
     "span",
     "instant",
     "complete",
+    "phase",
+    "phase_table",
+    "phase_totals",
     "export",
     "dump",
 ]
@@ -416,18 +424,70 @@ def span(
 
 # the device trace's clock: `jax.profiler.TraceAnnotation`, set by
 # engine/profiler.py when it is imported (this module stays off jax);
-# None = no profiler in this process, a phase is a ring event only
+# None = no profiler in this process
 annotation = None
+
+# the host's clock, always on: every thread sums the seconds and the
+# count of each phase it closes in a table of its own (no lock on the
+# hot path; `phase_totals` adds the tables up). A thread that has ended
+# leaves its table to `_phase_retired`, so a server that turns its
+# worker threads over keeps the totals and not the tables.
+_phase_local = threading.local()
+_phase_tables: list = []  # (weakref to the thread, its table)
+_phase_retired: dict = {}
+
+
+def phase_table() -> dict:
+    """The calling thread's live `{name: [seconds, count]}`: what a
+    caller on that thread reads twice to get its own growth over a
+    stretch (the engine's digest columns `lock_s` ... `unphased_s`)."""
+    table = getattr(_phase_local, "table", None)
+    if table is None:
+        table = _phase_local.table = {}
+        with _tracks_lock:
+            _phase_tables.append(
+                (weakref.ref(threading.current_thread()), table))
+    return table
+
+
+def phase_totals() -> dict:
+    """`{name: [seconds, count]}` of every phase closed since the
+    process began, over all threads, whether or not a capture or the
+    ring was on. A phase that spans an await holds whatever its thread's
+    event loop ran meanwhile; a phase still open is not in it yet."""
+    with _tracks_lock:
+        live = []
+        for ref, table in _phase_tables:
+            thread = ref()
+            if thread is not None and thread.is_alive():
+                live.append((ref, table))
+            else:
+                _add_phases(_phase_retired, table)
+        _phase_tables[:] = live
+        out = {name: list(cell) for name, cell in _phase_retired.items()}
+    for _, table in live:
+        # copy(): one C call, safe beside the owner's inserts
+        _add_phases(out, table.copy())
+    return out
+
+
+def _add_phases(into: dict, table: dict) -> None:
+    for name, (seconds, count) in table.items():
+        cell = into.setdefault(name, [0.0, 0])
+        cell[0] += seconds
+        cell[1] += count
 
 
 class phase:
-    """The one way to mark a host phase, on both clocks: an annotation
-    named `name` carrying `attrs` on the device trace (a no-op of well
-    under a microsecond while no capture runs) and a complete event on
-    this ring when it is armed (the request's track inside a request,
-    else ``engine.phases``). `set()` adds attributes found inside the
-    body; they reach the ring only (the annotation's are fixed when it
-    opens). Names: docs/observability.md."""
+    """The one way to mark a host phase, on three clocks: the host's
+    (always: the elapsed seconds and one count go to the thread's table,
+    `phase_totals`), an annotation named `name` carrying `attrs` on the
+    device trace (a no-op of well under a microsecond while no capture
+    runs) and a complete event on this ring when it is armed (the
+    request's track inside a request, else ``engine.phases``). `set()`
+    adds attributes found inside the body; they reach the ring only (the
+    annotation's are fixed when it opens). Names:
+    docs/observability.md."""
 
     __slots__ = ("_name", "_attrs", "_req", "_ann", "_t0")
 
@@ -436,25 +496,32 @@ class phase:
         self._attrs = attrs
         self._req = req
         self._ann = annotation(name, **attrs) if annotation else None
-        self._t0 = None
+        self._t0 = 0.0
 
     def __enter__(self) -> "phase":
         if self._ann is not None:
             self._ann.__enter__()
-        if _enabled:
-            self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter()
         return self
 
     def set(self, **attrs) -> None:
         self._attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        if self._t0 is not None:
+        table = phase_table()
+        cell = table.get(self._name)
+        if cell is None:
+            table[self._name] = [t1 - self._t0, 1]
+        else:
+            cell[0] += t1 - self._t0
+            cell[1] += 1
+        if _enabled:
             req = self._req or current_request()
             complete(
-                self._name, self._t0, time.perf_counter(), cat="phase",
+                self._name, self._t0, t1, cat="phase",
                 req=req, track=None if req else "engine.phases",
                 **self._attrs,
             )

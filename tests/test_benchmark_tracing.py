@@ -3,7 +3,8 @@ scopes (benchmark/lib/trace_host.py) and the per-layer metrics PR 24
 added: the idle split on a hand-made trace and on a recording cut from a
 chip run of `mistral-7b-int8.decode-saturate` (TPU v5e, PR 24), and a
 rehearsal that prints the new metrics and leaves the old ones as they
-were."""
+were. Since PR 38 also the readers of the host's always-on clock
+(benchmark/lib/host_clock.py): the tick, its stops and set-up's split."""
 
 from __future__ import annotations
 
@@ -358,3 +359,145 @@ def test_old_metrics_do_not_see_the_new_columns(libs, rehearsal):
     # and the new readers leave a line from before PR 24 alone
     for name in NEW_COUNTS + NEW_TIMES:
         assert harness.read_metric("layer_metrics", name, old) is None, name
+
+
+# ------------------------------------- the host's clock in an untraced run
+
+TICK_METRICS = ("tick_p50_ms", "tick_max_ms", "stall_s", "tick_max_fetch_ms",
+                "tick_max_dispatch_ms", "tick_max_host_ms",
+                "tick_max_unphased_ms")
+SETUP_METRICS = ("setup_build_s", "setup_probe_s", "setup_warm_s",
+                 "setup_trace_lower_s", "setup_cache_read_s",
+                 "setup_weights_s")
+CLOCK_COLUMNS = ("lock_s", "upload_s", "enqueue_s", "tick_s", "admit_s",
+                 "join_s", "unphased_s")
+CLOCK_KEYS = ("trace_s", "lower_s", "phase_s", "at_s")
+
+
+@pytest.mark.parametrize("name", TICK_METRICS + SETUP_METRICS)
+def test_host_clock_metric_is_declared(name):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    m = next(m for m in bench["per_layer"] if m["name"] == name)
+    setup = name in SETUP_METRICS
+    assert m == {
+        "name": name, "unit": "ms" if name.endswith("_ms") else "s",
+        "better": "lower", "source": "program_span",
+        "layer": "set-up" if setup else "engine loop, scheduler",
+        "moves": "setup_s" if setup else "out_tok_s",
+        "workloads": [w["name"] for w in bench["workloads"]]}
+    assert os.path.isfile(os.path.join(
+        ROOT, "benchmark", "layer_metrics", name + ".py"))
+
+
+def _tick(tick_s, wall_s=0.14, join_s=0.0, unphased_s=0.002, emit_s=0.004):
+    return {"kind": "sync", "tick_s": tick_s, "wall_s": wall_s,
+            "join_s": join_s, "unphased_s": unphased_s, "emit_s": emit_s,
+            "admit_s": 0.001, "gc_s": 0.0}
+
+
+def _launch(enqueue_s=0.001, kind="decode"):
+    return {"kind": kind, "lock_s": 0.0, "upload_s": 0.001,
+            "enqueue_s": enqueue_s, "build_s": 0.002}
+
+
+@pytest.mark.parametrize("ticks,want", [
+    # a steady window: no tick passes twice the median
+    ([0.150, 0.148, 0.152, 0.170, 0.150, 0.290], 0.0),
+    # one stop: what it took beyond the median tick
+    ([0.150] * 9 + [2.650], 2.5),
+    # two: their sum, each less the median
+    ([0.150] * 9 + [2.650, 0.150, 3.150], 5.5),
+    # the first landing (and the first after an idle loop) opens no tick
+    ([0.0, 0.150, 0.150, 0.0, 0.150, 0.450], 0.3),
+])
+def test_stall_s_sums_what_stops_took_beyond_the_median(libs, ticks, want):
+    _, _, harness = libs
+    digests = [d for t in ticks for d in (_launch(), _tick(t))]
+    got = harness.read_metric("layer_metrics", "stall_s", {"digests": digests})
+    assert got == pytest.approx(want, abs=1e-9)
+    assert harness.read_metric(
+        "layer_metrics", "tick_max_ms", {"digests": digests}
+    ) == pytest.approx(max(ticks) * 1e3)
+
+
+@pytest.mark.parametrize("stop,part", [
+    # the loop awaited the device's tokens 3 s longer
+    (dict(tick=_tick(3.15, wall_s=3.14)), "fetch"),
+    # a worker sat 3 s in its launch, the loop 3 s awaiting the worker
+    (dict(tick=_tick(3.15, join_s=3.0), launch=_launch(3.0)), "dispatch"),
+    # the prefill worker alone (its row lies in the tick it stopped)
+    (dict(tick=_tick(3.15, join_s=3.0), launch=_launch(3.0, "prefill")),
+     "dispatch"),
+    # the loop's own landing took 3 s
+    (dict(tick=_tick(3.15, emit_s=3.004)), "host"),
+    # the loop's thread was in no phase of the engine's for 3 s
+    (dict(tick=_tick(3.15, unphased_s=3.002)), "unphased"),
+])
+def test_the_longest_ticks_excess_names_its_part(libs, stop, part):
+    _, _, harness = libs
+    steady = [d for _ in range(8) for d in (_launch(), _tick(0.15))]
+    digests = steady + [stop.get("launch", _launch()), stop["tick"]] + steady
+    got = {p: harness.read_metric(
+        "layer_metrics", f"tick_max_{p}_ms", {"digests": digests})
+        for p in ("fetch", "dispatch", "host", "unphased")}
+    assert got.pop(part) == pytest.approx(3000, abs=1)
+    assert all(abs(v) < 1 for v in got.values()), got
+    # a steady window reads ~0 in all four
+    for p in got:
+        assert harness.read_metric(
+            "layer_metrics", f"tick_max_{p}_ms", {"digests": steady}) == 0
+
+
+def test_rehearsal_prints_the_host_clock_metrics(libs, rehearsal):
+    _, _, harness = libs
+    line, art = rehearsal
+    # declared in every cell, so printed by this one; a CPU run gives
+    # counts and never a time, so each value is null here
+    for name in TICK_METRICS + SETUP_METRICS:
+        assert line["metrics"][name]["value"] is None, name
+    read = {n: harness.read_metric("layer_metrics", n, art)
+            for n in TICK_METRICS + SETUP_METRICS}
+    assert all(v is not None for v in read.values()), read
+    # the three stretches of set-up are set-up, to the clock's last digit
+    assert (read["setup_build_s"] + read["setup_probe_s"]
+            + read["setup_warm_s"]) == pytest.approx(art["setup_s"], abs=1e-6)
+    assert min(read[n] for n in SETUP_METRICS[:3]) > 0
+    before = art["compile"]["before"]
+    assert read["setup_trace_lower_s"] == pytest.approx(
+        before["trace_s"] + before["lower_s"]) and before["trace_s"] > 0
+    assert read["setup_weights_s"] == before["phase_s"]["eng.init.weights"] > 0
+    assert read["setup_weights_s"] < read["setup_build_s"]
+    assert 0 < read["tick_p50_ms"] <= read["tick_max_ms"]
+    assert read["stall_s"] >= 0
+    # the four snapshots are stamped in the order they were taken
+    stamps = [art["compile"][k]["at_s"]
+              for k in ("build", "probe", "before", "after")]
+    assert stamps == sorted(stamps) and stamps[2] <= art["window"][0] + 1
+
+
+def test_older_readers_do_not_see_the_host_clock(libs, rehearsal):
+    _, _, harness = libs
+    _, art = rehearsal
+    assert set(CLOCK_COLUMNS) <= set(art["digests"][0])
+    old = copy.deepcopy(art)
+    for d in old["digests"]:
+        for k in CLOCK_COLUMNS:
+            del d[k]
+    for stats in old["compile"].values():
+        if isinstance(stats, dict):
+            for k in CLOCK_KEYS:
+                del stats[k]
+    for name in ("host_ms_per_tick", "host_stall_max_ms",
+                 "starved_dispatch_pct", "device_idle_pct",
+                 "idle_in_programs_pct", "idle_enqueue_pct", "idle_host_pct",
+                 "idle_frontend_pct", "idle_dry_pct", "decode_rows_mean",
+                 "compiles_in_window", "true_compiles_in_window",
+                 "kv_pool_peak_pct", "decode_kv_read_amp"):
+        assert (harness.read_metric("layer_metrics", name, old)
+                == harness.read_metric("layer_metrics", name, art)), name
+    # and a program from before PR 38 gives the new readers nothing to
+    # read (`cache_read_s` is older than they are)
+    for name in TICK_METRICS + SETUP_METRICS:
+        got = harness.read_metric("layer_metrics", name, old)
+        assert (got is None) == (name != "setup_cache_read_s"), name

@@ -107,13 +107,16 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
     assert (d["kv_pages_streamed"], d["kv_pages_held"]) == want
     # columns are only ever appended: PR 36's five (a model with two
     # kinds of pool) follow the six that were the tail before it, PR 37's
-    # one (a window layer's work items) follows them
-    assert FIELDS[-12:] == ("kv_pages_streamed", "kv_pages_held",
-                            "moe_experts_hit", "moe_load_max",
-                            "frames", "gc_s",
-                            "kv_frac_full", "kv_frac_win",
-                            "kv_pages_held_full", "kv_win_pages_held",
-                            "kv_win_pages_released", "kv_win_items")
+    # one (a window layer's work items) follows them, then PR 38's seven
+    # (the host's clock by phase on dispatch and on sync / overlap rows)
+    assert FIELDS[16:] == ("kv_pages_streamed", "kv_pages_held",
+                           "moe_experts_hit", "moe_load_max",
+                           "frames", "gc_s",
+                           "kv_frac_full", "kv_frac_win",
+                           "kv_pages_held_full", "kv_win_pages_held",
+                           "kv_win_pages_released", "kv_win_items",
+                           "lock_s", "upload_s", "enqueue_s", "tick_s",
+                           "admit_s", "join_s", "unphased_s")
     assert d["kv_frac_win"] == d["kv_win_pages_held"] == 0
     assert d["kv_win_items"] == 0
     assert d["moe_experts_hit"] == d["moe_load_max"] == 0

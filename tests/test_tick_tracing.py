@@ -9,6 +9,8 @@ from __future__ import annotations
 import asyncio
 import glob
 import os
+import sys
+import time
 
 import jax
 import pytest
@@ -20,10 +22,28 @@ from dynamo_tpu.utils import tracing
 from .test_engine import collect, greedy_request, make_engine
 
 LOOP_PHASES = {"eng.tick", "eng.admit", "eng.prefill.build",
-               "eng.decode.build", "eng.fetch", "eng.emit", "eng.wait"}
+               "eng.decode.build", "eng.fetch", "eng.emit", "eng.wait",
+               "eng.join"}
 WORKER_PHASES = {"eng.lock", "eng.upload", "eng.enqueue"}
 DISPATCH = ("prefill", "decode", "mixed", "spec_verify")
 REPETITIVE = [5, 17, 42, 9] * 6
+# PR 38's digest columns: the worker's on every dispatch row, the loop's
+# on the landings
+WORKER_COLUMNS = ("lock_s", "upload_s", "enqueue_s")
+TICK_COLUMNS = ("tick_s", "admit_s", "join_s", "unphased_s")
+TICK_PARTS = ("fetch", "dispatch", "host", "unphased")
+LIB = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "benchmark", "lib")
+
+
+def _read(name, digests):
+    """The benchmark's reader of one per-layer metric, on these digests."""
+    sys.path.insert(0, LIB)
+    try:
+        import harness
+        return harness.read_metric("layer_metrics", name, {"digests": digests})
+    finally:
+        sys.path.remove(LIB)
 
 
 async def _capture(engine, tmp_path, prompts, max_tokens=24):
@@ -171,6 +191,177 @@ async def test_digest_columns():
     assert landed and all(r["emit_s"] > 0 for r in landed)
     assert all(r["emit_s"] == 0 for r in by["decode"])
     assert all(r["preempted"] == 0 for r in rows)
+    # the host's clock by phase (PR 38), appended: a dispatch row holds
+    # its worker's lock, uploads and launch, a landing the loop's tick
+    assert flightmod.FIELDS[-7:] == WORKER_COLUMNS + TICK_COLUMNS
+    for r in by["decode"] + by["prefill"]:
+        assert r["upload_s"] > 0 and r["enqueue_s"] > 0 and r["lock_s"] >= 0
+        assert (r["lock_s"] + r["upload_s"] + r["enqueue_s"]
+                <= r["wall_s"] + 1e-5)  # each is rounded to a microsecond
+        assert all(r[c] == 0 for c in TICK_COLUMNS)
+    assert all(r[c] == 0 for r in landed for c in WORKER_COLUMNS)
+    # the first landing opens no tick; every later one closes a tick that
+    # holds its own fetch and landing, and its parts fit inside it
+    assert landed[0]["tick_s"] == 0 and len(landed) >= 3
+    for r in landed[1:]:
+        assert r["tick_s"] >= r["wall_s"] + r["emit_s"] - 1e-5
+        assert 0 <= r["unphased_s"] <= r["tick_s"]
+        assert 0 <= r["admit_s"] < r["tick_s"] and r["join_s"] >= 0
+
+
+async def test_phase_totals_grow_with_no_capture_and_no_ring():
+    """The host's clock is always on: with the ring disarmed and no
+    profiler capture, every phase of a served request adds its seconds
+    and its count to `phase_totals()`, `compile_stats()` carries them,
+    and `Engine.metrics()` renders them as one labelled series."""
+    from dynamo_tpu.engine import telemetry
+    from dynamo_tpu.llm.http.metrics import EngineMetrics
+
+    assert not tracing.enabled()
+    before = tracing.phase_totals()
+    engine = make_engine()
+    await collect(engine, greedy_request([5, 6, 7], max_tokens=20))
+    after = tracing.phase_totals()
+    stats = telemetry.compile_stats()
+    text = list(EngineMetrics(engine).render())
+    flat = engine.metrics()
+    await engine.close()
+    assert not [e for e in tracing.export()["traceEvents"] if e["ph"] != "M"]
+    for name in (LOOP_PHASES | WORKER_PHASES
+                 | {"eng.init.weights", "eng.init.pools"}) - {"eng.wait"}:
+        was = before.get(name, [0.0, 0])
+        assert after[name][1] > was[1], name
+        assert after[name][0] > was[0], name
+        assert stats["phase_s"][name] >= round(after[name][0], 4) - 1e-4
+    assert stats["trace_s"] > 0 and stats["lower_s"] > 0
+    assert stats["at_s"] <= time.monotonic()
+    # the operator's view: seconds by phase, no stamp, nothing nested
+    assert "at_s" not in flat and "phase_s" not in flat
+    assert flat["phase_seconds_total"]["eng.fetch"] > 0
+    assert any(line.startswith(
+        'dynamo_tpu_engine_phase_seconds_total{phase="eng.fetch"} ')
+        for line in text)
+    assert "# TYPE dynamo_tpu_engine_phase_seconds_total counter" in text
+
+
+def test_phase_totals_lose_no_count_across_threads():
+    """Every thread adds to a table of its own, so threads that close
+    phases at once, more of them than cores and switched often, lose no
+    count while another thread sums the tables; a thread that has ended
+    leaves its seconds behind."""
+    import threading
+
+    n_threads, n_phases = 4 * (os.cpu_count() or 4), 2000
+    name = "test.stress_phase"
+    base = tracing.phase_totals().get(name, [0.0, 0])[1]
+
+    def work():
+        for _ in range(n_phases):
+            with tracing.phase(name):
+                pass
+
+    threads = [threading.Thread(target=work) for _ in range(n_threads)]
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 60
+        while any(t.is_alive() for t in threads):
+            seen = tracing.phase_totals().get(name, [0.0, 0])[1]
+            assert base <= seen <= base + n_threads * n_phases
+            assert time.monotonic() < deadline
+        for t in threads:
+            t.join(timeout=10)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(was)
+    for _ in range(2):  # the ended threads' tables are folded in once
+        seconds, count = tracing.phase_totals()[name]
+        assert count == base + n_threads * n_phases and seconds > 0
+
+
+async def test_a_stop_is_named_by_thread_and_phase(monkeypatch):
+    """One decode launch that takes 0.3 s longer shows in its dispatch
+    row's `enqueue_s` and in the `join_s` of the landing that waited for
+    it; one fetch that takes 0.3 s longer in its landing's `wall_s`. The
+    benchmark's readers (`lib/host_clock.py`) then say how long the stop
+    was and on which thread it sat."""
+    engine = make_engine()
+    slow = {"decode": 0, "fetch": 0}  # launches / fetches until the stop
+    launch = engine._decode_fn
+
+    def decode_fn(*args, **kw):
+        slow["decode"] -= 1
+        if slow["decode"] == 0:
+            time.sleep(0.3)
+        return launch(*args, **kw)
+
+    to_thread = asyncio.to_thread
+
+    async def fetch_to_thread(fn, *args, **kw):
+        if "_fetch.<locals>" in getattr(fn, "__qualname__", ""):
+            slow["fetch"] -= 1
+            if slow["fetch"] == 0:
+                def late():
+                    time.sleep(0.3)
+                    return fn(*args, **kw)
+                return await to_thread(late)
+        return await to_thread(fn, *args, **kw)
+
+    engine._decode_fn = decode_fn
+    monkeypatch.setattr(asyncio, "to_thread", fetch_to_thread)
+    request = greedy_request([5, 6, 7], max_tokens=100)
+    await collect(engine, request)  # every program this request meets
+    got = {}
+    for part in ("decode", "fetch"):
+        await asyncio.sleep(0.2)  # the overshoot dispatch drains
+        n = len(engine.flight.snapshot())
+        slow[part] = 6
+        await collect(engine, request)
+        got[part] = engine.flight.snapshot()[n:]
+    await engine.close()
+
+    # (upper limits are loose: six test workers share this machine)
+    rows = got["decode"]
+    late = max((r for r in rows if r["kind"] == "decode"),
+               key=lambda r: r["enqueue_s"])
+    assert 0.3 <= late["enqueue_s"] < 1.0 and late["lock_s"] < 0.05
+    joined = max((r for r in rows if r["kind"] in ("sync", "overlap")),
+                 key=lambda r: r["join_s"])
+    assert 0.25 <= joined["join_s"] <= joined["tick_s"]
+    assert joined["wall_s"] < 0.25 and joined["unphased_s"] < 0.25
+    rows = got["fetch"]
+    late = max((r for r in rows if r["kind"] in ("sync", "overlap")),
+               key=lambda r: r["wall_s"])
+    assert 0.3 <= late["wall_s"] <= late["tick_s"] and late["join_s"] < 0.25
+    for part, want in (("decode", "dispatch"), ("fetch", "fetch")):
+        # the readers on the dozen ticks around the stop: six test workers
+        # share this machine, and over a whole request another tick of
+        # this 3 ms loop may be stretched further than the 0.3 s put in
+        # (ISSUE 38's "`stall_s` within 0.05 of 0.3" holds on a quiet
+        # machine; the exact sums are the synthetic cases of
+        # tests/test_benchmark_tracing.py)
+        column, kinds = (("enqueue_s", ("decode",)) if part == "decode"
+                         else ("wall_s", ("sync", "overlap")))
+        at = max(range(len(got[part])), key=lambda i: (
+            got[part][i]["kind"] in kinds) * got[part][i][column])
+        near = got[part][max(at - 24, 0):at + 24]
+        assert _read("stall_s", near) >= 0.29
+        assert _read("tick_max_ms", near) >= 300
+        assert _read("tick_p50_ms", near) < 100
+        parts = {name: _read(f"tick_max_{name}_ms", near)
+                 for name in TICK_PARTS}
+        assert max(parts, key=parts.get) == want, (part, parts)
+        assert parts[want] >= 290, (part, parts)
+        assert all(ms < parts[want] / 2 for name, ms in parts.items()
+                   if name != want), (part, parts)
+    # and a program from before the columns gives the readers nothing
+    old = [{k: v for k, v in r.items()
+            if k not in WORKER_COLUMNS + TICK_COLUMNS} for r in rows]
+    for name in ("stall_s", "tick_p50_ms", "tick_max_ms",
+                 *(f"tick_max_{p}_ms" for p in TICK_PARTS)):
+        assert _read(name, old) is None, name
 
 
 async def test_decode_digests_count_the_kv_pages(tmp_path):
@@ -178,9 +369,9 @@ async def test_decode_digests_count_the_kv_pages(tmp_path):
     layer's kernel copies in over the dispatch's steps beside the pages
     its rows hold; the two are equal (the kernel reads what a sequence
     holds), 0 on every other row, and 0 where the gather path serves."""
-    # columns are only ever appended: PR 36's five and PR 37's one follow
-    # the six of which these were the first two
-    assert flightmod.FIELDS[-12:-10] == ("kv_pages_streamed", "kv_pages_held")
+    # columns are only ever appended: these were the first two of PR 32's
+    # six, and PRs 36, 37 and 38 put theirs behind
+    assert flightmod.FIELDS[16:18] == ("kv_pages_streamed", "kv_pages_held")
     engine = make_engine(attn_backend="pallas")
     ps, steps = engine.page_size, engine.config.decode_steps
     # one request alone: its first decode dispatch attends 4, 5, ...
