@@ -1898,7 +1898,8 @@ class JaxEngine:
                 ys = _sample()
             if moe:
                 # this step's expert load, mean over its expert layers:
-                # [experts with a token, most tokens on one expert]
+                # [experts with a token, most tokens on one expert,
+                # blocks of rows the pass ran, pairs the layer holds]
                 ys = ys + (jnp.mean(
                     jnp.asarray(moe, jnp.float32), axis=0
                 ),)
@@ -1938,7 +1939,7 @@ class JaxEngine:
                 tlp=keep(state.tlp, out_t[3][-1]),
             )
         if self._returns_expert_load:
-            # an expert model's dispatch ends in its load, [2] float32
+            # an expert model's dispatch ends in its load, [4] float32
             # (mean over the steps): fetched with the tokens, booked on
             # the sync digest (`_land`)
             S = S + (jnp.mean(out_t[-1], axis=0),)
@@ -5188,7 +5189,8 @@ class JaxEngine:
             }
             if d.moe is not None:
                 # the same program made it: ready since the tokens were
-                host["moe_experts_hit"], host["moe_load_max"] = (
+                (host["moe_experts_hit"], host["moe_load_max"],
+                 host["moe_row_blocks"], host["moe_pairs_held"]) = (
                     np.asarray(d.moe).tolist()
                 )
             self.flight.amend("overlap" if overlapped else "sync", **host)

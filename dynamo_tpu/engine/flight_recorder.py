@@ -113,6 +113,11 @@ FIELDS = (
     "join_s",        # ... in eng.join, awaiting a dispatch worker
     "unphased_s",    # ... in no eng.* phase under eng.tick: the event
                      # loop elsewhere, or the process not running
+    # beside `moe_experts_hit` / `moe_load_max`, the same rows and means:
+    "moe_row_blocks",     # blocks of sorted pairs an expert layer's pass
+                          # ran (models/moe.py `block_rows`): 1 where a
+                          # layer holds all its experts
+    "moe_pairs_held",     # (token, expert) pairs routed to a held expert
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

@@ -40,13 +40,31 @@ each under the `jax.named_scope` a profile finds it by:
 - `mlp.moe_combine`: each pair's output times its weight, un-sorted back
   to token order, the k rows of a token added up.
 
+The last three steps work the sorted pairs a BLOCK of `C` rows at a time
+(`block_rows`, static, from shapes the layer sees: the rows and the share
+of the scored experts it holds) and stop after the last block that holds
+a real pair. A layer that holds every expert is the case of one block,
+`C` = all rows: no loop, the steps as written above. A layer that holds a
+share gets `C` = twice the rows an even router would send it, in whole
+tiles (one chip of sixteen at top-8: 256 of the decode program's 2,048
+pairs, of which ~92 are real), and a device-side loop of `ceil(R / C)`
+blocks, R the pairs it holds: a block's tokens gathered, its part of each
+group (the groups' running ends clipped to the block), the three grouped
+matmuls, weight and select, and its rows ADDED to their tokens' rows of an
+[N, D] float32 sum (a scatter-add; the un-sort gather of N x k rows is the
+form that cannot shrink). Still drop-free: pairs past `C` are a second,
+third ... block's, which re-reads only the weights of the experts that
+have rows in it; nothing held, no block. Timed alone on the v5e at
+MiMo-V2-Flash's widths, 16 of 256 held, 6 layers
+(`scripts/moe_layer_tpu.py --shape share`, PERF.md section 6, PR 39).
+
 Shared experts (`num_shared_experts`, one SwiGLU of that many expert
 widths on EVERY token) are `mlp.moe_shared`. Expert weights live as
 [E, ...] arrays, sharded P('ep', ...) on a mesh that has the axis.
 
 `stats` (a list the caller passes) receives this layer's load over the
-experts it HOLDS as two int32 scalars, (distinct experts with a token,
-most tokens on one expert):
+experts it HOLDS as four int32 scalars, (distinct experts with a token,
+most tokens on one expert, blocks the pass ran, pairs held = R):
 the decode program returns their means with the tokens (engine.py).
 """
 
@@ -161,6 +179,32 @@ def grouped_matmul(xs, w, group_sizes, out_dtype=None):
     )
 
 
+def block_rows(m: int, held: int, scored: int) -> int:
+    """STATIC rows of the sorted pairs a pass works at a time: all `m`
+    where the layer holds every expert the router scores (one block, no
+    loop), else twice the rows an even router would send to the held
+    share, in whole grouped-matmul tiles."""
+    if held == scored:
+        return m
+    return min(m, -(-2 * m * held // (scored * GMM_ROWS)) * GMM_ROWS)
+
+
+def _pair_weights(top_w, m: int):
+    """The router's weights [N, k] by pair, padded to the `m` rows sorted."""
+    return jnp.pad(top_w.reshape(-1), (0, m - top_w.size))
+
+
+def _experts(lp: dict, xs, group_sizes):
+    """The SwiGLU of each row's expert: xs [M, D] sorted by expert ->
+    [M, D] float32 (rows past the groups' end: whatever was there)."""
+    with jax.named_scope("mlp.moe_experts"):
+        gate = grouped_matmul(xs, lp["we_gate"], group_sizes)
+        up = grouped_matmul(xs, lp["we_up"], group_sizes)
+        return grouped_matmul(
+            jax.nn.silu(gate) * up, lp["we_down"], group_sizes, jnp.float32
+        )
+
+
 def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None,
               stats: list | None = None) -> jnp.ndarray:
     """x [B, T, D] -> [B, T, D]; `real_mask` [B, T] bool marks genuine
@@ -174,6 +218,12 @@ def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None,
     xf = x.reshape(n, d)
     top_w, top_i = route(lp, cfg, xf)
 
+    # the grouped matmul works on whole tiles of rows, the pass on whole
+    # blocks of `c` rows: the pairs are padded with sentinel rows
+    m = -(-n * k // GMM_ROWS) * GMM_ROWS
+    c = block_rows(m, e, cfg.num_experts)
+    m = -(-m // c) * c
+
     with jax.named_scope("mlp.moe_dispatch"):
         expert_of = top_i.reshape(n * k)
         if e != cfg.num_experts:
@@ -186,9 +236,6 @@ def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None,
             expert_of = jnp.where(
                 jnp.repeat(real_mask.reshape(n), k), expert_of, e
             )
-        # the grouped matmul works on whole tiles of rows: the pairs are
-        # padded with sentinel rows up to a multiple of GMM_ROWS
-        m = -(-n * k // GMM_ROWS) * GMM_ROWS
         expert_of = jnp.pad(expert_of, (0, m - n * k), constant_values=e)
         pair = jnp.arange(m, dtype=jnp.int32)
         sorted_expert, order = jax.lax.sort(
@@ -196,29 +243,65 @@ def moe_block(lp: dict, cfg, x: jnp.ndarray, real_mask=None,
         )
         # rows per expert; the sentinel's bin is cut off
         group_sizes = jnp.zeros((e + 1,), jnp.int32).at[expert_of].add(1)[:e]
-        xs = xf[jnp.minimum(order // k, n - 1)]              # [M, D]
     if stats is not None:
-        stats.append((jnp.sum(group_sizes > 0), jnp.max(group_sizes)))
+        held = jnp.sum(group_sizes)
+        stats.append((jnp.sum(group_sizes > 0), jnp.max(group_sizes),
+                      -(-held // c) if c < m else 1, held))
 
-    with jax.named_scope("mlp.moe_experts"):
-        gate = grouped_matmul(xs, lp["we_gate"], group_sizes)
-        up = grouped_matmul(xs, lp["we_up"], group_sizes)
-        ys = grouped_matmul(
-            jax.nn.silu(gate) * up, lp["we_down"], group_sizes, jnp.float32
-        )                                                    # [M, D]
-
-    with jax.named_scope("mlp.moe_combine"):
-        # a row past the groups' end holds whatever the grouped matmul
-        # left there: selected out, never multiplied by a zero
-        w_sorted = jnp.pad(top_w.reshape(n * k), (0, m - n * k))[order]
-        ys = jnp.where(
-            (sorted_expert < e)[:, None], ys * w_sorted[:, None], 0.0
-        )
-        back = jnp.zeros((m,), jnp.int32).at[order].set(pair)
-        out = ys[back[: n * k]].reshape(n, k, d).sum(axis=1).astype(x.dtype)
+    if c < m:
+        out = _blocked(lp, xf, top_w, c, order, group_sizes)
+    else:
+        with jax.named_scope("mlp.moe_dispatch"):
+            xs = xf[jnp.minimum(order // k, n - 1)]          # [M, D]
+        ys = _experts(lp, xs, group_sizes)                   # [M, D]
+        with jax.named_scope("mlp.moe_combine"):
+            # a row past the groups' end holds whatever the grouped matmul
+            # left there: selected out, never multiplied by a zero
+            w_sorted = _pair_weights(top_w, m)[order]
+            ys = jnp.where(
+                (sorted_expert < e)[:, None], ys * w_sorted[:, None], 0.0
+            )
+            back = jnp.zeros((m,), jnp.int32).at[order].set(pair)
+            out = ys[back[: n * k]].reshape(n, k, d).sum(axis=1)
+    out = out.astype(x.dtype)
 
     if cfg.num_shared_experts:
         with jax.named_scope("mlp.moe_shared"):
             hidden = jax.nn.silu(mm(xf, lp["ws_gate"])) * mm(xf, lp["ws_up"])
             out = out + mm(hidden, lp["ws_down"])
     return out.reshape(b, t, d)
+
+
+def _blocked(lp: dict, xf, top_w, c: int, order, group_sizes):
+    """The held pairs, rows [0, R) of the sorted order, `c` rows a block:
+    ceil(R / c) blocks on the device's own count, each gathered, run
+    through its experts, weighted and added to its tokens' rows of an
+    [N, D] float32 sum. A block past the first re-reads only the weights
+    of the experts that have rows in it."""
+    (n, d), k, m = xf.shape, top_w.shape[1], order.shape[0]
+    with jax.named_scope("mlp.moe_dispatch"):
+        pair_w = _pair_weights(top_w, m)
+        ends = jnp.cumsum(group_sizes)      # running ends of the groups
+        held = ends[-1]
+
+    def block(i, acc):
+        start = i * c
+        with jax.named_scope("mlp.moe_dispatch"):
+            rows = jax.lax.dynamic_slice(order, (start,), (c,))
+            token = jnp.minimum(rows // k, n - 1)
+            xs = xf[token]                                   # [C, D]
+            # the block's part of each group: its ends clipped to [0, C]
+            sizes = jnp.diff(jnp.clip(ends - start, 0, c), prepend=0)
+        ys = _experts(lp, xs, sizes)
+        with jax.named_scope("mlp.moe_combine"):
+            # as above: a row past the last held pair is selected out
+            ys = jnp.where(
+                (start + jnp.arange(c) < held)[:, None],
+                ys * pair_w[rows][:, None], 0.0,
+            )
+            return acc.at[token].add(ys)
+
+    with jax.named_scope("mlp.moe_experts"):
+        return jax.lax.fori_loop(
+            0, -(-held // c), block, jnp.zeros((n, d), jnp.float32)
+        )

@@ -5,14 +5,27 @@ at a decode shape (128 tokens = 768 routed rows) and a prefill chunk's
 (512 tokens = 3,072 rows). Variants: `jax.lax.ragged_dot` on sorted rows,
 the megablox grouped-matmul kernel on sorted rows at several tilings, and
 every expert over every token (batched matmul + a weighted sum over the
-experts). Exits non-zero without a TPU; results go to
-`chiprun_out/moe_layer_shapes.json` (PERF.md section 6, PR 34).
+experts) (PERF.md section 6, PR 34).
 
-    chiprun -- python scripts/moe_layer_tpu.py
+`--shape share` (PR 39): a layer that HOLDS A SHARE of the experts, at
+MiMo-V2-Flash's widths as one chip of sixteen runs them (16 held of 256
+scored, top-8, 4,096 x 2,048, 6 expert layers = 4.83 GB of bf16), at 256
+tokens (the decode program: 2,048 pairs, ~128 of them held) and at 512 (a
+prefill chunk). The layer's glue at the whole width (gather, weigh and
+un-sort all 2,048 rows) against the held pairs a block at a time
+(`models/moe.py: block_rows`), the block's rows added to their tokens by
+a scatter-add or by a one-hot matmul at float32 precision, and the
+layer as the program runs it (`moe_block`, its router included).
+
+Exits non-zero without a TPU; results go to
+`chiprun_out/moe_layer_shapes.json`, one key a shape.
+
+    chiprun -- python scripts/moe_layer_tpu.py [--shape deepseek|share|all]
 """
 
 from __future__ import annotations
 
+import argparse
 import functools
 import json
 import os
@@ -22,6 +35,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from dynamo_tpu.models import moe  # noqa: E402
+from dynamo_tpu.models.config import get_config  # noqa: E402
 
 LAYERS, E, K, D, F = 8, 64, 6, 2048, 1408
 PEAK = 819e9
@@ -61,11 +79,31 @@ def dense(x, w, top_w, top_i):
     return jnp.einsum("end,ne->nd", y, weight.astype(y.dtype))
 
 
-def main() -> int:
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"needs a TPU, found {dev.platform}", file=sys.stderr)
-        return 3
+def timed(row: dict, step, args, floor_ms: float, ref):
+    """Median wall of `step(*args)` over 10 calls into `row`; the output
+    (float32) for the next variant to be compared with `ref`."""
+    try:
+        f = jax.jit(step)
+        y = jax.block_until_ready(f(*args))
+        if isinstance(y, tuple):    # (output, the layers' mean `stats`)
+            y, row["stats_mean"] = y[0], np.asarray(y[1]).tolist()
+        times = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(*args))
+            times.append(time.perf_counter() - t0)
+        row["ms"] = float(np.median(times)) * 1e3
+        row["pct_of_hbm_peak"] = floor_ms / row["ms"] * 100
+        y = np.asarray(y, np.float32)
+        ref = y if ref is None else ref
+        row["max_abs_diff_vs_first"] = float(np.abs(y - ref).max())
+    except Exception as e:  # noqa: BLE001 — a tiling Mosaic refuses
+        row["error"] = f"{type(e).__name__}: {e}"[:300]
+    print(json.dumps(row), flush=True)
+    return ref
+
+
+def deepseek(dev) -> dict:
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     def mega(tiling):
@@ -104,26 +142,142 @@ def main() -> int:
                     x = x + fn(x, w, top_w, top_i.astype(jnp.int32))
                 return x
             row = {"tokens": n, "variant": name}
-            try:
-                f = jax.jit(step)
-                y = jax.block_until_ready(f(ws, x))
-                times = []
-                for _ in range(10):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(f(ws, x))
-                    times.append(time.perf_counter() - t0)
-                row["ms"] = float(np.median(times)) * 1e3
-                row["pct_of_hbm_peak"] = out["floor_ms"] / row["ms"] * 100
-                y = np.asarray(y, np.float32)
-                if ref is None:
-                    ref = y
-                row["max_abs_diff_vs_first"] = float(np.abs(y - ref).max())
-            except Exception as e:  # noqa: BLE001 — a tiling Mosaic refuses
-                row["error"] = f"{type(e).__name__}: {e}"[:300]
-            print(json.dumps(row), flush=True)
+            ref = timed(row, step, (ws, x), out["floor_ms"], ref)
             out["rows"].append(row)
+    return out
+
+
+# ------------------------------------------------- a share of the experts
+
+S_LAYERS, S_HELD, S_SCORED, S_K, S_D, S_F = 6, 16, 256, 8, 4096, 2048
+
+
+def share_layer(form: str, x, w, top_w, top_i):
+    """One expert layer over the pairs routed to experts [0, S_HELD):
+    `whole` as `moe_block` runs a layer of one block, `scatter` /
+    `onehot` a block of `block_rows` rows at a time."""
+    n, d = x.shape
+    m = n * S_K
+    expert_of = top_i.reshape(m)
+    expert_of = jnp.where(expert_of < S_HELD, expert_of, S_HELD)
+    pair = jnp.arange(m, dtype=jnp.int32)
+    sorted_expert, order = jax.lax.sort(
+        (expert_of, pair), num_keys=1, is_stable=True)
+    sizes = jnp.zeros((S_HELD + 1,), jnp.int32).at[expert_of].add(1)[:S_HELD]
+    pair_w = top_w.reshape(m)
+
+    def experts(xs, sizes):
+        gate = moe.grouped_matmul(xs, w[0], sizes)
+        up = moe.grouped_matmul(xs, w[1], sizes)
+        return moe.grouped_matmul(
+            jax.nn.silu(gate) * up, w[2], sizes, jnp.float32)
+
+    if form == "whole":
+        ys = experts(x[order // S_K], sizes)
+        ys = jnp.where((sorted_expert < S_HELD)[:, None],
+                       ys * pair_w[order][:, None], 0.0)
+        back = jnp.zeros((m,), jnp.int32).at[order].set(pair)
+        return ys[back].reshape(n, S_K, d).sum(1).astype(x.dtype)
+
+    c = moe.block_rows(m, S_HELD, S_SCORED)
+    ends = jnp.cumsum(sizes)
+    held = ends[-1]
+
+    def block(i, acc):
+        start = i * c
+        rows = jax.lax.dynamic_slice(order, (start,), (c,))
+        token = rows // S_K
+        ys = experts(
+            x[token], jnp.diff(jnp.clip(ends - start, 0, c), prepend=0))
+        real = start + jnp.arange(c) < held
+        if form == "scatter":
+            ys = jnp.where(real[:, None], ys * pair_w[rows][:, None], 0.0)
+            return acc.at[token].add(ys)
+        # [N, C] weights, a pair's in its token's row, times the block's
+        # rows on the MXU (garbage rows selected out first: NaN x 0)
+        ys = jnp.where(real[:, None], ys, 0.0)
+        hot = jnp.where(
+            (token[None, :] == jnp.arange(n)[:, None]) & real[None, :],
+            pair_w[rows][None, :], 0.0)
+        return acc + jnp.dot(hot, ys, precision=jax.lax.Precision.HIGHEST)
+
+    return jax.lax.fori_loop(
+        0, -(-held // c), block, jnp.zeros((n, d), jnp.float32)
+    ).astype(x.dtype)
+
+
+def share(dev) -> dict:
+    cfg = get_config("mimo-v2-flash").with_(
+        experts_held=S_HELD, num_experts=S_SCORED, num_experts_per_tok=S_K,
+        hidden_size=S_D, moe_intermediate_size=S_F)
+    key = jax.random.PRNGKey(0)
+    lps = [moe.init_moe_params(cfg, jax.random.fold_in(key, i))
+           for i in range(S_LAYERS)]
+    ws = [(lp["we_gate"], lp["we_up"], lp["we_down"]) for lp in lps]
+    weight_bytes = S_LAYERS * 3 * S_HELD * S_D * S_F * 2
+    out = {"device": dev.device_kind, "weight_bytes": weight_bytes,
+           "floor_ms": weight_bytes / PEAK * 1e3, "rows": []}
+    for n in (256, 512):
+        x = jax.random.normal(key, (n, S_D), jnp.bfloat16)
+        logits = jax.random.normal(jax.random.fold_in(key, 99), (n, S_SCORED))
+        top_w, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), S_K)
+        top_w = top_w / top_w.sum(-1, keepdims=True)
+        top_i = top_i.astype(jnp.int32)
+        held = int((top_i < S_HELD).sum())
+        ref = None
+        for form in ("whole", "scatter", "onehot"):
+            # the routing is an ARGUMENT: closed over, XLA folds the sort
+            # and the blocks' count into constants and unrolls the loop
+            def step(ws, x, top_w, top_i, form=form):
+                for w in ws:
+                    x = x + share_layer(form, x, w, top_w, top_i)
+                return x
+            row = {"tokens": n, "variant": form, "pairs_held": held,
+                   "block_rows": moe.block_rows(n * S_K, S_HELD, S_SCORED)}
+            ref = timed(row, step, (ws, x, top_w, top_i), out["floor_ms"],
+                        ref)
+            out["rows"].append(row)
+        # the layer as the program runs it, its own router's pairs (so no
+        # output to compare), at the whole width and by blocks
+        rows_of = moe.block_rows
+        for name, fn in (("moe_block_whole", lambda m, *_: m),
+                         ("moe_block", rows_of)):
+            def step(lps, x):
+                stats = []
+                for lp in lps:
+                    x = x + moe.moe_block(lp, cfg, x[None], stats=stats)[0]
+                return x, jnp.mean(jnp.asarray(stats, jnp.float32), axis=0)
+            moe.block_rows = fn
+            row = {"tokens": n, "variant": name}
+            try:
+                timed(row, step, (lps, x), out["floor_ms"], None)
+            finally:
+                moe.block_rows = rows_of
+            out["rows"].append(row)
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", choices=("deepseek", "share", "all"),
+                    default="all")
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"needs a TPU, found {dev.platform}", file=sys.stderr)
+        return 3
+    path = "chiprun_out/moe_layer_shapes.json"
+    out = {}
+    if os.path.exists(path):
+        with open(path) as f:
+            old = json.load(f)
+        # PR 34's file was the DeepSeek shape's table itself
+        out = {"deepseek": old} if "rows" in old else old
+    for shape, fn in (("deepseek", deepseek), ("share", share)):
+        if args.shape in (shape, "all"):
+            out[shape] = fn(dev)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/moe_layer_shapes.json", "w") as f:
+    with open(path, "w") as f:
         json.dump(out, f, indent=1)
     return 0
 

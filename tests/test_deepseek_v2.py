@@ -237,6 +237,9 @@ async def test_served_logprobs_match_the_reference(backend):
         assert loads and all(r["kind"] in ("sync", "overlap") for r in loads)
         assert all(r["moe_experts_hit"] == 2.0 and r["moe_load_max"] == 1.0
                    for r in loads)
+        # all experts held: the pass is one block, over the row's 2 pairs
+        assert all(r["moe_row_blocks"] == 1.0 and r["moe_pairs_held"] == 2.0
+                   for r in loads)
     await engine.close()
 
 

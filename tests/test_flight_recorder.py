@@ -108,7 +108,8 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
     # columns are only ever appended: PR 36's five (a model with two
     # kinds of pool) follow the six that were the tail before it, PR 37's
     # one (a window layer's work items) follows them, then PR 38's seven
-    # (the host's clock by phase on dispatch and on sync / overlap rows)
+    # (the host's clock by phase on dispatch and on sync / overlap rows),
+    # then PR 39's two (an expert layer's pass by blocks of rows)
     assert FIELDS[16:] == ("kv_pages_streamed", "kv_pages_held",
                            "moe_experts_hit", "moe_load_max",
                            "frames", "gc_s",
@@ -116,15 +117,38 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
                            "kv_pages_held_full", "kv_win_pages_held",
                            "kv_win_pages_released", "kv_win_items",
                            "lock_s", "upload_s", "enqueue_s", "tick_s",
-                           "admit_s", "join_s", "unphased_s")
+                           "admit_s", "join_s", "unphased_s",
+                           "moe_row_blocks", "moe_pairs_held")
     assert d["kv_frac_win"] == d["kv_win_pages_held"] == 0
     assert d["kv_win_items"] == 0
     assert d["moe_experts_hit"] == d["moe_load_max"] == 0
+    assert d["moe_row_blocks"] == d["moe_pairs_held"] == 0
     with open(rec.trigger("manual")) as f:
         art = json.load(f)
     row = dict(zip(art["digest_fields"], art["digests"][-1]))
     assert (row["kv_pages_streamed"], row["kv_pages_held"]) == want
     assert row["rows"] == 4 and row["tokens"] == 32
+
+
+@pytest.mark.parametrize("kind", ["sync", "overlap"])
+def test_an_expert_pass_s_blocks_ride_the_landing_s_row(tmp_path, kind):
+    """What an expert model's decode program returns with its tokens (the
+    four means of `models/moe.py` `stats`) is amended onto the landing's
+    row, the newest of its kind, and rides the artifact by name."""
+    rec = make_recorder(tmp_path)
+    rec.record(kind, 0.09, rows=184, tokens=1472)
+    rec.record("decode", 0.002, rows=184, tokens=1472)
+    rec.amend(kind, moe_experts_hit=15.75, moe_load_max=12.5,
+              moe_row_blocks=1.0, moe_pairs_held=92.25)
+    landing, dispatch = rec.snapshot()[-2:]
+    assert (landing["moe_row_blocks"], landing["moe_pairs_held"]) == (
+        1.0, 92.25)
+    assert landing["moe_experts_hit"] == 15.75
+    assert dispatch["moe_row_blocks"] == dispatch["moe_pairs_held"] == 0
+    with open(rec.trigger("manual")) as f:
+        art = json.load(f)
+    row = dict(zip(art["digest_fields"], art["digests"][-2]))
+    assert (row["moe_row_blocks"], row["moe_pairs_held"]) == (1.0, 92.25)
 
 
 # --------------------------------------------------- trigger + rate limit

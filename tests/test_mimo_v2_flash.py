@@ -344,25 +344,33 @@ def test_sigmoid_bias_selection_matches_a_numpy_oracle():
     assert (np.argsort(-s, axis=1)[:, :2] != want_i).any()
 
 
-def test_the_shares_add_up():
-    """The expert layer's output summed over all shares (4 x 2 experts
-    held) equals the uncut layer's, and the reference's uncut layer."""
+@pytest.mark.parametrize("scored,tokens", [(8, 9), (16, 128)])
+def test_the_shares_add_up(scored, tokens):
+    """The expert layer's output summed over all shares (2 experts held
+    each) equals the uncut layer's, and the reference's uncut layer: at 4
+    shares of 8 (a share's pass is one block, the whole width) and at 8
+    shares of 16 over 512 pairs (a pass of 128-row blocks, as many as
+    hold a pair of the share's: `models/moe.py: block_rows`)."""
     from dynamo_tpu.models.moe import init_moe_params, moe_block
 
     key = jax.random.PRNGKey(5)
-    x = jax.random.normal(jax.random.PRNGKey(6), (2, 9, CFG.hidden_size))
-    whole_cfg = CFG.with_(experts_held=8)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, tokens, CFG.hidden_size))
+    whole_cfg = CFG.with_(num_experts=scored, experts_held=scored)
     whole = init_moe_params(whole_cfg, key, dtype=jnp.float32)
     want = moe_block(whole, whole_cfg, x)
     total = jnp.zeros_like(want)
-    for share in range(4):
-        cfg = CFG.with_(experts_held=2, expert_offset=2 * share)
+    ran = []
+    for share in range(scored // 2):
+        cfg = whole_cfg.with_(experts_held=2, expert_offset=2 * share)
         lp = init_moe_params(cfg, key, dtype=jnp.float32)
         np.testing.assert_array_equal(
             lp["we_gate"], whole["we_gate"][2 * share:2 * share + 2])
         stats = []
         total = total + moe_block(lp, cfg, x, stats=stats)
         assert int(stats[0][0]) <= 2   # load over the experts HELD
+        ran.append(int(stats[0][2]))
+        assert ran[-1] == (1 if scored == 8 else -(-int(stats[0][3]) // 128))
+    assert scored == 8 or max(ran) <= 2 < 4    # never the whole width
     np.testing.assert_allclose(total, want, atol=1e-5)
     ref = _reference()
     lp = {**whole, "mlp_norm": jnp.ones((CFG.hidden_size,))}
@@ -427,6 +435,10 @@ async def test_served_logprobs_match_the_reference(backend):
                if backend == "gather" or r["kind"] != "decode")
     loads = [r for r in rows if r["moe_experts_hit"]]
     assert loads and all(r["moe_experts_hit"] <= 4.0 for r in loads)
+    # 4 of 8 held: twice the even share is every row, one block a pass,
+    # over at most the one row's two pairs
+    assert all(r["moe_row_blocks"] == 1.0 and 0 < r["moe_pairs_held"] <= 2.0
+               for r in loads)
     await engine.close()
 
 

@@ -97,8 +97,10 @@ def test_no_token_is_dropped_at_any_imbalance(routing):
     got = np.asarray(moe_block(lp, cfg, x, stats=stats))
     ref = moe_oracle(lp, cfg, np.asarray(x))
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
-    (hit, load_max), = stats
+    (hit, load_max, blocks, held), = stats
     assert int(load_max) == n  # every token reached its first expert
+    # a layer that holds all its experts: one block, every pair held
+    assert (int(blocks), int(held)) == (1, n * cfg.num_experts_per_tok)
     assert int(hit) == cfg.num_experts_per_tok if routing == "uniform" \
         else 2 <= int(hit) <= cfg.num_experts
 
@@ -267,16 +269,22 @@ ALL_HELD_GOLDEN = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(ALL_HELD_GOLDEN))
-def test_all_held_softmax_presets_are_bit_equal_to_before(name):
-    import hashlib
-
+def _preset(name):
     from dynamo_tpu.models.config import PRESETS
-    from dynamo_tpu.models.moe import init_moe_params, moe_block, route
 
     cfg = PRESETS[name]
     if name == "mixtral-8x7b":   # its router and counts, at a CPU's width
         cfg = cfg.with_(hidden_size=64, intermediate_size=128)
+    return cfg
+
+
+@pytest.mark.parametrize("name", sorted(ALL_HELD_GOLDEN))
+def test_all_held_softmax_presets_are_bit_equal_to_before(name):
+    import hashlib
+
+    from dynamo_tpu.models.moe import init_moe_params, moe_block, route
+
+    cfg = _preset(name)
     assert cfg.held_experts == cfg.num_experts and cfg.expert_offset == 0
     lp = init_moe_params(cfg, jax.random.PRNGKey(11), dtype=jnp.float32)
     x = jax.random.normal(
@@ -288,8 +296,11 @@ def test_all_held_softmax_presets_are_bit_equal_to_before(name):
     h = hashlib.sha256()
     for a in (y, w, i, *[v for _, v in sorted(lp.items())]):
         h.update(np.asarray(a).tobytes())
-    assert (h.hexdigest()[:16], [int(s) for s in stats[0]]) == (
+    assert (h.hexdigest()[:16], [int(s) for s in stats[0][:2]]) == (
         ALL_HELD_GOLDEN[name])
+    # beside the load: one block, and the real rows' pairs (14 - 2 rows)
+    assert [int(s) for s in stats[0][2:]] == [
+        1, 12 * cfg.num_experts_per_tok]
 
 
 def test_a_share_computes_only_its_own_experts():
@@ -320,3 +331,146 @@ def test_a_share_computes_only_its_own_experts():
              ) @ whole["we_down"][e]
         want = want + y * jnp.sum(jnp.where(idx == e, w, 0.0), 1)[:, None]
     np.testing.assert_allclose(got[0], want, atol=1e-5)
+
+
+# ------------------------- a small share: the held pairs, a block at a time
+
+# 2 of 16 experts held, top-2, 256 tokens: 512 sorted pairs worked 128 at
+# a time (`block_rows`), about 64 of them real under an even router
+def _share():
+    from dynamo_tpu.models.config import PRESETS
+
+    return PRESETS["tiny-mimo"].with_(
+        num_experts=16, experts_held=2, expert_offset=4)
+
+
+def _held_part(lp, cfg, x, mask=None):
+    """Per held expert, over every token: its SwiGLU times the weight the
+    router gave the pair (0 where the token did not choose it)."""
+    from dynamo_tpu.models.moe import route
+
+    xf = x.reshape(-1, cfg.hidden_size)
+    w, idx = route(lp, cfg, xf)
+    if mask is not None:
+        w = jnp.where(mask.reshape(-1, 1), w, 0.0)
+    want = jnp.zeros_like(xf)
+    for j in range(cfg.held_experts):
+        y = (jax.nn.silu(xf @ lp["we_gate"][j]) * (xf @ lp["we_up"][j])
+             ) @ lp["we_down"][j]
+        want = want + y * jnp.sum(
+            jnp.where(idx == cfg.expert_offset + j, w, 0.0), 1)[:, None]
+    return want.reshape(x.shape), (idx >= cfg.expert_offset) & (
+        idx < cfg.expert_offset + cfg.held_experts)
+
+
+def test_block_rows_follow_from_the_share_held_and_the_row_count():
+    from dynamo_tpu.models.moe import GMM_ROWS, block_rows
+
+    # every expert held: the whole width, whatever it is
+    assert block_rows(768, 64, 64) == 768
+    assert block_rows(GMM_ROWS, 8, 8) == GMM_ROWS
+    # 16 of 256 at top-8: 256 decode slots, a 512-token chunk
+    assert block_rows(2048, 16, 256) == 256
+    assert block_rows(4096, 16, 256) == 512
+    # half held: twice the even share is everything
+    assert block_rows(256, 4, 8) == 256
+    # never less than a tile, never more than the rows there are
+    assert block_rows(GMM_ROWS, 1, 256) == GMM_ROWS
+    assert block_rows(512, 2, 16) == 128
+    assert block_rows(384, 2, 16) == 128
+
+
+@pytest.mark.parametrize("load,blocks", [
+    ("even", 1), ("all-held", 4), ("half-held", 2), ("none-held", 0)])
+def test_a_small_share_is_drop_free_a_block_at_a_time(load, blocks):
+    """A router that sends EVERY token's pairs to the held experts runs
+    m / C blocks and computes them all; an even one runs one block, one
+    that sends nothing here runs none; padding rows are nobody's."""
+    cfg = _share()
+    lp = _layer(cfg, seed=3)
+    n = 256
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, n // 2, cfg.hidden_size))
+    held = slice(cfg.expert_offset, cfg.expert_offset + cfg.held_experts)
+    if load != "even":
+        # sigmoid(0) = 0.5 everywhere, the held columns far above (or
+        # below) it on positive inputs: both held experts win (or lose)
+        x = jnp.abs(x)
+        lp["router"] = jnp.zeros_like(lp["router"]).at[:, held].set(
+            -100.0 if load == "none-held" else 100.0)
+    if load == "half-held":
+        # every second token's input negated: its held scores fall to 0
+        x = x * jnp.where(jnp.arange(n // 2) % 2 == 0, 1.0, -1.0)[None, :, None]
+    mask = jnp.ones((2, n // 2), bool).at[1, 100:].set(False)
+    if load == "all-held":
+        mask = jnp.ones_like(mask)      # all 512 pairs real: every block
+    stats = []
+    got = moe_block(lp, cfg, x, real_mask=mask, stats=stats)
+    want, chosen = _held_part(lp, cfg, x, mask)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-5)
+    pairs = int((chosen & mask.reshape(-1, 1)).sum())
+    hit, load_max, ran, pairs_held = (int(s) for s in stats[0])
+    assert (ran, pairs_held) == (blocks, pairs)
+    assert ran == -(-pairs // 128)
+    if load == "all-held":
+        assert (pairs, load_max, hit) == (2 * n, n, 2)
+    if load == "none-held":
+        assert pairs == hit == 0 and not np.asarray(got).any()
+    # a padding row comes out empty and, whatever it holds, moves no
+    # real row
+    keep = np.asarray(mask.reshape(-1))
+    flat = np.asarray(got).reshape(n, -1)
+    assert not flat[~keep].any()
+    x2 = jnp.where(mask[..., None], x, 1e3 * x + 7.0)
+    again = np.asarray(moe_block(lp, cfg, x2, real_mask=mask)).reshape(n, -1)
+    np.testing.assert_array_equal(again[keep], flat[keep])
+
+
+def test_pairs_that_are_not_whole_blocks_are_padded_to_them():
+    """192 tokens x 2 = 384 pairs, 128 a block: three blocks of rows."""
+    cfg = _share()
+    lp = _layer(cfg, seed=4)
+    x = jnp.abs(jax.random.normal(
+        jax.random.PRNGKey(8), (1, 192, cfg.hidden_size)))
+    held = slice(cfg.expert_offset, cfg.expert_offset + cfg.held_experts)
+    lp["router"] = jnp.zeros_like(lp["router"]).at[:, held].set(100.0)
+    stats = []
+    got = moe_block(lp, cfg, x, stats=stats)
+    want, _ = _held_part(lp, cfg, x)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-5)
+    assert [int(s) for s in stats[0][2:]] == [3, 384]
+
+
+# sha256 (first 16 hex) of str(jax.make_jaxpr(moe_block)) with the tree of
+# PR 38, before a pass knew of blocks: a layer that holds all its experts
+# (and tiny-mimo's half) traces to the program it traced to then
+WHOLE_WIDTH_JAXPR = {
+    "tiny-moe": "91fa5adfd1cd7942",
+    "tiny-mla": "701a98e77cf35805",
+    "mixtral-8x7b": "886d0140e9dff74c",
+    "tiny-mimo": "bc3f8955766d97e2",
+}
+
+
+def _jaxpr(cfg, tokens):
+    lp = init_moe_params(cfg, jax.random.PRNGKey(11), dtype=jnp.float32)
+    x = jnp.zeros((2, tokens, cfg.hidden_size), jnp.float32)
+    mask = jnp.ones((2, tokens), bool)
+    return str(jax.make_jaxpr(
+        lambda lp, x, mask: moe_block(lp, cfg, x, real_mask=mask)
+    )(lp, x, mask))
+
+
+@pytest.mark.parametrize("name", sorted(WHOLE_WIDTH_JAXPR))
+def test_a_layer_of_one_block_traces_to_the_parent_s_program(name):
+    import hashlib
+
+    text = _jaxpr(_preset(name), 7)
+    assert "while" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        WHOLE_WIDTH_JAXPR[name])
+
+
+def test_a_small_share_loops_around_one_body_s_three_kernels():
+    whole, share = _jaxpr(_preset("tiny-mimo"), 7), _jaxpr(_share(), 128)
+    assert share.count("while[") == 1
+    assert share.count("name=gmm") == whole.count("name=gmm") > 0

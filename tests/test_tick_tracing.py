@@ -193,7 +193,9 @@ async def test_digest_columns():
     assert all(r["preempted"] == 0 for r in rows)
     # the host's clock by phase (PR 38), appended: a dispatch row holds
     # its worker's lock, uploads and launch, a landing the loop's tick
-    assert flightmod.FIELDS[-7:] == WORKER_COLUMNS + TICK_COLUMNS
+    # (then PR 39's two, an expert layer's pass by blocks of rows)
+    assert flightmod.FIELDS[-9:-2] == WORKER_COLUMNS + TICK_COLUMNS
+    assert flightmod.FIELDS[-2:] == ("moe_row_blocks", "moe_pairs_held")
     for r in by["decode"] + by["prefill"]:
         assert r["upload_s"] > 0 and r["enqueue_s"] > 0 and r["lock_s"] >= 0
         assert (r["lock_s"] + r["upload_s"] + r["enqueue_s"]
