@@ -202,33 +202,52 @@ def test_padding_positions_advance_nothing():
 
 
 @pytest.mark.parametrize("heads,p,n,rows", [
-    (64, 64, 128, 5), (16, 8, 16, 3), (8, 32, 16, 2)])
+    (64, 64, 128, 5), (16, 8, 16, 3), (8, 32, 16, 2), (8, 32, 16, 32)])
 def test_the_one_pass_kernel_is_the_plain_update(heads, p, n, rows):
     """`ops/pallas_ssm.py`: the kernel (interpreted here) against
-    `reference_update` against the recurrence as written (`_step` on the
-    unpacked state), at the published widths and at two small ones; rows
-    past the program's width come back untouched."""
+    `reference_update`, the plain span from `w_in`'s output to `w_out`'s
+    input (the gated, normed row, the state and the tail), and that
+    span's state against the recurrence as written (`_step` on the unpacked
+    state), at the published widths and at two small ones, a program
+    narrower than a group of rows and one of two groups; a row that does
+    not advance, a row that starts a sequence; rows past the program's
+    width come back untouched."""
     from dynamo_tpu.ops import pallas_ssm as ps
 
-    groups, f = 1, ps.pack_factor(p)
-    q, lanes, r = heads // f, f * p, heads
-    ks = jax.random.split(jax.random.PRNGKey(heads), 6)
-    pool = jax.random.normal(ks[0], (rows + 2, q, n, lanes)).astype(
-        jnp.bfloat16)
-    x = jax.random.normal(ks[1], (rows, groups, r, p))
-    dt = jax.nn.softplus(jax.random.normal(ks[2], (rows, groups, r)) - 2.0)
-    a = -jnp.exp(jax.random.normal(ks[3], (groups, r)))
-    b = jax.random.normal(ks[4], (rows, groups, n))
-    c = jax.random.normal(ks[5], (rows, groups, n))
-    decay = jnp.broadcast_to(jnp.exp(dt * a)[..., None],
-                             (rows, groups, r, p)).reshape(rows, q, lanes)
-    dtx = (dt[..., None] * x).reshape(rows, q, lanes)
-    y_ref, pool_ref = ps.reference_update(pool, decay, dtx, b, c)
-    h0 = ps.unpack(pool[:rows], f).astype(jnp.float32).reshape(
-        rows, groups, r, p, n)
-    y_rec, h_rec = _step(h0, x, b, c, dt, a)
-    np.testing.assert_allclose(y_ref.reshape(y_rec.shape), y_rec,
-                               rtol=1e-5, atol=1e-5)
+    f, k = ps.pack_factor(p), 4
+    q, lanes, inner = heads // f, f * p, heads * p
+    cw = inner + 2 * n
+    ks = jax.random.split(jax.random.PRNGKey(heads), 9)
+    bf = jnp.bfloat16
+    pool = jax.random.normal(ks[0], (rows + 2, q, n, lanes)).astype(bf)
+    tails = jax.random.normal(ks[1], (rows + 2, k - 1, cw)).astype(bf)
+    zxbcdt = jax.random.normal(ks[2], (rows, 1, inner + cw + heads)).astype(bf)
+    w = ps.StepWeights(
+        conv_w=(jax.random.normal(ks[3], (k, cw)) / 2).astype(bf),
+        conv_b=(jax.random.normal(ks[4], (cw,)) / 2).astype(bf),
+        dt_bias=jax.random.normal(ks[5], (heads,)) - 2.0,
+        a_log=jax.random.normal(ks[6], (heads,)),
+        d=jax.random.normal(ks[7], (heads,)),
+        norm=jax.random.normal(ks[8], (inner,)).astype(bf))
+    real = jnp.arange(rows) != 1
+    fresh = jnp.arange(rows) == 0
+    y_ref, pool_ref, tails_ref = ps.reference_update(
+        zxbcdt, pool, tails, real, fresh, w, eps=1e-5)
+
+    # the recurrence as written, from the same convolved x, B, C and dt
+    seq = jnp.concatenate([jnp.where(
+        fresh[:, None, None], 0, tails[:rows]), zxbcdt[:, :, inner:-heads]], 1)
+    xbc = jax.nn.silu(
+        (seq.astype(jnp.float32) * w.conv_w.astype(jnp.float32)).sum(1)
+        + w.conv_b.astype(jnp.float32)).astype(bf).astype(jnp.float32)
+    dt = jnp.where(real[:, None], jax.nn.softplus(
+        zxbcdt[:, 0, -heads:].astype(jnp.float32) + w.dt_bias), 0.0)
+    h0 = jnp.where(fresh[:, None, None, None], 0, ps.unpack(pool[:rows], f))
+    _, h_rec = _step(
+        h0.astype(jnp.float32)[:, None], xbc[:, None, :inner].reshape(
+            rows, 1, heads, p), xbc[:, None, inner:inner + n],
+        xbc[:, None, inner + n:], dt[:, None], -jnp.exp(w.a_log)[None])
+
     def same_rounding(got, want):
         # one rounding on the write: equal but for ties that a fused
         # multiply-add in one of the two programs rounds the other way
@@ -236,20 +255,31 @@ def test_the_one_pass_kernel_is_the_plain_update(heads, p, n, rows):
         np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=1e-6)
         assert (got != want).mean() < 1e-4
 
-    same_rounding(pool_ref[:rows], ps.pack(
-        h_rec.reshape(rows, heads, p, n).astype(jnp.bfloat16), f))
-    np.testing.assert_array_equal(pool_ref[rows:], pool[rows:])
-    y_k, pool_k = ps.kernel_update(pool, decay, dtx, b, c, interpret=True)
-    np.testing.assert_allclose(y_k, y_ref, rtol=1e-5, atol=1e-5)
+    same_rounding(pool_ref[:rows], ps.pack(h_rec[:, 0].astype(bf), f))
+    np.testing.assert_array_equal(pool_ref[1], pool[1])    # not advanced
+    np.testing.assert_array_equal(tails_ref[1], tails[1])
+    np.testing.assert_array_equal(tails_ref[0, :-1], 0)    # fresh
+    np.testing.assert_array_equal(tails_ref[0, -1], zxbcdt[0, 0, inner:-heads])
+    if rows > 2:
+        np.testing.assert_array_equal(tails_ref[2, :-1], tails[2, 1:])
+    y_k, pool_k, tails_k = ps.kernel_update(
+        zxbcdt, pool, tails, real, fresh, w, eps=1e-5, interpret=True)
+    assert y_k.shape == y_ref.shape == (rows, 1, inner) and y_k.dtype == bf
+    np.testing.assert_allclose(np.asarray(y_k, np.float32), np.asarray(
+        y_ref, np.float32), rtol=2 ** -7, atol=1e-5)
     same_rounding(pool_k[:rows], pool_ref[:rows])
-    np.testing.assert_array_equal(pool_k[rows:], pool[rows:])
-    np.testing.assert_array_equal(ps.unpack(ps.pack(h_rec.reshape(
-        rows, heads, p, n), f), f), h_rec.reshape(rows, heads, p, n))
+    np.testing.assert_array_equal(tails_k[:rows], tails_ref[:rows])
+    for before, after in ((pool, pool_k), (tails, tails_k),
+                          (pool, pool_ref), (tails, tails_ref)):
+        np.testing.assert_array_equal(after[rows:], before[rows:])
+    np.testing.assert_array_equal(
+        ps.unpack(ps.pack(h_rec[:, 0], f), f), h_rec[:, 0])
 
 
 def test_a_decode_row_that_is_not_advanced_keeps_its_state():
     """`slots` None: row b IS slot b; `real` False (the fused path's
-    write_pos == -1) leaves state and tail to the bit."""
+    write_pos == -1) leaves state and tail to the bit; a `fresh` row
+    starts from zero whatever its slot holds."""
     u = jax.random.normal(jax.random.PRNGKey(2), (3, 1, CFG.hidden_size))
     ssm, conv = _pools()
     real = jnp.asarray([[True], [False], [True]])
@@ -258,6 +288,14 @@ def test_a_decode_row_that_is_not_advanced_keeps_its_state():
     np.testing.assert_array_equal(c2[1], conv[1])
     np.testing.assert_array_equal(s2[3:], ssm[3:])
     assert float(jnp.abs(s2[0] - ssm[0]).max()) > 0
+    # a fresh row starts from zero whatever its slot holds
+    of, sf, cf = _mixer(u, ssm, conv, None, real, jnp.asarray(
+        [False, False, True]))
+    oz, sz, cz = _mixer(u, *_pools(fill=0.0), None, real, jnp.zeros(3, bool))
+    for got, want in ((of, oz), (sf, sz), (cf, cz)):
+        np.testing.assert_array_equal(got[2], want[2])
+    np.testing.assert_array_equal(sf[:2], s2[:2])
+    np.testing.assert_array_equal(cf[:2], c2[:2])
     # one token at a time = the chunk form over the same tokens
     seq = jax.random.normal(jax.random.PRNGKey(3), (1, 6, CFG.hidden_size))
     one = jnp.ones((1, 1), bool)

@@ -573,14 +573,37 @@ def _state_shapes(cfg, page, num_pages, slots):
     return params, kv
 
 
+def _scan_body_ops(text: str, scope: str) -> list[str]:
+    """The device operations (fusions, kernels, copies: what the step
+    launches) in the body of the program's outermost loop whose metadata
+    names `scope`, by opcode."""
+    blocks = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    entry = next(b for b in blocks if b.startswith("ENTRY"))
+    body = re.search(r" while\(.*body=(%[\w.\-]+)", entry).group(1)
+    block = next(b for b in blocks if b.startswith(body + " ("))
+    ops = []
+    for ln in block.splitlines():
+        m = re.match(
+            r"\s+(?:ROOT )?%[\w.\-]+ = (?:\(.*?\)|\S+) ([\w\-]+)\(", ln)
+        if m and scope in ln and m.group(1) not in (
+                "bitcast", "get-tuple-element", "tuple", "parameter",
+                "constant"):
+            ops.append(m.group(1))
+    return ops
+
+
 def test_state_decode_scan_updates_the_state_pools_in_place(
         one_chip, no_persistent_cache):
     """The `granite-4.0-h-micro.reason-wide` cell's decode dispatch (width
     128, pages of 128, bf16) through a two-step scan: the decode kernel
     takes 64-wide heads (8 KV heads folded into 512 lanes) with no rope, and
-    no state pool ([129, 32, 128, 128]: 135 MB a layer, 4.9 GB over 36) is
-    copied anywhere in the step: the one-pass kernel (`ops/pallas_ssm.py`)
-    takes it and hands it back aliased."""
+    no state pool ([129, 32, 128, 128]: 135 MB a layer, 4.9 GB over 36;
+    [129, 3, 4352] of tails) is copied anywhere in the step: the one-pass
+    kernel (`ops/pallas_ssm.py`) takes both and hands them back aliased.
+    And between a MAMBA layer's two matmuls the step launches that kernel
+    and NOTHING else (PR 42; the parent launched ~25 operations a layer
+    there: slices, relayout copies, a gather, float32 [rows, H P] arrays of
+    what is one number a head)."""
     cfg = _state_cfg()
     page, num_pages, width, max_len = 128, 2048, 128, 4096
     params, kv = _state_shapes(cfg, page, num_pages, width + 1)
@@ -612,13 +635,27 @@ def test_state_decode_scan_updates_the_state_pools_in_place(
     text = compiled.as_text()
     # the attention layer's decode kernel and three state updates, a step
     assert text.count("tpu_custom_call") >= 4
+    # a tail lies [d_conv - 1, slots, CW] on the device: either spelling
     moved = [ln.strip()[:160] for ln in text.splitlines() if re.search(
-        r"= bf16\[129,32,128,128\]\S* (copy|copy-start)\(", ln)]
+        r"= bf16\[(129,32,128,128|129,3,4352|3,129,4352)\]\S* "
+        r"(copy|copy-start)\(", ln)]
     assert not moved, "state pools copied inside the step:\n" + "\n".join(
         moved)
     # nothing of a pool's size beside the pools: three layers' float32
     # states at once would be 3 x 268 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 600e6
+    # what is one number a head is spread over the head's lanes INSIDE the
+    # kernel: no float32 [rows, H P] array (`exp(dt A)`, `dt x`, the
+    # kernel's `y`) in either of its two layouts
+    assert not re.search(r"= f32\[128,(32,128|64,64|4096)\]", text)
+    # the convolution and the gated norm are scopes of the prefill programs
+    assert "attn.ssm_conv" not in text and "attn.ssm_gate_norm" not in text
+    # under attn.ssm_*, a step: the two matmuls and the kernel a layer (9
+    # over three layers) + the rows' `real` / `fresh` flags made columns
+    # once for all layers (a fusion and two relayout copies); the parent: 84
+    ops = _scan_body_ops(text, "attn.ssm_")
+    assert ops.count("custom-call") == 3, ops
+    assert len(ops) <= 3 * 3 + 3, ops
 
 
 @pytest.mark.parametrize("rows,bucket,wb", [(1, 512, 8), (4, 128, 8)],
