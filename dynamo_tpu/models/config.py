@@ -3,7 +3,11 @@ Gemma), Mixtral-style expert models, the DeepSeek-V2 family (latent
 attention, softmax-routed experts beside shared ones, leading dense layers)
 and the MiMo-V2 family (window layers with a learned sink beside
 full-attention layers, keys wider than values, sigmoid-routed experts of
-which a deployment's chip holds a share).
+which a deployment's chip holds a share), the Granite 4.0-H family
+(Mamba-2 layers beside attention) and the Xing4.0 family (`xing4_0`:
+latent attention with LOW-RANK queries, sigmoid-routed experts beside a
+shared one with a scaling factor, and a residual of `hc_mult` streams a
+token mixed at every sublayer by manifold-constrained hyper-connections).
 
 One config dataclass covers the architectures the reference serves through
 vLLM/sglang (reference: examples/llm/configs/*.yaml serve Llama/DeepSeek
@@ -36,6 +40,19 @@ Conventions:
 - an expert layer may hold a SHARE of the experts: the router scores all
   `num_experts`, the layer holds `experts_held` of them from
   `expert_offset` (0 held = all of them, every other preset).
+- low-rank queries (`q_lora_rank > 0`, latent attention only): `q =
+  RMSNorm(x W_qa) W_qb` in place of one matrix `W_q` (0 = one matrix,
+  every other preset).
+- the residual convention: every preset but one carries ONE stream a
+  token, `x [B, T, D]`, and a sublayer adds to it (`hc_mult` 1). With
+  `hc_mult` n > 1 a token carries n streams, `X [B, T, n, D]`: each
+  sublayer reads a learned, input-dependent mix of them and writes back
+  through a doubly-stochastic n x n matrix made by `hc_sinkhorn_iters`
+  Sinkhorn iterations on `exp(clamp(., -hc_res_clamp, hc_res_clamp))`
+  (models/mhc.py). `forward` copies the embedding into the n streams and
+  sums them before the final norm, so nothing outside `models/` sees
+  them; such a model runs on ONE device (the stage executors and every
+  mesh axis refuse it).
 - dtypes: weights/activations bfloat16 on TPU (MXU-native), float32 for
   norms/softmax accumulation inside the ops.
 """
@@ -148,6 +165,21 @@ class ModelConfig:
     mamba_d_conv: int = 4
     mamba_chunk: int = 256
     mamba_conv_bias: bool = True
+    # low-rank queries of latent attention (xing4_0; 0 = one matrix W_q)
+    q_lora_rank: int = 0
+    # the residual's streams a token (1 = one stream, a plain add) and,
+    # for more, the maps of models/mhc.py: Sinkhorn iterations, the
+    # epsilon in each of their divisions, the clamp on exp's argument
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # of SEEDED weights only (a checkpoint's come from training), stated by
+    # the preset so that no other preset's weights follow from them: the
+    # routed experts' down-projection beside its fan-in scale and the
+    # selection bias's deviation (moe.py: init_moe_params has the reasons)
+    seed_expert_down_scale: float = 1.0
+    seed_router_bias_std: float = 0.02
 
     @property
     def hybrid(self) -> bool:
@@ -243,13 +275,15 @@ class ModelConfig:
     def from_hf_config(cls, hf: dict, name: str = "hf-model") -> "ModelConfig":
         """Build from a HuggingFace config.json dict (llama / mistral /
         qwen2 / gemma / mixtral / deepseek_v2 / mimo_v2_flash /
-        granitemoehybrid)."""
+        granitemoehybrid / xing4_0)."""
         if hf.get("model_type") == "deepseek_v2":
             return cls._from_deepseek_v2(hf, name)
         if hf.get("model_type") == "mimo_v2_flash":
             return cls._from_mimo_v2_flash(hf, name)
         if hf.get("model_type") == "granitemoehybrid":
             return cls._from_granitemoehybrid(hf, name)
+        if hf.get("model_type") == "xing4_0":
+            return cls._from_xing4_0(hf, name)
         num_heads = hf["num_attention_heads"]
         head_dim = hf.get("head_dim") or hf["hidden_size"] // num_heads
         return cls(
@@ -492,6 +526,84 @@ class ModelConfig:
             mamba_d_conv=hf.get("mamba_d_conv", 4),
             mamba_chunk=hf.get("mamba_chunk_size", 256),
             mamba_conv_bias=bool(hf.get("mamba_conv_bias", True)),
+        )
+
+    @classmethod
+    def _from_xing4_0(cls, hf: dict, name: str) -> "ModelConfig":
+        """The `xing4_0` keys: the `deepseek_v3` layout (latent attention
+        with low-rank queries, sigmoid-routed experts chosen with a
+        correction bias, renormalised and scaled, beside shared ones,
+        leading dense layers, YaRN) and a residual of `hc_mult` streams
+        (`hc_*`, `mhc_h_res_clamp_*`). The multi-token-prediction layer
+        (`num_nextn_predict_layers`) is not built: a server that does not
+        draft from it drops it at load. What is not served is refused by
+        name."""
+        sc = hf.get("rope_scaling") or {}
+        clamp = hf.get("mhc_h_res_clamp_max", 30)
+        unsupported = {
+            "n_group": hf.get("n_group", 1) not in (None, 1),
+            "topk_group": hf.get("topk_group", 1) not in (None, 1),
+            "ep_size": hf.get("ep_size", 1) not in (None, 1),
+            "attention_bias": bool(hf.get("attention_bias")),
+            "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+            "scoring_func": hf.get("scoring_func") != "sigmoid",
+            "topk_method": hf.get("topk_method") != "noaux_tc",
+            "q_lora_rank": not hf.get("q_lora_rank"),
+            "hidden_act": hf.get("hidden_act", "silu") != "silu",
+            "rope_scaling":
+                bool(sc) and (sc.get("type") != "yarn" or sc.get(
+                    "mscale", 1.0) != sc.get("mscale_all_dim", 0.0)),
+            "hc_mult": hf.get("hc_mult", 1) < 2,
+            "mhc_h_res_clamp_min":
+                hf.get("mhc_h_res_clamp_min", -clamp) != -clamp,
+        }
+        bad = [k for k, v in unsupported.items() if v]
+        if bad:
+            raise ValueError(
+                f"xing4_0 config: {bad[0]}={hf.get(bad[0])!r} is not served "
+                "(group-limited routing, experts over an ep group, "
+                "attention bias, expert layers at a period other than 1, a "
+                "router other than sigmoid with a noaux_tc bias, full-rank "
+                "queries, an activation other than silu, rope scaling other "
+                "than YaRN with mscale == mscale_all_dim, a residual of one "
+                "stream, a clamp that is not symmetric)"
+            )
+        nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+        return cls(
+            name=name,
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf.get(
+                "num_key_value_heads", hf["num_attention_heads"]
+            ),
+            head_dim=nope + rope,
+            rope_theta=hf.get("rope_theta", 10000.0),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            rope_scaling=hf.get("rope_scaling"),
+            num_experts=hf.get("n_routed_experts") or 0,
+            num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+            moe_intermediate_size=hf.get("moe_intermediate_size", 0),
+            num_shared_experts=hf.get("n_shared_experts") or 0,
+            first_dense_layers=hf.get("first_k_dense_replace", 0),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=hf["v_head_dim"],
+            scoring_func="sigmoid",
+            router_bias=True,
+            q_lora_rank=hf["q_lora_rank"],
+            hc_mult=hf["hc_mult"],
+            hc_sinkhorn_iters=hf.get("hc_sinkhorn_iters", 20),
+            hc_eps=float(hf.get("hc_eps", 1e-6)),
+            hc_res_clamp=float(clamp),
+            **_XING_SEEDS,
         )
 
 
@@ -861,6 +973,92 @@ TINY_GRANITE = _preset(ModelConfig(
     mamba_groups=1,
     mamba_d_conv=4,
     mamba_chunk=8,
+))
+
+
+# Xing4.0 family: the deepseek_v3 layout (latent attention with low-rank
+# queries at 32 heads, 64 sigmoid-routed experts top-4 chosen with a
+# correction bias, renormalised, x 2, beside one shared expert, two
+# leading dense layers, YaRN x 64 with mscale = mscale_all_dim = 1) around
+# a residual of FOUR streams a token (models/mhc.py).
+_XING_YARN = {
+    "beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+    "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+    "type": "yarn",
+}
+
+# the family's seeded weights (no key of the published config): a chosen
+# expert weighs ~0.5 here (top-4 renormalised, x 2), so the seeded routed
+# experts are scaled to a quarter, and a layer holds every expert, so a
+# bias of N(0, 0.05) starves none
+_XING_SEEDS = {"seed_expert_down_scale": 0.25, "seed_router_bias_std": 0.05}
+
+_preset(ModelConfig(
+    name="xing4.0-29b-a4b",
+    vocab_size=131072,
+    hidden_size=3584,
+    intermediate_size=9216,
+    num_layers=40,
+    num_heads=32,
+    num_kv_heads=32,
+    head_dim=192,
+    rope_theta=10000,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=262144,
+    rope_scaling=_XING_YARN,
+    num_experts=64,
+    num_experts_per_tok=4,
+    moe_intermediate_size=1024,
+    num_shared_experts=1,
+    first_dense_layers=2,
+    norm_topk_prob=True,
+    routed_scaling_factor=2.0,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    scoring_func="sigmoid",
+    router_bias=True,
+    q_lora_rank=768,
+    hc_mult=4,
+    hc_sinkhorn_iters=20,
+    hc_eps=1e-6,
+    hc_res_clamp=30.0,
+    **_XING_SEEDS,
+))
+
+# the same family at a size the CPU tests finish in seconds: 3 layers of
+# which 1 dense, 8 experts top-2, 1 shared, latent rank 32, rope 16,
+# query rank 24, four streams
+TINY_XING = _preset(ModelConfig(
+    name="tiny-xing",
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=3,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=48,
+    rope_theta=10000.0,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=2048,
+    rope_scaling={**_XING_YARN, "original_max_position_embeddings": 64},
+    num_experts=8,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    num_shared_experts=1,
+    first_dense_layers=1,
+    norm_topk_prob=True,
+    routed_scaling_factor=2.0,
+    kv_lora_rank=32,
+    qk_nope_head_dim=32,
+    qk_rope_head_dim=16,
+    v_head_dim=32,
+    scoring_func="sigmoid",
+    router_bias=True,
+    q_lora_rank=24,
+    hc_mult=4,
+    **_XING_SEEDS,
 ))
 
 

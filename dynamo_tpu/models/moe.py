@@ -109,17 +109,29 @@ def init_moe_params(cfg, key, dtype=jnp.bfloat16) -> dict:
         def experts(k, shape, scale):
             return dense(k, (e, *shape), scale)
 
+    # a checkpoint's experts resemble each other; seeded ones are
+    # strangers, so a near-tie at the top-k threshold that bf16 activations
+    # decide the other way than float32 (a few in a hundred pairs, any
+    # router) exchanges two unrelated outputs. Where a chosen expert weighs
+    # much (renormalised and scaled up: ~0.5, not a softmax's ~0.03) those
+    # exchanges, not the arithmetic, would be what a comparison with a
+    # float32 reference reads, so such a preset STATES a scale for its
+    # seeded down-projection (`seed_expert_down_scale`, 1 = fan-in scale,
+    # every preset but the Xing4.0 family's)
+    down = cfg.seed_expert_down_scale * f ** -0.5
     lp = {
         "router": dense(k_router, (d, e), d ** -0.5),
         "we_gate": experts(k_gate, (d, f), d ** -0.5),
         "we_up": experts(k_up, (d, f), d ** -0.5),
-        "we_down": experts(k_down, (f, d), f ** -0.5),
+        "we_down": experts(k_down, (f, d), down),
     }
     if cfg.router_bias:
-        # N(0, 0.02) so that the selection bias is judged (HF initialises it
-        # to zero) and the load stays near even, as the trained bias of a
-        # checkpoint keeps it: at 0.05 the draw starves 2-3 experts in 16
-        lp["router_bias"] = 0.02 * jax.random.normal(
+        # seeded so that the selection bias is judged (HF initialises it to
+        # zero), at the deviation the preset states
+        # (`seed_router_bias_std`): N(0, 0.02) keeps the load near even, as
+        # the trained bias of a checkpoint does (at 0.05 the draw starves
+        # 2-3 experts of a held share of 16)
+        lp["router_bias"] = cfg.seed_router_bias_std * jax.random.normal(
             jax.random.fold_in(k_router, 1), (e,), jnp.float32
         )
     if cfg.num_shared_experts:

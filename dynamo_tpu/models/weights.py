@@ -72,6 +72,13 @@ def load_params(
             "attention) is not written yet: the published tensor names "
             "are not on this machine; such a model runs on seeded weights"
         )
+    if cfg.hc_mult > 1 or cfg.q_lora_rank:
+        raise ValueError(
+            f"checkpoint loading for '{cfg.name}' (low-rank queries, a "
+            "residual of several streams) is not written yet: the published "
+            "tensor names are not on this machine; such a model runs on "
+            "seeded weights"
+        )
     put = put or (lambda _path, arr: jnp.asarray(arr))
 
     def convert(name: str, t: np.ndarray, transpose: bool) -> jnp.ndarray:

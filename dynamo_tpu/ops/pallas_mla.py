@@ -26,13 +26,27 @@ chip) with float32 accumulation; the softmax state is float32.
 
 Bytes: a token's row is 576 values = 1,152 B in bf16 (1,280 B as it
 lies, with the pad lanes); 16 heads do 2 x 16 x (576 + 512) flops on it,
-~30 flops a byte, an eighth of the v5e's ridge: the kernel is bound by
-HBM, and its share of that roofline is the benchmark's
+~30 flops a byte, an eighth of the v5e's ridge (197 TFLOP/s over 819
+GB/s = 240); 32 heads (Xing4.0) do ~60, a quarter of it. Both are bound
+by HBM, and the kernel's share of that roofline is the benchmark's
 `mla_decode_attn_roofline` (which counts the 1,152 B).
 
-VMEM at the benchmark's shape (width 640 lanes, page 128, 4 pages
-an item, ring of 4, decode width 128, 16 heads): ring 2.6 MB, queries
-2.6 MB (bf16), output 4.2 MB (float32 [128, 16, 512]), new rows 0.2 MB.
+VMEM (width 640 lanes, page 128, 4 pages an item, ring of 4: 2.6 MB),
+with every row's queries (bf16) and outputs (float32 `[B, H, 512]`)
+resident for the whole call, at the benchmark's two shapes: decode width
+128 x 16 heads (`deepseek-v2-lite-l9`): queries 2.6 MB, outputs 4.2 MB,
+new rows 0.2 MB (2.6 MB if Mosaic pads the row to a tile of sublanes):
+inside the compiler's default 16 MiB of scoped VMEM, and the call passes
+no compiler parameter, as it never has. Decode width 256 x 32 heads
+(`xing4.0-29b-a4b-l6`): queries 10.5 MB, outputs 16.8 MB: the call asks
+for the scoped VMEM it holds (`vmem_limit_bytes`, of the chip's 128 MiB;
+past 96 MiB it refuses with a sentence). What that costs: the queries are
+copied in before the loop and the outputs out after it, 27 MB at the HBM
+rate, ~33 us a layer that nothing overlaps, beside ~410 us of latent rows
+at 256 rows x ~1,030 tokens; blocking both by the work item's sequence
+(a ring of `[H, width]` query blocks filled with the pages' ring, the
+output written back a sequence at a time) would put them under the loop
+and is owed (ROADMAP M3).
 """
 
 from __future__ import annotations
@@ -55,6 +69,13 @@ from dynamo_tpu.ops.pallas_attention import (
     switch_live_pages,
     work_list,
 )
+
+
+# scoped VMEM: the compiler's default limit, the most a call asks for
+# (of the v5e's 128 MiB), and the room left for what Mosaic adds
+_VMEM_DEFAULT = 16 << 20
+_VMEM_MOST = 96 << 20
+_VMEM_HEADROOM = 4 << 20
 
 
 def _mla_decode_kernel(
@@ -221,6 +242,36 @@ def _mla_decode_kernel(
         drain_wb(j)
 
 
+def resident_vmem_bytes(b: int, h: int, width: int, rank: int, item: int,
+                        *, page_size: int,
+                        pages_per_block: int = PAGES_PER_BLOCK,
+                        nbuf: int = NBUF) -> int:
+    """What the decode kernel keeps in VMEM (module docstring): every
+    row's queries and float32 outputs, the new rows (a row in 16 sublanes)
+    and the ring of page blocks."""
+    return (
+        b * h * (width * item + rank * 4) + b * 16 * width * item
+        + nbuf * pages_per_block * page_size * width * item
+    )
+
+
+def vmem_request(resident: int) -> int | None:
+    """The scoped VMEM the call asks the compiler for: None while what is
+    resident fits the compiler's default (16 MiB less headroom: 128 rows x
+    16 heads lower as they always have, with no parameter;
+    tests/test_tpu_compile.py holds that shape under the line), else what
+    it holds and headroom, of the v5e's 128 MiB."""
+    if resident <= _VMEM_DEFAULT - _VMEM_HEADROOM:
+        return None
+    if resident + _VMEM_HEADROOM > _VMEM_MOST:
+        raise ValueError(
+            f"latent decode kernel: {resident / 2**20:.0f} MiB of queries, "
+            f"outputs and ring resident, over the {_VMEM_MOST >> 20} MiB "
+            "it may ask for"
+        )
+    return resident + _VMEM_HEADROOM
+
+
 @functools.partial(
     jax.jit,
     static_argnames=["rank", "page_size", "pages_per_block", "nbuf",
@@ -282,9 +333,17 @@ def mla_paged_decode_attention(
         _mla_decode_kernel, page_size=page_size,
         pages_per_block=pages_per_block, nbuf=nbuf, rank=rank,
     )
+    params = None
+    if not interpret:
+        limit = vmem_request(resident_vmem_bytes(
+            b, h, width, rank, jnp.dtype(pool.dtype).itemsize,
+            page_size=page_size, pages_per_block=pages_per_block, nbuf=nbuf))
+        if limit:
+            params = pltpu.CompilerParams(vmem_limit_bytes=limit)
     out, pages = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        compiler_params=params,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
             jax.ShapeDtypeStruct(pages.shape, pool.dtype) if interpret

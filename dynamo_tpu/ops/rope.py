@@ -80,16 +80,20 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 def softmax_scale(cfg: ModelConfig) -> float:
     """What scores are multiplied by before the softmax: head_dim^-0.5,
     times YaRN's `mscale_all_dim` term squared where the configuration
-    has one (deepseek_v2: 192^-0.5 x 1.2608^2 at the published values).
-    The factor on cos / sin, mscale / mscale_all_dim, is 1 for every
-    published deepseek_v2 configuration and is refused otherwise."""
+    has one (192^-0.5 x 1.2608^2 at DeepSeek-V2-Lite's factor 40 x 0.707,
+    192^-0.5 x 1.4159^2 at Xing4.0's factor 64 x 1). The rule, whatever
+    the family: the factor on cos / sin is yarn_mscale(mscale) /
+    yarn_mscale(mscale_all_dim), which is 1 where mscale ==
+    mscale_all_dim; only that is served, another pair is refused."""
     scale = cfg.head_dim ** -0.5
     sc = cfg.rope_scaling
     if sc and sc.get("type", sc.get("rope_type")) == "yarn":
         if sc.get("mscale", 1.0) != sc.get("mscale_all_dim", 0.0):
             raise ValueError(
-                "yarn rope_scaling with mscale != mscale_all_dim (a factor "
-                "on cos / sin other than 1) is not served"
+                f"yarn rope_scaling with mscale={sc.get('mscale', 1.0)!r} "
+                f"and mscale_all_dim={sc.get('mscale_all_dim', 0.0)!r}: "
+                "only mscale == mscale_all_dim (a factor of 1 on cos / "
+                "sin) is served"
             )
         scale *= yarn_mscale(sc["factor"], sc["mscale_all_dim"]) ** 2
     return scale

@@ -75,6 +75,13 @@ def validate_model_mesh(cfg: ModelConfig, mc: MeshConfig) -> None:
     """Fail fast with a clear message instead of an opaque XLA sharding
     error when head counts don't divide the tp axis (e.g. qwen2.5-0.5b has
     2 KV heads — tp=8 can never work)."""
+    if cfg.hc_mult > 1 and mc.num_devices > 1:
+        raise ValueError(
+            f"model '{cfg.name}' carries a residual of {cfg.hc_mult} "
+            "streams a token, which is served on one device: no mesh axis "
+            f"(tp={mc.tp} pp={mc.pp} sp={mc.sp} ep={mc.ep} dp={mc.dp}) has "
+            "a rule for the streams or for the boundary's maps"
+        )
     if cfg.num_kv_heads % mc.tp:
         raise ValueError(
             f"model '{cfg.name}' has num_kv_heads={cfg.num_kv_heads}, which "
@@ -140,8 +147,10 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
             # latent attention is served on one device (the engine
             # refuses a larger mesh): every leaf whole
             lp = {k: ns() for k in (
-                "attn_norm", "wq", "w_kva", "kv_norm", "w_kvb", "wo",
-                "mlp_norm",
+                "attn_norm",
+                *(("w_qa", "q_norm", "w_qb") if cfg.q_lora_rank
+                  else ("wq",)),
+                "w_kva", "kv_norm", "w_kvb", "wo", "mlp_norm",
             )}
         else:
             lp = {
@@ -182,6 +191,12 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
             lp["bq"] = ns("tp")
             lp["bk"] = ns("tp")
             lp["bv"] = ns("tp")
+        if cfg.hc_mult > 1:
+            # a residual of several streams is served on one device
+            # (`validate_model_mesh`): every leaf of a boundary whole
+            for name in ("hc_attn", "hc_mlp"):
+                lp[name] = {k: ns() for k in (
+                    "w", "phi", "alpha", "b_pre", "b_post", "b_res")}
         return lp
 
     out = {

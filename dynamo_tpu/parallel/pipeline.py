@@ -115,6 +115,10 @@ def pp_forward(
     collective)."""
     if cfg.num_experts:
         raise NotImplementedError("pp v1 covers dense models")
+    if cfg.hc_mult > 1:
+        raise NotImplementedError(
+            f"pp v1 carries one residual stream [B, T, D] between stages; "
+            f"'{cfg.name}' carries {cfg.hc_mult} a token (models/mhc.py)")
     b = tokens.shape[0]
     m = n_microbatches
     if b % m:

@@ -1,6 +1,6 @@
 """Served log-probabilities against the float32 reference at the
-`mimo-v2-flash-l7` configuration's published widths, for many seeds in one
-call: what `correct` judges in a cell run (16 greedy tokens with their
+`mimo-v2-flash-l7` configuration's published widths (or, with `--config`,
+any other configuration's), for many seeds in one call: what `correct` judges in a cell run (16 greedy tokens with their
 log-probabilities after each of the configuration's `check_prompts`, through
 chunked prefill then decode on the engine's normal tick), without a window.
 The readings behind the configuration's `tolerance` (PERF.md section 6, PR
@@ -9,6 +9,7 @@ prompts; `lib/reference.compare` is the harness's. Exits non-zero without a
 TPU; results go to `chiprun_out/hybrid_correct_seeds.json`.
 
     chiprun -- python scripts/hybrid_correct_seeds.py --seeds 101 102 ...
+        [--config xing4.0-29b-a4b-l6] [--set num_experts_per_tok=64]
 """
 
 from __future__ import annotations
@@ -32,7 +33,8 @@ from reference import compare  # noqa: E402
 CONFIG = "mimo-v2-flash-l7"
 
 
-async def one_seed(hf, cb, token_logprobs, seed: int) -> dict:
+async def one_seed(hf, cb, token_logprobs, seed: int,
+                   config: str = CONFIG) -> dict:
     from dynamo_tpu.engine.config import EngineConfig
     from dynamo_tpu.engine.engine import JaxEngine
     from dynamo_tpu.llm.protocols.common import (
@@ -45,7 +47,7 @@ async def one_seed(hf, cb, token_logprobs, seed: int) -> dict:
 
     flags = dict(zip(cb["engine_flags"][::2], cb["engine_flags"][1::2]))
     engine = JaxEngine(EngineConfig(
-        model=ModelConfig.from_hf_config(hf, name=CONFIG),
+        model=ModelConfig.from_hf_config(hf, name=config),
         max_batch_size=int(flags["--max-batch-size"]),
         max_model_len=int(flags["--max-model-len"]),
         prefill_chunk=int(flags["--prefill-chunk"]),
@@ -83,21 +85,35 @@ async def one_seed(hf, cb, token_logprobs, seed: int) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--config", default=CONFIG,
+                    help="any configuration of benchmark/configs")
+    ap.add_argument("--set", nargs="*", default=[], metavar="KEY=INT",
+                    help="a key of the configuration changed on BOTH sides, "
+                         "program and reference: a witness, never a reading "
+                         "behind a limit (num_experts_per_tok=64: every "
+                         "expert chosen, so no selection can differ)")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU", file=sys.stderr)
         return 3
     hf, cb = harness.split_config(harness.load_json(
-        ROOT, "benchmark", "configs", CONFIG + ".json"), CONFIG)
+        ROOT, "benchmark", "configs", args.config + ".json"), args.config)
     token_logprobs = harness.load_by_name(
         "references", cb["reference"], "token_logprobs")
+    changed = {k: int(v) for k, v in (kv.split("=") for kv in args.set)}
+    hf.update(changed)
     res = {}
     for seed in args.seeds:
-        res[str(seed)] = asyncio.run(one_seed(hf, cb, token_logprobs, seed))
+        res[str(seed)] = asyncio.run(
+            one_seed(hf, cb, token_logprobs, seed, args.config))
         print(json.dumps({"seed": seed, **res[str(seed)]}), flush=True)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out",
-                           "hybrid_correct_seeds.json"), "w") as f:
+    name = ("hybrid_correct_seeds.json" if args.config == CONFIG
+            else f"correct_seeds_{args.config}.json")
+    if changed:
+        name = name[:-5] + "".join(
+            f".{k}-{v}" for k, v in changed.items()) + ".json"
+    with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
         json.dump(res, f, indent=1)
     return 0
 

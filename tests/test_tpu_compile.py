@@ -439,6 +439,128 @@ def test_latent_prefill_layer_compiles(one_chip, no_persistent_cache,
     _assert_kernel(compiled, at_least=5)
 
 
+# ------------------------------ four streams, low-rank queries (Xing4.0)
+
+
+def _xing_cfg():
+    return PRESETS["xing4.0-29b-a4b"].with_(
+        num_layers=2, first_dense_layers=1)
+
+
+def test_xing_latent_decode_scan_compiles_at_256_rows_x_32_heads(
+        one_chip, no_persistent_cache):
+    """Xing4.0 at its published widths, the dense layer and one expert
+    layer through a two-step decode scan at the `decode-wide` cell's shape
+    (width 256, pages of 128, bf16): the latent decode kernel with every
+    row's 32 heads of queries and float32 outputs resident (27 MB: it asks
+    for the scoped VMEM it holds), the grouped expert matmuls, four mHC
+    boundaries, and no copy of a latent pool anywhere in the step. The
+    same kernel at DeepSeek-V2-Lite's 128 rows x 16 heads asks for nothing
+    (`test_latent_decode_scan_compiles_at_the_benchmark_shape`)."""
+    cfg = _xing_cfg()
+    page, num_pages, width, max_len = 128, 2048, 256, 4096
+    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+                         page=page, num_pages=num_pages)
+    assert kv.v is None and kv.k[0].shape == (num_pages * page, 640)
+    assert params["layers"][0]["w_qb"].shape == (768, 32 * 192)
+    assert params["layers"][1]["hc_mlp"]["phi"].shape == (4 * 3584, 24)
+    step = _decode_fn(cfg, page)
+
+    def dispatch(params, kv, tokens, positions, tables):
+        def body(carry, _):
+            tokens, positions, kv = carry
+            lg, kv = step(params, kv, tokens, positions, tables,
+                          positions + 1, positions)
+            return (jnp.argmax(lg, -1).astype(jnp.int32), positions + 1,
+                    kv), None
+
+        (tokens, _, kv), _ = jax.lax.scan(
+            body, (tokens, positions, kv), None, length=2)
+        return tokens, kv
+
+    compiled = jax.jit(dispatch, donate_argnums=(1,)).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((width,), one_chip), _i32((width,), one_chip),
+        _i32((width, max_len // page), one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # the latent kernel in both layers + three grouped matmuls
+    assert text.count("tpu_custom_call") >= 5
+    assert "conditional(" not in text
+    pool = re.compile(r"= bf16\[\d+,(?:128,)?640\]\S* (copy|copy-start)\(")
+    moved = [ln.strip()[:160] for ln in text.splitlines() if pool.search(ln)]
+    assert not moved, "latent pools copied inside the step:\n" + "\n".join(moved)
+    # the boundaries are in the program under their scopes
+    for scope in ("attn.mhc/mhc.maps", "attn.mhc/mhc.pre",
+                  "attn.mhc/mhc.post", "mlp.mhc/mhc.post"):
+        assert scope in text, scope
+
+
+@pytest.mark.parametrize("width", [8, 64, 128],
+                         ids=lambda w: f"rows{w}")
+def test_xing_latent_decode_kernel_compiles_at_every_width(
+        one_chip, no_persistent_cache, width):
+    """The kernel alone at 32 heads and the decode widths below the
+    widest: it keeps the default scoped VMEM while it fits (8, 64 rows)
+    and asks for more past it (128)."""
+    from dynamo_tpu.ops.pallas_mla import mla_paged_decode_attention
+
+    page, num_pages, heads = 128, 512, 32
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        mla_paged_decode_attention, rank=512, page_size=page,
+    ), donate_argnums=(2,)).lower(
+        s((width, heads, 640), jnp.bfloat16), s((width, 640), jnp.bfloat16),
+        s((num_pages * page, 640), jnp.bfloat16),
+        _i32((width, 32), one_chip), _i32((width,), one_chip),
+        _i32((width,), one_chip),
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("rows,heads,asks", [
+    (128, 16, False), (64, 32, False), (128, 32, True), (256, 32, True),
+], ids=["deepseek-128x16", "xing-64x32", "xing-128x32", "xing-256x32"])
+def test_latent_decode_kernel_asks_for_vmem_only_past_the_default(
+        rows, heads, asks):
+    """Which side of the line a shape lies on is part of what a cell
+    measures: `deepseek-v2-lite-l9.decode-wide`'s kernel (128 rows x 16
+    heads, 11.5 MiB resident of the 12 the default leaves) lowers with NO
+    compiler parameter, as it always has, and the 32-head family asks from
+    128 rows on. A wider ring or row that moved the accepted shape over
+    the line would change its lowering silently: it fails here first."""
+    from dynamo_tpu.ops.pallas_mla import resident_vmem_bytes, vmem_request
+
+    resident = resident_vmem_bytes(rows, heads, 640, 512, 2, page_size=128)
+    limit = vmem_request(resident)
+    assert (limit is not None) == asks, (resident / 2**20, limit)
+    if (rows, heads) == (128, 16):
+        assert resident == 12_058_624  # 11.5 MiB
+    if asks:
+        assert limit == resident + (4 << 20) <= 96 << 20
+
+
+def test_xing_prefill_layer_with_a_boundary_compiles(
+        one_chip, no_persistent_cache):
+    """A whole prefill chunk (512 tokens at the longest attended bucket)
+    through the dense layer and one expert layer: the page writer, the
+    absorbed attention with low-rank queries, four boundaries over 512
+    rows of 4 x 3,584."""
+    cfg = _xing_cfg()
+    page, rows, bucket, wb = 128, 1, 512, 32
+    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+                         page=page, num_pages=512)
+    compiled = _prefill_step(cfg, page).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((rows, bucket), one_chip), _i32((rows, bucket), one_chip),
+        _i32((rows * (bucket // page),), one_chip),
+        _i32((rows, wb), one_chip), _i32((rows,), one_chip),
+    ).compile()
+    # a page write a layer + three grouped matmuls
+    _assert_kernel(compiled, at_least=5)
+    assert "mhc.post" in compiled.as_text()
+
+
 # --------------------------------- window beside full attention (MiMo-V2)
 
 
