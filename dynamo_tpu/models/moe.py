@@ -30,13 +30,36 @@ each under the `jax.named_scope` a profile finds it by:
   which visits an expert's weights once per 128-row tile that holds one
   of its rows; compiled where the program is lowered for a TPU, in the
   pallas interpreter elsewhere (CPU tests run the tiling, the sentinel
-  rows and the unwritten tail the chip runs). Timed alone on the v5e at
-  DeepSeek-V2-Lite's widths (8 expert layers, 10.8 ms of weights at the
-  HBM peak; `scripts/moe_layer_tpu.py`, PERF.md section 6, PR 34), decode
-  (768 rows) / a prefill chunk (3,072 rows): megablox at tiles of 128 x
-  2,048 x 1,408 13.8 / 16.6 ms, smaller or wider tiles slower; XLA:TPU's
-  `jax.lax.ragged_dot` 35.0 / 47.1 ms (why it is not the layer's form);
-  every expert over every token (no sort, a batched matmul) 12.7 / 28.2.
+  rows and the unwritten tail the chip runs). Its weight tiles are
+  `gmm_tiles`'s, a function of the matrix's shape alone: a `tk` that
+  DIVIDES k and a `tn` that DIVIDES n, because the kernel (jax 0.9.0's
+  `megablox/gmm.py`) runs a ragged last n tile at the whole tile's cost
+  (`out_block_spec`, `_store_accum`: the dot, the accumulator and the
+  masked store are a full tile's whatever part of it exists) and masks a
+  ragged last k tile with a float32 select over both operands
+  (`mask_k_rem`, lines 424-431). Timed alone on the v5e
+  (`scripts/moe_layer_tpu.py`, PERF.md section 6, PRs 34 and 44), the
+  expert layers of one step, decode rows / a 512-token chunk, tiles of
+  the gate and up calls | of the down call:
+  DeepSeek-V2-Lite (8 layers, 10.8 ms of weights at the HBM peak): one
+  tile is the whole matrix, 2,048 x 1,408 | 1,408 x 2,048: 13.5 / 16.3 ms
+  (PR 34's sweep gave all three calls one tile, so its down call ran
+  1,408 x 1,408: 13.8 / 16.9; 1,024- or 512-deep k tiles and 256 rows
+  slower); XLA:TPU's `jax.lax.ragged_dot` 34.9 / 47.3 ms (why it is not
+  the layer's form); every expert over every token 12.7 / 28.3.
+  Xing4.0 (5 layers, 64 of 3,584 x 1,024, 8.6 ms): PR 43's 2,048 x 1,024 |
+  1,024 x 3,072: 12.2 / 14.3 ms; the down call's second n tile, 3,072
+  wide for 512 real columns, was 1.0-1.1 ms of that and the gate / up
+  calls' ragged k tile (2,048 + 1,536, the mask) NOTHING measurable;
+  1,792 x 1,024 | 1,024 x 1,792 (the rule's): 11.1 / 13.1; the whole k,
+  3,584 x 512: 11.0 / 12.6; 896-wide tiles 11.1-11.2 / 13.1-13.3.
+  MiMo-V2-Flash's share (6 layers, 16 held of 4,096 x 2,048, 5.9 ms, a
+  256-row block): PR 36's 2,048 x 1,536 in all three calls: 8.7-8.8 /
+  9.8 ms; its cost was the gate / up calls' second n tile (512 real
+  columns of 1,536, under two k tiles), the down call's third (1,024 of
+  1,536) nothing; 2,048 x 1,024 in all three (the rule's): 8.0-8.2 / 9.0-9.2;
+  the whole k, 4,096 x 512: 8.1 / 9.0 in one call and 8.5-8.6 / 9.3-9.5 in
+  the next (why `tk` stays within `GMM_K_MOST`).
 - `mlp.moe_combine`: each pair's output times its weight, un-sorted back
   to token order, the k rows of a token added up.
 
@@ -77,10 +100,15 @@ import jax.numpy as jnp
 
 from dynamo_tpu.ops.quant import mm
 
-# rows a grouped-matmul tile holds, and the most bytes of an expert's
-# weights a tile may take (two such tiles are in flight): 2,048 x 1,408
-# bf16 at DeepSeek-V2-Lite's widths, the fastest tiling timed
+# rows a grouped-matmul tile holds, the deepest k tile, and the most bytes
+# of an expert's weights a tile may take (two such tiles are in flight,
+# inside the scoped VMEM a kernel has without asking: 2,048 x 2,048 bf16
+# ran out of it). `gmm_tiles` takes the widest tiles within them that
+# DIVIDE the matrix: at DeepSeek-V2-Lite's widths the whole matrix (2,048
+# x 1,408), at Xing4.0's 1,792 x 1,024 and 1,024 x 1,792, at MiMo-V2-
+# Flash's 2,048 x 1,024; the timings are in the module's docstring
 GMM_ROWS = 128
+GMM_K_MOST = 2048
 GMM_WEIGHT_TILE_BYTES = 6 << 20
 
 
@@ -168,6 +196,26 @@ def route(lp: dict, cfg, xf: jnp.ndarray):
     return top_w, top_i.astype(jnp.int32)
 
 
+def _dividing_tile(width: int, most: int) -> int:
+    """The widest tile of whole 128-lane columns that divides `width` and
+    is at most `most`: `width` itself where it fits; where no multiple of
+    128 from half of `most` up divides it, `most` in whole 128s (a ragged
+    last tile: many thin tiles would cost more than the one)."""
+    most = max(most // 128 * 128, 128)
+    if width <= most:
+        return width
+    return next((t for t in range(most, most // 2 - 1, -128)
+                 if width % t == 0), most)
+
+
+def gmm_tiles(k: int, n: int, itemsize: int) -> tuple[int, int]:
+    """(tk, tn) of a grouped matmul over [E, k, n] weights, from the
+    shape alone: `tk` divides `k` and `tn` divides `n` wherever the widths
+    allow it, a tile of weights within `GMM_WEIGHT_TILE_BYTES`."""
+    tk = _dividing_tile(k, GMM_K_MOST)
+    return tk, _dividing_tile(n, GMM_WEIGHT_TILE_BYTES // (tk * itemsize))
+
+
 def grouped_matmul(xs, w, group_sizes, out_dtype=None):
     """xs [M, in] (rows sorted by group, M a multiple of GMM_ROWS) x
     w [E, in, out] -> [M, out]: rows [0, g0) times w[0], the next g1 times
@@ -177,13 +225,9 @@ def grouped_matmul(xs, w, group_sizes, out_dtype=None):
     other platform."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    k, n = w.shape[1], w.shape[2]
-    tk = min(k, 2048)
-    tn = min(n, max(GMM_WEIGHT_TILE_BYTES // (tk * w.dtype.itemsize)
-                    // 128 * 128, 128))
     kernel = functools.partial(
         gmm, preferred_element_type=out_dtype or xs.dtype,
-        tiling=(GMM_ROWS, tk, tn),
+        tiling=(GMM_ROWS, *gmm_tiles(*w.shape[1:], w.dtype.itemsize)),
     )
     return jax.lax.platform_dependent(
         xs, w, group_sizes, tpu=kernel,

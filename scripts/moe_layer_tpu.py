@@ -1,11 +1,28 @@
-"""Time the routed-expert block ALONE on the chip at DeepSeek-V2-Lite's
-widths (64 experts of 2,048 x 1,408, top-6), 8 expert layers chained with
-their own weights (= their share of a step: 8.86 GB of bf16 to stream),
-at a decode shape (128 tokens = 768 routed rows) and a prefill chunk's
-(512 tokens = 3,072 rows). Variants: `jax.lax.ragged_dot` on sorted rows,
-the megablox grouped-matmul kernel on sorted rows at several tilings, and
-every expert over every token (batched matmul + a weighted sum over the
-experts) (PERF.md section 6, PR 34).
+"""Time the routed-expert block ALONE on the chip, the expert layers of
+one step chained with their own weights, the routing an ARGUMENT (closed
+over, XLA folds the sort into constants). Three shapes, a key each of
+`chiprun_out/moe_layer_shapes.json`; the row `rule_<gate / up tile>_<down
+tile>` (in `share`: `scatter`) is `models/moe.py: grouped_matmul` itself,
+at what `gmm_tiles` gives today, the rows `gmm_...` megablox at the tiles
+their names give (k x n of the gate and up calls, then of the down call).
+
+`--shape deepseek` (PR 34): DeepSeek-V2-Lite's widths (64 experts of
+2,048 x 1,408, top-6), 8 layers = 8.86 GB of bf16, at a decode shape (128
+tokens = 768 routed rows) and a prefill chunk's (512 tokens = 3,072
+rows): `jax.lax.ragged_dot` on sorted rows, every expert over every
+token (a batched matmul + a weighted sum), PR 34's tilings (ONE (rows,
+tk, tn) for all three calls, clamped: 2,048 / 1,024 / 512 / 1,408 of k
+by 1,408 or 1,024 of n, 128 / 256 / 512 rows) and, since PR 44, the
+rule's (the whole matrices, 2,048 x 1,408 | 1,408 x 2,048) beside a down
+call of 1,408 x 1,024.
+
+`--shape xing` (PR 44): Xing4.0-29B-A4B's widths (64 experts of 3,584 x
+1,024, top-4), 5 layers = 7.05 GB, at 192 tokens (768 rows) and 512
+(2,048 rows): PR 43's tiles (2,048 x 1,024 | 1,024 x 3,072: a ragged
+second k tile, a second n tile of 512 real columns), each call repaired
+alone, and tiles that divide both widths: 1,792 x 1,024, the whole k
+3,584 x 512 or x 256, 896 x 1,024, 1,792 x 512 | down 1,024 x 1,792,
+1,024 x 3,584 (the whole n), 1,024 x 896, 1,024 x 512, 512 x 3,584.
 
 `--shape share` (PR 39): a layer that HOLDS A SHARE of the experts, at
 MiMo-V2-Flash's widths as one chip of sixteen runs them (16 held of 256
@@ -15,12 +32,16 @@ prefill chunk). The layer's glue at the whole width (gather, weigh and
 un-sort all 2,048 rows) against the held pairs a block at a time
 (`models/moe.py: block_rows`), the block's rows added to their tokens by
 a scatter-add or by a one-hot matmul at float32 precision, and the
-layer as the program runs it (`moe_block`, its router included).
+layer as the program runs it (`moe_block`, its router included). Since
+PR 44 the `scatter` form (the program's) also at PR 36's tiles (2,048 x
+1,536 in all three calls), each call repaired alone, and 2,048 x 1,024,
+the whole k 4,096 x 512, 1,024 x 2,048, 2,048 x 512 and 2,048 x 2,048
+(which runs out of scoped VMEM).
 
-Exits non-zero without a TPU; results go to
-`chiprun_out/moe_layer_shapes.json`, one key a shape.
+Exits non-zero without a TPU; results in PERF.md section 6 (PRs 34, 39,
+44) and `models/moe.py`'s docstring.
 
-    chiprun -- python scripts/moe_layer_tpu.py [--shape deepseek|share|all]
+    chiprun -- python scripts/moe_layer_tpu.py [--shape deepseek|xing|share|all]
 """
 
 from __future__ import annotations
@@ -41,32 +62,48 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from dynamo_tpu.models import moe  # noqa: E402
 from dynamo_tpu.models.config import get_config  # noqa: E402
 
-LAYERS, E, K, D, F = 8, 64, 6, 2048, 1408
 PEAK = 819e9
 
+# the shapes whose experts are all held: (expert layers, experts, top-k, D, F,
+# tokens of a decode step and of a prefill chunk)
+ROUTED = {
+    "deepseek": (8, 64, 6, 2048, 1408, (128, 512)),
+    "xing": (5, 64, 4, 3584, 1024, (192, 512)),
+}
 
-def sort_rows(x, top_i):
-    n = x.shape[0]
-    expert_of = top_i.reshape(n * K)
-    pair = jnp.arange(n * K, dtype=jnp.int32)
-    _, order = jax.lax.sort((expert_of, pair), num_keys=1, is_stable=True)
-    sizes = jnp.zeros((E,), jnp.int32).at[expert_of].add(1)
-    back = jnp.zeros((n * K,), jnp.int32).at[order].set(pair)
-    return x[order // K], sizes, order, back
+
+def mega(gate_up, down, rows: int = moe.GMM_ROWS):
+    """megablox at the tiles (tk, tn) given for the gate / up calls and for
+    the down call (told apart by the call's output type: the layer asks
+    float32 of its down call alone), each clamped to the matrix."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    def mm3(xs, w, sizes, out_dtype=None):
+        tk, tn = gate_up if out_dtype is None else down
+        return gmm(xs, w, sizes, preferred_element_type=out_dtype or xs.dtype,
+                   tiling=(rows, min(tk, w.shape[1]), min(tn, w.shape[2])))
+    return mm3
+
+
+def ragged(xs, w, sizes, out_dtype=None):
+    return jax.lax.ragged_dot(
+        xs, w, sizes, preferred_element_type=out_dtype or xs.dtype)
 
 
 def grouped(mm3, x, w, top_w, top_i):
-    n = x.shape[0]
-    xs, sizes, order, back = sort_rows(x, top_i)
+    """One expert layer over rows sorted by expert, every expert held."""
+    (n, d), k, e = x.shape, top_i.shape[1], w[0].shape[0]
+    expert_of = top_i.reshape(n * k)
+    pair = jnp.arange(n * k, dtype=jnp.int32)
+    _, order = jax.lax.sort((expert_of, pair), num_keys=1, is_stable=True)
+    sizes = jnp.zeros((e,), jnp.int32).at[expert_of].add(1)
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(pair)
+    xs = x[order // k]
     gate, up, down = w
     h = jax.nn.silu(mm3(xs, gate, sizes)) * mm3(xs, up, sizes)
-    ys = mm3(h.astype(x.dtype), down, sizes).astype(jnp.float32)
-    ys = ys * top_w.reshape(n * K)[order][:, None]
-    return ys[back].reshape(n, K, D).sum(1).astype(x.dtype)
-
-
-def ragged(xs, w, sizes):
-    return jax.lax.ragged_dot(xs, w, sizes)
+    ys = mm3(h, down, sizes, jnp.float32)
+    ys = ys * top_w.reshape(n * k)[order][:, None]
+    return ys[back].reshape(n, k, d).sum(1).astype(x.dtype)
 
 
 def dense(x, w, top_w, top_i):
@@ -75,7 +112,8 @@ def dense(x, w, top_w, top_i):
         "nd,edf->enf", x, up)
     y = jnp.einsum("enf,efd->end", h, down)
     weight = jnp.sum(jnp.where(
-        top_i[..., None] == jnp.arange(E), top_w[..., None], 0.0), axis=1)
+        top_i[..., None] == jnp.arange(gate.shape[0]), top_w[..., None], 0.0),
+        axis=1)
     return jnp.einsum("end,ne->nd", y, weight.astype(y.dtype))
 
 
@@ -103,46 +141,70 @@ def timed(row: dict, step, args, floor_ms: float, ref):
     return ref
 
 
-def deepseek(dev) -> dict:
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+def tile_variants(candidates) -> dict:
+    """name -> megablox at (gate / up tile, down tile)."""
+    return {"gmm_%dx%d_%dx%d" % (*gate_up, *down): mega(gate_up, down)
+            for gate_up, down in candidates}
 
-    def mega(tiling):
-        def mm3(xs, w, sizes):
-            tm, tk, tn = tiling
-            tk, tn = min(tk, w.shape[1]), min(tn, w.shape[2])
-            return gmm(xs, w, sizes, preferred_element_type=xs.dtype,
-                       tiling=(tm, tk, tn))
-        return mm3
 
-    variants = {"ragged_dot": functools.partial(grouped, ragged),
-                "dense_all_experts": dense}
-    for tiling in ((128, 1024, 1408), (128, 2048, 1408), (256, 1024, 1408),
-                   (128, 512, 1408), (512, 1024, 1408), (128, 1408, 1024)):
-        variants["gmm_%d_%d_%d" % tiling] = functools.partial(
-            grouped, mega(tiling))
+def routed(dev, shape: str) -> dict:
+    layers, e, k, d, f, token_counts = ROUTED[shape]
+    if shape == "deepseek":
+        # PR 34's sweep: one (rows, tk, tn) for all three calls, clamped,
+        # so its down call ran 1,408 x 1,408 tiles over 2,048 columns
+        variants = {"ragged_dot": functools.partial(grouped, ragged),
+                    "dense_all_experts": dense}
+        for rows, tk, tn in ((128, 1024, 1408), (128, 2048, 1408),
+                             (256, 1024, 1408), (128, 512, 1408),
+                             (512, 1024, 1408), (128, 1408, 1024)):
+            variants["gmm_%d_%d_%d" % (rows, tk, tn)] = functools.partial(
+                grouped, mega((tk, tn), (tk, tn), rows))
+        tiles = tile_variants([((2048, 1408), (1408, 1024))])
+    else:
+        # PR 43's tiles first ((2048, 1024) leaves a ragged second k tile,
+        # (1024, 3072) a second n tile of 512 real columns), then each call
+        # repaired alone, then tiles that divide both widths
+        tiles = tile_variants([
+            ((2048, 1024), (1024, 3072)), ((1792, 1024), (1024, 3072)),
+            ((2048, 1024), (1024, 1792)), ((1792, 1024), (1024, 1792)),
+            ((3584, 512), (1024, 1792)), ((3584, 512), (1024, 3584)),
+            ((1792, 1024), (1024, 896)), ((896, 1024), (1024, 1792)),
+            ((1792, 512), (512, 3584)), ((3584, 256), (1024, 512))])
+        variants = {}
+    # first the layer's own call, at whatever `gmm_tiles` gives today
+    rule = "rule_%dx%d_%dx%d" % (*moe.gmm_tiles(d, f, 2),
+                                 *moe.gmm_tiles(f, d, 2))
+    variants[rule] = functools.partial(grouped, moe.grouped_matmul)
+    variants.update({name: functools.partial(grouped, mm3)
+                     for name, mm3 in tiles.items()})
 
     key = jax.random.PRNGKey(0)
     ws = []
-    for i in range(LAYERS):
+    for i in range(layers):
         k1, k2, k3 = jax.random.split(jax.random.fold_in(key, i), 3)
-        ws.append((jax.random.normal(k1, (E, D, F), jnp.bfloat16) * 0.02,
-                   jax.random.normal(k2, (E, D, F), jnp.bfloat16) * 0.02,
-                   jax.random.normal(k3, (E, F, D), jnp.bfloat16) * 0.02))
-    weight_bytes = LAYERS * 3 * E * D * F * 2
+        ws.append((jax.random.normal(k1, (e, d, f), jnp.bfloat16) * 0.02,
+                   jax.random.normal(k2, (e, d, f), jnp.bfloat16) * 0.02,
+                   jax.random.normal(k3, (e, f, d), jnp.bfloat16) * 0.02))
+    weight_bytes = layers * 3 * e * d * f * 2
     out = {"device": dev.device_kind, "weight_bytes": weight_bytes,
            "floor_ms": weight_bytes / PEAK * 1e3, "rows": []}
-    for n in (128, 512):
-        x = jax.random.normal(key, (n, D), jnp.bfloat16)
-        logits = jax.random.normal(jax.random.fold_in(key, 99), (n, E))
-        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits), K)
+    for n in token_counts:
+        x = jax.random.normal(key, (n, d), jnp.bfloat16)
+        logits = jax.random.normal(jax.random.fold_in(key, 99), (n, e))
+        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits), k)
+        top_i = top_i.astype(jnp.int32)
         ref = None
         for name, fn in variants.items():
-            def step(ws, x, fn=fn):
+            # the routing is an ARGUMENT: closed over, XLA folds the sort
+            # into constants (PR 39, call 2)
+            def step(ws, x, top_w, top_i, fn=fn):
                 for w in ws:
-                    x = x + fn(x, w, top_w, top_i.astype(jnp.int32))
+                    x = x + fn(x, w, top_w, top_i)
                 return x
-            row = {"tokens": n, "variant": name}
-            ref = timed(row, step, (ws, x), out["floor_ms"], ref)
+            row = {"tokens": n, "variant": name,
+                   "experts_hit": int(jnp.unique(top_i).size)}
+            ref = timed(row, step, (ws, x, top_w, top_i), out["floor_ms"],
+                        ref)
             out["rows"].append(row)
     return out
 
@@ -152,10 +214,11 @@ def deepseek(dev) -> dict:
 S_LAYERS, S_HELD, S_SCORED, S_K, S_D, S_F = 6, 16, 256, 8, 4096, 2048
 
 
-def share_layer(form: str, x, w, top_w, top_i):
+def share_layer(form: str, x, w, top_w, top_i, mm3=moe.grouped_matmul):
     """One expert layer over the pairs routed to experts [0, S_HELD):
     `whole` as `moe_block` runs a layer of one block, `scatter` /
-    `onehot` a block of `block_rows` rows at a time."""
+    `onehot` a block of `block_rows` rows at a time; `mm3` the grouped
+    matmul (the layer's own, or megablox at given tiles)."""
     n, d = x.shape
     m = n * S_K
     expert_of = top_i.reshape(m)
@@ -167,10 +230,8 @@ def share_layer(form: str, x, w, top_w, top_i):
     pair_w = top_w.reshape(m)
 
     def experts(xs, sizes):
-        gate = moe.grouped_matmul(xs, w[0], sizes)
-        up = moe.grouped_matmul(xs, w[1], sizes)
-        return moe.grouped_matmul(
-            jax.nn.silu(gate) * up, w[2], sizes, jnp.float32)
+        gate, up = mm3(xs, w[0], sizes), mm3(xs, w[1], sizes)
+        return mm3(jax.nn.silu(gate) * up, w[2], sizes, jnp.float32)
 
     if form == "whole":
         ys = experts(x[order // S_K], sizes)
@@ -225,14 +286,31 @@ def share(dev) -> dict:
         top_i = top_i.astype(jnp.int32)
         held = int((top_i < S_HELD).sum())
         ref = None
-        for form in ("whole", "scatter", "onehot"):
+        # the three forms at the layer's own tiles, then the form the
+        # program runs (`scatter`) at PR 36's tiles ((2048, 1536) leaves a
+        # second n tile of 512 real columns, and a third of 1,024 in the
+        # down call) and at tiles that divide both widths
+        forms = {form: (form, moe.grouped_matmul)
+                 for form in ("whole", "scatter", "onehot")}
+        forms.update({"scatter_" + name: ("scatter", mm3)
+                      for name, mm3 in tile_variants([
+                          ((2048, 1536), (2048, 1536)),
+                          ((2048, 1024), (2048, 1536)),
+                          ((2048, 1536), (2048, 1024)),
+                          ((2048, 1024), (2048, 1024)),
+                          ((4096, 512), (2048, 1024)),
+                          ((1024, 2048), (1024, 2048)),
+                          ((2048, 1024), (1024, 2048)),
+                          ((2048, 512), (2048, 512)),
+                          ((2048, 2048), (2048, 2048))]).items()})
+        for name, (form, mm3) in forms.items():
             # the routing is an ARGUMENT: closed over, XLA folds the sort
             # and the blocks' count into constants and unrolls the loop
-            def step(ws, x, top_w, top_i, form=form):
+            def step(ws, x, top_w, top_i, form=form, mm3=mm3):
                 for w in ws:
-                    x = x + share_layer(form, x, w, top_w, top_i)
+                    x = x + share_layer(form, x, w, top_w, top_i, mm3)
                 return x
-            row = {"tokens": n, "variant": form, "pairs_held": held,
+            row = {"tokens": n, "variant": name, "pairs_held": held,
                    "block_rows": moe.block_rows(n * S_K, S_HELD, S_SCORED)}
             ref = timed(row, step, (ws, x, top_w, top_i), out["floor_ms"],
                         ref)
@@ -259,7 +337,7 @@ def share(dev) -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shape", choices=("deepseek", "share", "all"),
+    ap.add_argument("--shape", choices=("deepseek", "xing", "share", "all"),
                     default="all")
     args = ap.parse_args()
     dev = jax.devices()[0]
@@ -273,12 +351,12 @@ def main() -> int:
             old = json.load(f)
         # PR 34's file was the DeepSeek shape's table itself
         out = {"deepseek": old} if "rows" in old else old
-    for shape, fn in (("deepseek", deepseek), ("share", share)):
-        if args.shape in (shape, "all"):
-            out[shape] = fn(dev)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
+    for shape in ("xing", "share", "deepseek"):
+        if args.shape in (shape, "all"):
+            out[shape] = share(dev) if shape == "share" else routed(dev, shape)
+            with open(path, "w") as f:
+                json.dump(out, f, indent=1)
     return 0
 
 

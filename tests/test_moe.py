@@ -105,22 +105,66 @@ def test_no_token_is_dropped_at_any_imbalance(routing):
         else 2 <= int(hit) <= cfg.num_experts
 
 
-def test_grouped_matmul_kernel_matches_ragged_dot():
+@pytest.mark.parametrize("k,n,tiles", [
+    (64, 256, (64, 256)),
+    # a k of two whole tiles and an n of two, within a budget made small:
+    # the accumulation across k and the second n tile the chip runs
+    (256, 512, (128, 256)),
+], ids=["one-tile", "two-k-tiles-two-n-tiles"])
+def test_grouped_matmul_kernel_matches_ragged_dot(monkeypatch, k, n, tiles):
     """The layer's grouped matmul (megablox; off a TPU it runs in the
     pallas interpreter) against `jax.lax.ragged_dot` on rows sorted by
     group: uneven groups, an empty one, and rows past the groups' end
     (which nobody reads)."""
-    from dynamo_tpu.models.moe import GMM_ROWS, grouped_matmul
+    from dynamo_tpu.models import moe
 
+    monkeypatch.setattr(moe, "GMM_K_MOST", 128)
+    monkeypatch.setattr(moe, "GMM_WEIGHT_TILE_BYTES", 128 * 256 * 4)
+    assert moe.gmm_tiles(k, n, 4) == tiles
     rng = np.random.RandomState(0)
-    xs = jnp.asarray(rng.randn(2 * GMM_ROWS, 64).astype(np.float32))
-    w = jnp.asarray(rng.randn(4, 64, 256).astype(np.float32))
+    xs = jnp.asarray(rng.randn(2 * moe.GMM_ROWS, k).astype(np.float32))
+    w = jnp.asarray(rng.randn(4, k, n).astype(np.float32))
     sizes = jnp.asarray([70, 0, 129, 31], jnp.int32)
     used = int(sizes.sum())
-    got = grouped_matmul(xs, w, sizes)
+    got = moe.grouped_matmul(xs, w, sizes)
     want = jax.lax.ragged_dot(xs, w, sizes)
     np.testing.assert_allclose(
         np.asarray(got)[:used], np.asarray(want)[:used], rtol=1e-5, atol=1e-4)
+
+
+def _tiles_before_pr44(k: int, n: int, itemsize: int):
+    """The choice `grouped_matmul` made until PR 44: 2,048 of k, and of n
+    what 6 MiB leave in whole 128s, whether or not they divide."""
+    tk = min(k, 2048)
+    return tk, min(n, max((6 << 20) // (tk * itemsize) // 128 * 128, 128))
+
+
+@pytest.mark.parametrize("call", ["gate", "up", "down"])
+@pytest.mark.parametrize("name", [
+    "deepseek-v2-lite", "mimo-v2-flash", "xing4.0-29b-a4b", "tiny-moe"])
+def test_grouped_matmul_tiles_divide_the_published_widths(name, call):
+    """The tile rule at each configuration's three grouped matmuls (bf16
+    as served): DeepSeek-V2-Lite's tiles are literally the ones it had,
+    the whole matrices (its programs are the parent's); MiMo's and Xing's
+    divide both widths in whole 128-lane columns inside the byte budget,
+    so no call masks a ragged k tile or multiplies an n tile for a
+    fraction of its columns; a tiny preset keeps its choice."""
+    from dynamo_tpu.models.moe import (
+        GMM_K_MOST, GMM_WEIGHT_TILE_BYTES, gmm_tiles)
+
+    cfg = get_config(name)
+    d, f = cfg.hidden_size, cfg.expert_width
+    k, n = (f, d) if call == "down" else (d, f)
+    tk, tn = gmm_tiles(k, n, 2)
+    before = _tiles_before_pr44(k, n, 2)
+    if name == "deepseek-v2-lite":
+        assert (tk, tn) == before == (k, n)
+    elif name == "tiny-moe":
+        assert (tk, tn) == before
+    else:
+        assert (tk, tn) != before
+        assert k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+        assert tk <= GMM_K_MOST and tk * tn * 2 <= GMM_WEIGHT_TILE_BYTES
 
 
 def test_padding_rows_route_nowhere_and_change_no_real_row():

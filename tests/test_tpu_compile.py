@@ -540,6 +540,37 @@ def test_latent_decode_kernel_asks_for_vmem_only_past_the_default(
         assert limit == resident + (4 << 20) <= 96 << 20
 
 
+@pytest.mark.parametrize("call", ["gate_up", "down"])
+@pytest.mark.parametrize("preset,held,rows", [
+    ("xing4.0-29b-a4b", 64, 768), ("xing4.0-29b-a4b", 64, 2048),
+    ("mimo-v2-flash", 16, 256),
+], ids=["xing-decode", "xing-chunk", "mimo-block"])
+def test_grouped_matmul_compiles_at_tiles_that_divide_the_widths(
+        one_chip, no_persistent_cache, preset, held, rows, call):
+    """The grouped matmul alone at Xing4.0's and MiMo-V2-Flash's published
+    expert widths, bf16, at the rows a decode step, a prefill chunk and a
+    share's block hand it: Mosaic takes the tiles that divide both widths
+    (`models/moe.py: gmm_tiles`) inside the scoped VMEM the call always
+    had (megablox passes no compiler parameter; two weight tiles in
+    flight, the rows, a float32 accumulator and the down call's float32
+    output tiles)."""
+    from dynamo_tpu.models.moe import grouped_matmul
+
+    cfg = PRESETS[preset]
+    d, f = cfg.hidden_size, cfg.expert_width
+    k, n = (d, f) if call == "gate_up" else (f, d)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        grouped_matmul,
+        out_dtype=jnp.float32 if call == "down" else None,
+    )).lower(
+        s((rows, k), jnp.bfloat16), s((held, k, n), jnp.bfloat16),
+        _i32((held,), one_chip),
+    ).compile()
+    _assert_kernel(compiled)
+    assert "conditional(" not in compiled.as_text()
+
+
 def test_xing_prefill_layer_with_a_boundary_compiles(
         one_chip, no_persistent_cache):
     """A whole prefill chunk (512 tokens at the longest attended bucket)
