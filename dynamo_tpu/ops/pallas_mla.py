@@ -17,19 +17,32 @@ pieces it imports: one grid program over the flat work list of (sequence,
 page-block) items, an NBUF-deep ring of page DMAs that stays full across
 sequence boundaries, a work item that copies and computes over only the
 pages its sequence holds (`live_pages` / `switch_live_pages`: the engine
-books `streamed_pages` by the same rule), the new token's row injected
-into its page in VMEM and only that page written back (no XLA scatter on
-the decode path), and the pool taken through `in_hbm` / `hbm_out` so that
-XLA's memory-space assignment has nothing to decide about a loop-carried
-pool (KVCache docstring). The dots take the pool's dtype (bf16 on the
-chip) with float32 accumulation; the softmax state is float32.
+books `streamed_pages` by the same rule), and the pool taken through
+`in_hbm` / `hbm_out` so that XLA's memory-space assignment has nothing to
+decide about a loop-carried pool (KVCache docstring). The dots take the
+pool's dtype (bf16 on the chip) with float32 accumulation; the softmax
+state is float32.
+
+The fused cache update (no XLA scatter on the decode path) is
+`_decode_window_kernel`'s: in the ONE work item that owns the new token's
+position, under `pl.when`, the row is merged into the `WRITE_BACK_ROWS`
+(16: one packed bf16 tile of sublanes) aligned rows around it where they
+lie in VMEM, and those 16 rows go back to the pool: 20,480 B a row a
+layer. Every other item reads its block as the copies left it, and no
+item may do more to it than that: the kernel runs at the time of its
+copies with or without its dots (`scripts/mla_kernel_tpu.py`: `nomerge`,
+`nocompute`; PERF.md section 6, PR 45), and one vector pass over an
+item's `[512, 640]` block (the row selected into the whole block) costs
+it a third more.
 
 Bytes: a token's row is 576 values = 1,152 B in bf16 (1,280 B as it
 lies, with the pad lanes); 16 heads do 2 x 16 x (576 + 512) flops on it,
 ~30 flops a byte, an eighth of the v5e's ridge (197 TFLOP/s over 819
 GB/s = 240); 32 heads (Xing4.0) do ~60, a quarter of it. Both are bound
 by HBM, and the kernel's share of that roofline is the benchmark's
-`mla_decode_attn_roofline` (which counts the 1,152 B).
+`mla_decode_attn_roofline` (which counts the 1,152 B; the rows lie in
+640 lanes and a sequence's last page is copied whole, so ~85% is what
+the copies alone would read).
 
 VMEM (width 640 lanes, page 128, 4 pages an item, ring of 4: 2.6 MB),
 with every row's queries (bf16) and outputs (float32 `[B, H, 512]`)
@@ -62,6 +75,7 @@ from dynamo_tpu.ops.pallas_attention import (
     _NEG_INF,
     NBUF,
     PAGES_PER_BLOCK,
+    WRITE_BACK_ROWS,
     hbm_out,
     in_hbm,
     lax_cdiv,
@@ -97,15 +111,17 @@ def _mla_decode_kernel(
     # scratch
     buf,               # [NBUF, ppb, page_size, width] VMEM
     sems,              # DMA sems [NBUF]
-    w_sem,             # DMA sem for page write-backs
+    w_sem,             # DMA sem for the slab write-backs
     wb_pending,        # SMEM [NBUF]: write-back in flight from this slot
     *,
     page_size: int,
     pages_per_block: int,
     nbuf: int,
     rank: int,
+    ablate: str = "",  # perf bisection: "nomerge" | "nocompute"
 ):
     t_blk = pages_per_block * page_size
+    wb_rows = min(WRITE_BACK_ROWS, page_size)
     h, width = q_ref.shape[1], q_ref.shape[2]
     n_work = n_work_ref[0]
 
@@ -132,11 +148,13 @@ def _mla_decode_kernel(
 
     def drain_wb(slot):
         # a pending write-back reads from buf[slot]; it must land before
-        # that slot is reused as a DMA-in target
+        # that slot is reused as a DMA-in target (a wait names a copy of
+        # the size that was started: the slab)
         @pl.when(wb_pending[slot] == 1)
         def _():
+            rows = pl.ds(0, wb_rows)
             pltpu.make_async_copy(
-                buf.at[0, 0], pages_out_hbm.at[0], w_sem
+                buf.at[0, 0, rows], pages_out_hbm.at[0, rows], w_sem
             ).wait()
             wb_pending[slot] = 0
 
@@ -170,28 +188,47 @@ def _mla_decode_kernel(
             # times a probability of 0 is NaN)
             t = n * page_size
             wait_work_dma(slot, n)
-            kb = buf[slot, :n].reshape(t, width)
+            if ablate == "nocompute":
+                # (times an iota's zeros: a splat accumulator is a layout
+                # Mosaic cannot carry into the emit)
+                touch = jnp.sum(
+                    buf[slot, :n].reshape(t, width).astype(jnp.float32),
+                    axis=0, keepdims=True)
+                zeros = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0) < 0
+                return m_prev, l_prev, acc + touch[:, :rank] * zeros.astype(
+                    jnp.float32)
 
-            # fused cache update: the new token's row goes into the block
-            # that owns position `wpos` (attended, so its page is live);
-            # only that page is written back
+            # fused cache update, in the one item that owns position
+            # `wpos` (attended, so its page is live): the new token's row
+            # is merged into the `wb_rows` aligned rows around it where
+            # they lie in VMEM, and just those rows go back
             do_write = (
                 (wpos >= 0) & (wpos < length)
                 & (blk == jax.lax.div(wpos, t_blk))
             )
-            row = jax.lax.broadcasted_iota(jnp.int32, (t, width), 0)
-            off = wpos - blk * t_blk
-            kb = jnp.where(do_write & (row == off), new_ref[seq], kb)
+            if ablate != "nomerge":
 
-            @pl.when(do_write)
-            def _store_back():
-                buf[slot, :n] = kb.reshape(n, page_size, width)
-                p_local = jax.lax.div(off, page_size)
-                page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
-                pltpu.make_async_copy(
-                    buf.at[slot, p_local], pages_out_hbm.at[page_id], w_sem
-                ).start()
-                wb_pending[slot] = 1
+                @pl.when(do_write)
+                def _merge():
+                    off = wpos - blk * t_blk
+                    p_local = jax.lax.div(off, page_size)
+                    r = jax.lax.rem(off, page_size)
+                    row0 = pl.multiple_of(
+                        jax.lax.div(r, wb_rows) * wb_rows, wb_rows)
+                    slab = pl.ds(row0, wb_rows)
+                    row = jax.lax.broadcasted_iota(
+                        jnp.int32, (wb_rows, width), 0)
+                    buf[slot, p_local, slab] = jnp.where(
+                        row == r - row0, new_ref[seq], buf[slot, p_local, slab]
+                    )
+                    page_id = tables_ref[seq, jax.lax.div(wpos, page_size)]
+                    pltpu.make_async_copy(
+                        buf.at[slot, p_local, slab],
+                        pages_out_hbm.at[page_id, slab], w_sem,
+                    ).start()
+                    wb_pending[slot] = 1
+
+            kb = buf[slot, :n].reshape(t, width)
 
             # every head against the ONE row a token keeps: [H, W] x [t, W]
             s = jax.lax.dot_general(
@@ -275,7 +312,7 @@ def vmem_request(resident: int) -> int | None:
 @functools.partial(
     jax.jit,
     static_argnames=["rank", "page_size", "pages_per_block", "nbuf",
-                     "interpret"],
+                     "interpret", "ablate"],
 )
 def mla_paged_decode_attention(
     qa: jax.Array,            # [B, H, width] absorbed queries, pre-scaled
@@ -290,6 +327,7 @@ def mla_paged_decode_attention(
     pages_per_block: int = PAGES_PER_BLOCK,
     nbuf: int = NBUF,
     interpret: bool = False,
+    ablate: str = "",  # scripts/mla_kernel_tpu.py's timings only
 ):
     """Absorbed latent decode attention fused with the pool update.
     Returns (latent output [B, H, rank] float32, pool); the pool is
@@ -331,7 +369,7 @@ def mla_paged_decode_attention(
     )
     kernel = functools.partial(
         _mla_decode_kernel, page_size=page_size,
-        pages_per_block=pages_per_block, nbuf=nbuf, rank=rank,
+        pages_per_block=pages_per_block, nbuf=nbuf, rank=rank, ablate=ablate,
     )
     params = None
     if not interpret:

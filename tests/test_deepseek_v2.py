@@ -125,34 +125,59 @@ def test_yarn_table_and_softmax_scale_match_the_closed_form():
             rope_scaling={**sc, "mscale": 1.0}))
 
 
-def _decode_case(seed=0, b=4, h=4, rank=32, width=128, ps=8, pages=40):
+def _decode_case(ps=8, wrow=None, seed=0, h=4, rank=32, width=128):
+    """Inputs of one decode step. `wrow` None: four short rows, the new
+    row each one's last (PR 34's case). Else five rows in pages of `ps`,
+    three of them longer than a work item (4 pages), with the new row on
+    row `wrow` of the FIRST, a MIDDLE and the LAST live page; a row of
+    length 0 that names a position and real pages; a row that attends
+    and writes nothing."""
     rng = np.random.RandomState(seed)
-    pool = rng.randn(pages * ps, width).astype(np.float32)
-    lengths = np.array([13, 0, 64, 33], np.int32)  # attended, new row incl.
-    wpos = np.array([12, -1, 63, 32], np.int32)
+    if wrow is None:
+        lengths = np.array([13, 0, 64, 33], np.int32)  # attended, new row incl.
+        wpos = np.array([12, -1, 63, 32], np.int32)
+    else:
+        lengths = np.array(
+            [5 * ps + 5, 0, 5 * ps + 3, 5 * ps + wrow + 1, ps + 3], np.int32)
+        wpos = np.array([wrow, 3, 2 * ps + wrow, 5 * ps + wrow, -1], np.int32)
+    b = len(lengths)
+    held = np.maximum(-(-lengths // ps), 1)  # the inactive row holds a page
     tables = np.zeros((b, 9), np.int32)
     nxt = 1
     for i in range(b):
-        n = -(-int(lengths[i]) // ps)
-        tables[i, :n] = np.arange(nxt, nxt + n)
-        nxt += n
+        tables[i, :held[i]] = np.arange(nxt, nxt + held[i])
+        nxt += held[i]
+    pool = rng.randn((nxt + 2) * ps, width).astype(np.float32)
     qa = rng.randn(b, h, width).astype(np.float32)
     new = rng.randn(b, width).astype(np.float32)
     return pool, lengths, wpos, tables, qa, new, rank, ps
 
 
-def test_latent_decode_kernel_reads_and_writes_like_the_oracle():
+@pytest.mark.parametrize("ps,wrow", [
+    (8, None),
+    # a slab is a whole page: its first and last row
+    (8, 0), (8, 7),
+    # a slab is half a page: a page's first and last row, and both sides
+    # of the slab boundary
+    (32, 0), (32, 15), (32, 16), (32, 31),
+])
+def test_latent_decode_kernel_reads_and_writes_like_the_oracle(ps, wrow):
     """The paged latent decode kernel (interpret mode): the new row lands
-    in its page, every head attends the ONE row a token keeps, an inactive
-    row emits zeros and writes nothing, and only the trash page and the
-    written rows differ from the pool it was given."""
-    pool, lengths, wpos, tables, qa, new, rank, ps = _decode_case()
+    in its page, whichever work item of its sequence owns that page; every
+    head attends the ONE row a token keeps; an inactive row emits zeros
+    and writes nothing; and the pool that comes back differs from the one
+    that went in ONLY in the written rows and the trash page: the rest of
+    a written row's slab, the rest of its page and every other page are
+    bit-equal."""
+    pool, lengths, wpos, tables, qa, new, rank, ps = _decode_case(ps, wrow)
     out, pool2 = mla_paged_decode_attention(
         jnp.asarray(qa), jnp.asarray(new), jnp.asarray(pool),
         jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(wpos),
         rank=rank, page_size=ps, interpret=True)
     want_pool = pool.copy()
-    for i in np.flatnonzero(wpos >= 0):
+    writes = np.flatnonzero((wpos >= 0) & (wpos < lengths))
+    assert len(writes) == 3
+    for i in writes:
         want_pool[tables[i, wpos[i] // ps] * ps + wpos[i] % ps] = new[i]
     np.testing.assert_array_equal(np.asarray(pool2)[ps:], want_pool[ps:])
     smat = (tables[:, :, None] * ps + np.arange(ps)).reshape(len(tables), -1)
