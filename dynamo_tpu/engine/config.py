@@ -67,13 +67,6 @@ class EngineConfig:
     decode_steps: int = 8         # decode steps per jit dispatch (lax.scan):
     # amortizes host<->device round trips; finished sequences overshoot at
     # most decode_steps-1 positions (discarded host-side)
-    # prefill-priority gate: during a PURE admission wave (prompts still
-    # prefilling, no stream has emitted a token yet), hold the decode
-    # dispatch until this fraction of slots is decode-ready — a
-    # quarter-full decode dispatch costs the same device time as a full
-    # one (fixed [max_batch] shape), so waves would otherwise run decode
-    # at ~2x the needed steps. Never delays running streams. 0 disables.
-    decode_ready_frac: float = 1.0
     # self-speculative decoding (engine/spec.py): draft the next k tokens
     # by prompt-lookup over the sequence's own history, verify all of
     # them in ONE multi-query model step (rejection-sampling acceptance
@@ -93,23 +86,20 @@ class EngineConfig:
     # token-budgeted model step — decode rows ride as q_len=1 rows next
     # to the prefill chunks, so an admission wave never stalls running
     # decode streams for longer than one budgeted step. Composes with
-    # spec_decode (see mixed_spec); unsupported with pp>1 and sp>1.
+    # spec_decode: spec-eligible decode rows inside a mixed step carry
+    # their n-gram drafts as ragged q_len = 1+k verify rows (the budget
+    # counts 1+k per row, so drafts trade off against prefill chunk
+    # size). Unsupported with sp>1.
     # Composes with the int32-packed pallas+quantized KV pools: mid-page
     # decode rows land via byte-lane surgery on the packed rows
     # (ops/quant.scatter_packed_kv_rows), width-agnostic so the int4
     # nibble tier rides too. Runtime-togglable like spec_decode: incompatible
     # engines just never build a mixed step (logged once).
     mixed_batching: bool = False
-    # spec x mixed composition: with both features on, spec-eligible
-    # decode rows inside a mixed step carry their n-gram drafts as
-    # ragged q_len = 1+k verify rows (budget counts 1+k per row, so
-    # drafts trade off transparently against prefill chunk size). False
-    # keeps decode rows at q_len=1 inside mixed steps; spec then only
-    # runs standalone verify dispatches between admission waves.
-    mixed_spec: bool = True
-    # token budget of one mixed step: decode rows cost 1 each, prefill
-    # chunks shrink to fit the leftover (non-final chunks round down to
-    # a page multiple). Bounds how long one step can stall decode — the
+    # token budget of one mixed step: decode rows always join at 1 each
+    # and prefill chunks shrink to fit the leftover (non-final chunks
+    # round down to a page multiple). Bounds how long one step can stall
+    # decode — the
     # knob that trades ITL (smaller) against prefill throughput (larger).
     # NOTE the budget counts REAL tokens; the dispatch itself is a dense
     # [pow2 rows, chunk-bucket] rectangle, so each decode row also pays
@@ -118,11 +108,6 @@ class EngineConfig:
     # that skips padded query tiles is the named follow-up
     # (ops/pallas_attention.ragged_paged_attention).
     mixed_step_tokens: int = 1024
-    # True: decode rows always join and prefill shrinks around them
-    # (latency-leaning, the stall-free default). False: prefill chunks
-    # keep their full size and decode rows join only when the budget has
-    # room left (throughput-leaning; decode may wait a step).
-    mixed_decode_priority: bool = True
     # zero-stall step pipeline: build and dispatch step N+1 while step
     # N's sampled tokens are still in flight to the host. Mixed steps'
     # q_len=1 decode rows read their input token from the carry the
@@ -151,20 +136,9 @@ class EngineConfig:
     # GSPMD path, with XLA's latency-hiding scheduler flags requested
     # at init (logged once, reason in tp_overlap_refusal_reason;
     # metrics() attributes tp_overlap_dispatches vs
-    # gspmd_fallback_dispatches). pp>1 is handled by the pipeline
-    # executor's own flag. Also feeds the collective_bytes /
+    # gspmd_fallback_dispatches). Also feeds the collective_bytes /
     # collective_wall_s phase counters the flight recorder digests.
     tp_overlap: bool = False
-    # admission batching window for PACED arrivals: when decode streams
-    # are running and fewer than `prefill_batch_min_rows` sequences are
-    # pending prefill, hold the prefill dispatch up to this many seconds
-    # so trickling arrivals amortize one dispatch (each small group costs
-    # a fixed dispatch+fetch overhead that otherwise serializes against
-    # the decode plane — measured: paced throughput at 0.35x closed-loop
-    # rate was 55% of offered with groups of 1-2). 0 disables; TTFT-
-    # sensitive deployments keep it well under their TTFT budget.
-    prefill_batch_window_s: float = 0.0
-    prefill_batch_min_rows: int = 8
     # ---- fault-tolerance spine (docs/robustness.md) ----
     # default end-to-end deadline per request, seconds (0 = none). A
     # request-level `x-request-timeout` header overrides it. Expired
@@ -207,16 +181,6 @@ class EngineConfig:
     # default 5.0; 0 disables the audit (transition stamping stays on —
     # it is O(1) per transition and feeds /debug/kv either way).
     kv_audit_s: Optional[float] = None
-    # ---- fleet control plane (docs/control.md) ----
-    # tenant-priority scheduling: admission picks the highest-priority
-    # waiting class (FIFO within a class) and preemption evicts the
-    # lowest-priority, most-recently-admitted sequence first
-    # (Sequence.priority, stamped from Context metadata by the frontend
-    # admission gate). With no priorities in flight both policies reduce
-    # to the pre-priority FIFO/recency behavior, byte-identical; False
-    # forces that reduction even when priority metadata is present
-    # (serialized-baseline comparisons).
-    priority_scheduling: bool = True
     seed: int = 0
 
     def model_config(self) -> ModelConfig:

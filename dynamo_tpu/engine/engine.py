@@ -106,6 +106,92 @@ log = logging.getLogger("dynamo_tpu.engine")
 # `upload_s`, `enqueue_s`
 _WORKER_PHASES = ("eng.lock", "eng.upload", "eng.enqueue")
 
+# how a refusal names each option of `EngineConfig` that a kind of cache
+# may refuse at construction ("mesh": one of more than one device)
+_REFUSABLE_OPTIONS = {
+    "kv_quantization": "kv_quantization={!r}",
+    "quantization": "quantization={!r}",
+    "mesh": "a mesh of more than one device",
+    "host_kv_pages": "host KV offload (host_kv_pages)",
+    "spec_decode": "spec_decode",
+    "mixed_batching": "mixed_batching",
+}
+
+# What a cache that is not "a K pool and a V pool a layer under one list
+# of page ids" refuses, a row a kind, keyed by the `ModelConfig` property
+# that says the model has it: `why` is the sentence every refused plane
+# or option gets (`JaxEngine._refuse_plane`), `options` what construction
+# refuses with the few words of why (`_refuse_config`), `mixed` what a
+# runtime toggle of mixed batching logs (`_mixed_unsupported_reason`).
+# Planes asked of a running engine (disaggregation, prefix export, page
+# inject / extract, device-path transfer) call `_refuse_plane` themselves.
+CACHE_KIND_REFUSALS = {
+    "latent": {
+        "why": (
+            "{plane} is not served with latent attention ('{name}'): it "
+            "is written for a K pool and a V pool a layer, and a latent "
+            "cache is ONE pool of [c ; k_r] rows"
+        ),
+        "options": {
+            "kv_quantization": "the latent pool is served in the model's "
+                               "dtype; no quantized latent rows yet",
+            "quantization": "int8 weights",
+            "mesh": "the latent pool has no head axis to shard; tp / sp / "
+                    "ep / dp all refuse",
+            "host_kv_pages": "",
+            "spec_decode": "",
+        },
+        "mixed": (
+            "mixed_batching unsupported with latent attention: the "
+            "ragged kernel reads a K pool and a V pool"
+        ),
+    },
+    "hybrid": {
+        "why": (
+            "{plane} is not served with window beside full attention "
+            "('{name}'): it is written for one list of page ids a "
+            "sequence, and this cache has two kinds of page, of which the "
+            "window kind releases behind the window"
+        ),
+        "options": {
+            "kv_quantization": "the two kinds of pool are served in the "
+                               "model's dtype",
+            "quantization": "int8 weights",
+            "mesh": "tp / sp / ep / dp: the layer holds its share of the "
+                    "experts without an exchange",
+            "host_kv_pages": "",
+            "spec_decode": "the verify step",
+            "mixed_batching": "",
+        },
+        "mixed": (
+            "mixed_batching unsupported with window beside full "
+            "attention: the ragged step takes one block table a row"
+        ),
+    },
+    "recurrent": {
+        "why": (
+            "{plane} is not served with Mamba-2 layers beside attention "
+            "('{name}'): it moves or shares pages, and this model also "
+            "keeps a fixed-size state a sequence that no page holds; pages "
+            "without the state at their boundary give a wrong answer"
+        ),
+        "options": {
+            "kv_quantization": "the pages and the state are served in the "
+                               "model's dtype",
+            "quantization": "int8 weights",
+            "mesh": "tp / sp / ep / dp: the state pool has no sharding rule",
+            "host_kv_pages": "",
+            "spec_decode": "a rejected draft would have to roll the state "
+                           "back",
+            "mixed_batching": "",
+        },
+        "mixed": (
+            "mixed_batching unsupported with Mamba-2 layers beside "
+            "attention: the ragged step has no state slot a row"
+        ),
+    },
+}
+
 
 class StepState(NamedTuple):
     """What the decode path keeps on the device between dispatches. The
@@ -221,7 +307,7 @@ class JaxEngine:
 
         backend = jax.default_backend()
         # the serving engine's mesh is tp-only (dp = separate workers, sp
-        # for long prefill, pp/ep future); the pallas decode kernel runs
+        # for long prefill, ep future); the pallas decode kernel runs
         # under tp via shard_map (AttnSpec.mesh) — other axes fall back
         mc = config.mesh
         tp_only = mc.num_devices == mc.tp
@@ -252,11 +338,7 @@ class JaxEngine:
                 for w in (self.model_cfg.attn_kind(kind).k_width,
                           self.model_cfg.attn_kind(kind).v_width)
             )
-            self._refuse_hybrid_config()
-        if self.model_cfg.latent:
-            self._refuse_latent_config()
-        if self._recurrent:
-            self._refuse_state_config()
+        self._refuse_config()
         if config.attn_backend == "auto":
             self._attn_pallas = backend == "tpu" and tp_only and kw_ok
             self._attn_interpret = False
@@ -266,11 +348,11 @@ class JaxEngine:
                 # dp>1 inside ONE engine cannot run the fused kernel
                 # soundly (it writes pages; dp-replicated pools would
                 # diverge per shard) — dp is designed as separate
-                # workers (docs/parallelism.md); sp/pp are documented v1
-                # kernel limits; kw misalignment is a model-shape limit.
+                # workers (docs/parallelism.md); sp is a documented v1
+                # kernel limit; kw misalignment is a model-shape limit.
                 why = (
                     "mesh has non-tp axes "
-                    f"(dp={mc.dp} sp={mc.sp} pp={mc.pp} ep={mc.ep})"
+                    f"(dp={mc.dp} sp={mc.sp} ep={mc.ep})"
                     if not tp_only
                     else "folded KV width not lane-aligned per tp shard"
                 )
@@ -287,7 +369,7 @@ class JaxEngine:
                 raise ValueError(
                     "attn_backend='pallas' supports single-device or "
                     "tp-only meshes (got "
-                    f"{dict(dp=mc.dp, sp=mc.sp, pp=mc.pp, ep=mc.ep)}); "
+                    f"{dict(dp=mc.dp, sp=mc.sp, ep=mc.ep)}); "
                     "use 'auto'"
                 )
             self._attn_pallas = True
@@ -335,7 +417,7 @@ class JaxEngine:
         # gather, prefill + decode, disagg, offload) AND ring (sp) long-
         # context serving (the ring attends the fresh chunk's bf16 k/v;
         # quantization touches the pool write and the cached-prefix
-        # gather); only the pp stage executor keeps model-dtype KV
+        # gather)
         self._kv_quant = config.kv_quantization
         if self._kv_quant is not None and self._kv_quant not in ("int8", "int4"):
             raise ValueError(
@@ -373,8 +455,6 @@ class JaxEngine:
                     "pallas kernels need one scale group per head", grp,
                 )
                 self._attn_pallas = False
-        if self._kv_quant and mc.pp > 1:
-            raise ValueError("kv_quantization unsupported with pp>1 (v1)")
         if self._kv_quant and self._attn_pallas and config.page_size % 128:
             # the int8 kernels put scale-page tokens in lanes: page_size
             # must be a lane multiple for Mosaic to slice the scale tiles
@@ -392,11 +472,10 @@ class JaxEngine:
         # int32-PACKED int8 pools (ops/quant.pack_kv_slots): f32-class DMA
         # tiling recovers the int8 (32,128)-tile penalty (the packed
         # format is what both benchmark cells run). Serving (pallas) path
-        # only — the gather/sp/pp paths keep dense int8 pools, and the
+        # only — the gather/sp paths keep dense int8 pools, and the
         # wire/offload formats stay dense int8 (pack/unpack at the edges)
         self._kv_packed = bool(
-            self._kv_quant and self._attn_pallas
-            and not self._sp and mc.pp == 1
+            self._kv_quant and self._attn_pallas and not self._sp
         )
 
         # self-speculative decoding (engine/spec.py): the verify step is
@@ -405,19 +484,10 @@ class JaxEngine:
         # flash kernel (pallas backends, same path mixed steps read
         # through). int32-PACKED pools row-scatter through the byte-lane
         # write (ops/quant.scatter_packed_kv_rows), so the packed
-        # pallas+quantized tier composes; pp's stage executor has no
-        # multi-query decode, so pp>1 gates it off loudly.
-        if config.spec_decode:
-            if config.spec_k_max < 1:
-                raise ValueError("spec_k_max must be >= 1")
-            if mc.pp > 1:
-                raise ValueError("spec_decode unsupported with pp>1 (v1)")
+        # pallas+quantized tier composes.
+        if config.spec_decode and config.spec_k_max < 1:
+            raise ValueError("spec_k_max must be >= 1")
 
-        # pipeline-parallel serving: pp > 1 runs the GPipe stage executor
-        # (parallel/pipeline.py) — layers AND KV pools live stage-local;
-        # gather attention (the pallas kernels are not pp-aware), no
-        # disagg extract/inject or host offload in pp mode (v1)
-        self._pp = mc.pp > 1
         # stall-free mixed batching (docs/architecture.md "Stall-free
         # mixed batching"): decode rows ride chunked-prefill steps as
         # q_len=1 rows of one token-budgeted dispatch. The flag is
@@ -433,20 +503,6 @@ class JaxEngine:
             why = self._mixed_unsupported_reason()
             if why:
                 raise ValueError(why)
-        if self._pp and self._sp:
-            raise ValueError("pp>1 with sp>1 unsupported (v1)")
-        if self._pp:
-            if self._attn_pallas:
-                raise ValueError("attn_backend='pallas' unsupported with pp>1")
-            if config.host_kv_pages:
-                raise ValueError("host KV offload unsupported with pp>1")
-            if self.model_cfg.num_experts:
-                raise ValueError("MoE unsupported with pp>1 (pipeline v1)")
-            if self.model_cfg.num_layers % mc.pp:
-                raise ValueError(
-                    f"num_layers={self.model_cfg.num_layers} not divisible "
-                    f"by pp={mc.pp}"
-                )
 
         # TP comm/compute overlap (EngineConfig.tp_overlap,
         # docs/parallelism.md "TP comm/compute overlap"): prefer the
@@ -458,10 +514,9 @@ class JaxEngine:
         # pools run inside the executor's single shard_map (the
         # kernels' per-layer shard_maps collapse into it), and int8
         # quantized weights ride the ring matmuls with an int32
-        # reduce-scatter epilogue. pp>1 composes through the pipeline
-        # stage executor's own flag; the remaining refusals (MoE
-        # routing, sp>1 / non-tp mesh axes) fall back to GSPMD with
-        # XLA's latency-hiding scheduler flags requested instead.
+        # reduce-scatter epilogue. The refusals (MoE routing, sp>1 /
+        # non-tp mesh axes) fall back to GSPMD with XLA's
+        # latency-hiding scheduler flags requested instead.
         self._tp_overlap_manual = bool(
             config.tp_overlap and mc.tp > 1 and tp_only
             and not self.model_cfg.num_experts
@@ -471,41 +526,31 @@ class JaxEngine:
         # tp_overlap is off/moot)
         self.tp_overlap_refusal_reason = ""
         if config.tp_overlap and mc.tp > 1 and not self._tp_overlap_manual:
-            if self._pp:
-                self.tp_overlap_refusal_reason = (
-                    "pp>1 pipeline stage executor"
+            why = (
+                "MoE routing" if self.model_cfg.num_experts
+                else "sp>1 ring prefill" if self._sp
+                else "non-tp mesh axes"
+            )
+            self.tp_overlap_refusal_reason = why
+            added = []
+            if backend == "tpu":
+                from dynamo_tpu.parallel.tp_overlap import (
+                    request_gspmd_overlap_flags,
                 )
-                log.info(
-                    "tp_overlap: pp>1 — pipeline stage executor runs "
-                    "scattered-residual layers (ring collectives per "
-                    "stage, parallel/pipeline.py)"
-                )
-            else:
-                why = (
-                    "MoE routing" if self.model_cfg.num_experts
-                    else "sp>1 ring prefill" if self._sp
-                    else "non-tp mesh axes"
-                )
-                self.tp_overlap_refusal_reason = why
-                added = []
-                if backend == "tpu":
-                    from dynamo_tpu.parallel.tp_overlap import (
-                        request_gspmd_overlap_flags,
-                    )
 
-                    added = request_gspmd_overlap_flags()
-                log.info(
-                    "tp_overlap: manual ring executor refused (%s) — "
-                    "GSPMD fallback%s",
-                    why,
-                    (
-                        f" with XLA overlap flags {added}"
-                        " (effective for computations compiled after this"
-                        " point; set them in the launch env to cover"
-                        " already-compiled executables)"
-                        if added else ""
-                    ),
-                )
+                added = request_gspmd_overlap_flags()
+            log.info(
+                "tp_overlap: manual ring executor refused (%s) — "
+                "GSPMD fallback%s",
+                why,
+                (
+                    f" with XLA overlap flags {added}"
+                    " (effective for computations compiled after this"
+                    " point; set them in the launch env to cover"
+                    " already-compiled executables)"
+                    if added else ""
+                ),
+            )
         elif self._tp_overlap_manual:
             log.info(
                 "tp_overlap: manual ring executor is the serving path "
@@ -515,10 +560,6 @@ class JaxEngine:
         # make or load, quantize, place; closed when the leaves are ready
         with profiler.phase("eng.init.weights"):
             if params is None:
-                if config.quantization and self._pp:
-                    raise ValueError(
-                        "quantization unsupported with pp>1 (stage stacking)"
-                    )
                 if config.checkpoint_dir:
                     from dynamo_tpu.models.weights import load_params
 
@@ -550,14 +591,13 @@ class JaxEngine:
                     params = llama.init_params(
                         self.model_cfg, jax.random.PRNGKey(config.seed),
                         dtype=self._dtype, quantize=bool(config.quantization),
-                        shardings=None if self._pp
-                        else meshmod.param_shardings(self.model_cfg, self.mesh),
+                        shardings=meshmod.param_shardings(
+                            self.model_cfg, self.mesh),
                     )
                     self.param_count = logical_param_count(
                         params, self.model_cfg)
-                if not self._pp:
-                    params = meshmod.shard_params(
-                        params, self.model_cfg, self.mesh)
+                params = meshmod.shard_params(
+                    params, self.model_cfg, self.mesh)
             else:
                 from dynamo_tpu.ops.quant import (
                     is_quantized,
@@ -582,19 +622,18 @@ class JaxEngine:
             self.win_num_pages = self._win_pool_pages() if self._hybrid else 0
             self.num_pages = config.num_pages or self._auto_num_pages(params)
             num_slots = self.num_pages * self.page_size
-            # the pools are created UNDER their shardings (pp keeps its own
-            # stage-stacked placement): the pool is sized to each device's
-            # free memory, so a layer's whole unsharded pool is tp times
-            # what one device can hold. Scale pools [P, SUBL, S] shard over
-            # tp on the sublane-row dim (each shard gets an aligned >=8-row
-            # block of its heads)
-            kv = llama.init_kv_cache(
+            # the pools are created UNDER their shardings: the pool is
+            # sized to each device's free memory, so a layer's whole
+            # unsharded pool is tp times what one device can hold. Scale
+            # pools [P, SUBL, S] shard over tp on the sublane-row dim (each
+            # shard gets an aligned >=8-row block of its heads)
+            self.kv = llama.init_kv_cache(
                 self.model_cfg, num_slots, dtype=self._dtype,
                 kv_quant=self._kv_quant, page_size=self.page_size,
                 tp=config.mesh.tp, packed=self._kv_packed,
                 kv_quant_group=config.kv_quant_group,
-                sharding=None if self._pp else self._kv_sharding,
-                scale_sharding=None if self._pp else jax.sharding.NamedSharding(
+                sharding=self._kv_sharding,
+                scale_sharding=jax.sharding.NamedSharding(
                     self.mesh, jax.sharding.PartitionSpec(None, "tp", None)
                 ),
                 win_slots=self.win_num_pages * self.page_size,
@@ -604,19 +643,6 @@ class JaxEngine:
                     config.max_batch_size + 1 if self._recurrent else 0
                 ),
             )
-            if self._pp:
-                from dynamo_tpu.parallel.pipeline import (
-                    pp_sharded_put,
-                    stack_layer_params,
-                )
-
-                k_st, v_st = kv.stacked()
-                params, k_st, v_st = pp_sharded_put(
-                    self.mesh, stack_layer_params(params), k_st, v_st
-                )
-                self.kv = (k_st, v_st)  # stacked [L, N, KW] pair in pp mode
-            else:
-                self.kv = kv
             jax.block_until_ready(self.kv)
         self.params = params
 
@@ -889,7 +915,7 @@ class JaxEngine:
         self._phase_lap: dict[str, float] = {}
         # per-token exposed collective bytes across the layer stack (0
         # when tp collectives are absent or owned by another executor:
-        # tp=1, sp ring prefill, pp stage rotation)
+        # tp=1, sp ring prefill)
         self._collective_tok_bytes = 0
         self._collective_bps = 0.0
         if mc.tp > 1 and tp_only:
@@ -1095,7 +1121,7 @@ class JaxEngine:
             )
 
         self._inject_fn = jax.jit(_inject, donate_argnums=(0,))
-        if self.model_cfg.latent or self._hybrid or self._recurrent:
+        if self._cache_kind():
             def _refuse(*_a, **_k):
                 self._refuse_plane("KV page inject / extract")
 
@@ -1148,103 +1174,36 @@ class JaxEngine:
                 lambda a, s: _dq(a, s, out_dtype=self._dtype)
             )
 
-    @property
-    def _returns_expert_load(self) -> bool:
-        """An expert model's decode program returns its expert load after
-        its tokens (`_decode_multi`); the pp stage executor has no expert
-        layer."""
-        return bool(self.model_cfg.num_experts) and not self._pp
+    def _cache_kind(self) -> Optional[str]:
+        """The row of `CACHE_KIND_REFUSALS` this model's cache is (the
+        first that applies), None for K and V pools under one page list."""
+        return next(
+            (k for k in CACHE_KIND_REFUSALS if getattr(self.model_cfg, k)),
+            None)
 
     def _refuse_plane(self, plane: str) -> None:
         """A plane that moves, shares or converts pages by one list of
         page ids over a K pool and a V pool a layer, asked of a model
         whose cache is something else: refused with the first reason
-        that applies."""
-        self._refuse_latent_plane(plane)
-        self._refuse_hybrid_plane(plane)
-        self._refuse_state_plane(plane)
+        that applies, never run on half a cache."""
+        kind = self._cache_kind()
+        if kind:
+            raise ValueError(CACHE_KIND_REFUSALS[kind]["why"].format(
+                plane=plane, name=self.model_cfg.name))
 
-    def _refuse_state_plane(self, plane: str) -> None:
-        """A plane that moves, shares or re-reads pages, asked of a model
-        whose Mamba-2 layers keep a state a sequence beside the pages:
-        pages without the state at their boundary are half a cache."""
-        if self._recurrent:
-            raise ValueError(
-                f"{plane} is not served with Mamba-2 layers beside "
-                f"attention ('{self.model_cfg.name}'): it moves or shares "
-                "pages, and this model also keeps a fixed-size state a "
-                "sequence that no page holds; pages without the state at "
-                "their boundary give a wrong answer"
-            )
-
-    def _refuse_state_config(self) -> None:
-        """What a model with state pools cannot be combined with yet,
-        each refused at construction (docs/kv_cache.md "State pools")."""
-        cfg, mc = self.config, self.config.mesh
-        asked = {
-            f"kv_quantization={cfg.kv_quantization!r} (the pages and the "
-            "state are served in the model's dtype)":
-                cfg.kv_quantization is not None,
-            f"quantization={cfg.quantization!r} (int8 weights)":
-                cfg.quantization is not None,
-            "a mesh of more than one device (tp / pp / sp / ep / dp: the "
-            "state pool has no sharding rule)":
-                mc.num_devices > 1,
-            "host KV offload (host_kv_pages)": bool(cfg.host_kv_pages),
-            "spec_decode (a rejected draft would have to roll the state "
-            "back)": bool(cfg.spec_decode),
-            "mixed_batching": bool(cfg.mixed_batching),
-        }
-        for what, on in asked.items():
-            if on:
-                self._refuse_state_plane(what)
-
-    def _refuse_latent_plane(self, plane: str) -> None:
-        """A plane that moves or converts K and V pools, asked of a
-        model whose cache is one latent pool a layer: refused with the
-        reason, never run on half a cache."""
-        if self.model_cfg.latent:
-            raise ValueError(
-                f"{plane} is not served with latent attention "
-                f"('{self.model_cfg.name}'): it is written for a K pool and "
-                "a V pool a layer, and a latent cache is ONE pool of "
-                "[c ; k_r] rows"
-            )
-
-    def _refuse_hybrid_plane(self, plane: str) -> None:
-        """A plane that moves, shares or re-reads pages by ONE list of
-        page ids, asked of a model whose layers keep two kinds of page
-        (full-attention pools beside window pools that release behind
-        the window): refused with the reason."""
-        if self._hybrid:
-            raise ValueError(
-                f"{plane} is not served with window beside full attention "
-                f"('{self.model_cfg.name}'): it is written for one list of "
-                "page ids a sequence, and this cache has two kinds of "
-                "page, of which the window kind releases behind the window"
-            )
-
-    def _refuse_hybrid_config(self) -> None:
-        """What a window-and-full-attention model cannot be combined
-        with yet, each refused at construction (docs/kv_cache.md
-        "Window pools")."""
-        cfg, mc = self.config, self.config.mesh
-        asked = {
-            f"kv_quantization={cfg.kv_quantization!r} (the two kinds of "
-            "pool are served in the model's dtype)":
-                cfg.kv_quantization is not None,
-            f"quantization={cfg.quantization!r} (int8 weights)":
-                cfg.quantization is not None,
-            "a mesh of more than one device (tp / pp / sp / ep / dp: the "
-            "layer holds its share of the experts without an exchange)":
-                mc.num_devices > 1,
-            "host KV offload (host_kv_pages)": bool(cfg.host_kv_pages),
-            "spec_decode (the verify step)": bool(cfg.spec_decode),
-            "mixed_batching": bool(cfg.mixed_batching),
-        }
-        for what, on in asked.items():
-            if on:
-                self._refuse_hybrid_plane(what)
+    def _refuse_config(self) -> None:
+        """What this model's kind of cache cannot be combined with yet,
+        each refused at construction (docs/kv_cache.md "Latent pools",
+        "Window pools", "State pools")."""
+        kind = self._cache_kind()
+        options = CACHE_KIND_REFUSALS[kind]["options"] if kind else {}
+        for option, why in options.items():
+            value = getattr(self.config, option)
+            if (value.num_devices > 1 if option == "mesh"
+                    else value not in (None, 0, False)):
+                self._refuse_plane(
+                    _REFUSABLE_OPTIONS[option].format(value)
+                    + (f" ({why})" if why else ""))
 
     def _win_pool_pages(self) -> int:
         """Pages of the window kind's pool, from what the configuration
@@ -1264,26 +1223,6 @@ class JaxEngine:
             # never more than every row's whole context
             1 + cfg.max_batch_size * cfg.max_pages_per_seq,
         )
-
-    def _refuse_latent_config(self) -> None:
-        """What a latent-attention model cannot be combined with yet,
-        each refused at construction (docs/kv_cache.md "Latent pools")."""
-        cfg, mc = self.config, self.config.mesh
-        asked = {
-            f"kv_quantization={cfg.kv_quantization!r} (the latent pool is "
-            "served in the model's dtype; no quantized latent rows yet)":
-                cfg.kv_quantization is not None,
-            f"quantization={cfg.quantization!r} (int8 weights)":
-                cfg.quantization is not None,
-            "a mesh of more than one device (the latent pool has no head "
-            "axis to shard; tp / pp / sp / ep / dp all refuse)":
-                mc.num_devices > 1,
-            "host KV offload (host_kv_pages)": bool(cfg.host_kv_pages),
-            "spec_decode": bool(cfg.spec_decode),
-        }
-        for what, on in asked.items():
-            if on:
-                self._refuse_latent_plane(what)
 
     @property
     def attention_backend(self) -> dict:
@@ -1640,23 +1579,6 @@ class JaxEngine:
                 overlap=self._tp_overlap_manual,
             )
 
-    def _pp_forward(self, params, kv, tokens, positions, write_slots,
-                    slot_matrix):
-        """pp>1 forward: GPipe stage executor over stacked stage-local
-        params/pools (parallel/pipeline.py). Microbatching m=1 — serving
-        correctness first; the fill/drain bubble is the price of a model
-        that doesn't fit one stage's HBM."""
-        from dynamo_tpu.parallel.pipeline import pp_forward
-
-        k_st, v_st = kv
-        b, t = tokens.shape
-        hidden, (k_st, v_st) = pp_forward(
-            params, self.model_cfg, tokens, positions, k_st, v_st,
-            write_slots.reshape(b, t), slot_matrix, self.mesh, 1,
-            tp_overlap=self.config.tp_overlap,
-        )
-        return hidden, (k_st, v_st)
-
     def _forward(self, params, kv, tokens, positions, write_slots, attn,
                  embeds=None, embeds_mask=None, moe_stats=None):
         """llama.forward, rerouted through the latency-hiding manual-TP
@@ -1713,11 +1635,7 @@ class JaxEngine:
             )
             return toks, jnp.zeros(toks.shape[0], jnp.float32)
 
-        if self._pp:
-            hidden, kv = self._pp_forward(
-                params, kv, tokens, positions, write_slots, slot_matrix
-            )
-        elif self._hybrid:
+        if self._hybrid:
             # every per-kind input arrives as (full, window)
             full, win = (
                 self._prefill_attn(
@@ -1946,17 +1864,11 @@ class JaxEngine:
                         attn, llama.AttnSpec.gather(win_smat, page_size=s),
                         slots_in(win_tables),
                     )
-            moe = [] if self._returns_expert_load else None
-            if self._pp:
-                hidden, kv = self._pp_forward(
-                    params, kv, tokens[:, None], positions[:, None],
-                    wslots, smat,
-                )
-            else:
-                hidden, kv = self._forward(
-                    params, kv, tokens[:, None], positions[:, None],
-                    wslots, attn, moe_stats=moe,
-                )
+            moe = [] if self.model_cfg.num_experts else None
+            hidden, kv = self._forward(
+                params, kv, tokens[:, None], positions[:, None],
+                wslots, attn, moe_stats=moe,
+            )
             lg = llama.logits(params, self.model_cfg, hidden[:, 0])
 
             def _sample(**kw):
@@ -2021,7 +1933,7 @@ class JaxEngine:
                 tid=keep(state.tid, out_t[2][-1]),
                 tlp=keep(state.tlp, out_t[3][-1]),
             )
-        if self._returns_expert_load:
+        if self.model_cfg.num_experts:
             # an expert model's dispatch ends in its load, [4] float32
             # (mean over the steps): fetched with the tokens, booked on
             # the sync digest (`_land`)
@@ -2247,14 +2159,6 @@ class JaxEngine:
                 f"prompt of {len(pre.token_ids)} tokens exceeds "
                 f"max_model_len={self.config.max_model_len}"
             )
-        so = pre.sampling_options
-        if self._pp and (
-            so.frequency_penalty or so.presence_penalty
-            or (so.repetition_penalty not in (None, 1.0)) or so.seed is not None
-        ):
-            raise ValueError(
-                "sampling penalties / per-request seeds unsupported with pp>1"
-            )
         # a prompt needing more pages than the pool can ever supply would
         # hang admission forever (and head-of-line block the queue)
         usable_tokens = (self.num_pages - 1) * self.page_size
@@ -2265,11 +2169,9 @@ class JaxEngine:
             )
         if len(pre.token_ids) == 0:
             raise ValueError("empty prompt")
-        if (self._pp or self._sp) and _preloaded is not None:
-            raise ValueError("disagg KV ingest unsupported with pp/sp>1 (v1)")
+        if self._sp and _preloaded is not None:
+            raise ValueError("disagg KV ingest unsupported with sp>1 (v1)")
         if pre.prompt_embeds is not None:
-            if self._pp:
-                raise ValueError("prompt_embeds unsupported with pp>1 (v1)")
             # fail fast: a silently dropped/misaligned embed span would
             # produce plausible but image-blind output
             n_emb = len(pre.prompt_embeds)
@@ -2421,8 +2323,6 @@ class JaxEngine:
             "disaggregated prefill (prefill_only: the send side of the "
             "host-staged and device-path planes)"
         )
-        if self._pp:
-            raise ValueError("disagg prefill_only unsupported with pp>1 (v1)")
         ctx = ctx or Context(pre.to_dict())
         usable_tokens = (self.num_pages - 1) * self.page_size
         if len(pre.token_ids) + 1 > usable_tokens:
@@ -3016,11 +2916,7 @@ class JaxEngine:
             # priority-aware pick: highest class first, FIFO within a
             # class (scheduler.pick_admission_index) — index 0 whenever
             # no priorities are in flight, i.e. plain FIFO
-            idx = (
-                pick_admission_index(self.waiting)
-                if self.config.priority_scheduling and len(self.waiting) > 1
-                else 0
-            )
+            idx = pick_admission_index(self.waiting)
             seq = self.waiting[idx]
             if seq.ctx.is_stopped():
                 del self.waiting[idx]
@@ -3427,29 +3323,6 @@ class JaxEngine:
         group dispatch per tick, decode interleaves between waves."""
         if not self._prefilling:
             return False
-        # admission batching window (paced arrivals): while decode
-        # streams run, hold a small pending set briefly so trickling
-        # arrivals share one dispatch — each tiny group pays a fixed
-        # dispatch+fetch overhead that serializes against decode.
-        # Mid-prompt continuations (num_computed > 0) never wait.
-        win = self.config.prefill_batch_window_s
-        if win > 0 and len(self._prefilling) < self.config.prefill_batch_min_rows:
-            now = time.perf_counter()
-            # fresh = first chunk of this serve (a prefix-cache hit has
-            # num_computed == num_cached at admission and is still a
-            # fresh arrival); mid-prompt chunk continuations never wait
-            fresh = all(
-                s.num_computed == s.num_cached and s.preloaded is None
-                for s in self._prefilling
-            )
-            oldest = min(s.t_admit for s in self._prefilling)
-            if fresh and self._any_mid_decode() and now - oldest < win:
-                # re-arm the loop when the window expires
-                loop = asyncio.get_running_loop()
-                loop.call_later(
-                    max(win - (now - oldest), 0.001), self._wake.set
-                )
-                return False
         with profiler.phase("eng.prefill.build"):
             groups, progressed = self._pick_prefill_groups()
         for bucket, seqs in groups.items():
@@ -3764,11 +3637,10 @@ class JaxEngine:
 
         generated == 1 wave members (first token from the prefill-group
         fetch, no decode dispatched yet) deliberately do NOT count on
-        their own: treating them as mid-decode would (a) hold the
-        admission batching window against the decode_ready_frac gate
-        (which still sees a pure admission wave) for a full window, and
-        (b) suppress the sibling prefill groups' early first-token
-        emits. A generated == 1 stream whose decode IS under way is
+        their own: treating them as mid-decode would suppress the
+        sibling prefill groups' early first-token emits (and lift the
+        decode-ready gate of `_build_decode`, which still sees a pure
+        admission wave). A generated == 1 stream whose decode IS under way is
         caught by the in-flight test instead — the gap the bare
         `generated > 1` predicate used to mislabel idle.
 
@@ -4219,23 +4091,9 @@ class JaxEngine:
         logs it once and keeps the normal paths. spec_decode COMPOSES
         (spec-eligible decode rows ride mixed steps as ragged q_len=1+k
         verify rows — see _build_mixed); it is no longer an exclusion."""
-        if self.model_cfg.latent:
-            return (
-                "mixed_batching unsupported with latent attention: the "
-                "ragged kernel reads a K pool and a V pool"
-            )
-        if self._hybrid:
-            return (
-                "mixed_batching unsupported with window beside full "
-                "attention: the ragged step takes one block table a row"
-            )
-        if self._recurrent:
-            return (
-                "mixed_batching unsupported with Mamba-2 layers beside "
-                "attention: the ragged step has no state slot a row"
-            )
-        if self._pp:
-            return "mixed_batching unsupported with pp>1 (v1)"
+        kind = self._cache_kind()
+        if kind:
+            return CACHE_KIND_REFUSALS[kind]["mixed"]
         if self._sp:
             return (
                 "mixed_batching unsupported with sp>1: ring attention "
@@ -4374,7 +4232,7 @@ class JaxEngine:
             return None
         carry_rows = {i for i, s in rows if stale_det.get(i) is s}
         if (
-            carry_rows and self._spec_on() and self.config.mixed_spec
+            carry_rows and self._spec_on()
             and any(
                 s.spec is not None and s.spec.gate_open()
                 for i, s in rows if i in carry_rows
@@ -4405,7 +4263,7 @@ class JaxEngine:
         # proposer would continue the wrong suffix — shed, don't stall.
         drafts: dict[int, list[int]] = {}
         shed = 0
-        if self._spec_on() and self.config.mixed_spec:
+        if self._spec_on():
             k_cap = min(self.config.spec_k_max, self.config.prefill_chunk - 1)
             for i, seq in rows:
                 if i in carry_rows:
@@ -4442,23 +4300,13 @@ class JaxEngine:
                 cost -= len(d)
             return cost
 
-        if self.config.mixed_decode_priority:
-            # latency-leaning default: every decode row joins (1 + k
-            # budget tokens each), prefill shrinks into what is left
-            dec_cost = shed_drafts_to(budget - 1)
-            leftover = budget - dec_cost
-            if leftover < 1:
-                return None  # budget cannot fit both planes
-            picks = self._select_mixed_prefill(leftover)
-        else:
-            # throughput-leaning: prefill chunks keep their full size;
-            # decode rows join only when the remainder has room for ALL
-            # of them (a partial decode batch would starve the tail rows
-            # — the normal alternating paths serve this case better)
-            picks = self._select_mixed_prefill(budget)
-            dec_cost = shed_drafts_to(budget - sum(c for _, c in picks))
-            if budget - sum(c for _, c in picks) < dec_cost:
-                return None
+        # every decode row joins (1 + k budget tokens each), prefill
+        # shrinks into what is left
+        dec_cost = shed_drafts_to(budget - 1)
+        leftover = budget - dec_cost
+        if leftover < 1:
+            return None  # budget cannot fit both planes
+        picks = self._select_mixed_prefill(leftover)
         if not picks:
             return None
         if self._inflight is not None and not pipeline:
@@ -4866,7 +4714,7 @@ class JaxEngine:
             return None
         if (
             self._prefilling
-            and len(ready) < self.config.decode_ready_frac * len(self.slots)
+            and len(ready) < len(self.slots)
             and all(s.generated <= 1 for _, s in ready)
         ):
             # pure admission wave (no stream has DECODED yet — first
@@ -5213,7 +5061,7 @@ class JaxEngine:
         self._step_count += 1
         for arr in S:
             arr.copy_to_host_async()
-        if self._returns_expert_load:
+        if self.model_cfg.num_experts:
             return _Dispatch(S[:-1], bld.active, bld.steps, moe=S[-1])
         return _Dispatch(S, bld.active, bld.steps)
 
@@ -5423,14 +5271,11 @@ class JaxEngine:
                 grew = True
                 continue
             live = [s for s in self.slots if s is not None]
-            if self.config.priority_scheduling:
-                # lowest priority class first, most-recent within it —
-                # batch traffic yields pages before interactive tenants
-                # (scheduler.pick_preemption_victim; reduces to
-                # max(seq_id) when no priorities are in flight)
-                victim = pick_preemption_victim(live)
-            else:
-                victim = max(live, key=lambda s: s.seq_id)
+            # lowest priority class first, most-recent within it —
+            # batch traffic yields pages before interactive tenants
+            # (scheduler.pick_preemption_victim; reduces to
+            # max(seq_id) when no priorities are in flight)
+            victim = pick_preemption_victim(live)
             self._preempt(victim)
             if victim is seq:
                 return False

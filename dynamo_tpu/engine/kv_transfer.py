@@ -58,7 +58,7 @@ def device_transfer_kv(
             f"{dst_engine.page_size}: repack_pages first"
         )
     for engine in (src_engine, dst_engine):
-        engine._refuse_latent_plane("device-path KV transfer")
+        engine._refuse_plane("device-path KV transfer")
     src_slots = jnp.asarray(
         _expand_slots(src_page_ids, src_engine.page_size, n_tokens)
     )

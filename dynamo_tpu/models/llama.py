@@ -278,10 +278,6 @@ class KVCache(NamedTuple):
     def latent(self) -> bool:
         return self.v is None
 
-    def stacked(self) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """[L, N, K*Hd] copies (host extraction / wire format only)."""
-        return jnp.stack(self.k), jnp.stack(self.v)
-
 
 def init_kv_cache(
     cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16,
@@ -1217,8 +1213,8 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
                tp_axis=None, tp_overlap: bool = False, bt_shape=None,
                moe_stats=None, *, layer: int):
     """One transformer layer (attention + FFN, pre-norm residuals) over
-    the paged pools — shared by `forward` and the pipeline-parallel
-    stage executor (parallel/pipeline.py). `tp_axis` enables manual-tp
+    the paged pools — shared by `forward` and the manual-tp layer
+    executor (parallel/tp_overlap.py). `tp_axis` enables manual-tp
     semantics for use inside a shard_map (explicit psums after the
     row-parallel projections). `tp_overlap` (with `tp_axis` and the
     static `bt_shape=(b, t)`) is the latency-hiding variant: x arrives
@@ -1254,7 +1250,7 @@ def layer_step(lp, cfg, x, cos, sin, kv_k, kv_v, write_slots, attn,
             raise ValueError(
                 f"a residual of {cfg.hc_mult} streams ('{cfg.name}') is "
                 "served on one device: the stage executors (manual tp, "
-                "tp_overlap, pipeline stages) carry one stream [B, T, D] "
+                "tp_overlap) carry one stream [B, T, D] "
                 "and have no rule for the boundary's maps"
             )
         from dynamo_tpu.models import mhc

@@ -2,7 +2,7 @@
 
 The reference delegates intra-model parallelism to its engines (NCCL inside
 vLLM/sglang; Ray/torch.distributed bootstrap — SURVEY.md §2.4). On TPU this
-layer is first-class: TP/PP/SP/EP/DP are axes of one `jax.sharding.Mesh`,
+layer is first-class: TP/SP/EP/DP are axes of one `jax.sharding.Mesh`,
 collectives are XLA's over ICI/DCN, and multi-host bootstrap is
 `jax.distributed` per-host processes.
 """

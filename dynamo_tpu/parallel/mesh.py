@@ -4,7 +4,7 @@ Replaces the reference's `--tensor-parallel-size` passthrough + NCCL
 (reference: launch/dynamo-run/src/flags.rs:67, lib/engines/sglang/src/lib.rs:64-73)
 with native mesh-axis shardings. One mesh carries every axis:
 
-    axes (dp, pp, ep, sp, tp)  —  tp innermost so TP collectives ride the
+    axes (dp, ep, sp, tp)  —  tp innermost so TP collectives ride the
                                   fastest ICI links; dp outermost so replicas
                                   can span hosts/DCN.
 
@@ -12,8 +12,6 @@ with native mesh-axis shardings. One mesh carries every axis:
   so the paged-KV path needs no collectives.
 - **sp**: sequence (context) parallel — long-prefill activations sharded
   over the token axis (ring/all-gather attention lives in ops/).
-- **pp**: layer-sharded pipeline v1 — layer weights live on their stage;
-  XLA moves the activation stream between stages.
 - **ep**: expert parallel axis for MoE models (axis exists on every mesh so
   graphs are portable; size 1 for dense models).
 - **dp**: engine-internal data parallel over decode slots / prefill batch.
@@ -34,20 +32,19 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.config import MAMBA, ModelConfig
 
-AXES = ("dp", "pp", "ep", "sp", "tp")
+AXES = ("dp", "ep", "sp", "tp")
 
 
 @dataclass(frozen=True)
 class MeshConfig:
     tp: int = 1
-    pp: int = 1
     sp: int = 1
     ep: int = 1
     dp: int = 1
 
     @property
     def num_devices(self) -> int:
-        return self.tp * self.pp * self.sp * self.ep * self.dp
+        return self.tp * self.sp * self.ep * self.dp
 
     @classmethod
     def for_devices(cls, n: int, tp: Optional[int] = None) -> "MeshConfig":
@@ -66,7 +63,7 @@ def build_mesh(cfg: MeshConfig, devices=None) -> Mesh:
             f"mesh {cfg} needs {cfg.num_devices} devices, have {len(devices)}"
         )
     arr = np.asarray(devices[: cfg.num_devices]).reshape(
-        cfg.dp, cfg.pp, cfg.ep, cfg.sp, cfg.tp
+        cfg.dp, cfg.ep, cfg.sp, cfg.tp
     )
     return Mesh(arr, AXES)
 
@@ -79,7 +76,7 @@ def validate_model_mesh(cfg: ModelConfig, mc: MeshConfig) -> None:
         raise ValueError(
             f"model '{cfg.name}' carries a residual of {cfg.hc_mult} "
             "streams a token, which is served on one device: no mesh axis "
-            f"(tp={mc.tp} pp={mc.pp} sp={mc.sp} ep={mc.ep} dp={mc.dp}) has "
+            f"(tp={mc.tp} sp={mc.sp} ep={mc.ep} dp={mc.dp}) has "
             "a rule for the streams or for the boundary's maps"
         )
     if cfg.num_kv_heads % mc.tp:
@@ -123,11 +120,7 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
 
     Column-parallel (out-dim over tp): wq/wk/wv, w_gate/w_up;
     row-parallel (in-dim over tp): wo, w_down; vocab over tp for
-    embed/lm_head; norms replicated. Layer weights additionally live on
-    their pipeline stage via the leading per-layer list — pp shards
-    nothing inside a layer, stages are assigned by the engine splitting
-    the layer list (v1: pp=1 in-engine; cross-stage serving composes
-    engines).
+    embed/lm_head; norms replicated.
     """
 
     def ns(*spec):

@@ -16,7 +16,7 @@ hosts. Two serving topologies follow:
   requests. No cross-host collectives on the serving path — this is the
   reference's multiple-workers-per-deployment shape and works today via
   the SDK/runtime.
-- **model sharded across hosts** (tp/pp spanning DCN): every process
+- **model sharded across hosts** (tp spanning DCN): every process
   executes the same jitted step SPMD-style over a global mesh
   (multi-controller). `global_mesh` builds that mesh; the serving loop
   must then run lockstep on every host (MaxText-style), which large-model
@@ -90,7 +90,7 @@ def shutdown() -> None:
 
 
 def global_mesh(mesh_config, devices=None):
-    """Mesh over ALL processes' devices (cross-host tp/pp axes ride DCN;
+    """Mesh over ALL processes' devices (a cross-host tp axis rides DCN;
     lay the fastest-varying axis (tp) within a host so its collectives
     stay on ICI)."""
     import jax

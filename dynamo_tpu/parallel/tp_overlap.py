@@ -46,8 +46,7 @@ already carries vs tp=1 — and greedy streams stay byte-identical to
 tp=1 (gated by scripts/multichip_smoke.py and tests/test_tp_overlap.py).
 
 Composition matrix (docs/parallelism.md "TP comm/compute overlap"):
-composes with mixed batching, the step pipeline, spec decode, the
-pipeline stage executor (parallel/pipeline.py takes `tp_overlap=True`),
+composes with mixed batching, the step pipeline, spec decode,
 the pallas serving backend (the kernels' per-layer shard_maps collapse
 into the executor's single one — `tp_overlap_forward` takes the full
 AttnSpec and the shard body reruns the kernels on shard-local pools

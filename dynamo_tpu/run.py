@@ -48,7 +48,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--router-mode", default="round_robin",
                    choices=["random", "round_robin", "kv"])
     p.add_argument("--tensor-parallel-size", "--tp", type=int, default=1, dest="tp")
-    p.add_argument("--pipeline-parallel-size", "--pp", type=int, default=1, dest="pp")
     p.add_argument("--sequence-parallel-size", "--sp", type=int, default=1, dest="sp",
                    help="ring-attention long-context prefill (needs prefill-chunk >= max-model-len)")
     p.add_argument("--max-batch-size", type=int, default=8)
@@ -208,7 +207,7 @@ def build_engine_config_kwargs(args) -> dict:
     from dynamo_tpu.parallel.mesh import MeshConfig
 
     kw = dict(
-        mesh=MeshConfig(tp=args.tp, pp=args.pp, sp=args.sp),
+        mesh=MeshConfig(tp=args.tp, sp=args.sp),
         dtype=args.dtype,
         page_size=args.page_size,
         num_pages=args.num_pages,

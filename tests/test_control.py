@@ -271,24 +271,49 @@ async def test_priority_admission_jumps_queue():
         await engine.close()
 
 
-async def test_priority_idle_byte_identical():
-    """Priority machinery on but no priorities in flight: greedy streams
-    byte-identical to an engine with priority_scheduling forced off."""
-    prompts = [[3, 4, 5], [7, 8, 9, 10], [11, 12]]
-    on = _engine()
-    off = _engine(priority_scheduling=False)
+async def test_one_priority_class_is_fifo_and_preempts_most_recent():
+    """Requests of ONE priority class (unset, or every request the same):
+    admitted in arrival order, and when the pages run out the sequence
+    admitted last gives way first."""
+    engine = _engine(max_batch_size=1)
     try:
-        got_on = await asyncio.gather(
-            *(_collect(on, _pre(p, 6)) for p in prompts)
-        )
-        got_off = await asyncio.gather(
-            *(_collect(off, _pre(p, 6)) for p in prompts)
-        )
-        assert got_on == got_off
-        assert all(got_on)
+        hold_t = asyncio.create_task(_collect(engine, _pre([5, 6, 7], 6)))
+        await asyncio.sleep(0.2)  # occupy the single slot
+        order: list[int] = []
+
+        async def tagged(i, priority):
+            await _collect(engine, _pre([9 + i, 10, 11], 3), priority)
+            order.append(i)
+
+        tasks = []
+        for i in range(3):
+            tasks.append(asyncio.create_task(tagged(i, 5)))
+            await asyncio.sleep(0.05)  # queued in this order
+        await asyncio.gather(hold_t, *tasks)
+        assert order == [0, 1, 2], order
     finally:
-        await on.close()
-        await off.close()
+        await engine.close()
+
+    engine = _engine(num_pages=30, decode_steps=4)
+    victims: list[tuple[int, int]] = []
+    preempt = engine._preempt
+
+    def spy(seq):
+        live = [s.seq_id for s in engine.slots if s is not None]
+        victims.append((seq.seq_id, max(live)))
+        preempt(seq)
+
+    engine._preempt = spy
+    try:
+        got = await asyncio.gather(*(
+            _collect(engine, _pre(range(3 + i, 27 + 4 * i), 60))
+            for i in range(4)
+        ))
+        assert all(len(g) == 60 for g in got)
+        assert victims and all(v == newest for v, newest in victims), victims
+        assert engine.kv_ledger.audit() == []
+    finally:
+        await engine.close()
 
 
 # ------------------------------------------------- disagg deadline clamp

@@ -320,34 +320,39 @@ def test_deepseek_v2_weight_loading(tmp_path):
                                    rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("backend", ["gather", "pallas"])
+async def test_a_prompt_sent_twice_reuses_its_latent_pages(backend):
+    """The in-engine prefix cache over a latent pool: the second serve of
+    a prompt of several pages reserves the pages the first registered,
+    prefills only the tail, and serves the same tokens and
+    log-probabilities."""
+    engine = make_engine(model=CFG, attn_backend=backend, prefill_chunk=32)
+    summaries = []
+    engine.subscribe_requests(summaries.append)
+    rng = np.random.RandomState(5)
+    prompt = [int(x) for x in rng.randint(1, CFG.vocab_size, (44,))]
+
+    async def serve():
+        pre = greedy_request(prompt, max_tokens=8)
+        pre.sampling_options.logprobs = True
+        tokens, _, frames = await collect(engine, pre)
+        return tokens, np.asarray(
+            [lp for f in frames for lp in f.get("log_probs") or []])
+
+    first_t, first_lp = await serve()
+    assert engine.allocator.pages_cached > 0
+    assert engine.peek_prefix_tokens(prompt) == 40  # 5 whole pages of 8
+    prefilled = engine.phase_stats["prefill_tokens"]
+    again_t, again_lp = await serve()
+    assert engine.phase_stats["prefill_tokens"] - prefilled == 4
+    assert [s["prefix"]["reused_blocks"] for s in summaries] == [0, 5]
+    assert again_t == first_t and len(again_lp) == 8
+    np.testing.assert_allclose(again_lp, first_lp, atol=5e-5)
+    assert engine.kv_ledger.audit() == []
+    await engine.close()
+
+
 # ------------------------------------------------- what a latent cache refuses
-
-REFUSED_AT_INIT = {
-    "kv_quantization": dict(kv_quantization="int8"),
-    "quantization": dict(quantization="int8"),
-    "host KV offload": dict(host_kv_pages=8),
-    "spec_decode": dict(spec_decode=True),
-    "mixed_batching": dict(mixed_batching=True),
-}
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED_AT_INIT))
-def test_latent_engine_refuses_at_construction(what):
-    with pytest.raises(ValueError, match="latent"):
-        make_engine(model=CFG, **REFUSED_AT_INIT[what])
-
-
-@pytest.mark.parametrize("axis", ["tp", "pp", "sp", "ep", "dp"])
-def test_latent_engine_refuses_every_mesh_axis(axis):
-    """tp, the pipeline stage executor, the ring (sp) executor, ep, dp: a
-    latent pool has no head axis to shard and the stage / ring executors
-    carry two pools."""
-    from dynamo_tpu.parallel.mesh import MeshConfig
-
-    with pytest.raises(ValueError, match="latent"):
-        make_engine(model=CFG, mesh=MeshConfig(**{axis: 2}),
-                    prefill_chunk=128)
-
 
 async def test_latent_engine_refuses_the_page_moving_planes():
     """The host-staged and device-path disaggregation planes

@@ -348,34 +348,55 @@ async def test_engine_phase_stats_and_first_meta_timing():
     await engine.close()
 
 
-async def test_prefill_batch_window_serves_trickling_arrivals():
-    """The admission batching window (paced-arrival throughput knob) must
-    not deadlock or drop requests: trickling arrivals while another
-    stream decodes are held briefly, batched, and all served; an idle
-    engine dispatches immediately."""
-    engine = make_engine(
-        prefill_batch_window_s=0.15, prefill_batch_min_rows=4,
-        max_batch_size=8,
+def _spy_decode_builds(engine) -> list:
+    """Every `_build_decode` call as (prompts still prefilling, rows
+    decode-ready, the most tokens a ready row has, did it build)."""
+    seen, build = [], engine._build_decode
+
+    def spy():
+        ready = [s for s in engine.slots if s is not None and not s.prefilling]
+        state = (len(engine._prefilling), len(ready),
+                 max((s.generated for s in ready), default=0))
+        out = build()
+        seen.append(state + (out is not None,))
+        return out
+
+    engine._build_decode = spy
+    return seen
+
+
+async def test_decode_ready_gate_holds_a_pure_admission_wave():
+    """A short prompt beside one of four chunks, nothing decoding yet:
+    the short one's first token leaves with its prefill, and no decode
+    is dispatched until the long one is ready too, so the first decode
+    dispatch carries the whole wave."""
+    engine = make_engine()
+    seen = _spy_decode_builds(engine)
+    outs = await asyncio.gather(
+        collect(engine, greedy_request([5, 6, 7], max_tokens=6)),
+        collect(engine, greedy_request(list(range(1, 101)), max_tokens=6)),
     )
-    # idle engine: no decode running -> immediate dispatch (well under
-    # the window even on a slow CPU test box)
-    t0 = asyncio.get_event_loop().time()
-    toks, fin, _ = await collect(engine, greedy_request([5, 6, 7], max_tokens=12))
-    assert len(toks) == 12
-    assert asyncio.get_event_loop().time() - t0 < 5.0  # not window-held
-    # (the window is 0.15 s; the real assertion is the trickle case
-    # below completing promptly — wall bounds on CPU are too noisy for
-    # a tight idle-latency check)
-    # trickling arrivals during an active decode
-    async def late(delay, prompt):
-        await asyncio.sleep(delay)
-        return await collect(engine, greedy_request(prompt, max_tokens=4))
-    results = await asyncio.gather(
-        late(0.0, [10, 11, 12, 13]),
-        late(0.03, [20, 21, 22]),
-        late(0.06, [30, 31, 32, 33, 34]),
-        late(0.09, [40, 41]),
-    )
-    for toks, fin, _ in results:
-        assert len(toks) == 4 and fin == "length"
     await engine.close()
+    assert all(len(t) == 6 and fin == "length" for t, fin, _ in outs)
+    held = [s for s in seen if s[0] and s[1]]
+    assert held and all(s[2] == 1 and not s[3] for s in held), seen
+    assert next(s for s in seen if s[3])[:2] == (0, 2), seen
+
+
+async def test_decode_ready_gate_never_holds_a_stream_mid_decode():
+    """A prompt of four chunks arriving beside a stream that is past its
+    first token: every decode build asked for while it prefills is
+    dispatched, one between chunks, so the stream keeps its cadence."""
+    engine = make_engine()
+    running = asyncio.create_task(
+        collect(engine, greedy_request([5, 6, 7], max_tokens=80)))
+    while not any(s is not None and s.generated > 1 for s in engine.slots):
+        await asyncio.sleep(0.01)
+    seen = _spy_decode_builds(engine)
+    late, fin, _ = await collect(
+        engine, greedy_request(list(range(1, 101)), max_tokens=4))
+    tokens, _, _ = await running
+    await engine.close()
+    assert len(tokens) == 80 and len(late) == 4 and fin == "length"
+    during = [s for s in seen if s[0] and s[1]]
+    assert len(during) >= 2 and all(s[2] > 1 and s[3] for s in during), seen

@@ -1,4 +1,4 @@
-"""Engine serving with pipeline parallelism: pp=2 (and pp x tp) engines
+"""Engine serving with sequence parallelism: sp=2 (and sp x tp) engines
 must reproduce the single-device engine's greedy output exactly."""
 
 from __future__ import annotations
@@ -11,51 +11,6 @@ from dynamo_tpu.parallel.mesh import MeshConfig
 from .test_engine import collect, greedy_request, make_engine
 
 CFG4 = get_config("tiny").with_(dtype="float32", num_layers=4)
-
-
-async def test_pp2_engine_matches_single_device():
-    prompt = [5, 17, 42, 9, 88, 3, 14, 21]
-    ref_engine = make_engine(model=CFG4)
-    ref, _, _ = await collect(ref_engine, greedy_request(prompt, max_tokens=6))
-    await ref_engine.close()
-
-    engine = make_engine(model=CFG4, mesh=MeshConfig(pp=2))
-    tokens, finish, _ = await collect(
-        engine, greedy_request(prompt, max_tokens=6)
-    )
-    assert finish == "length"
-    assert tokens == ref
-    await engine.close()
-
-
-async def test_pp2_tp2_engine_concurrent_requests():
-    prompt_a = [5, 17, 42, 9, 88, 3, 14, 21]
-    prompt_b = [7, 7, 9, 30]
-    ref_engine = make_engine(model=CFG4)
-    ref_a, _, _ = await collect(ref_engine, greedy_request(prompt_a, max_tokens=5))
-    ref_b, _, _ = await collect(ref_engine, greedy_request(prompt_b, max_tokens=5))
-    await ref_engine.close()
-
-    import asyncio
-
-    engine = make_engine(model=CFG4, mesh=MeshConfig(pp=2, tp=2))
-    (a, _, _), (b, _, _) = await asyncio.gather(
-        collect(engine, greedy_request(prompt_a, max_tokens=5)),
-        collect(engine, greedy_request(prompt_b, max_tokens=5)),
-    )
-    assert a == ref_a and b == ref_b
-    await engine.close()
-
-
-def test_pp_mode_rejects_unsupported_combos():
-    with pytest.raises(ValueError, match="pallas"):
-        make_engine(model=CFG4, mesh=MeshConfig(pp=2), attn_backend="pallas")
-    with pytest.raises(ValueError, match="offload"):
-        make_engine(model=CFG4, mesh=MeshConfig(pp=2), host_kv_pages=8)
-    with pytest.raises(ValueError, match="divisible"):
-        make_engine(
-            model=CFG4.with_(num_layers=3), mesh=MeshConfig(pp=2)
-        )
 
 
 async def test_sp2_engine_ring_prefill_matches_single_device():

@@ -177,28 +177,6 @@ async def test_tails_that_meet_under_load_find_their_group_program_loaded():
 # ----------------------------------------------- what a state pool refuses
 
 SENTENCE = "Mamba-2 layers beside attention"
-REFUSED_AT_INIT = {
-    "kv_quantization": dict(kv_quantization="int8"),
-    "quantization": dict(quantization="int8"),
-    "host KV offload": dict(host_kv_pages=8),
-    "spec_decode": dict(spec_decode=True),
-    "mixed_batching": dict(mixed_batching=True),
-}
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED_AT_INIT))
-def test_state_engine_refuses_at_construction(what):
-    with pytest.raises(ValueError, match=SENTENCE):
-        make_engine(model=CFG, **REFUSED_AT_INIT[what])
-
-
-@pytest.mark.parametrize("axis", ["tp", "pp", "sp", "ep", "dp"])
-def test_state_engine_refuses_every_mesh_axis(axis):
-    from dynamo_tpu.parallel.mesh import MeshConfig
-
-    with pytest.raises(ValueError, match=SENTENCE):
-        make_engine(model=CFG, mesh=MeshConfig(**{axis: 2}),
-                    prefill_chunk=128)
 
 
 async def test_state_engine_refuses_the_page_moving_planes():

@@ -123,19 +123,27 @@ def test_init_params_under_target_shardings_matches_eager_init(quantize):
     assert all(jax.tree.leaves(same))
 
 
-def test_engine_creates_kv_pools_under_their_shardings():
+@pytest.mark.parametrize("mesh", [
+    dict(tp=4), dict(tp=2), dict(sp=2)], ids=["tp4", "tp2", "sp2"])
+def test_engine_creates_kv_pools_under_their_shardings(mesh):
     """The pool is sized to each device's free memory, so a layer's whole
     unsharded pool is tp times what a device holds: it must never be
-    built on one device first (that OOMed chip 0 of a four-chip host)."""
+    built on one device first (that OOMed chip 0 of a four-chip host).
+    Every mesh's engine holds ONE type of cache, its pools under the
+    shardings `_kv_sharding` names."""
     from dynamo_tpu.engine import EngineConfig, JaxEngine
 
     cfg = cfgmod.get_config("tiny").with_(num_kv_heads=4)
     eng = JaxEngine(EngineConfig(
-        model=cfg, mesh=meshmod.MeshConfig(tp=4), num_pages=32, page_size=16,
-        kv_quantization="int8",
+        model=cfg, mesh=meshmod.MeshConfig(**mesh), num_pages=32,
+        page_size=16, kv_quantization="int8", max_model_len=256,
+        prefill_chunk=256,
     ))
+    tp, n = eng.config.mesh.tp, eng.config.mesh.num_devices
+    assert type(eng.kv) is llama.KVCache
     k0, ks0 = eng.kv.k[0], eng.kv.ks[0]
+    assert all(x.sharding == eng._kv_sharding for x in eng.kv.k + eng.kv.v)
     assert k0.sharding.spec == jax.sharding.PartitionSpec(None, "tp")
-    assert k0.addressable_shards[0].data.shape == (k0.shape[0], k0.shape[1] // 4)
+    assert k0.addressable_shards[0].data.shape == (k0.shape[0], k0.shape[1] // tp)
     assert ks0.sharding.spec == jax.sharding.PartitionSpec(None, "tp", None)
-    assert len({s.device for s in ks0.addressable_shards}) == 4
+    assert len({s.device for s in ks0.addressable_shards}) == n

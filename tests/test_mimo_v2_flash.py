@@ -578,30 +578,6 @@ async def test_tails_that_meet_under_load_find_their_group_program_loaded():
 
 # ------------------------------------------- what two kinds of page refuse
 
-REFUSED_AT_INIT = {
-    "kv_quantization": dict(kv_quantization="int8"),
-    "quantization": dict(quantization="int8"),
-    "host KV offload": dict(host_kv_pages=8),
-    "spec_decode": dict(spec_decode=True),
-    "mixed_batching": dict(mixed_batching=True),
-}
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED_AT_INIT))
-def test_hybrid_engine_refuses_at_construction(what):
-    with pytest.raises(ValueError, match="window beside full attention"):
-        make_engine(model=CFG, **REFUSED_AT_INIT[what])
-
-
-@pytest.mark.parametrize("axis", ["tp", "pp", "sp", "ep", "dp"])
-def test_hybrid_engine_refuses_every_mesh_axis(axis):
-    from dynamo_tpu.parallel.mesh import MeshConfig
-
-    with pytest.raises(ValueError, match="window beside full attention"):
-        make_engine(model=CFG.with_(num_kv_heads=2, swa_num_kv_heads=2),
-                    mesh=MeshConfig(**{axis: 2}), prefill_chunk=128)
-
-
 async def test_hybrid_engine_refuses_the_page_moving_planes():
     """Disaggregation (both sides, host-staged and device-path), prefix
     ingest / export, the device-path transfer: each refuses with its

@@ -213,29 +213,13 @@ async def test_mixed_runtime_toggle_on_unsupported_engine_degrades():
     the normal paths, not corrupt or crash."""
     from dynamo_tpu.parallel.mesh import MeshConfig
 
-    # pp>1: the stage executor has no ragged multi-query step
-    engine = make_engine(mesh=MeshConfig(pp=2))
+    # sp>1: ring attention prefills whole prompts, no chunk to ride
+    engine = make_engine(mesh=MeshConfig(sp=2), prefill_chunk=128)
     engine.config.mixed_batching = True
     held, streams = await _admission_wave(engine)
     ps = engine.phase_stats
     await engine.close()
     assert ps["mixed_steps"] == 0  # degraded, never built a mixed step
-    assert len(held) == 40 and all(len(s) == 10 for s in streams)
-
-
-async def test_mixed_decode_priority_off_defers_decode_when_budget_tight():
-    """mixed_decode_priority=False with a budget that cannot fit decode
-    rows next to a full chunk: mixed stands down (normal alternating
-    paths) instead of shrinking prefill. Wave prompts are an exact
-    multiple of prefill_chunk so EVERY chunk (final included) fills the
-    whole budget and never leaves decode-row room."""
-    engine = make_engine(
-        mixed_batching=True, mixed_step_tokens=32, mixed_decode_priority=False
-    )
-    held, streams = await _admission_wave(engine, wave_len=64)
-    ps = engine.phase_stats
-    await engine.close()
-    assert ps["mixed_steps"] == 0
     assert len(held) == 40 and all(len(s) == 10 for s in streams)
 
 

@@ -136,7 +136,11 @@ async def test_deadline_expires_in_admission_queue_resolves_timeout():
     engine = make_engine(max_batch_size=1)
     long_ctx = Context(greedy_request([5, 17, 42], max_tokens=100).to_dict())
     long_stream = await engine.generate(long_ctx)
-    # the slot is taken; this one queues and its 0.2s budget dies there
+    # the slot is taken and its programs are compiled (a compile beside
+    # five other workers can hold the loop longer than the budget below,
+    # which then dies at submission, not in the queue)
+    await long_stream.__anext__()
+    # this one queues and its 0.2s budget dies there
     waiter = asyncio.create_task(
         collect(engine, greedy_request([9, 8, 7]), deadline=time.time() + 0.2)
     )

@@ -60,7 +60,3 @@ def test_batch_mode_end_to_end(tmp_path):
     assert all(o["output"] for o in out_lines)
 
 
-def test_pp_flag_plumbed():
-    args = build_parser().parse_args(["in=http", "out=jax", "--pp", "2"])
-    kw = build_engine_config_kwargs(args)
-    assert kw["mesh"].pp == 2

@@ -13,8 +13,6 @@ Contract under test (docs/architecture.md "Ragged verify rows"):
 - the composition actually engages (mixed_spec_rows > 0) and the token
   budget counts 1 + k per spec row (mixed_step_tokens_max never exceeds
   the budget);
-- `mixed_spec=False` keeps decode rows at q_len=1 inside mixed steps
-  (no composed verify rows) while both features stay on;
 - standalone spec verify on a pallas engine routes through the ragged
   flash kernel and still reproduces the plain engine's greedy stream;
 - rollback under composition: a re-serve rides the prefix cache without
@@ -144,24 +142,6 @@ async def test_budget_counts_spec_rows():
     assert 0 < ps["mixed_step_tokens_max"] <= budget
     assert m["mixed_spec_rows"] == ps["mixed_spec_rows"]
     assert len(held) == 48 and all(len(s) == 10 for s in streams)
-
-
-async def test_mixed_spec_toggle_off_keeps_plain_rows():
-    """mixed_spec=False: both features on, but decode rows stay q_len=1
-    inside mixed steps — no composed verify rows, streams still exact."""
-    plain = make_engine()
-    held_a, wave_a = await _admission_wave(plain)
-    await plain.close()
-    engine = make_engine(
-        mixed_batching=True, mixed_step_tokens=64, spec_decode=True,
-        mixed_spec=False,
-    )
-    held_b, wave_b = await _admission_wave(engine)
-    ps = engine.phase_stats
-    await engine.close()
-    assert ps["mixed_steps"] > 0
-    assert ps["mixed_spec_rows"] == 0
-    assert held_a == held_b and wave_a == wave_b
 
 
 async def test_standalone_spec_verify_pallas_routes_flash():

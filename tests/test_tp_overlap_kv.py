@@ -13,9 +13,8 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.models import llama
-from dynamo_tpu.parallel import tp_overlap as ov
 
-from .test_tp_overlap import CFG, TP, _inputs, _mesh
+from .test_tp_overlap import CFG, TP, _inputs, _mesh, _overlap_forward
 
 
 def test_forward_overlap_int8_kv_matches_tp1():
@@ -38,11 +37,8 @@ def test_forward_overlap_int8_kv_matches_tp1():
     # tp=8 pools carry the tp-blocked scale layout (ops/quant.kv_scale_subl)
     kv8 = llama.init_kv_cache(CFG, 512, kv_quant="int8", page_size=8, tp=TP)
     spec8 = llama.AttnSpec.gather(jnp.asarray(smat), page_size=8, kv_tp=TP)
-    with jax.set_mesh(mesh):
-        hidden, kv_out = ov.tp_overlap_forward(
-            params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
-            jnp.asarray(wslots.reshape(-1)), spec8, mesh,
-        )
+    hidden, kv_out = _overlap_forward(
+        mesh, params, tokens, positions, kv8, wslots, spec8)
     assert kv_out.k[0].dtype == jnp.int8
     assert kv_out.ks[0].shape[1] == TP * 8  # tp-blocked scale sublanes
     np.testing.assert_allclose(np.asarray(hidden), np.asarray(ref_hidden),
@@ -122,11 +118,8 @@ def test_forward_overlap_packed_pallas_prefill_matches_tp1(tier):
     kv8 = llama.init_kv_cache(
         CFG, 512, kv_quant=quant, page_size=page, tp=TP, packed=True
     )
-    with jax.set_mesh(mesh):
-        hidden, kv_out = ov.tp_overlap_forward(
-            params, CFG, jnp.asarray(tokens), jnp.asarray(positions), kv8,
-            jnp.asarray(wslots.reshape(-1)), spec(TP), mesh,
-        )
+    hidden, kv_out = _overlap_forward(
+        mesh, params, tokens, positions, kv8, wslots, spec(TP))
     assert kv_out.k[0].dtype == jnp.int32
     np.testing.assert_allclose(np.asarray(hidden), np.asarray(ref_hidden),
                                rtol=3e-4, atol=3e-4)

@@ -74,33 +74,30 @@ async def test_preempt_and_resume_equals_an_undisturbed_run():
     np.testing.assert_allclose(outs[-1][1], alone_lp, atol=5e-5)
 
 
+@pytest.mark.parametrize("backend", ["gather", "pallas"])
+async def test_a_prompt_sent_twice_reuses_its_latent_pages(backend):
+    """The in-engine prefix cache over the latent pool of a four-stream
+    model: the second serve reserves the first's five whole pages,
+    prefills the tail alone and serves the same tokens and
+    log-probabilities."""
+    engine = make_engine(model=CFG, attn_backend=backend, prefill_chunk=32)
+    summaries = []
+    engine.subscribe_requests(summaries.append)
+    prompt = _prompt(44, seed=5)
+    first_t, first_lp = await _serve(engine, prompt)
+    assert engine.allocator.pages_cached > 0
+    assert engine.peek_prefix_tokens(prompt) == 40  # 5 whole pages of 8
+    prefilled = engine.phase_stats["prefill_tokens"]
+    again_t, again_lp = await _serve(engine, prompt)
+    assert engine.phase_stats["prefill_tokens"] - prefilled == 4
+    assert [s["prefix"]["reused_blocks"] for s in summaries] == [0, 5]
+    assert again_t == first_t
+    np.testing.assert_allclose(again_lp, first_lp, atol=5e-5)
+    assert engine.kv_ledger.audit() == []
+    await engine.close()
+
+
 # --------------------------------------------------------- what it refuses
-
-REFUSED_AT_INIT = {
-    "kv_quantization": dict(kv_quantization="int8"),
-    "quantization": dict(quantization="int8"),
-    "host KV offload": dict(host_kv_pages=8),
-    "spec_decode": dict(spec_decode=True),
-    "mixed_batching": dict(mixed_batching=True),
-}
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED_AT_INIT))
-def test_engine_refuses_at_construction(what):
-    """The planes a latent model is refused on today stay refused."""
-    with pytest.raises(ValueError, match="latent"):
-        make_engine(model=CFG, **REFUSED_AT_INIT[what])
-
-
-@pytest.mark.parametrize("axis", ["tp", "pp", "sp", "ep", "dp"])
-def test_engine_refuses_every_mesh_axis(axis):
-    """No mesh axis has a rule for the streams or the boundary's maps."""
-    from dynamo_tpu.parallel.mesh import MeshConfig
-
-    with pytest.raises(ValueError, match="residual of 4 streams"):
-        make_engine(model=CFG, mesh=MeshConfig(**{axis: 2}),
-                    prefill_chunk=128)
-
 
 async def test_engine_refuses_the_page_moving_planes():
     engine = make_engine(model=CFG)
