@@ -83,6 +83,7 @@ from dynamo_tpu.engine.spec import NgramProposer
 from dynamo_tpu.ops.sampling import (
     TOP_LOGPROBS_MAX,
     bump_counts,
+    sample_block,
     sample_tokens,
     verify_draft_tokens,
 )
@@ -190,6 +191,32 @@ CACHE_KIND_REFUSALS = {
             "attention: the ragged step has no state slot a row"
         ),
     },
+    # not a kind of CACHE (the pools are plain K and V under one list of
+    # page ids) but of STEP: what is written for a step that carries one
+    # token a sequence, or for rows that stay as a step wrote them
+    "dlm": {
+        "why": (
+            "{plane} is not served with generation by diffusion over "
+            "blocks ('{name}'): it is written for a step that carries one "
+            "token a sequence over rows that stay as they were written, "
+            "and this model's step carries a block whose rows are "
+            "rewritten by every pass until its commit pass"
+        ),
+        "options": {
+            "kv_quantization": "a block pass reads its own rows back "
+                               "through the ragged kernel in the model's "
+                               "dtype",
+            "quantization": "int8 weights are not judged for this family",
+            "mesh": "tp / sp / ep / dp: the block step is one device's",
+            "host_kv_pages": "",
+            "spec_decode": "a block pass is its own draft and verify",
+            "mixed_batching": "",
+        },
+        "mixed": (
+            "mixed_batching unsupported with generation by diffusion "
+            "over blocks: the ragged step has no block rows"
+        ),
+    },
 }
 
 
@@ -209,6 +236,11 @@ class StepState(NamedTuple):
     # [B, V] int8 occurrence counts: rides only into the penalty / seeded
     # programs (allocated on first use); None for every other program
     counts: Optional[jax.Array] = None
+    # a model generated by diffusion over blocks (`_dlm_multi`), a row a
+    # slot: (the open block's ids [B, L] i32, which of its positions still
+    # hold a mask [B, L] bool, its first position [B] i32). Rides only
+    # into the block step program; None for every other program and model
+    dlm: Optional[tuple] = None
 
     def split(self) -> tuple:
         """(state holding the successor key, this program's key): the
@@ -267,11 +299,13 @@ class _DecodeBuild:
                  "rows_i", "rows_f", "use_ext", "want_lps",
                  "want_tops", "active", "steps", "all_greedy",
                  "width", "spec", "tokens", "draft", "dlen", "pos0",
-                 "build_s")
+                 "build_s", "win_pages")
 
     def __init__(self, **kw):
         self.spec = False  # speculative verify build (host-built tokens)
         self.build_s = 0.0  # host time of the build (the digest's column)
+        # a hybrid model's decode build: `_kv_window_pages` of it
+        self.win_pages = None
         for k, v in kw.items():
             setattr(self, k, v)
 
@@ -329,6 +363,13 @@ class JaxEngine:
         # would name pages whose window-kind twin is released, or whose
         # state at the page's boundary nobody kept
         self._no_prefix_cache = self._hybrid or self._recurrent
+        # generation by diffusion over blocks: the decode dispatch is the
+        # block step (`_dlm_multi`), the prompt is encoded under the
+        # block-causal mask up to its last whole block, and what a pass
+        # writes counts only once the block's commit pass has run
+        # (docs/kv_cache.md "Block steps")
+        self._dlm = self.model_cfg.dlm
+        self._mask_block = self.model_cfg.block_length or 1
         # (bucket, page width, statics) whose wider groups are loaded
         self._tail_groups_loaded: set[tuple] = set()
         if self._hybrid:
@@ -775,6 +816,11 @@ class JaxEngine:
                 tid=np.zeros((_B, TOP_LOGPROBS_MAX), np.int32),
                 tlp=np.zeros((_B, TOP_LOGPROBS_MAX), np.float32),
                 key=jax.random.PRNGKey(config.seed ^ 0x5EED),
+                dlm=(
+                    np.zeros((_B, self._mask_block), np.int32),
+                    np.ones((_B, self._mask_block), bool),
+                    np.zeros(_B, np.int32),
+                ) if self._dlm else None,
             ),
             self._state_sharding,
         )
@@ -807,6 +853,22 @@ class JaxEngine:
             "spec_drafted": 0,
             "spec_accepted": 0,
             "spec_emitted": 0,
+            # generation by diffusion over blocks: one dlm dispatch =
+            # `decode_steps` PASSES of the block step, each carrying a
+            # whole block a row. Counted where a dispatch LANDS, over the
+            # rows still live then: dlm_row_passes = rows x passes (the
+            # commit passes among them), dlm_filled = masked positions
+            # those passes filled, dlm_committed = blocks whose commit
+            # pass ran; dlm_filled / dlm_row_passes is the tokens a row a
+            # pass (block / (steps + 1) when every row is in step).
+            # dlm_passes counts passes dispatched (dispatches x passes)
+            "dlm_dispatch_s": 0.0,
+            "dlm_sync_s": 0.0,
+            "dlm_dispatches": 0,
+            "dlm_passes": 0,
+            "dlm_row_passes": 0,
+            "dlm_filled": 0,
+            "dlm_committed": 0,
             # mixed prefill+decode steps (stall-free batching): one
             # mixed_step = ONE dispatch carrying mixed_decode_rows
             # decode rows (1 budget token each) + mixed_prefill_tokens
@@ -995,6 +1057,12 @@ class JaxEngine:
         # per-step logsumexp over [B, V]
         self._decode_fn = jax.jit(
             self._decode_multi, donate_argnums=(1, 2),
+            static_argnums=(5, 6, 7),
+        )
+        # the block step of a model generated by diffusion over blocks:
+        # `decode_steps` passes a dispatch, the same statics
+        self._dlm_fn = jax.jit(
+            self._dlm_multi, donate_argnums=(1, 2),
             static_argnums=(5, 6, 7),
         )
         # speculative verify: one multi-query step over [carry, drafts]
@@ -1462,6 +1530,20 @@ class JaxEngine:
                 ps["spec_emitted"] / ps["spec_rows"]
                 if ps["spec_rows"] else 0.0
             ),
+            # generation by diffusion over blocks (see _phase_stats; 0
+            # for every other model): passes dispatched, rows x passes
+            # landed, masked positions they filled, blocks committed, and
+            # tokens a row a pass: block / (steps + 1) when every row is
+            # in step, lower when rows join on a partial block or end
+            # inside one (non-diffusion decode is 1.0 a step)
+            "dlm_passes": ps["dlm_passes"],
+            "dlm_row_passes": ps["dlm_row_passes"],
+            "dlm_filled": ps["dlm_filled"],
+            "dlm_committed": ps["dlm_committed"],
+            "dlm_tokens_per_pass": (
+                ps["dlm_filled"] / ps["dlm_row_passes"]
+                if ps["dlm_row_passes"] else 0.0
+            ),
             # stall-free mixed batching health (see _phase_stats):
             # steps taken, decode rows that rode them instead of
             # stalling, and prefill tokens computed inside them
@@ -1708,6 +1790,7 @@ class JaxEngine:
                 block_tables=btables, q_pos0=positions[:, 0],
                 lengths=last_idx + 1, kv_tp=self.config.mesh.tp,
                 int4_groups=self._kv_int4_groups,
+                mask_block=self._mask_block,
             )
         if self._sp:
             # long-context mode: ring attention over sp; on a prefix-
@@ -1730,6 +1813,7 @@ class JaxEngine:
             slot_matrix, page_size=self.page_size,
             kv_tp=self.config.mesh.tp,
             int4_groups=self._kv_int4_groups,
+            mask_block=self._mask_block,
         )
 
     @staticmethod
@@ -1938,6 +2022,127 @@ class JaxEngine:
             # (mean over the steps): fetched with the tokens, booked on
             # the sync digest (`_land`)
             S = S + (jnp.mean(out_t[-1], axis=0),)
+        return S, kv, self._pin_state(state)
+
+    def _dlm_multi(self, params, kv, state, rows_i, rows_f,
+                   all_greedy=False, want_lps=False, want_tops=False):
+        """`decode_steps` PASSES of the block step in ONE dispatch (a
+        `lax.scan`, as `_decode_multi` scans steps), for a model generated
+        by diffusion over blocks of L = `block_length` positions. A row
+        carries its open block: the ids [L], which positions still hold a
+        mask [L], and the block's first position. One pass feeds the whole
+        block (the mask token where a position is masked) at positions
+        `pos0 .. pos0 + L - 1`, scatters its L rows of keys and values into
+        the pages (the verify step's write), attends every committed
+        position before `pos0` and the block itself (`mask_block`, no
+        causal line inside it), and reads logits at every position: the
+        logits at position i predict position i.
+
+        What the pass does with them is the row's PHASE, which is data: a
+        row with a mask left fills `L / denoising_steps` of its masked
+        positions (`ops/sampling.sample_block`); a row with none left was
+        in its COMMIT pass: what it just wrote are the finished block's
+        keys and values, its logits are not read, its position moves by L
+        and its block resets to masks. So one compiled program a width
+        serves rows that joined at different times, and nothing a
+        denoising pass writes is read by a later block (the commit pass
+        rewrites it first).
+
+        The dispatch is two fused uploads of the program's width `w`:
+        `rows_i` [w, 6 + W + 2 L] = [first position, active, arm, -, top_k,
+        seed, block table, ids, masked] and `rows_f` as `_decode_multi`'s.
+        A row whose `arm` is set (it joined since the last dispatch) takes
+        its block from the upload: the prompt's tail, never masked, then
+        masks; every other row its carry, `state.dlm`. Returns ((filled
+        ids [K, w, L] with -1 where a pass filled nothing, their
+        log-probabilities [K, w, L][, alternatives], the expert load),
+        kv, state)."""
+        cfg = self.model_cfg
+        n = cfg.block_length
+        w = rows_i.shape[0]
+        active = rows_i[:, 1].astype(bool)
+        arm = rows_i[:, 2].astype(bool)
+        topk = rows_i[:, 4]
+        block_tables = rows_i[:, 6:-2 * n]
+        temp, topp = rows_f[:, 0], rows_f[:, 1]
+        state, key = state.split()
+        ids0, masked0, pos00 = state.dlm
+        ids = jnp.where(arm[:, None], rows_i[:, -2 * n:-n], ids0[:w])
+        masked = jnp.where(
+            arm[:, None], rows_i[:, -n:].astype(bool), masked0[:w])
+        pos0 = jnp.where(arm, rows_i[:, 0], pos00[:w])
+        s = self.page_size
+        w_pages = block_tables.shape[1]
+        max_len = self.config.max_model_len
+        q_lens = jnp.where(active, n, 0).astype(jnp.int32)
+        smat = None
+        if not self._attn_pallas:
+            smat = (
+                block_tables[:, :, None] * s + jnp.arange(s, dtype=jnp.int32)
+            ).reshape(w, -1)
+
+        def body(carry, _):
+            ids, masked, pos0, kv, key = carry
+            key, sub = jax.random.split(key)
+            commit = active & ~jnp.any(masked, axis=1)
+            tokens = jnp.where(masked, cfg.mask_token_id, ids)
+            positions = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)
+            page_idx = jnp.minimum(positions // s, w_pages - 1)
+            # inactive rows and positions past the model's length write
+            # the trash page (the expert layers route them nowhere)
+            wslots = jnp.where(
+                active[:, None] & (positions < max_len),
+                jnp.take_along_axis(block_tables, page_idx, axis=1) * s
+                + positions % s,
+                0,
+            ).astype(jnp.int32)
+            attn = llama.AttnSpec.gather(
+                smat, page_size=s, interpret=self._attn_interpret,
+                block_tables=block_tables if self._attn_pallas else None,
+                q_pos0=pos0 if self._attn_pallas else None,
+                lengths=q_lens, mask_block=n,
+            )
+            moe = []
+            hidden, kv = self._forward(
+                params, kv, tokens, positions, wslots.reshape(-1), attn,
+                moe_stats=moe,
+            )
+            lg = llama.logits(
+                params, cfg, hidden.reshape(w * n, -1))  # [w L, V]
+            out = sample_block(
+                lg, masked & active[:, None], sub, temp, topk, topp,
+                n_fill=n // cfg.denoising_steps,
+                mask_token_id=cfg.mask_token_id, all_greedy=all_greedy,
+                return_logprobs=want_lps,
+                top_n=TOP_LOGPROBS_MAX if want_tops else 0,
+            )
+            sampled, fill = out[0], out[1]
+            ids = jnp.where(fill, sampled, ids)
+            masked = masked & ~fill
+            ys = (jnp.where(fill, sampled, -1), *out[2:], jnp.mean(
+                jnp.asarray(moe, jnp.float32), axis=0))
+            return (
+                jnp.where(commit[:, None], cfg.mask_token_id, ids),
+                masked | commit[:, None],
+                pos0 + jnp.where(commit, n, 0), kv, key,
+            ), ys
+
+        (ids, masked, pos0, kv, _), out_t = jax.lax.scan(
+            body, (ids, masked, pos0, kv, key), None,
+            length=self.config.decode_steps,
+        )
+
+        def keep(old, new):
+            # active rows carry their block on; a row this dispatch does
+            # not advance keeps what it held
+            mask = active.reshape((w,) + (1,) * (new.ndim - 1))
+            return old.at[:w].set(jnp.where(mask, new, old[:w]))
+
+        state = state._replace(dlm=(
+            keep(ids0, ids), keep(masked0, masked), keep(pos00, pos0),
+        ))
+        # the passes' expert load, [4] float32 (mean over the passes)
+        S = (*out_t[:-1], jnp.mean(out_t[-1], axis=0))
         return S, kv, self._pin_state(state)
 
     def _spec_verify_step(self, params, kv, state, tokens, positions,
@@ -2195,6 +2400,22 @@ class JaxEngine:
             request, pre, self.page_size, self.config.max_model_len,
             blocks=_blocks,
         )
+        if self._dlm:
+            refused = {
+                "frequency / presence / repetition penalties":
+                    seq.has_penalties,
+                "a per-request seed": seq.seed >= 0,
+                "prompt_embeds": seq.prompt_embeds is not None,
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{what}: not served with generation by diffusion "
+                        f"over blocks ('{self.model_cfg.name}'): a block "
+                        "pass samples every position of a block at once "
+                        "(greedy, temperature, top-k and top-p a row)"
+                    )
+            seq.dlm_block = self._mask_block
         if not seq.deadline and self.config.request_timeout_s > 0:
             # deployment default budget; a request-level x-request-timeout
             # (ridden in via Context metadata) takes precedence
@@ -2965,7 +3186,13 @@ class JaxEngine:
                 seq.spec.extend(seq.tokens)
             if seq.has_penalties:
                 self._count_prompt(seq)
-            self._prefilling.append(seq)
+            if self._dlm and seq.num_computed >= seq.prefill_end:
+                # no whole block left to encode (a prompt shorter than a
+                # block, or every whole block of it cached): straight to
+                # its first block pass
+                self._dlm_ready(seq)
+            else:
+                self._prefilling.append(seq)
             progressed = True
         return progressed
 
@@ -2990,28 +3217,36 @@ class JaxEngine:
         )
         self._host_samp_i[i] = (seq.top_k, seq.seed)
 
-    def _take_state(self, counts: bool = False) -> StepState:
+    def _take_state(self, counts: bool = False,
+                    dlm: bool = False) -> StepState:
         """The state a step program is about to consume (under
         `_kv_lock`). The counts ride only into the penalty / seeded
-        programs, allocated on first use: an upload, not a launch."""
+        programs, allocated on first use: an upload, not a launch; the
+        open blocks (`dlm`) only into the block step program."""
         st = self._state
+        if not dlm:
+            st = st._replace(dlm=None)
         if not counts:
             return st._replace(counts=None)
         if st.counts is None:
-            st = self._state = st._replace(counts=jax.device_put(
+            st = st._replace(counts=jax.device_put(
                 np.zeros(
                     (self.config.max_batch_size, self.model_cfg.vocab_size),
                     np.int8,
                 ),
                 self._state_sharding,
             ))
+            self._state = self._state._replace(counts=st.counts)
         return st
 
     def _put_state(self, new: StepState) -> None:
         """The state a step program returned (under `_kv_lock`); a
-        program that did not take the counts left them where they are."""
+        program that did not take the counts, or the open blocks, left
+        them where they are."""
         if new.counts is None:
             new = new._replace(counts=self._state.counts)
+        if new.dlm is None:
+            new = new._replace(dlm=self._state.dlm)
         self._state = new
 
     def _reset_and_count(self, counts, row, tokens, reset=True):
@@ -3281,7 +3516,7 @@ class JaxEngine:
                     self._mark_decode_ready(seq, tok)
                 continue
             chunk = min(
-                seq.total_tokens - seq.num_computed, self.config.prefill_chunk
+                seq.prefill_end - seq.num_computed, self.config.prefill_chunk
             )
             if self._hybrid:
                 # this chunk's window-kind pages (the full kind's were all
@@ -3362,7 +3597,7 @@ class JaxEngine:
                 for seq in seqs:
                     b1 = self._bucket_for(
                         min(
-                            seq.total_tokens - seq.num_computed,
+                            seq.prefill_end - seq.num_computed,
                             self.config.prefill_chunk,
                         )
                     )
@@ -3376,7 +3611,9 @@ class JaxEngine:
                         log.exception("prefill of seq %s failed", seq.seq_id)
                         self._finish(seq, FINISH_REASON_ERROR)
                         continue
-                    if seq.num_computed >= seq.total_tokens:
+                    if self._dlm and seq.num_computed >= seq.prefill_end:
+                        self._dlm_ready(seq)
+                    elif seq.num_computed >= seq.total_tokens:
                         self._mark_decode_ready(seq)
                         self._start_first_emit([(seq, 0)], tok1)
                     else:
@@ -3385,7 +3622,12 @@ class JaxEngine:
             with profiler.phase("eng.emit"):
                 finals = []
                 for j, seq in enumerate(seqs):
-                    if seq.num_computed >= seq.total_tokens:
+                    if self._dlm and seq.num_computed >= seq.prefill_end:
+                        # the prompt's whole blocks are encoded: its tail
+                        # is the head of the first block, which the next
+                        # block dispatch arms (no token was sampled)
+                        self._dlm_ready(seq)
+                    elif seq.num_computed >= seq.total_tokens:
                         # final chunk: the step wrote the sampled token
                         # into the slot's decode carry AND one per-GROUP
                         # async fetch emits it early (_start_first_emit)
@@ -3549,6 +3791,8 @@ class JaxEngine:
                 st[f"{fam}_dispatches"] += 1
             if fam in ("prefill", "decode"):
                 st[f"{fam}_tokens"] += tokens
+            if fam == "dlm":
+                st["dlm_passes"] += rec["dlm_passes"]
         self._note_collectives(fam, rec["phys_rows"], t1)
         self._flight_record(
             kind, t1 - t0, rows=rows, tokens=tokens,
@@ -3560,7 +3804,7 @@ class JaxEngine:
             **{k: rec[k] for k in (
                 "kv_pages_held_full", "kv_win_pages_held",
                 "kv_win_pages_released", "kv_win_items",
-                "state_rows_advanced",
+                "state_rows_advanced", "dlm_passes",
             ) if k in rec},
         )
         if tracing.enabled():
@@ -3569,7 +3813,8 @@ class JaxEngine:
                 rows=rows, tokens=tokens, **rec.get("span", {}),
             )
 
-    def _enqueue(self, rec: dict, fn, *args, counts: bool = False, **static):
+    def _enqueue(self, rec: dict, fn, *args, counts: bool = False,
+                 dlm: bool = False, **static):
         """The dispatch's ONE launch (under `_kv_lock`) as
         ``eng.enqueue``: the step program `fn` takes the params, the
         donated kv and state (with the counts on the penalty path), then
@@ -3582,7 +3827,7 @@ class JaxEngine:
             rec["starved"] = int(last is None or last.is_ready())
         except Exception:  # noqa: BLE001 — a deleted buffer at shutdown
             rec["starved"] = 0
-        state = self._take_state(counts)
+        state = self._take_state(counts, dlm)
         with profiler.phase("eng.enqueue"):
             out, self.kv, state = fn(
                 self.params, self.kv, state, *args, **static
@@ -3713,6 +3958,20 @@ class JaxEngine:
             self._t_fetched = time.perf_counter()
             self._emit(seq, [int(tok)], first=True)
 
+    def _dlm_ready(self, seq: Sequence) -> None:
+        """A block-diffusion row whose whole blocks are encoded joins the
+        block step: its first block opens at `prefill_end` with the rest of
+        its tokens as the given, never-masked head (the prompt's tail; after
+        a preemption also what the client already has of the block that was
+        open) and masks behind it. The next dispatch arms the device's
+        carry from this (`_build_dlm`); no token was sampled."""
+        seq.prefilling = False
+        seq.carry_pending = False
+        seq.num_computed = seq.device_pos = seq.prefill_end
+        seq.dlm_open = seq.dlm_left = (
+            seq.dlm_block - (seq.total_tokens - seq.prefill_end))
+        seq.dlm_arm = True
+
     def _start_first_emit(self, finals, S) -> None:
         """One async host fetch per prefill GROUP that emits the group's
         first tokens as soon as the copy lands (one device-to-host
@@ -3821,7 +4080,7 @@ class JaxEngine:
             # bucketed to a power of two so compile families stay bounded —
             # full width would DMA every (mostly trash) page per query tile
             w_need = max(
-                -(-(seq.num_computed + min(seq.total_tokens - seq.num_computed,
+                -(-(seq.num_computed + min(seq.prefill_end - seq.num_computed,
                                            bucket)) // ps)
                 for seq in seqs
             )
@@ -3836,7 +4095,7 @@ class JaxEngine:
             for j, seq in enumerate(seqs):
                 tokens = seq.tokens
                 start = seq.num_computed
-                chunk = min(len(tokens) - start, bucket)
+                chunk = min(seq.prefill_end - start, bucket)
                 smat[j] = self._slot_matrix_row(seq)
                 tok_arr[j, :chunk] = tokens[start : start + chunk]
                 idx = np.arange(start, start + chunk)
@@ -3873,7 +4132,10 @@ class JaxEngine:
                     self._state_resets += 1  # position 0: a zero state
                 rows_i[j] = (
                     chunk - 1, seq.top_k, seq.slot,
-                    seq.num_computed + chunk >= seq.total_tokens, seq.seed,
+                    # a block-diffusion prompt samples no first token: no
+                    # row of its prefill is final
+                    seq.num_computed + chunk >= seq.total_tokens
+                    and not self._dlm, seq.seed,
                 )
                 rows_f[j] = (
                     seq.temperature, seq.top_p, seq.frequency_penalty,
@@ -3882,7 +4144,7 @@ class JaxEngine:
         t_dispatch0 = time.perf_counter()  # dispatch section only: the
         # host-side input build above is the digest's build_s
         n_tok = int(
-            sum(min(s.total_tokens - s.num_computed, bucket) for s in seqs)
+            sum(min(s.prefill_end - s.num_computed, bucket) for s in seqs)
         )
         rec = dict(
             rows=len(seqs), tokens=n_tok, phys_rows=len(seqs) * bucket,
@@ -3938,8 +4200,8 @@ class JaxEngine:
         now = time.perf_counter()
         for seq in seqs:
             if seq.num_computed + min(
-                seq.total_tokens - seq.num_computed, bucket
-            ) >= seq.total_tokens:
+                seq.prefill_end - seq.num_computed, bucket
+            ) >= seq.prefill_end:
                 seq.t_first_dispatched = now
                 if tracing.enabled():
                     tracing.instant(
@@ -4009,7 +4271,7 @@ class JaxEngine:
         """Post-dispatch bookkeeping (loop thread only): advance computed
         counts and register full pages in the prefix cache."""
         for seq in seqs:
-            chunk = min(seq.total_tokens - seq.num_computed, bucket)
+            chunk = min(seq.prefill_end - seq.num_computed, bucket)
             seq.num_computed += chunk
             seq.prefill_chunks += 1
             self._register_full_pages(seq)
@@ -4735,6 +4997,8 @@ class JaxEngine:
             # checks — runtime toggles must not let a normal dispatch
             # launch from stale host state.
             return None
+        if self._dlm:
+            return self._build_dlm(ready)
         if self._spec_on():
             bld = self._maybe_build_spec(ready)
             if bld == "wait":
@@ -4794,10 +5058,74 @@ class JaxEngine:
             if slot < b and rows_i[slot, 1]:
                 rows_i[slot, 2:4] = (tok, 1)
         self._overrides.clear()
-        return _DecodeBuild(
+        bld = _DecodeBuild(
             rows_i=rows_i, rows_f=self._host_samp_f[:b].copy(),
             use_ext=use_ext, want_lps=want_lps, want_tops=want_tops,
             active=active, steps=k_steps, width=b, all_greedy=all_greedy,
+        )
+        if self._hybrid:
+            # counted here, on the loop's thread: by the time the dispatch
+            # worker books its digest a landing may have finished a row
+            # and released the window pages this reads
+            bld.win_pages = self._kv_window_pages(bld)
+        return bld
+
+    def _dlm_after(self, seq: Sequence, passes: int) -> tuple[int, int]:
+        """(first position of the open block, masks left in it) once
+        `passes` more passes have run on the device, from what it holds
+        after every pass dispatched so far. The program's own rule, which
+        fills a fixed count a pass: a block with no mask left commits (the
+        position moves by a block, the block resets to masks), any other
+        fills `block / steps` of its masks or what is left of them."""
+        n = seq.dlm_block
+        fill = n // self.model_cfg.denoising_steps
+        pos, left = seq.device_pos, seq.dlm_left
+        for _ in range(passes):
+            if left == 0:
+                pos, left = pos + n, n
+            else:
+                left -= min(fill, left)
+        return pos, left
+
+    def _build_dlm(self, ready):
+        """Host side of a block dispatch (`_dlm_multi`): pages a whole
+        block ahead of the last pass, then the two fused uploads. A row
+        that joined since the last dispatch is armed with its block (the
+        given head, then masks); every other row's block is the device's."""
+        n = self._mask_block
+        passes = self.config.decode_steps  # what the program scans
+        after = {seq.slot: self._dlm_after(seq, passes) for _, seq in ready}
+        # every position a pass of this dispatch writes: through the end
+        # of the block that is open after the last pass
+        prep = self._grow_and_collect(
+            ready, lambda seq: after[seq.slot][0] + n - 1
+        )
+        if prep is None:
+            return None
+        active, b = prep
+        h = self._host_rows_i.shape[1]
+        rows_i = np.zeros((b, 4 + h + 2 * n), np.int32)
+        rows_i[:, 4:4 + h] = self._host_rows_i[:b]
+        want_lps = want_tops = False
+        all_greedy = True
+        for i, seq in active:
+            rows_i[i, :2] = (seq.device_pos, 1)
+            if seq.dlm_arm:
+                # nothing of the row has landed since `_dlm_ready`: the
+                # tokens past the encoded blocks are the block's given head
+                seq.dlm_arm = False
+                given = seq.tokens[seq.num_computed:]
+                rows_i[i, 2] = 1
+                rows_i[i, -2 * n:-2 * n + len(given)] = given
+                rows_i[i, -n + len(given):] = 1
+            seq.device_pos, seq.dlm_left = after[i]
+            all_greedy = all_greedy and seq.temperature <= 0.0
+            want_lps = want_lps or seq.want_logprobs
+            want_tops = want_tops or seq.top_logprobs > 0
+        return _DecodeBuild(
+            rows_i=rows_i, rows_f=self._host_samp_f[:b].copy(),
+            use_ext=False, want_lps=want_lps, want_tops=want_tops,
+            active=active, steps=passes, width=b, all_greedy=all_greedy,
         )
 
     def _grow_and_collect(self, ready, upto):
@@ -4932,6 +5260,14 @@ class JaxEngine:
                 rows=rows, tokens=rows + int(np.sum(bld.dlen)),
                 phys_rows=int(np.asarray(bld.tokens).size),
             )
+        elif self._dlm:
+            n = self._mask_block
+            rec = dict(
+                # token rows through the layer stack: a block a row a pass
+                rows=rows, tokens=rows * bld.steps * n,
+                phys_rows=bld.width * bld.steps * n,
+                span={"passes": bld.steps}, dlm_passes=bld.steps,
+            )
         else:
             rec = dict(
                 # dispatched decode token-SLOTS (active rows x steps):
@@ -4944,7 +5280,7 @@ class JaxEngine:
                 phys_rows=bld.width * bld.steps,
                 span={"steps": bld.steps},
             )
-            if self._attn_pallas:
+            if self._attn_pallas and not self._dlm:
                 rec["kv_pages_streamed"], rec["kv_pages_held"] = (
                     self._kv_pages(bld)
                 )
@@ -4958,7 +5294,7 @@ class JaxEngine:
                     rec["kv_pages_held"] if self._attn_pallas
                     else self._kv_pages(bld)[1]
                 )
-                streamed, items, held = self._kv_window_pages(bld)
+                streamed, items, held = bld.win_pages
                 rec["kv_win_pages_held"] = held
                 rec["kv_win_pages_released"] = self._win_released
                 if self._attn_pallas:
@@ -4966,14 +5302,14 @@ class JaxEngine:
                     rec["kv_pages_held"] += held
                     rec["kv_win_items"] = items
         rec["build_s"] = bld.build_s
+        kind = ("spec_verify" if bld.spec else "dlm" if self._dlm
+                else "decode")
         wd = self._op_begin("spec.dispatch" if bld.spec else "decode.dispatch")
         try:
             # inside the watchdog's window: an injected slow dispatch is
             # a slow dispatch
             faults.fire("engine.dispatch")
-            with self._dispatching(
-                "spec_verify" if bld.spec else "decode", t0, rec
-            ):
+            with self._dispatching(kind, t0, rec):
                 if bld.spec:
                     return self._run_spec_dispatch_locked(bld, rec)
                 return self._run_decode_dispatch_locked(bld, rec)
@@ -5057,7 +5393,11 @@ class JaxEngine:
                 jnp.asarray(bld.rows_i), jnp.asarray(bld.rows_f),
                 bld.all_greedy, bld.want_lps, bld.want_tops,
             )
-        S = self._enqueue(rec, self._decode_fn, *args, counts=bld.use_ext)
+        if self._dlm:
+            S = self._enqueue(rec, self._dlm_fn, *args, dlm=True)
+        else:
+            S = self._enqueue(
+                rec, self._decode_fn, *args, counts=bld.use_ext)
         self._step_count += 1
         for arr in S:
             arr.copy_to_host_async()
@@ -5103,7 +5443,8 @@ class JaxEngine:
         `emit_s`)."""
         self._t_fetched = t1
         self._record_sync(
-            "mixed" if d.mixed else "spec" if d.spec else "decode",
+            "mixed" if d.mixed else "spec" if d.spec
+            else "dlm" if self._dlm else "decode",
             len(d.bld["entries"]) if d.mixed else len(d.snapshot),
             t0, t1, overlapped, bld_t0=d.bld["t0"] if d.mixed else None,
         )
@@ -5113,6 +5454,8 @@ class JaxEngine:
                 self._sync_mixed(d.bld, arrs)
             elif d.spec:
                 self._sync_spec(d, arrs)
+            elif self._dlm:
+                landed = self._sync_dlm(d, arrs)
             else:
                 self._sync_decode(d, arrs)
         if self.flight is not None:
@@ -5125,6 +5468,8 @@ class JaxEngine:
                 "gc_s": self._heap.lap(),
                 **self._tick_lap(now),
             }
+            if self._dlm:
+                host.update(landed)
             if d.moe is not None:
                 # the same program made it: ready since the tokens were
                 (host["moe_experts_hit"], host["moe_load_max"],
@@ -5183,6 +5528,61 @@ class JaxEngine:
                 if k else None,
                 first=first,
             )
+
+    def _sync_dlm(self, d: _Dispatch, arrs) -> dict:
+        """Land a block dispatch, a sequence at a time, replaying its
+        passes in order on the masks the host knows the open block to
+        hold (`dlm_open`). A pass that finds none left was the block's
+        COMMIT pass: its keys and values count from now on, the block
+        resets. Any other pass filled what its row of `toks` names (-1: not
+        this pass), the leftmost masks, each token with the
+        log-probability of the pass that filled it; they go to the client
+        with this landing, not after the commit pass. One frame a sequence
+        a landing; `_emit` cuts at `max_tokens` or a stop token inside a
+        block, and what the device did past it is discarded. Returns the
+        digest's counts over the rows still live."""
+        toks, lps = arrs[0], arrs[1]    # [K, w, L]
+        tops = arrs[2:4] if len(arrs) == 4 else None
+        n = self._mask_block
+        row_passes = filled = committed = 0
+        for i, seq in d.snapshot:
+            if self.slots[i] is not seq:
+                continue  # finished/preempted earlier: the passes discarded
+            out, blocks = [], 0
+            k = seq.top_logprobs if tops is not None else 0
+            for p in range(d.steps):
+                row_passes += 1
+                if seq.dlm_open == 0:
+                    blocks += 1
+                    seq.dlm_open = n
+                    continue
+                for j in np.flatnonzero(toks[p, i] >= 0):
+                    out.append((
+                        int(toks[p, i, j]), float(lps[p, i, j]),
+                        (tops[0][p, i, j, :k].tolist(),
+                         tops[1][p, i, j, :k].tolist()) if k else None,
+                    ))
+                    seq.dlm_open -= 1
+            filled += len(out)
+            committed += blocks
+            if out:
+                self._emit(
+                    seq, [e[0] for e in out],
+                    [e[1] for e in out] if seq.want_logprobs else None,
+                    ([e[2][0] for e in out], [e[2][1] for e in out])
+                    if k else None,
+                    first=seq.generated == 0, computed=blocks * n,
+                )
+            elif blocks:
+                seq.num_computed = min(
+                    seq.num_computed + blocks * n, seq.total_tokens)
+                self._register_full_pages(seq)
+        with self._phase_lock:
+            self._phase_stats["dlm_row_passes"] += row_passes
+            self._phase_stats["dlm_filled"] += filled
+            self._phase_stats["dlm_committed"] += committed
+        return {"dlm_row_passes": row_passes, "dlm_filled": filled,
+                "dlm_committed": committed}
 
     def _emit_verify_row(self, slot: int, seq: Sequence, out_row,
                          n: int, drafted: int, base: int,
@@ -5571,6 +5971,7 @@ class JaxEngine:
     def _emit(
         self, seq: Sequence, toks: list, lps: Optional[list] = None,
         alts: Optional[tuple] = None, first: bool = False,
+        computed: Optional[int] = None,
     ) -> int:
         """The ONE emit path: what one fetch brought for one sequence
         (`toks`, in order; `lps` its log-probabilities when asked for,
@@ -5579,8 +5980,10 @@ class JaxEngine:
         to the one that finishes it; the rest lie past its end and are
         discarded, and the final frame follows. `first`: toks[0] is the
         prompt's first token (its KV is the prefill's, so `num_computed`
-        stands for it) and `first_meta` rides in the frame. Returns the
-        number of tokens kept."""
+        stands for it) and `first_meta` rides in the frame. `computed` (a
+        block-diffusion landing): the positions whose keys and values this
+        landing made final, in place of one a token kept; never past the
+        tokens kept. Returns the number of tokens kept."""
         if not toks:
             return 0
         base, reason = seq.generated, None
@@ -5593,7 +5996,11 @@ class JaxEngine:
         seq.blocks.extend(toks)
         if seq.spec is not None:
             seq.spec.extend(toks)
-        seq.num_computed += n - first
+        if computed is None:
+            seq.num_computed += n - first
+        else:
+            seq.num_computed = min(
+                seq.num_computed + computed, seq.total_tokens)
         self._register_full_pages(seq)
         if base == 0:
             seq.t_first_emit = time.perf_counter()

@@ -52,9 +52,11 @@ from dynamo_tpu.utils.logging import get_logger
 log = get_logger("dynamo_tpu.flight")
 
 # digest step kinds; dispatch phases additionally run anomaly detection
-KINDS = ("prefill", "decode", "spec_verify", "mixed", "sync", "overlap")
+KINDS = ("prefill", "decode", "spec_verify", "mixed", "sync", "overlap",
+         # a block dispatch of a model generated by diffusion over blocks
+         "dlm")
 _KIND_CODE = {k: i for i, k in enumerate(KINDS)}
-ANOMALY_PHASES = ("prefill", "decode", "spec_verify", "mixed")
+ANOMALY_PHASES = ("prefill", "decode", "spec_verify", "mixed", "dlm")
 
 # one digest = one row of these columns (float64; ints round-trip
 # exactly up to 2^53). The schema rides every artifact as
@@ -122,6 +124,13 @@ FIELDS = (
     "state_slots_held",     # every dispatch row: slots whose state a
                             # sequence holds
     "state_rows_advanced",  # decode rows: rows x steps the program advanced
+    # a model generated by diffusion over blocks. A `dlm` row (`rows` =
+    # live rows, `tokens` = rows x passes x block: token rows dispatched):
+    "dlm_passes",      # passes the dispatch's program runs
+    # sync / overlap rows of such a dispatch, over the rows still live:
+    "dlm_row_passes",  # rows x passes landed (the commit passes among them)
+    "dlm_filled",      # masked positions those passes filled
+    "dlm_committed",   # blocks whose commit pass ran
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

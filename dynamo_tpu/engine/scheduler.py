@@ -137,6 +137,26 @@ class Sequence:
     max_new_tokens: int = 0
     eos_ids: frozenset[int] = frozenset()
     ignore_eos: bool = False
+    # generation by diffusion over blocks (0 = every other model): the
+    # block's length; the masks the device still holds in the open block
+    # once every dispatched pass has run (the build's deterministic
+    # mirror; `device_pos` is that block's first position); whether the
+    # next dispatch must arm the row's carry from the host; and the masks
+    # the open block still holds after every pass that has LANDED (what
+    # stands before them is the client's already, the prompt's tail
+    # among it; 0 = the next pass to land is the block's commit pass)
+    dlm_block: int = 0
+    dlm_left: int = 0
+    dlm_arm: bool = False
+    dlm_open: int = 0
+
+    @property
+    def prefill_end(self) -> int:
+        """Tokens the prefill programs encode: all of them, or, for a model
+        generated by diffusion over blocks, the whole blocks (the rest is
+        the given head of the first generated block)."""
+        t = self.total_tokens
+        return t - t % self.dlm_block if self.dlm_block else t
 
     @property
     def has_penalties(self) -> bool:

@@ -95,7 +95,13 @@ class AttnSpec:
                  write_pos=None, page_size: int = 16, interpret: bool = False,
                  mesh=None, write_tables=None, q_pos0=None, ring: bool = False,
                  kv_tp: int = 1, prefix_cols: int = 0, int4_groups: int = 0,
-                 win=None, write_slots=None, state_slots=None):
+                 win=None, write_slots=None, state_slots=None,
+                 mask_block: int = 1):
+        # the causal mask's block (static, a power of two): a query at
+        # `q_pos` sees keys up to `q_pos | (mask_block - 1)`, the end of
+        # its own block; 1 = causal by position, every model but one that
+        # is generated by diffusion over blocks (`ModelConfig.dlm`)
+        self.mask_block = mask_block
         # a recurrent model's MAMBA layers (models/mamba2.py): the state
         # slot of each row, [B] int32; None = row b is slot b (a decode
         # program) and for every model that keeps no state
@@ -142,11 +148,12 @@ class AttnSpec:
     def gather(cls, slot_matrix, write_tables=None, page_size: int = 16,
                interpret: bool = False, mesh=None, block_tables=None,
                q_pos0=None, lengths=None, kv_tp: int = 1,
-               int4_groups: int = 0):
+               int4_groups: int = 0, mask_block: int = 1):
         return cls(slot_matrix=slot_matrix, write_tables=write_tables,
                    page_size=page_size, interpret=interpret, mesh=mesh,
                    block_tables=block_tables, q_pos0=q_pos0, lengths=lengths,
-                   kv_tp=kv_tp, int4_groups=int4_groups)
+                   kv_tp=kv_tp, int4_groups=int4_groups,
+                   mask_block=mask_block)
 
     @classmethod
     def ring(cls, slot_matrix, mesh, page_size: int = 16, q_pos0=None,
@@ -184,7 +191,7 @@ jax.tree_util.register_pytree_node(
         (s.slot_matrix, s.block_tables, s.lengths, s.write_pos,
          s.write_tables, s.q_pos0, s.win, s.write_slots, s.state_slots),
         (s.page_size, s.interpret, s.mesh, s.ring, s.kv_tp, s.prefix_cols,
-         s.int4_groups),
+         s.int4_groups, s.mask_block),
     ),
     lambda aux, children: AttnSpec(
         slot_matrix=children[0], block_tables=children[1], lengths=children[2],
@@ -192,6 +199,7 @@ jax.tree_util.register_pytree_node(
         win=children[6], write_slots=children[7], state_slots=children[8],
         page_size=aux[0], interpret=aux[1], mesh=aux[2], ring=aux[3],
         kv_tp=aux[4], prefix_cols=aux[5], int4_groups=aux[6],
+        mask_block=aux[7],
     ),
 )
 
@@ -550,10 +558,23 @@ def _attn_block(
         k = k.reshape(b, t, kh, hd)
         v = v.reshape(b, t, kh, vd)
 
+    if cfg.qk_norm:
+        # an RMSNorm over each head's values, queries and keys alike,
+        # before the rotation
+        q = _norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = _norm(k, lp["k_norm"], cfg.rms_norm_eps)
     if cfg.use_rope:
         with jax.named_scope("attn.rope"):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
+    # the mask's block rides to the readers only where it has one: every
+    # other model's calls, and so its programs, are what they were
+    masked = {"mask_block": attn.mask_block} if attn.mask_block > 1 else {}
+    if masked and (spec.window or spec.sink or quant):
+        raise ValueError(
+            "a block-causal mask is written for K/V pools in the model's "
+            f"dtype, every position attended and no sink ('{cfg.name}')"
+        )
     kernel = jax.named_scope(
         "attn.swa_kernel" if spec.window else "attn.kernel"
     )
@@ -719,7 +740,7 @@ def _attn_block(
             fl = functools.partial(
                 flash_prefill_attention,
                 page_size=ps, interpret=attn.interpret, int4=int4,
-                window=spec.window, sink=sink,
+                window=spec.window, sink=sink, **masked,
             )
             if attn.mesh is not None:
                 P = jax.sharding.PartitionSpec
@@ -752,7 +773,7 @@ def _attn_block(
                 q, kv_k, kv_v, attn.slot_matrix, positions,
                 k_scales=kv_ks, v_scales=kv_vs, scale_tp=attn.kv_tp,
                 int4_groups=attn.int4_groups or None,
-                window=spec.window, sink=sink,
+                window=spec.window, sink=sink, **masked,
             )
     elif attn.ring and attn.mesh is not None:
         one_kind_only("ring attention")
@@ -824,6 +845,10 @@ def _attn_block(
             kv_k, kv_v, kv_ks, kv_vs,
             k.reshape(b * t, kh * hd), v.reshape(b * t, kh * vd),
         )
+        if masked and attn.lengths is not None:
+            # a block pass (engine.py `_dlm_multi`): the rows just
+            # scattered are the block's own, which every query of it reads
+            kernel = jax.named_scope("attn.block")
         if attn.block_tables is not None and attn.q_pos0 is not None:
             # mixed prefill+decode and spec-verify steps on the pallas
             # backend: the WRITE is the row scatter above — decode and
@@ -837,7 +862,7 @@ def _attn_block(
             rg = functools.partial(
                 ragged_paged_attention,
                 page_size=attn.page_size, interpret=attn.interpret,
-                int4=int4,
+                int4=int4, **masked,
             )
             if attn.mesh is not None:
                 P = jax.sharding.PartitionSpec
@@ -909,7 +934,7 @@ def _attn_block(
                 k_scales=kv_ks, v_scales=kv_vs, scale_tp=attn.kv_tp,
                 q_lens=attn.lengths,
                 int4_groups=attn.int4_groups or None,
-                window=spec.window, sink=sink,
+                window=spec.window, sink=sink, **masked,
             )
     with jax.named_scope("attn.o"):
         if tp_overlap:
@@ -1453,6 +1478,12 @@ def init_params(
             # scale nor a position encoding is judged
             qk = (d * cfg.attn_scale * cfg.head_dim ** 0.5) ** -0.5 \
                 if cfg.attn_scale else None
+            if cfg.qk_norm:
+                # twice the fan-in scale: the norm a head then ACTS (at
+                # fan-in scale a seeded head has unit RMS already, and a
+                # program that dropped the norm would pass the benchmark's
+                # comparison: the same reason as `w_qa` above)
+                qk = 2.0 * d ** -0.5
             lp = {
                 "attn_norm": jnp.ones((d,), dtype),
                 "wq": dense(next(keys), (d, qs), qk, sharding=sh.get("wq")),
@@ -1461,6 +1492,9 @@ def init_params(
                 "wo": dense(next(keys), (qs, d), sharding=sh.get("wo")),
                 "mlp_norm": jnp.ones((d,), dtype),
             }
+            if cfg.qk_norm:
+                lp["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
+                lp["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
         if cfg.is_moe_layer(i):
             from dynamo_tpu.models.moe import init_moe_params
 

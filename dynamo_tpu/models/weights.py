@@ -106,6 +106,9 @@ def load_params(
         "self_attn.kv_a_proj_with_mqa.weight": ("w_kva", True),
         "self_attn.kv_a_layernorm.weight": ("kv_norm", False),
         "self_attn.kv_b_proj.weight": ("w_kvb", True),
+        # sdar_moe: the norm a head on queries and keys
+        "self_attn.q_norm.weight": ("q_norm", False),
+        "self_attn.k_norm.weight": ("k_norm", False),
         "mlp.gate.weight": ("router", True),
         "mlp.shared_experts.gate_proj.weight": ("ws_gate", True),
         "mlp.shared_experts.up_proj.weight": ("ws_up", True),
@@ -173,6 +176,7 @@ def load_params(
         )
     def required(i: int) -> list[str]:
         need = ["wq"] + (["w_kva", "kv_norm", "w_kvb"] if cfg.latent else [])
+        need += ["q_norm", "k_norm"] if cfg.qk_norm else []
         if cfg.is_moe_layer(i):
             need += ["router", "we_gate", "we_up", "we_down"]
             if cfg.num_shared_experts:

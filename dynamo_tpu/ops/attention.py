@@ -92,11 +92,18 @@ def paged_attention(
     int4_groups: int | None = None,       # int4 pools: scale groups per head
     window: int = 0,                      # tokens a query sees (0 = all)
     sink: jnp.ndarray | None = None,      # [H] f32 learned sink logits
+    mask_block: int = 1,                  # the causal mask's block (static)
 ) -> jnp.ndarray:
     """Gathered-slot attention. Gathered slot j holds absolute position j of
     the sequence, so causality is `j <= positions[b, t]`; padded queries and
     0-padded slot-table tails are masked out by the same comparison (their
     garbage KV rides the trash page).
+
+    `mask_block` B > 1 (a power of two) makes the mask causal by BLOCK: a
+    query sees every position up to the end of its own block of B, `j <=
+    positions[b, t] | (B - 1)`, which is `j // B <= position // B` (a model
+    generated by diffusion over blocks; the caller has written the whole
+    block's rows). 1 is causal by position, the program it always was.
 
     `q_lens` makes the per-row RAGGED query contract explicit (mixed
     prefill+decode steps: decode rows q_len=1 beside chunk rows): query
@@ -160,7 +167,8 @@ def paged_attention(
     ) * scale  # [B, K, G, T, C]
 
     j = jnp.arange(c)
-    mask = j[None, None, :] <= positions[:, :, None]  # [B, T, C]
+    reach = positions | (mask_block - 1) if mask_block > 1 else positions
+    mask = j[None, None, :] <= reach[:, :, None]  # [B, T, C]
     if window:
         mask = mask & (j[None, None, :] > positions[:, :, None] - window)
     if q_lens is not None:

@@ -1637,14 +1637,19 @@ def ragged_paged_attention(
     page_size: int,
     interpret: bool = False,
     int4: bool = False,
+    mask_block: int = 1,
 ) -> jax.Array:
     """Read-only paged attention with PER-ROW query lengths — the kernel
-    behind the mixed prefill+decode step AND the pallas spec-verify path
+    behind the mixed prefill+decode step, the pallas spec-verify path AND
+    the block passes of a model generated by diffusion over blocks
     (KV already written, row-scattered by the caller): decode rows are
     q_len=1 at an arbitrary (mid-page) position, speculative verify rows
     span q_len = draft_len+1 from a mid-page q_pos0, chunked-prefill
-    rows span [q_pos0, q_pos0+q_len) with causal masking inside the
-    chunk, padding rows (q_len=0) emit zeros.
+    rows span [q_pos0, q_pos0+q_len) with the mask causal by position
+    inside the chunk (`mask_block` 1) or by blocks of `mask_block`
+    positions (a power of two: a query sees keys up to `q_pos |
+    (mask_block - 1)`; a block pass is `q_len = mask_block` from the
+    block's first position), padding rows (q_len=0) emit zeros.
 
     Delegates to the flash prefill kernel (ops/pallas_prefill.py), whose
     online-softmax grid already handles per-row ragged lengths; unlike
@@ -1657,5 +1662,5 @@ def ragged_paged_attention(
     return flash_prefill_attention(
         q, k_cache, v_cache, block_tables, q_pos0, q_lens,
         k_scales, v_scales, page_size=page_size, interpret=interpret,
-        int4=int4,
+        int4=int4, **({"mask_block": mask_block} if mask_block > 1 else {}),
     )

@@ -65,6 +65,7 @@ def _kernel(
     vd: int = 0,
     window: int = 0,
     sink: bool = False,
+    mask_block: int = 1,
 ):
     vd = vd or hd   # values may be narrower than keys (unquantized pools)
     if sink:
@@ -126,8 +127,16 @@ def _kernel(
         jnp.int32, (tg, blk), 0
     ) // g
     k_pos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, (tg, blk), 1)
-    valid = (k_pos <= q_pos) & (q_pos < pos0 + tlen)  # [TG, BLK]
-    in_reach = kb * blk <= pos0 + (tt + 1) * t_tile - 1
+    if mask_block > 1:
+        # causal by BLOCK: a query sees every key up to the end of its own
+        # block of `mask_block` positions, and the page-blocks in reach are
+        # those up to the end of the tile's last query's block
+        valid = (k_pos <= q_pos | (mask_block - 1)) & (q_pos < pos0 + tlen)
+        in_reach = kb * blk <= (
+            pos0 + (tt + 1) * t_tile - 1) | (mask_block - 1)
+    else:
+        valid = (k_pos <= q_pos) & (q_pos < pos0 + tlen)  # [TG, BLK]
+        in_reach = kb * blk <= pos0 + (tt + 1) * t_tile - 1
     if window:
         # a window: a query sees the `window` positions up to its own;
         # page-blocks wholly behind the tile's first query's window are
@@ -245,7 +254,7 @@ def _kernel(
     jax.jit,
     static_argnames=(
         "page_size", "t_tile", "pages_per_block", "interpret", "int4",
-        "window",
+        "window", "mask_block",
     ),
 )
 def flash_prefill_attention(
@@ -269,9 +278,15 @@ def flash_prefill_attention(
     interpret: bool = False,
     int4: bool = False,
     window: int = 0,          # tokens a query sees, itself included (0 = all)
+    mask_block: int = 1,      # the causal mask's block, a power of two: a
+    # query sees keys up to `q_pos | (mask_block - 1)`; 1 = by position
 ) -> jax.Array:
     """Causal chunked-prefill attention over gathered pages; rows past
-    t_valid produce zeros. Returns [B, T, H, Vd] in q.dtype (Vd = Hd
+    t_valid produce zeros. With `mask_block` B > 1 the mask is causal by
+    block (`k_pos // B <= q_pos // B`): a chunk whose start and valid
+    length are multiples of B never reads a row it has not been given,
+    and a row of `q_len` B from a block's first position reads the whole
+    block (written by the caller beforehand). Returns [B, T, H, Vd] in q.dtype (Vd = Hd
     unless the unquantized value pool is narrower than the key pool). With scale
     pools the pages hold per-token-per-kv-head int8; scale blocks ride
     the same page routing and dequantization happens per head slice in
@@ -402,6 +417,7 @@ def flash_prefill_attention(
             _kernel, t_tile=t_tile, page=page_size, kh=kh, g=g, hd=hd,
             wb=wb, ppb=ppb, quant=quant, subl=subl, packed=packed,
             int4=int4, vd=vd, window=window, sink=sink is not None,
+            **({"mask_block": mask_block} if mask_block > 1 else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, t_pad * g, vd), q.dtype),

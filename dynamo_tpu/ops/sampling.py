@@ -180,6 +180,55 @@ def sample_tokens(
 
 
 @jax.named_scope("sample")
+def sample_block(
+    logits: jnp.ndarray,       # [R * B, V]: a block of B positions a row,
+    # flat (a [R, B, V] array pads its B rows to a tile of 8: at B = 4 twice
+    # the bytes, and a copy to flatten it)
+    masked: jnp.ndarray,       # [R, B] bool: the position still holds a mask
+    key: jax.Array,
+    temperature: jnp.ndarray,  # [R] f32 (<= 0 treated as greedy)
+    top_k: jnp.ndarray,        # [R] i32
+    top_p: jnp.ndarray,        # [R] f32
+    *,
+    n_fill: int,               # static: masked positions a pass fills
+    mask_token_id: int,        # static: never sampled, outside the softmax
+    all_greedy: bool = False,
+    return_logprobs: bool = False,
+    top_n: int = 0,
+):
+    """One denoising pass's sampling and TRANSFER for a model generated by
+    diffusion over blocks: position i's logits predict position i's token
+    (no shift). Every position samples (greedy / temperature / top-k /
+    top-p a row, as `sample_tokens`, the mask token's logit excluded from
+    the softmax); the pass then fills `min(n_fill, masked positions)` of
+    a row's masked positions, the leftmost (the `sequential` transfer, the
+    one a configuration may state: `models/config.py: _from_sdar_moe`).
+
+    Returns (ids [R, B] i32, fill [R, B] bool, logprobs [R, B] f32[, top
+    ids [R, B, n], top logprobs [R, B, n]]): `ids` is the sampled token
+    at every position, `fill` the masked positions this pass fills; the
+    log-probabilities are zeros unless the caller asks (`return_logprobs`)."""
+    r, b = masked.shape
+    v = logits.shape[-1]
+    flat = jnp.where(jnp.arange(v) == mask_token_id, -jnp.inf,
+                     logits.astype(jnp.float32))
+    out = sample_tokens(
+        flat, key, jnp.repeat(temperature, b), jnp.repeat(top_k, b),
+        jnp.repeat(top_p, b), all_greedy=all_greedy,
+        return_logprobs=return_logprobs,
+        top_n=top_n if return_logprobs else 0,
+    )
+    out = out if isinstance(out, tuple) else (out,)
+    ids = out[0].reshape(r, b)
+    lps = (out[1].reshape(r, b) if return_logprobs
+           else jnp.zeros((r, b), jnp.float32))
+    rank = jnp.cumsum(masked, axis=1) - 1              # among the masked
+    fill = masked & (rank < n_fill)
+    tops = tuple(a.reshape(r, b, -1) for a in out[2:])
+    return (ids, fill, lps, *tops)
+
+
+@jax.named_scope("sample")
 def verify_draft_tokens(
     logits: jnp.ndarray,       # [B, T, V] float; row j is the model's
     #                            distribution for position pos0 + j + 1

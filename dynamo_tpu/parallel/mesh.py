@@ -154,6 +154,8 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
                 "wo": ns("tp", None),
                 "mlp_norm": ns(),
             }
+            if cfg.qk_norm:
+                lp["q_norm"] = lp["k_norm"] = ns()
         if cfg.is_moe_layer(i):
             # sparse MoE: experts over ep, each expert's FFN column/row
             # parallel over tp (models/moe.py; GSPMD inserts the

@@ -261,7 +261,17 @@ def test_decode_kv_read_amp_is_declared():
         "name": "decode_kv_read_amp", "unit": "x", "better": "lower",
         "source": "program_counter", "layer": "kernels",
         "moves": "tpot_p95_ms",
-        "workloads": [w["name"] for w in bench["workloads"]]}
+        # every cell whose decode dispatch is the one-token scan, which
+        # books the page counts the reader sums: a configuration whose
+        # file states a `block_length` is generated by diffusion over
+        # blocks, and its block dispatch (digest kind `dlm`) books none
+        "workloads": [w["name"] for w in bench["workloads"]
+                      if "block_length" not in _config_file(w["config"])]}
+
+
+def _config_file(name):
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
+        return json.load(f)
 
 
 @pytest.mark.parametrize("digests,want", [

@@ -2,7 +2,8 @@
 is driven FROM `engine.CACHE_KIND_REFUSALS`, the table `_refuse_plane`,
 `_refuse_config` and `_mixed_unsupported_reason` read, on a tiny
 configuration of each kind of cache (DeepSeek's and Xing's latent pool,
-MiMo's window pools, granite's state pools). The planes asked of a
+MiMo's window pools, granite's state pools) and of the one kind of STEP
+that is not a token a sequence (SDAR's block step). The planes asked of a
 running engine (disaggregation, prefix export / ingest) stay with each
 family's own tests; what PR 46 took out is proved gone at the end."""
 
@@ -28,6 +29,8 @@ TINY = {
     "tiny-mimo": ("hybrid", "window beside full attention"),
     "tiny-granite": ("recurrent", "Mamba-2 layers beside attention"),
     "tiny-xing": ("latent", "residual of 4 streams"),
+    # not a kind of cache but of step: a block a sequence a pass
+    "tiny-sdar": ("dlm", "generation by diffusion over blocks"),
 }
 # how a configuration asks for each option the table may refuse
 ASK = {

@@ -195,10 +195,12 @@ async def test_digest_columns():
     # its worker's lock, uploads and launch, a landing the loop's tick
     # (then PR 39's two, an expert layer's pass by blocks of rows, and
     # PR 41's two, a model with state pools: zero on a dense model's rows)
-    assert flightmod.FIELDS[-11:-4] == WORKER_COLUMNS + TICK_COLUMNS
-    assert flightmod.FIELDS[-4:] == (
+    # (and PR 47's four, a model generated by diffusion over blocks)
+    assert flightmod.FIELDS[-15:-8] == WORKER_COLUMNS + TICK_COLUMNS
+    assert flightmod.FIELDS[-8:] == (
         "moe_row_blocks", "moe_pairs_held",
-        "state_slots_held", "state_rows_advanced")
+        "state_slots_held", "state_rows_advanced",
+        "dlm_passes", "dlm_row_passes", "dlm_filled", "dlm_committed")
     assert all(r["state_slots_held"] == r["state_rows_advanced"] == 0
                for r in rows)
     for r in by["decode"] + by["prefill"]:

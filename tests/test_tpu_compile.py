@@ -841,3 +841,70 @@ def test_state_prefill_layers_compile(one_chip, no_persistent_cache,
     ).compile()
     _assert_kernel(compiled, at_least=2)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+# ---------------- generation by diffusion over blocks (the SDAR family)
+
+def test_dlm_block_scan_compiles_at_the_benchmark_shape(
+        one_chip, no_persistent_cache):
+    """SDAR-30B-A3B at its published widths, one layer through the
+    engine's OWN block step (`JaxEngine._dlm_multi`, as `_dlm_fn` jits it:
+    the arm, the inactive rows' trash page and the carry with it) at the
+    `decode-wide` cell's widest program (256 rows of a block of 4 = 1,024
+    token rows, pages of 128, bf16, two passes): every row's block is
+    row-scattered into the pools, the ragged flash kernel reads `q_len` 4
+    from a mid-page first position under `mask_block` 4, the three
+    grouped matmuls take 8,192 pairs over 128 experts of 768, the head
+    and the transfer run over [1,024, 151,936] logits, and no K or V pool
+    is copied anywhere in the step."""
+    import types
+
+    from dynamo_tpu.engine.engine import (
+        TOP_LOGPROBS_MAX, JaxEngine, StepState,
+    )
+
+    cfg = _one_layer("sdar-30b-a3b")
+    page, num_pages, width, max_len = 128, 2048, 256, 4096
+    n = cfg.block_length
+    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+                         page=page, num_pages=num_pages)
+    assert kv.k[0].shape == kv.v[0].shape == (num_pages * page, 512)
+    assert params["layers"][0]["q_norm"].shape == (128,)
+    assert params["layers"][0]["we_gate"].shape == (128, 2048, 768)
+
+    # what the method reads of its engine, and nothing built
+    eng = object.__new__(JaxEngine)
+    eng.model_cfg, eng.page_size = cfg, page
+    eng.config = types.SimpleNamespace(max_model_len=max_len, decode_steps=2)
+    eng.mesh = types.SimpleNamespace(size=1)
+    eng._attn_pallas, eng._attn_interpret = True, False
+    eng._tp_overlap_manual = False
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = StepState(
+        toks=arr((width,), jnp.int32), lps=arr((width,), jnp.float32),
+        tid=arr((width, TOP_LOGPROBS_MAX), jnp.int32),
+        tlp=arr((width, TOP_LOGPROBS_MAX), jnp.float32),
+        key=arr((2,), jnp.uint32),
+        dlm=(arr((width, n), jnp.int32), arr((width, n), jnp.bool_),
+             arr((width,), jnp.int32)),
+    )
+    compiled = jax.jit(
+        eng._dlm_multi, donate_argnums=(1, 2), static_argnums=(5, 6, 7),
+    ).lower(
+        _on(params, one_chip), _on(kv, one_chip), state,
+        _i32((width, 6 + max_len // page + 2 * n), one_chip),
+        arr((width, 5), jnp.float32), True, True, False,
+    ).compile()
+    text = compiled.as_text()
+    # the ragged kernel + three grouped matmuls
+    assert text.count("tpu_custom_call") >= 4
+    assert "attn.block" in text
+    pool = re.compile(r"= bf16\[262144,512\]\S* (copy|copy-start)\(")
+    moved = [ln.strip()[:160] for ln in text.splitlines() if pool.search(ln)]
+    assert not moved, "K/V pools copied inside the step:\n" + "\n".join(moved)
+    # what the step holds beside weights and pools: the logits and the
+    # transfer's temporaries must fit what `hbm_utilization` 0.85 leaves
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
