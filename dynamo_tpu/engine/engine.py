@@ -299,7 +299,7 @@ class _DecodeBuild:
                  "rows_i", "rows_f", "use_ext", "want_lps",
                  "want_tops", "active", "steps", "all_greedy",
                  "width", "spec", "tokens", "draft", "dlen", "pos0",
-                 "build_s", "win_pages")
+                 "build_s", "win_pages", "block_pos0")
 
     def __init__(self, **kw):
         self.spec = False  # speculative verify build (host-built tokens)
@@ -3804,7 +3804,7 @@ class JaxEngine:
             **{k: rec[k] for k in (
                 "kv_pages_held_full", "kv_win_pages_held",
                 "kv_win_pages_released", "kv_win_items",
-                "state_rows_advanced", "dlm_passes",
+                "state_rows_advanced", "dlm_passes", "dlm_work_items",
             ) if k in rec},
         )
         if tracing.enabled():
@@ -5070,22 +5070,26 @@ class JaxEngine:
             bld.win_pages = self._kv_window_pages(bld)
         return bld
 
-    def _dlm_after(self, seq: Sequence, passes: int) -> tuple[int, int]:
-        """(first position of the open block, masks left in it) once
-        `passes` more passes have run on the device, from what it holds
-        after every pass dispatched so far. The program's own rule, which
-        fills a fixed count a pass: a block with no mask left commits (the
-        position moves by a block, the block resets to masks), any other
-        fills `block / steps` of its masks or what is left of them."""
+    def _dlm_after(self, seq: Sequence, passes: int) -> list[tuple[int, int]]:
+        """[(first position of the open block, masks left in it)] once 0,
+        1, .. `passes` more passes have run on the device, from what it
+        holds after every pass dispatched so far: entry k is what pass k of
+        the next dispatch finds, the last what stands after it. The
+        program's own rule, which fills a fixed count a pass: a block with
+        no mask left commits (the position moves by a block, the block
+        resets to masks), any other fills `block / steps` of its masks or
+        what is left of them."""
         n = seq.dlm_block
         fill = n // self.model_cfg.denoising_steps
         pos, left = seq.device_pos, seq.dlm_left
+        walk = [(pos, left)]
         for _ in range(passes):
             if left == 0:
                 pos, left = pos + n, n
             else:
                 left -= min(fill, left)
-        return pos, left
+            walk.append((pos, left))
+        return walk
 
     def _build_dlm(self, ready):
         """Host side of a block dispatch (`_dlm_multi`): pages a whole
@@ -5094,11 +5098,11 @@ class JaxEngine:
         given head, then masks); every other row's block is the device's."""
         n = self._mask_block
         passes = self.config.decode_steps  # what the program scans
-        after = {seq.slot: self._dlm_after(seq, passes) for _, seq in ready}
+        walk = {seq.slot: self._dlm_after(seq, passes) for _, seq in ready}
         # every position a pass of this dispatch writes: through the end
         # of the block that is open after the last pass
         prep = self._grow_and_collect(
-            ready, lambda seq: after[seq.slot][0] + n - 1
+            ready, lambda seq: walk[seq.slot][-1][0] + n - 1
         )
         if prep is None:
             return None
@@ -5118,7 +5122,7 @@ class JaxEngine:
                 rows_i[i, 2] = 1
                 rows_i[i, -2 * n:-2 * n + len(given)] = given
                 rows_i[i, -n + len(given):] = 1
-            seq.device_pos, seq.dlm_left = after[i]
+            seq.device_pos, seq.dlm_left = walk[i][-1]
             all_greedy = all_greedy and seq.temperature <= 0.0
             want_lps = want_lps or seq.want_logprobs
             want_tops = want_tops or seq.top_logprobs > 0
@@ -5126,6 +5130,12 @@ class JaxEngine:
             rows_i=rows_i, rows_f=self._host_samp_f[:b].copy(),
             use_ext=False, want_lps=want_lps, want_tops=want_tops,
             active=active, steps=passes, width=b, all_greedy=all_greedy,
+            # [passes, active rows]: the open block's first position in
+            # each pass of this dispatch (`_kv_pages` books the kernel's
+            # reads by them)
+            block_pos0=np.asarray(
+                [[p for p, _ in walk[i][:-1]] for i, _ in active],
+                np.int64).T,
         )
 
     def _grow_and_collect(self, ready, upto):
@@ -5268,6 +5278,16 @@ class JaxEngine:
                 phys_rows=bld.width * bld.steps * n,
                 span={"passes": bld.steps}, dlm_passes=bld.steps,
             )
+            if self._attn_pallas:
+                from dynamo_tpu.ops.pallas_attention import PAGES_PER_BLOCK
+
+                rec["kv_pages_streamed"], rec["kv_pages_held"] = (
+                    self._kv_pages(bld)
+                )
+                # work items ONE layer's block kernel walks over the passes
+                rec["dlm_work_items"] = int(np.sum(-(
+                    -self._attended_lengths(bld)
+                    // (self.page_size * PAGES_PER_BLOCK))))
         else:
             rec = dict(
                 # dispatched decode token-SLOTS (active rows x steps):
@@ -5280,7 +5300,7 @@ class JaxEngine:
                 phys_rows=bld.width * bld.steps,
                 span={"steps": bld.steps},
             )
-            if self._attn_pallas and not self._dlm:
+            if self._attn_pallas:
                 rec["kv_pages_streamed"], rec["kv_pages_held"] = (
                     self._kv_pages(bld)
                 )
@@ -5321,8 +5341,9 @@ class JaxEngine:
         dispatch's steps (`ops.pallas_attention.streamed_pages`, the rule
         its work list is built to) and the pages those rows hold: the
         attended lengths are `_decode_multi`'s, from the build's
-        positions. Equal while the kernel reads only what a sequence
-        holds; the digest keeps both so a reader sees when it does not."""
+        positions (a block dispatch's: its passes', `_attended_lengths`).
+        Equal while the kernel reads only what a sequence holds; the
+        digest keeps both so a reader sees when it does not."""
         from dynamo_tpu.ops.pallas_attention import streamed_pages
 
         lengths, ps = self._attended_lengths(bld), self.page_size
@@ -5330,7 +5351,16 @@ class JaxEngine:
 
     def _attended_lengths(self, bld: "_DecodeBuild") -> np.ndarray:
         """[steps, active rows]: the KV count each step of this dispatch
-        attends, as `_decode_multi` derives it from the build's positions."""
+        attends, as `_decode_multi` derives it from the build's positions;
+        of a block dispatch each PASS, through the end of the row's open
+        block (`ops.pallas_block.block_lengths`, the kernel's own rule)."""
+        if self._dlm:
+            from dynamo_tpu.ops.pallas_block import block_lengths
+
+            n = self._mask_block
+            return block_lengths(
+                bld.block_pos0, 1, n,
+                (bld.rows_i.shape[1] - 6 - 2 * n) * self.page_size, xp=np)
         pos = bld.rows_i[[i for i, _ in bld.active], 0]
         return np.minimum(
             pos[None, :] + 1 + np.arange(bld.steps)[:, None],

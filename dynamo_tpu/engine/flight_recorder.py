@@ -131,6 +131,9 @@ FIELDS = (
     "dlm_row_passes",  # rows x passes landed (the commit passes among them)
     "dlm_filled",      # masked positions those passes filled
     "dlm_committed",   # blocks whose commit pass ran
+    # a `dlm` row on the pallas backend (`kv_pages_streamed` /
+    # `kv_pages_held` there: one layer's block kernel over the passes):
+    "dlm_work_items",  # work items that kernel walks over the passes
 )
 _COL = {f: i for i, f in enumerate(FIELDS)}
 

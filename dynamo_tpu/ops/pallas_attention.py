@@ -1651,12 +1651,27 @@ def ragged_paged_attention(
     (mask_block - 1)`; a block pass is `q_len = mask_block` from the
     block's first position), padding rows (q_len=0) emit zeros.
 
-    Delegates to the flash prefill kernel (ops/pallas_prefill.py), whose
-    online-softmax grid already handles per-row ragged lengths; unlike
-    the prefill WRITE path, `q_pos0` here need not be page-aligned (no
-    page-granular scatter is involved). A dedicated kernel that skips
-    the padded query tiles of q_len=1 rows would land behind this
-    signature. Returns [B, T, H, Hd] in q.dtype."""
+    Which call takes which kernel is decided by what the call can see in
+    its input, both static: a BLOCK PASS (`mask_block > 1`, every row
+    `q.shape[1] == mask_block` queries, pools in the model's dtype) takes
+    the block kernel (ops/pallas_block.py: a flat work list over the pages
+    the rows hold, a block's queries one tile a KV head). Every other call
+    (`mask_block` 1: the mixed step, the verify step; a chunk of whole
+    blocks: such a model's prefill groups) delegates to the flash prefill
+    kernel (ops/pallas_prefill.py), whose online-softmax grid handles
+    per-row ragged lengths; unlike the prefill WRITE path, `q_pos0` here
+    need not be page-aligned (no page-granular scatter is involved). A
+    dedicated kernel that skips the padded query tiles of q_len=1 rows
+    would land behind this signature too. Returns [B, T, H, Hd] in
+    q.dtype."""
+    if mask_block > 1 and q.shape[1] == mask_block and k_scales is None:
+        from dynamo_tpu.ops.pallas_block import block_paged_attention
+
+        return block_paged_attention(
+            q, k_cache, v_cache, block_tables, q_pos0, q_lens,
+            page_size=page_size, mask_block=mask_block, interpret=interpret,
+        )
+
     from dynamo_tpu.ops.pallas_prefill import flash_prefill_attention
 
     return flash_prefill_attention(

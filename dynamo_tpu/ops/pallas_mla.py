@@ -292,7 +292,7 @@ def resident_vmem_bytes(b: int, h: int, width: int, rank: int, item: int,
     )
 
 
-def vmem_request(resident: int) -> int | None:
+def vmem_request(resident: int, what="latent decode kernel") -> int | None:
     """The scoped VMEM the call asks the compiler for: None while what is
     resident fits the compiler's default (16 MiB less headroom: 128 rows x
     16 heads lower as they always have, with no parameter;
@@ -302,7 +302,7 @@ def vmem_request(resident: int) -> int | None:
         return None
     if resident + _VMEM_HEADROOM > _VMEM_MOST:
         raise ValueError(
-            f"latent decode kernel: {resident / 2**20:.0f} MiB of queries, "
+            f"{what}: {resident / 2**20:.0f} MiB of queries, "
             f"outputs and ring resident, over the {_VMEM_MOST >> 20} MiB "
             "it may ask for"
         )

@@ -111,7 +111,8 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
     # (the host's clock by phase on dispatch and on sync / overlap rows),
     # then PR 39's two (an expert layer's pass by blocks of rows), then
     # PR 41's two (a model with state pools), then PR 47's four (a model
-    # generated by diffusion over blocks)
+    # generated by diffusion over blocks), then PR 48's one (its block
+    # kernel's work items)
     assert FIELDS[16:] == ("kv_pages_streamed", "kv_pages_held",
                            "moe_experts_hit", "moe_load_max",
                            "frames", "gc_s",
@@ -123,9 +124,10 @@ def test_kv_page_columns_round_trip(tmp_path, kind, pages):
                            "moe_row_blocks", "moe_pairs_held",
                            "state_slots_held", "state_rows_advanced",
                            "dlm_passes", "dlm_row_passes", "dlm_filled",
-                           "dlm_committed")
+                           "dlm_committed", "dlm_work_items")
     assert d["state_slots_held"] == d["state_rows_advanced"] == 0
     assert d["dlm_passes"] == d["dlm_row_passes"] == d["dlm_filled"] == 0
+    assert d["dlm_work_items"] == 0
     assert d["kv_frac_win"] == d["kv_win_pages_held"] == 0
     assert d["kv_win_items"] == 0
     assert d["moe_experts_hit"] == d["moe_load_max"] == 0

@@ -238,6 +238,131 @@ def test_block_1_is_the_program_it_was():
     assert str(blocked) != str(unsaid)
 
 
+# rows of a block pass, as (first position of the block, live): page 8 and
+# 2 pages a work item, so an item is 16 positions
+_BLOCK_ROWS = {
+    "mid-page": [(12, True), (4, True)],
+    "ends-on-a-page": [(4, True), (20, True)],       # positions 4-7, 20-23
+    "ends-on-an-item": [(12, True), (28, True)],     # positions 12-15, 28-31
+    "one-page": [(0, True), (4, True)],
+    "partly-live-last-item": [(40, True), (16, True), (68, True)],
+    "padding-rows": [(0, False), (24, True), (0, False), (36, True),
+                     (0, False)],
+    "every-row-padding": [(0, False), (0, False)],
+}
+
+
+@pytest.mark.parametrize("g,vd", [(1, 16), (8, 16), (2, 8)],
+                         ids=["G1", "G8", "narrow-values"])
+@pytest.mark.parametrize("case", sorted(_BLOCK_ROWS))
+def test_block_kernel_against_the_gather_oracle(case, g, vd):
+    """The block pass's own kernel (ops/pallas_block.py, interpret) against
+    the gather oracle under `mask_block` 4, keys and values already in the
+    pool: a block that starts mid-page, one whose 4 positions end exactly
+    on a page's and on a work item's last position, a row of one page, a
+    row of several items whose last is partly live, padding rows between
+    live ones (zeros out, no item) and nothing but padding; one query head
+    a KV head and eight; values narrower than keys."""
+    from dynamo_tpu.ops.pallas_block import block_paged_attention
+
+    ps, ppb, kh, hd, width = 8, 2, 2, 16, 10
+    rows = _BLOCK_ROWS[case]
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    slots = (1 + sum(-(-(p + 4) // ps) for p, live in rows if live)) * ps
+    k_pool, v_pool = _pool(ks[0], slots, kh * hd), _pool(ks[1], slots, kh * vd)
+    q = jax.random.normal(ks[2], (len(rows), 4, kh * g, hd), jnp.float32)
+    tables, nxt = np.zeros((len(rows), width), np.int32), 1
+    for i, (p, live) in enumerate(rows):
+        n = -(-(p + 4) // ps) if live else 0
+        tables[i, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pos0 = jnp.asarray([p for p, _ in rows], jnp.int32)
+    lens = jnp.asarray([4 if live else 0 for _, live in rows], jnp.int32)
+    got = block_paged_attention(
+        q, k_pool, v_pool, jnp.asarray(tables), pos0, lens, page_size=ps,
+        mask_block=4, pages_per_block=ppb, interpret=True)
+    smat = (tables[:, :, None] * ps + np.arange(ps)).reshape(len(rows), -1)
+    want = paged_attention(
+        q, k_pool, v_pool, jnp.asarray(smat),
+        pos0[:, None] + jnp.arange(4)[None], q_lens=lens, mask_block=4)
+    assert got.shape == want.shape == (len(rows), 4, kh * g, vd)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    dead = [i for i, (_, live) in enumerate(rows) if not live]
+    assert not np.asarray(got)[dead].any()
+
+
+def test_block_kernel_reads_a_block_that_starts_off_a_multiple_as_the_oracle():
+    """No caller makes one (a block starts at a multiple of its length),
+    but the mask is the formula, not that fact: from position 6 the first
+    two queries see keys through 7 and the last two through 11."""
+    from dynamo_tpu.ops.pallas_block import block_paged_attention
+
+    ps, kh, hd = 8, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    k_pool, v_pool = _pool(ks[0], 32, kh * hd), _pool(ks[1], 32, kh * hd)
+    q = jax.random.normal(ks[2], (1, 4, 4, hd), jnp.float32)
+    tables = jnp.asarray([[1, 2, 0]], jnp.int32)
+    pos0, lens = jnp.asarray([6], jnp.int32), jnp.asarray([4], jnp.int32)
+    got = block_paged_attention(
+        q, k_pool, v_pool, tables, pos0, lens, page_size=ps, mask_block=4,
+        interpret=True)
+    smat = (tables[:, :, None] * ps + jnp.arange(ps)).reshape(1, -1)
+    want = paged_attention(
+        q, k_pool, v_pool, smat, pos0[:, None] + jnp.arange(4)[None],
+        q_lens=lens, mask_block=4)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert np.abs(np.asarray(got[0, 0] - got[0, 3])).max() > 1e-3
+
+
+def _custom_calls(jaxpr) -> list[str]:
+    """Names of the jitted functions that hold a pallas_call, outermost
+    first: which kernel a call traced to."""
+    found = []
+
+    def walk(jp, path):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(path[-1] if path else "")
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                name = eqn.params.get("name", "")
+                walk(sub, path + [name] if name else path)
+
+    walk(jaxpr.jaxpr, [])
+    return found
+
+
+def test_which_ragged_call_takes_which_kernel():
+    """`ragged_paged_attention` routes by what it can see in the call: a
+    block pass (`q_len == mask_block > 1`) traces to the block kernel; a
+    chunk of whole blocks under the same mask and every call at
+    `mask_block` 1 (said or unsaid, at any query length, 4 among them)
+    trace to the flash prefill kernel, the program they always were."""
+    from dynamo_tpu.ops.pallas_attention import ragged_paged_attention
+
+    ps, kh, hd, h = 8, 2, 16, 4
+
+    def args(t):
+        return (jnp.ones((2, t, h, hd)), jnp.ones((32, kh * hd)),
+                jnp.ones((32, kh * hd)), jnp.ones((2, 2), jnp.int32),
+                jnp.zeros((2,), jnp.int32), jnp.full((2,), t, jnp.int32))
+
+    def ragged(t, **kw):
+        return jax.make_jaxpr(lambda *a: ragged_paged_attention(
+            *a, page_size=ps, interpret=True, **kw))(*args(t))
+
+    def flash(t, **kw):
+        return jax.make_jaxpr(lambda *a: flash_prefill_attention(
+            *a, page_size=ps, interpret=True, **kw))(*args(t))
+
+    assert _custom_calls(ragged(4, mask_block=4)) == ["block_paged_attention"]
+    for t, kw in ((8, {"mask_block": 4}), (4, {}), (4, {"mask_block": 1}),
+                  (8, {}), (1, {})):
+        got = ragged(t, **kw)
+        assert _custom_calls(got) == ["flash_prefill_attention"], (t, kw)
+        flash_kw = kw if kw.get("mask_block", 1) > 1 else {}
+        assert str(got) == str(flash(t, **flash_kw)), (t, kw)
+
+
 # ---------------------------------------------------------- the transfer
 
 @pytest.mark.parametrize("n_fill", [1, 2, 4])

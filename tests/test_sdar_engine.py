@@ -256,11 +256,17 @@ async def test_sampling_a_position_and_top_logprobs():
     await engine.close()
 
 
-async def test_what_the_block_step_counts():
+@pytest.mark.parametrize("backend", BACKENDS)
+async def test_what_the_block_step_counts(backend):
     """Two rows in step: `dlm_tokens_per_pass` is 4/3 while both run whole
     blocks; the digests carry a `dlm` row a dispatch with its passes and a
-    sync row with what landed; the expert load rides the sync rows."""
-    engine = make_engine(model=CFG, attn_backend="gather", decode_steps=3)
+    sync row with what landed; the expert load rides the sync rows. On the
+    pallas backend a `dlm` row also books what ONE layer's block kernel
+    reads over the passes, by the kernel's own rule (the keys through the
+    end of each row's open block): the pages it copies in, the pages
+    those rows hold (equal: it reads what a row holds) and the work items
+    it walks; the gather backend books none."""
+    engine = make_engine(model=CFG, attn_backend=backend, decode_steps=3)
     await asyncio.gather(*(
         _serve(engine, _prompt(8, seed=s), n=24) for s in (1, 2)))
     m = engine.metrics()
@@ -276,6 +282,26 @@ async def test_what_the_block_step_counts():
     assert sum(r["dlm_committed"] for r in landed) == 12
     assert any(r["moe_experts_hit"] for r in landed)
     assert not [r for r in rows if r["kind"] == "decode"]
+    ps = engine.page_size
+    if backend == "pallas":
+        # a prompt of 8 is two whole blocks: the first dispatch's three
+        # passes (fill 2, fill 2, commit) all read 8 + 4 keys a row, in
+        # work items of 4 pages
+        row_passes = dlm[0]["rows"] * 3
+        assert dlm[0]["kv_pages_held"] == row_passes * -(-12 // ps)
+        assert dlm[0]["dlm_work_items"] == row_passes * -(-12 // (4 * ps))
+        for r in dlm:
+            assert r["kv_pages_streamed"] == r["kv_pages_held"] > 0
+            # a row a pass walks an item at least, and an item a page
+            assert (r["rows"] * 3 <= r["dlm_work_items"]
+                    <= r["kv_pages_streamed"] <= 4 * r["dlm_work_items"])
+        # the open block moves by 4 a commit: later dispatches read more
+        assert (dlm[-1]["kv_pages_held"] / dlm[-1]["rows"]
+                > dlm[0]["kv_pages_held"] / dlm[0]["rows"] or ps >= 36)
+    else:
+        assert all(r["kv_pages_streamed"] == r["kv_pages_held"]
+                   == r["dlm_work_items"] == 0 for r in dlm)
+    assert all(r["dlm_work_items"] == 0 for r in rows if r["kind"] != "dlm")
     await engine.close()
 
 

@@ -195,12 +195,14 @@ async def test_digest_columns():
     # its worker's lock, uploads and launch, a landing the loop's tick
     # (then PR 39's two, an expert layer's pass by blocks of rows, and
     # PR 41's two, a model with state pools: zero on a dense model's rows)
-    # (and PR 47's four, a model generated by diffusion over blocks)
-    assert flightmod.FIELDS[-15:-8] == WORKER_COLUMNS + TICK_COLUMNS
-    assert flightmod.FIELDS[-8:] == (
+    # (and PR 47's four, a model generated by diffusion over blocks, and
+    # PR 48's one, its block kernel's work items)
+    assert flightmod.FIELDS[-16:-9] == WORKER_COLUMNS + TICK_COLUMNS
+    assert flightmod.FIELDS[-9:] == (
         "moe_row_blocks", "moe_pairs_held",
         "state_slots_held", "state_rows_advanced",
-        "dlm_passes", "dlm_row_passes", "dlm_filled", "dlm_committed")
+        "dlm_passes", "dlm_row_passes", "dlm_filled", "dlm_committed",
+        "dlm_work_items")
     assert all(r["state_slots_held"] == r["state_rows_advanced"] == 0
                for r in rows)
     for r in by["decode"] + by["prefill"]:

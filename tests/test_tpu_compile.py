@@ -908,3 +908,102 @@ def test_dlm_block_scan_compiles_at_the_benchmark_shape(
     # what the step holds beside weights and pools: the logits and the
     # transfer's temporaries must fit what `hbm_utilization` 0.85 leaves
     assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
+def _kernel_events_under(text: str, scope: str) -> list[str]:
+    """Instructions of the optimized HLO (fused computations' insides
+    left out: a fusion is one event) that the benchmark's reader of the
+    block kernel would count under `scope`: the innermost model scope on
+    their `op_name` is `scope` (`trace_host.scope_of`) and their name is
+    not one XLA gives its own operations (`shapes_dlm.XLA_OP`)."""
+    import os
+    import sys
+
+    lib = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "lib")
+    sys.path.insert(0, lib)
+    try:
+        import shapes_dlm
+        import trace_host
+    finally:
+        sys.path.remove(lib)
+    found = []
+    for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        if block.startswith(("%fused_computation", "%region_")):
+            continue
+        for ln in block.splitlines():
+            m = re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = .*op_name=\"([^\"]*)\"",
+                         ln)
+            if (m and trace_host.scope_of(m.group(2)) == scope
+                    and not shapes_dlm.XLA_OP.match(m.group(1))):
+                found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("width", [64, 128, 256], ids=lambda w: f"rows{w}")
+def test_block_attention_kernel_compiles_at_the_sdar_widths(
+        one_chip, no_persistent_cache, width):
+    """The block pass's own kernel alone (ops/pallas_block.py) at the
+    decode widths the `sdar-30b-a3b-l6.decode-wide` cell compiles, 4 KV
+    heads x 8 x 128 in bf16, pages of 128, 32 table columns: Mosaic takes
+    its slices, and it is granted the scoped VMEM it asks for by the
+    latent kernel's rule (queries and outputs resident, 32 KiB a row each,
+    beside two 2 MiB rings: nothing asked at 64 and 128 rows, where 8 and
+    12 MiB fit the compiler's default, 20 + 4 MiB at 256). Under the caller's scope the benchmark's reader of
+    `dlm_block_attn_roofline` finds ONE event to count, the kernel's: the
+    work list's operations (a `cumsum`, a `searchsorted`, which XLA names
+    in ways that reader does not know) lie under the inner scope
+    `attn.block_work`."""
+    from dynamo_tpu.ops.pallas_block import block_paged_attention
+
+    page, num_pages = 128, 2048
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(jax.named_scope("attn.block")(functools.partial(
+        block_paged_attention, page_size=page, mask_block=4,
+    ))).lower(
+        s((width, 4, 32, 128), jnp.bfloat16),
+        s((num_pages * page, 512), jnp.bfloat16),
+        s((num_pages * page, 512), jnp.bfloat16),
+        _i32((width, 32), one_chip), _i32((width,), one_chip),
+        _i32((width,), one_chip),
+    ).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    call = next(ln for ln in text.splitlines() if "tpu_custom_call" in ln)
+    resident = width * 4 * 32 * 128 * 2 * 2 + 2 * (4 * 4 * 128 * 512 * 2)
+    asked = ('[]' if resident <= 12 << 20 else
+             f'[{{"memory_space":"1","offset":"0","size":"{resident + (4 << 20)}"}}]')
+    assert f'"scoped_memory_configs":{asked}' in call, call[-600:]
+    events = _kernel_events_under(text, "attn.block")
+    assert len(events) == 1 and events[0].startswith(
+        "%block_paged_attention"), events
+    assert _kernel_events_under(text, "attn.block_work")
+
+
+def test_dlm_block_scan_holds_one_kernel_event_a_layer_under_its_scope(
+        one_chip, no_persistent_cache):
+    """Two layers of SDAR-30B-A3B through the model's own forward as a
+    block pass hands it over (`AttnSpec.gather(... q_pos0, lengths,
+    mask_block)`, `q_len` 4 a row, 64 rows): under `attn.block` the
+    compiled program holds the block kernel's custom call once a layer
+    and nothing else the reader of passes would count."""
+    cfg = PRESETS["sdar-30b-a3b"].with_(num_layers=2)
+    page, num_pages, width, n = 128, 512, 64, 4
+    params, kv = _shapes(cfg, kv_quant=None, weights_int8=False,
+                         page=page, num_pages=num_pages)
+
+    def step(params, kv, tokens, positions, wslots, tables, pos0, lens):
+        attn = llama.AttnSpec.gather(
+            None, page_size=page, block_tables=tables, q_pos0=pos0,
+            lengths=lens, mask_block=n)
+        return llama.forward(params, cfg, tokens, positions, kv, wslots, attn)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        _on(params, one_chip), _on(kv, one_chip),
+        _i32((width, n), one_chip), _i32((width, n), one_chip),
+        _i32((width * n,), one_chip), _i32((width, 32), one_chip),
+        _i32((width,), one_chip), _i32((width,), one_chip),
+    ).compile()
+    events = _kernel_events_under(compiled.as_text(), "attn.block")
+    assert len(events) == 2 and all(
+        e.startswith("%block_paged_attention") for e in events), events
